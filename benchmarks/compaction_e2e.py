@@ -173,6 +173,9 @@ async def main() -> None:
     # remote, so every measurement needs a fresh copy.  Each measurement
     # runs in its OWN child process so its peak RSS is its own — the TPU
     # pipelined ingest's bounded-memory claim is only checkable that way.
+    # One process per chip holds: this parent never imports JAX, and the
+    # children run one after another (subprocess.run blocks), so exactly
+    # one process touches the chip at a time.
     # The TPU path runs twice — the first pays per-process jit tracing
     # (compiles come from the persistent cache) and warms it; the second
     # is the steady state a long-lived compactor sees.  Both are reported.

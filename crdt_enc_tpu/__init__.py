@@ -33,7 +33,7 @@ _LAZY = {
 __all__ = ["__version__", "enable_compilation_cache", *sorted(_LAZY)]
 
 
-def enable_compilation_cache(path: str | None = None) -> str:
+def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache for the fold kernels.
 
     First compilation of a fold shape costs tens of seconds on TPU; a
@@ -41,34 +41,30 @@ def enable_compilation_cache(path: str | None = None) -> str:
     the cache enabled, recompiles of previously-seen shapes load from disk
     in milliseconds — call this once at process start (before the first
     fold) in any deployment that runs compactions as short-lived jobs.
-    Returns the cache directory used.
+
+    The directory is placed from OUTSIDE the program: when
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own setting stands and no
+    directory is set in code.  Otherwise the cache lives at the fixed
+    ``.jax_cache`` beside the package (the checkout root) — the path is
+    part of the cache key, so it never varies by process, time or user.
+    Returns the cache directory in use.
     """
     import os
 
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    if path is None:
-        path = os.environ.get(
-            "CRDT_ENC_TPU_COMPILE_CACHE",
-            os.path.join(
-                os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-                "crdt_enc_tpu", "jax_cache",
-            ),
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache",
         )
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        jax.config.update("jax_compilation_cache_dir", path)
     # jax initializes the cache module lazily at the FIRST compile and
     # then latches: enabling a dir after any compile has happened would
-    # silently do nothing.  Reset so the new dir takes effect now.
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc,
-        )
-
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - cache module reshuffles
-        pass
+    # silently do nothing.  Reset so the dir takes effect now.
+    compilation_cache.reset_cache()
     return path
 
 

@@ -42,6 +42,7 @@ SCAN_GLOBS: tuple[tuple[str, str], ...] = (
     ("benchmarks", "**/*.py"),
     ("examples", "**/*.py"),
     (".", "bench.py"),
+    (".", "chip_smoke.py"),
 )
 
 _PRAGMA_RE = re.compile(r"#\s*lint:\s*disable=([A-Z]{3}\d{3}(?:\s*,\s*[A-Z]{3}\d{3})*)")

@@ -4,9 +4,12 @@ from __future__ import annotations
 
 import ctypes
 import fcntl
+import logging
 import os
 import subprocess
 import threading
+
+logger = logging.getLogger("crdt_enc_tpu.native")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_HERE, "build", "libcrdtnative.so")
@@ -50,7 +53,8 @@ def _build_and_load(target: str, so_path: str, dll_cls, bind_fn):
 
 
 def warm() -> None:
-    """Build/load both native libraries now, swallowing failures.
+    """Build/load both native libraries now; a failure is logged, loudly,
+    and does not raise.
 
     The loaders memoize success *and* failure, so after one ``warm()``
     every later ``load()``/``load_state()`` call is a cached dict hit —
@@ -58,13 +62,19 @@ def warm() -> None:
     via ``asyncio.to_thread`` at open (see ``Core.open``) so the
     first-use build never runs on the loop; callers that need the
     library still probe the loaders themselves and fall back to the
-    Python paths when the build failed.
+    Python paths when the build failed — correct, but far slower, so the
+    warning names what is missing.  A measurement must not run that way:
+    ``chip_smoke.py`` rebuilds both libraries from source and fails
+    unless both load.
     """
     for loader in (load, load_state):
         try:
             loader()
-        except Exception:
-            pass  # cached by the loader; pure-Python fallbacks take over
+        except Exception as e:  # cached by the loader
+            logger.warning(
+                "native library unavailable (%s: %s); the pure-Python "
+                "front end takes over, much slower", loader.__name__, e,
+            )
 
 
 def load() -> ctypes.CDLL:
@@ -161,6 +171,9 @@ def _bind_state(lib) -> None:
 
 
 def _bind(lib) -> None:
+    # SIMD lanes the AEAD batch paths dispatched to at load (4, 8 or 16)
+    lib.crdt_simd_lanes.argtypes = []
+    lib.crdt_simd_lanes.restype = ctypes.c_int
     lib.hchacha20.argtypes = [u8p, u8p, u8p]
     lib.hchacha20.restype = None
     for name in ("chacha20poly1305_encrypt", "xchacha20poly1305_encrypt"):

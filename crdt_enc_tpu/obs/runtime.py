@@ -38,7 +38,7 @@ _recompiles_explicit = False  # an operator choice must stick
 # The one duration event XLA emits exactly once per backend compilation
 # (jaxpr tracing and MLIR lowering emit siblings; counting those would
 # double-book a single cache miss).  NOTE: with a persistent compilation
-# cache configured (CRDT_JIT_CACHE / enable_compilation_cache), jax
+# cache configured (crdt_enc_tpu.enable_compilation_cache), jax
 # emits this event around the compile-or-retrieve step, so a disk-cache
 # RETRIEVAL also counts as a "compile" here — the cache_hits/cache_misses
 # events below split the two: ``jax_cache_misses`` is the count of real

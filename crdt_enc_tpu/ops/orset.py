@@ -405,13 +405,16 @@ def _merge_halves(c1, a1, r1, c2, a2, r2):
 
 
 def orset_merge_many(
-    clocks: jax.Array, adds: jax.Array, rms: jax.Array, impl: str | None = None
+    clocks: jax.Array, adds: jax.Array, rms: jax.Array,
+    impl: str | None = None, interpret: bool = False,
 ):
     """Merge a stacked batch of S states ``(S,R) / (S,E,R)`` into one.
 
     ``impl``: ``"tree"`` = ⌈log2 S⌉ rounds of the pairwise merge (XLA);
     ``"pallas"`` = single-HBM-pass streaming kernel (ops/pallas_merge.py);
     None = pallas on TPU for batches worth streaming, tree elsewhere.
+    ``interpret`` runs the Pallas kernel in the interpreter — an explicit
+    test-only choice, never derived from the backend.
     Merge associativity (tests/test_crdt_laws.py) makes any order legal.
     """
     # host-resident stacks upload here; device inputs re-wrap for free
@@ -425,9 +428,8 @@ def orset_merge_many(
     if impl == "pallas":
         from .pallas_merge import orset_merge_many_pallas
 
-        return orset_merge_many_pallas(
-            c, a, r, interpret=jax.default_backend() != "tpu"
-        )
+        trace.add("pallas_routed", 1)
+        return orset_merge_many_pallas(c, a, r, interpret=interpret)
     if impl != "tree":
         raise ValueError(f"unknown merge impl {impl!r}; use 'tree' or 'pallas'")
     while c.shape[0] > 1:

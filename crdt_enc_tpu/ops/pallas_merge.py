@@ -15,8 +15,8 @@ Inputs are the stacked planes ``clocks (S, R) int32``, ``adds/rms
 (cummax over S) host-of-kernel — it is S×R, negligible — because step s
 of the fold needs ``clock(acc after s-1)`` for the survival rule.
 
-On non-TPU backends the kernel runs in interpreter mode (slow, for
-tests); ``orset_merge_many`` only routes here on TPU by default.
+Tests on host backends pass ``interpret=True`` themselves (slow);
+``orset_merge_many`` only routes here on TPU by default, compiled.
 """
 
 from __future__ import annotations

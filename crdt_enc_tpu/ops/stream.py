@@ -319,6 +319,7 @@ def orset_fold_stream(
     tile_cap: int | None = None,
     h2d_lookahead: bool = True,
     pool: ChunkPool | None = None,
+    interpret: bool = False,
 ):
     """Fold an iterable of fixed-shape op chunks into the state planes.
 
@@ -335,7 +336,9 @@ def orset_fold_stream(
     ``impl="pallas"`` runs each chunk through the MXU fold
     (ops/pallas_fold.py); pass ``tile_cap`` computed over the WHOLE
     member column (``fold_cap``) so every chunk compiles once — a
-    per-chunk cap is bounded by the global one.
+    per-chunk cap is bounded by the global one.  ``interpret`` runs that
+    kernel in the Pallas interpreter — an explicit test-only choice,
+    never derived from the backend.
     """
     clock0 = np.asarray(clock0, np.int32)
     add0 = np.asarray(add0, np.int32)
@@ -353,8 +356,6 @@ def orset_fold_stream(
                 "impl='pallas' requires tile_cap (fold_cap over the whole "
                 "member column)"
             )
-        interpret = jax.default_backend() != "tpu"
-
         def fold_step(planes, chunk):
             return _fold_donated_pallas(
                 *planes, *chunk,

@@ -54,29 +54,20 @@ logger = logging.getLogger("crdt_enc_tpu.distributed")
 _INITIALIZED = False
 
 
-def _backend_untouched() -> bool | None:
-    """Whether the XLA backend is still uninitialized: True/False when the
-    probe works, None when it cannot tell.  Probes private jax internals —
-    no public API exposes this without initializing the backend as a side
-    effect — so a jax release that moves them degrades to None rather than
-    crashing; callers decide how to act on uncertainty."""
-    bridge = getattr(getattr(jax, "_src", None), "xla_bridge", None)
-    backends = getattr(bridge, "_backends", None)
-    if backends is None:
-        return None
-    return not backends
+def _backend_untouched() -> bool:
+    """Whether the XLA backend is still uninitialized.  No public API
+    answers this without initializing the backend as a side effect, so
+    this reads jax 0.9's ``xla_bridge.backends_are_initialized``."""
+    from jax._src import xla_bridge
+
+    return not xla_bridge.backends_are_initialized()
 
 
 def _already_initialized() -> bool:
     """Probe the distributed client WITHOUT touching the XLA backend
     (``jax.process_count()`` would initialize it, after which
     ``jax.distributed.initialize`` refuses to run)."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    from jax._src import distributed as _dist  # fallback for older jax
-
-    return getattr(_dist.global_state, "client", None) is not None
+    return bool(jax.distributed.is_initialized())
 
 
 def initialize(
@@ -122,10 +113,10 @@ def initialize(
         )
         _INITIALIZED = True
         return True
-    if _backend_untouched() is False:
-        return False  # backend provably up — too late to auto-detect; no-op
-    # backend untouched (or unknowable on this jax version): attempt
-    # auto-detection — the call itself degrades gracefully either way
+    if not _backend_untouched():
+        return False  # backend already up — too late to auto-detect; no-op
+    # backend untouched: attempt auto-detection — the call itself
+    # degrades gracefully when there is no pod metadata
     try:
         jax.distributed.initialize(**kwargs)
     except Exception as e:  # no pod metadata → plain single-process run

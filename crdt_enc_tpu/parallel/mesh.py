@@ -29,21 +29,6 @@ from ..ops.columnar import KIND_ADD, KIND_RM
 from ..ops.counters import sum_wide
 from ..utils import trace
 
-# jax < 0.5 ships shard_map under experimental only, with the replication
-# check named check_rep instead of check_vma; this module-local shim (the
-# only shard_map entry point in the repo) translates — without patching
-# the jax namespace, which other libraries feature-detect.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-
-    def _shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _experimental_sm(f, **kw)
-
-
 def parse_mesh_spec(spec: str) -> tuple[int, int]:
     """``"dp=N[,mp=M]"`` → ``(dp, mp)``.  The ONE parser behind every
     ``--mesh`` CLI flag (bench.py, tools/daemon) — raises ``ValueError``
@@ -225,7 +210,7 @@ def orset_fold_sharded(
     member_lo = np.arange(mp, dtype=np.int32) * E_local
 
     # op rows sharded over dp; plane member-axis sharded over mp
-    fold = _shard_map(
+    fold = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -248,7 +233,7 @@ def orset_merge_sharded(mesh: Mesh, clock_a, add_a, rm_a, clock_b, add_b, rm_b):
     """Pairwise state merge with planes sharded over mp — pure elementwise,
     so the spec is trivial; exists to keep compaction fully SPMD."""
 
-    merge = _shard_map(
+    merge = jax.shard_map(
         K.orset_merge,
         mesh=mesh,
         in_specs=(P(), P("mp", None), P("mp", None), P(), P("mp", None), P("mp", None)),
@@ -463,7 +448,7 @@ def orset_fold_tenants_sharded(
         return jax.vmap(one)(c0, a0, r0, k, m, ac, ct)
 
     member_lo = np.arange(mp, dtype=np.int32) * E_local
-    fold = _shard_map(
+    fold = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -506,7 +491,7 @@ def gcounter_fold_tenants_sharded(
 
         return jax.vmap(one)(c0, a, ct)
 
-    fold = _shard_map(
+    fold = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("dp", None), P("dp", None), P("dp", None)),
@@ -546,7 +531,7 @@ def tenant_plane_diff_sharded(
         code, count = jax.vmap(K.orset_plane_diff)(cb, ab, rb, cn, an, rn)
         return code, jax.lax.psum(count, "mp")
 
-    diff = _shard_map(
+    diff = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -638,7 +623,7 @@ def pncounter_fold_sharded(mesh: Mesh, p0, n0, sign, actor, counter):
         n = jnp.maximum(n0, jax.lax.pmax(n, "dp"))
         return p, n, sum_wide(p) - sum_wide(n)
 
-    fold = _shard_map(
+    fold = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(), P("dp"), P("dp"), P("dp")),
@@ -685,7 +670,7 @@ def lww_fold_sharded(mesh: Mesh, key, ts_hi, ts_lo, actor, value, *, num_keys: i
             acc = K.lww_table_merge(tuple(x[i] for x in g), acc)
         return acc
 
-    fold = _shard_map(
+    fold = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("dp"),) * 5,
@@ -732,7 +717,7 @@ def crdtmap_scatter_sharded(
         )
 
     n_rows = 3 + 4 + 4 + 5
-    fold = _shard_map(
+    fold = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P()) + (P("dp"),) * n_rows,
@@ -767,7 +752,7 @@ def mvreg_keep_sharded(mesh: Mesh, clocks, valid):
         dominated = jnp.any((ge & gt) & full_v[:, None], axis=0)
         return v_slice & ~dominated
 
-    keep = _shard_map(
+    keep = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("dp", None), P("dp")),

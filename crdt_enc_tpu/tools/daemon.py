@@ -39,7 +39,6 @@ from __future__ import annotations
 import argparse
 import asyncio
 import logging
-import os
 import signal
 import sys
 
@@ -291,9 +290,6 @@ def _cmd_selftest(args) -> int:
 
 
 def main(argv=None) -> int:
-    # the daemon's fleets are many small tenants: protocol-bound work
-    # where the CPU backend is the right default even on a device box
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     logging.basicConfig(level=logging.INFO, format="%(message)s")
     ap = argparse.ArgumentParser(
         prog="python -m crdt_enc_tpu.tools.daemon", description=__doc__,

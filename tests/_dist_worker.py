@@ -38,7 +38,6 @@ def main() -> int:
     port = sys.argv[2]
     mode = sys.argv[3] if len(sys.argv) > 3 else "fold"
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ.pop("PJRT_LIBRARY_PATH", None)
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

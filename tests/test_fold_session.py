@@ -120,7 +120,7 @@ def test_device_stream_pallas_route_matches_host():
     import crdt_enc_tpu.parallel.session as S
 
     host, ops = _history(400, 23, seed=6)
-    S.FORCE_PALLAS_STREAM = True
+    S.FORCE_PALLAS_STREAM = "interpret"
     try:
         folded = _run_session(ops, chunk_files=3, force_mode="device_stream")
     finally:

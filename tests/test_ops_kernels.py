@@ -461,7 +461,7 @@ def test_orset_fold_stream_matches_whole_batch():
         K.iter_orset_chunks(cols.kind, cols.member, cols.actor, cols.counter,
                             chunk_rows=16, num_replicas=R),
         num_members=E, num_replicas=R, impl="pallas",
-        tile_cap=fold_cap(cols.member, E),
+        tile_cap=fold_cap(cols.member, E), interpret=True,
     )
     streamed_p = K.orset_planes_to_state(
         np.asarray(clock), np.asarray(add), np.asarray(rm), members, replicas

@@ -3,7 +3,7 @@ result planes on device between rounds, so repeated ``read_remote`` /
 ``compact`` rounds in one process stop re-issuing the full-state
 ``device_put`` — provable via the ``h2d_bytes`` counter — while every
 byte of every resulting state stays identical to the host reference.
-Plus the CRDT_JIT_CACHE persistent-compilation-cache wiring.
+Plus the persistent-compilation-cache contract (enable_compilation_cache).
 """
 
 import asyncio
@@ -211,23 +211,25 @@ def test_device_stream_seeds_planes_on_device(monkeypatch):
 
 
 def test_jit_cache_second_instance_recompiles_nothing(tmp_path, monkeypatch):
-    """CRDT_JIT_CACHE wires jax's persistent compilation cache: after a
-    simulated process restart (jax.clear_caches), a second accelerator
-    instance serves every compile request it can from the disk cache —
-    zero new jax_cache_misses."""
+    """``enable_compilation_cache`` wires jax's persistent compilation
+    cache: after a simulated process restart (jax.clear_caches), a
+    second accelerator instance serves every compile request it can from
+    the disk cache — zero new jax_cache_misses.  The directory comes
+    from ``JAX_COMPILATION_CACHE_DIR`` (jax reads it into its config at
+    import, simulated here), and the code sets none."""
     import jax
 
+    import crdt_enc_tpu
     from crdt_enc_tpu.obs import runtime
 
     cache_dir = str(tmp_path / "jit-cache")
-    monkeypatch.setenv("CRDT_JIT_CACHE", cache_dir)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     runtime.track_recompiles()
 
     def fold_once():
-        accel = TpuAccelerator(min_device_batch=1)  # wires the cache dir
-        # CPU compiles are sub-second: persist them all for the test
-        # (the constructor's enable_compilation_cache resets the floor)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        assert crdt_enc_tpu.enable_compilation_cache() == cache_dir
+        accel = TpuAccelerator(min_device_batch=1)
         s, clock = ORSet(), {}
         rng = np.random.default_rng(42)  # identical batch both runs
         ops = []
@@ -240,6 +242,8 @@ def test_jit_cache_second_instance_recompiles_nothing(tmp_path, monkeypatch):
 
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
+        # CPU compiles are sub-second: persist them all for the test
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         # earlier tests may have compiled these very shapes: drop the
         # in-memory jit cache so run 1 really compiles (into the fresh
         # cache dir, so they are misses)
@@ -268,4 +272,7 @@ def test_jit_cache_second_instance_recompiles_nothing(tmp_path, monkeypatch):
             "jax_persistent_cache_min_compile_time_secs", prev_min
         )
         jax.config.update("jax_compilation_cache_dir", None)
+        from jax.experimental.compilation_cache import compilation_cache
+
+        compilation_cache.reset_cache()
         trace.reset()
