@@ -1,0 +1,287 @@
+"""The benchmark's own tests: the manifest is valid, the harness finds every
+configuration, mix, cell and per-layer metric by name, each driver runs end to
+end (here on the CPU, at a tiny size, through the Python API), and the oracle
+says ``correct: false`` when it should.
+
+Nothing here is a measurement: the CPU backend takes the product's XLA fold
+(the product never interprets a Pallas kernel on its own), and the sizes are
+toys.
+"""
+
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from cellbench import gen, reference, run
+
+ROOT = run.ROOT
+MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+LAYER = [m["name"] for m in MANIFEST["per_layer"]]
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+# toy sizes laid over the real files: an argument the command never passes
+TINY = {
+    "folder": {"devices": 8, "members": 32, "initial_files_per_device": 3},
+    "fleet": {"tenants": 6, "members": 16, "initial_files_per_device": 8},
+}
+TINY_WRITERS = {"backlog": 8, "trickle": 2, "busy": 6, "quiet": 2}
+
+
+def tiny(cell: str) -> dict:
+    entry = next(w for w in MANIFEST["workloads"] if w["name"] == cell)
+    driver = "folder" if "folder" in entry["config"] else "fleet"
+    key = "active_devices" if driver == "folder" else "active_tenants"
+    return {"config": TINY[driver],
+            "traffic": {key: TINY_WRITERS[entry["traffic"]], "max_ops_per_s": 2500}}
+
+
+def last_line(capsys) -> dict:
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+# ------------------------------------------------------------ the manifest
+
+
+def test_manifest_has_exactly_the_contract_keys():
+    assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
+                             "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    assert 1 <= MANIFEST["run_seconds"] <= 51
+    assert MANIFEST["paths"] == ["cellbench", "tests/cellbench"]
+    assert all(not w.startswith("/") and ".." not in w for w in MANIFEST["command"])
+
+
+def test_names_and_units_use_the_allowed_characters():
+    names = [x["name"] for k in ("configs", "workloads", "end_to_end", "per_layer")
+             for x in MANIFEST[k]]
+    names += [w["traffic"] for w in MANIFEST["workloads"]]
+    names += [k for c in MANIFEST["configs"] for k in c["reduced"]]
+    assert all(NAME.match(n) for n in names), [n for n in names if not NAME.match(n)]
+    for kind in ("configs", "workloads", "end_to_end", "per_layer"):
+        listed = [x["name"] for x in MANIFEST[kind]]
+        assert len(listed) == len(set(listed))
+    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+        assert UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+    for m in MANIFEST["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+        assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_file_agrees_with_the_manifest(cell):
+    entry = next(w for w in MANIFEST["workloads"] if w["name"] == cell)
+    assert set(entry) == {"name", "config", "traffic", "chips", "why"}
+    assert entry["chips"] == 1, "no user depends on a cross-chip path today"
+    assert len(entry["why"]) <= 200
+    loaded = run.load_cell(ROOT, cell)
+    assert {k: loaded["cell"][k] for k in entry} == entry
+    config = next(c for c in MANIFEST["configs"] if c["name"] == entry["config"])
+    assert set(config) == {"name", "source", "file", "reduced", "why"}
+    assert config["file"].startswith("cellbench/")
+    assert sorted(loaded["config"]["reduced"]) == config["reduced"]
+    assert loaded["config"]["guarantees"], "a deployment states its guarantees"
+    # every cell reports set-up, another end-to-end metric and a layer metric
+    reported = [m["name"] for m in loaded["end_to_end"]]
+    assert "setup_s" in reported and len(reported) >= 2
+    assert loaded["per_layer"]
+
+
+@pytest.mark.parametrize("metric", LAYER)
+def test_layer_metric_file_agrees_and_moves_a_metric_its_cells_report(metric):
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
+    assert set(entry) <= {"name", "unit", "better", "source", "layer", "moves",
+                          "workloads"}
+    assert entry["source"] in ("device_trace", "program_span", "program_counter",
+                               "host_clock")
+    spec = run.load_json(ROOT, "cellbench", "layer_metrics", metric + ".json")
+    for key in ("layer", "unit", "better", "source", "moves"):
+        assert spec[key] == entry[key], key
+    assert os.path.exists(
+        os.path.join(ROOT, "cellbench", "readers", spec["reader"] + ".py"))
+    moved = next(m for m in MANIFEST["end_to_end"] if m["name"] == entry["moves"])
+    for cell in entry["workloads"]:
+        assert cell in CELLS
+        assert cell in moved.get("workloads", CELLS), (metric, cell)
+        config = run.load_cell(ROOT, cell)["config"]
+        assert config["driver"] == spec["driver"]
+    if "_roofline" in metric:
+        assert entry["unit"] == "%"
+
+
+# --------------------------------------------------- the drivers, end to end
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_runs_end_to_end_and_prints_the_contract_line(cell, capsys):
+    assert run.run_cell(cell, 2**31 + 11, 0.5, False, require_tpu=False,
+                        shrink=tiny(cell)) == 0
+    line = last_line(capsys)
+    assert set(line) == RESULT_KEYS
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
+    wanted = {m["name"] for m in run.load_cell(ROOT, cell)["end_to_end"]}
+    assert set(line["metrics"]) == wanted
+    assert all(v["value"] > 0 for v in line["metrics"].values())
+    assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+
+
+@pytest.mark.parametrize("cell", CELLS[:2])
+def test_traced_run_reports_layer_metrics_by_name(cell, capsys):
+    assert run.run_cell(cell, 12, 0.5, True, require_tpu=False,
+                        shrink=tiny(cell)) == 0
+    line = last_line(capsys)
+    assert set(line) == RESULT_KEYS | {"breakdown"}
+    assert line["correct"] is True
+    listed = {m["name"] for m in run.load_cell(ROOT, cell)["per_layer"]}
+    # what reads the device trace finds nothing on the CPU and is left out
+    assert set(line["metrics"]) <= listed and len(line["metrics"]) >= 3
+    assert {"busy_s", "window_s"} <= set(line["device"])
+    assert line["device"]["window_s"] > 0
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+@pytest.mark.parametrize("cell", [c for c in CELLS if c.endswith((".backlog", ".busy"))])
+def test_control_a_withheld_op_file_is_not_correct(cell, capsys):
+    """The control: the guarantee 'an op file that was published is folded'
+    broken for one file of the first timed batch."""
+    assert run.run_cell(cell, 13, 0.5, False, require_tpu=False,
+                        shrink=tiny(cell), fault="withhold_file") == 0
+    out = capsys.readouterr().out
+    assert json.loads(out.strip().splitlines()[-1])["correct"] is False
+    assert "vs_reference: value" in out and "FAILED" in out
+
+
+@pytest.mark.parametrize("cell", [c for c in CELLS if c.endswith((".backlog", ".busy"))])
+def test_narrowed_counters_in_the_timed_path_are_not_correct(cell, capsys, monkeypatch):
+    """The timed path broken underneath: the planes the fold hands back lose
+    every counter bit above the low 7-bit limb (what skipping the kernel's
+    high-limb pass on large counters would do).  The rest of the run is
+    driven as always and must say ``correct: false``."""
+    import crdt_enc_tpu.ops as K
+
+    whole = K.orset_planes_to_state
+
+    def low_limb_only(clock, add, rm, members, replicas):
+        return whole(clock & 0x7F, add & 0x7F, rm & 0x7F, members, replicas)
+
+    monkeypatch.setattr(K, "orset_planes_to_state", low_limb_only)
+    assert run.run_cell(cell, 14, 0.5, False, require_tpu=False,
+                        shrink=tiny(cell)) == 0
+    assert last_line(capsys)["correct"] is False
+
+
+def test_command_exits_non_zero_without_a_tpu():
+    done = subprocess.run(
+        [sys.executable, "-m", "cellbench.run", "--workload", CELLS[0],
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert done.returncode != 0
+    assert done.stdout == ""
+
+
+# ------------------------------------------------------------ driven by data
+
+
+def test_new_config_mix_cell_and_metric_are_found_by_name(tmp_path, capsys):
+    """A later PR adds a deployment, a mix, a cell and a per-layer metric as
+    files plus one manifest entry each, and edits no file that is there."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    for sub in ("configs", "traffic", "cells", "layer_metrics"):
+        shutil.copytree(os.path.join(ROOT, "cellbench", sub),
+                        tmp_path / "cellbench" / sub)
+    shutil.copy(os.path.join(ROOT, "cellbench", "peaks.json"), tmp_path / "cellbench")
+    bench = tmp_path / "cellbench"
+    config = run.load_json(ROOT, "cellbench", "configs", "orset_folder_1k.json")
+    config.update(name="orset_folder_8", devices=8, members=32,
+                  initial_files_per_device=3)
+    (bench / "configs" / "orset_folder_8.json").write_text(json.dumps(config))
+    (bench / "traffic" / "drip.json").write_text(json.dumps({
+        "name": "drip", "active_tenants": 1, "active_devices": 3,
+        "files_per_device": 1, "warmup_rounds": 1, "max_ops_per_s": 2500,
+    }))
+    cell = {"name": "orset_folder_8.drip", "config": "orset_folder_8",
+            "traffic": "drip", "chips": 1, "why": "a cell a later PR adds"}
+    (bench / "cells" / "orset_folder_8.drip.json").write_text(json.dumps(cell))
+    metric = {"name": "gc_ms.folder", "unit": "ms", "better": "lower",
+              "source": "program_span", "layer": "storage list/load/GC",
+              "moves": "compact_ms"}
+    (bench / "layer_metrics" / "gc_ms.folder.json").write_text(json.dumps({
+        **metric, "driver": "folder", "reader": "span_ms",
+        "args": {"spans": ["compact.gc"]},
+    }))
+    manifest = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    manifest["configs"].append({
+        "name": "orset_folder_8", "source": config["source"],
+        "file": "cellbench/configs/orset_folder_8.json",
+        "reduced": sorted(config["reduced"]), "why": "a toy",
+    })
+    manifest["workloads"].append(cell)
+    manifest["per_layer"].append({**metric, "workloads": [cell["name"]]})
+    for m in manifest["end_to_end"]:
+        if m["name"] in ("compact_ms", "compact_ops_per_s"):
+            m["workloads"].append(cell["name"])
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+
+    assert run.run_cell(cell["name"], 15, 0.4, True, root=str(tmp_path),
+                        require_tpu=False) == 0
+    line = last_line(capsys)
+    assert line["correct"] is True
+    assert set(line["metrics"]) == {"gc_ms.folder"}
+    assert run.run_cell(cell["name"], 15, 0.4, False, root=str(tmp_path),
+                        require_tpu=False) == 0
+    assert {"compact_ms", "compact_ops_per_s", "setup_s"} == set(
+        last_line(capsys)["metrics"])
+
+
+# ---------------------------------------------- the generator and the oracle
+
+
+def test_every_seed_gives_the_same_work_in_another_order():
+    config = {"tenants": 3, "devices": 4, "members": 16, "ops_per_file": 24,
+              "remove_fraction": 0.1, "initial_files_per_device": 2}
+    mix = {"active_tenants": 2, "active_devices": 2, "files_per_device": 1,
+           "warmup_rounds": 1, "max_ops_per_s": 100}
+    a = gen.plan_run(config, mix, 1, 5)
+    b = gen.plan_run(config, mix, 2**31 + 5, 5)
+    assert a.round_files == b.round_files and len(a.kind) == len(b.kind)
+    assert (a.f_actor != b.f_actor).any() or (a.member != b.member).any()
+    again = gen.plan_run(config, mix, 1, 5)
+    assert (a.member == again.member).all() and (a.f_actor == again.f_actor).all()
+    # versions are dense from 1 per writer, dots dense from 1 per writer
+    for actor in set(a.f_actor.tolist()):
+        versions = a.f_version[a.f_actor == actor]
+        assert versions.tolist() == list(range(1, len(versions) + 1))
+        adds = a.counter[(a.actor == actor) & (a.kind == 0)]
+        assert adds.tolist() == list(range(1, len(adds) + 1))
+    assert gen.rounds_for(mix, config, 10) == 1 + 11
+
+
+def test_plain_reference_is_an_observed_remove_set():
+    s = reference.PlainORSet()
+    a, b = b"a" * 16, b"b" * 16
+    s.add(7, a, 1)
+    s.add(7, b, 1)
+    s.remove(7, {a: 1})           # observes a's add only: b's survives
+    assert s.canonical()[b"e"] == {7: {b: 1}}
+    s.add(7, a, 1)                # a replay of a seen dot changes nothing
+    assert s.canonical()[b"e"] == {7: {b: 1}}
+    s.remove(9, {a: 3})           # a remove that runs ahead of the clock waits
+    assert s.canonical()[b"d"] == {9: {a: 3}}
+    s.add(9, a, 2)                # ... and the add it observed is born dead
+    assert 9 not in s.canonical()[b"e"]
+    s.add(9, a, 4)
+    assert s.canonical()[b"e"][9] == {a: 4} and s.canonical()[b"d"] == {}
+    other = {b"c": {a: 4, b: 1}, b"e": {7: {b: 1}, 9: {a: 5}}, b"d": {}}
+    assert reference.differing(s.canonical(), s.canonical()) == 0
+    assert reference.differing(s.canonical(), other) == 1
