@@ -445,7 +445,7 @@ class Core:
             await core._open_from_checkpoint()
         # replication status at open: the backlog gauge here is the
         # answer to "how much will the first read_remote have to fold?"
-        await core._sample_replication()
+        await core._sample_replication("open")
         return core
 
     # -------------------------------------------------------------- identity
@@ -474,7 +474,9 @@ class Core:
         return LockBox(self._data.state).with_(fn)
 
     # ------------------------------------------------------- replication obs
-    async def replication_status(self, *, _backlog: list | None = None) -> dict:
+    async def replication_status(
+        self, *, _backlog: list | None = None, _caller: str | None = None
+    ) -> dict:
         """This replica's replication/convergence status: the causal
         stability watermark, per-actor op backlog (files + bytes past
         the local cursor, sized without reading — ``Storage.stat_ops``),
@@ -490,45 +492,50 @@ class Core:
         folded everything its own listing found, so its sample passes
         ``[]`` instead of paying a second per-actor storage probe on
         the polling hot path (ops sealed concurrently with the fold
-        surface in the next sample)."""
+        surface in the next sample).  ``_caller`` (``open`` /
+        ``read_remote`` / ``compact``) is the sampling entry point, kept
+        as the span's ``meta``."""
         from ..obs import replication
 
-        with trace.span("repl.status"):
+        with trace.span("repl.status", meta=_caller):
             d = self._data
             if _backlog is None:
-                actors = await self.storage.list_op_actors()
-                wanted = [
-                    (a, d.next_op_versions.get(a) + 1) for a in sorted(actors)
-                ]
-                backlog = (
-                    await self.storage.stat_ops(wanted) if wanted else []
-                )
+                with trace.span("repl.probe"):
+                    actors = await self.storage.list_op_actors()
+                    wanted = [
+                        (a, d.next_op_versions.get(a) + 1)
+                        for a in sorted(actors)
+                    ]
+                    backlog = (
+                        await self.storage.stat_ops(wanted) if wanted else []
+                    )
             else:
                 backlog = _backlog
             # sync section: clocks snapshot + compute, no await between
-            ckpt = self._checkpoint_sig
-            status = replication.compute_status(
-                self.actor_id,
-                d.next_op_versions.copy(),
-                {a: c.copy() for a, c in d.cursor_matrix.items()},
-                backlog,
-                self._remote_id(),
-                dict(ckpt[0]) if ckpt is not None else None,
-                self._checkpoint_enabled,
-            )
-            if self._membership is not None:
-                # the strong-read membership policy's loud surface: who
-                # the watermark denominator excludes rides with every
-                # status into /healthz and obs_report fleet (the key is
-                # absent without a configured policy, so the PR-6
-                # byte-stability contract is unchanged for everyone
-                # else)
-                status["membership"] = self._membership.summary()
+            with trace.span("repl.compute"):
+                ckpt = self._checkpoint_sig
+                status = replication.compute_status(
+                    self.actor_id,
+                    d.next_op_versions.copy(),
+                    {a: c.copy() for a, c in d.cursor_matrix.items()},
+                    backlog,
+                    self._remote_id(),
+                    dict(ckpt[0]) if ckpt is not None else None,
+                    self._checkpoint_enabled,
+                )
+                if self._membership is not None:
+                    # the strong-read membership policy's loud surface:
+                    # who the watermark denominator excludes rides with
+                    # every status into /healthz and obs_report fleet
+                    # (the key is absent without a configured policy, so
+                    # the PR-6 byte-stability contract is unchanged for
+                    # everyone else)
+                    status["membership"] = self._membership.summary()
         self.last_replication_status = status
         return status
 
     async def _sample_replication(
-        self, *, _backlog: list | None = None
+        self, caller: str, *, _backlog: list | None = None
     ) -> dict | None:
         """Status → registered gauges (obs.replication.sample) on every
         open / read_remote / compact; ``CRDT_REPL_SAMPLE=0`` opts out.
@@ -539,22 +546,25 @@ class Core:
         from ..obs import replication
 
         try:
-            status = await self.replication_status(_backlog=_backlog)
+            status = await self.replication_status(
+                _backlog=_backlog, _caller=caller
+            )
         except Exception:
             logger.debug("replication status sampling failed", exc_info=True)
             return None
-        replication.sample(status)
-        # freshness-SLO gauges + live /healthz publication: both are
-        # no-ops-with-one-check unless opted in (CRDT_OBS_HTTP / a
-        # configured server), and neither may kill the run it observes
-        try:
-            from ..obs import live as obs_live
-            from ..obs import slo as obs_slo
+        with trace.span("repl.publish"):
+            replication.sample(status)
+            # freshness-SLO gauges + live /healthz publication: both are
+            # no-ops-with-one-check unless opted in (CRDT_OBS_HTTP / a
+            # configured server), and neither may kill the run it observes
+            try:
+                from ..obs import live as obs_live
+                from ..obs import slo as obs_slo
 
-            obs_slo.sample_freshness(status)
-            obs_live.publish(status)
-        except Exception:
-            logger.debug("slo/live sampling failed", exc_info=True)
+                obs_slo.sample_freshness(status)
+                obs_live.publish(status)
+            except Exception:
+                logger.debug("slo/live sampling failed", exc_info=True)
         return status
 
     # ------------------------------------------------------------ strong reads
@@ -1403,7 +1413,7 @@ class Core:
             # the ingest above folded everything its own listing found,
             # so the backlog is empty as-of that listing — don't pay a
             # second per-actor storage probe on the polling hot path
-            await self._sample_replication(_backlog=[])
+            await self._sample_replication("read_remote", _backlog=[])
 
     async def _read_remote_states(self) -> None:
         with trace.span("states.list"):
@@ -1748,8 +1758,17 @@ class Core:
         async def produce():
             ci = 0  # chunk index: span meta, so overlap is event-auditable
             cut: set = set()  # actors ended by an unwrap quarantine
+            chunks = aiter(self.storage.iter_op_chunks(wanted))
             try:
-                async for files in self.storage.iter_op_chunks(wanted):
+                while True:
+                    # the generator cannot be wrapped: time each pull of
+                    # the next chunk (the file loads of this path, where
+                    # ops.load never fires)
+                    with trace.span("ops.chunk_load", meta=ci):
+                        try:
+                            files = await anext(chunks)
+                        except StopAsyncIteration:
+                            break
                     with trace.span("ops.chunk_unwrap", meta=ci):
                         kept, key_ids, middles = [], [], []
                         for f in files:
@@ -1914,7 +1933,9 @@ class Core:
 
         try:
             while True:
-                item = await q.get()
+                # the fold side starved by the ingest side
+                with trace.span("ops.chunk_wait"):
+                    item = await q.get()
                 tag = item[0]
                 if tag == "end":
                     break
@@ -2207,7 +2228,8 @@ class Core:
         if codec_cls is None:
             return None
         d = self._data
-        new_bytes = codec.pack(state_obj)
+        with trace.span("delta.pack"):
+            new_bytes = codec.pack(state_obj)
         plan = {
             "new_bytes": new_bytes,
             "cursor": cursor_obj,
@@ -2249,10 +2271,12 @@ class Core:
             trace.add("delta_seal_skipped", 1)
             return plan
         try:
-            base_state = self.adapter.state_from_obj(
-                codec.unpack(base["bytes"])
-            )
-            dobj = codec_cls.diff(base_state, d.state)
+            with trace.span("delta.base_unpack"):
+                base_state = self.adapter.state_from_obj(
+                    codec.unpack(base["bytes"])
+                )
+            with trace.span("delta.diff"):
+                dobj = codec_cls.diff(base_state, d.state)
         except Exception:
             logger.warning(
                 "delta diff failed; sealing snapshot only", exc_info=True
@@ -2309,22 +2333,23 @@ class Core:
                     # built — rebuild it from the plan-owned base
                     # planes (normalized by the fold kernel's output
                     # law; zero padding reconstructs to nothing)
-                    clock, add, rm, members, replicas = plan[
-                        "base_planes"
-                    ]
-                    import numpy as np
+                    with trace.span("delta.verify.rebuild"):
+                        clock, add, rm, members, replicas = plan[
+                            "base_planes"
+                        ]
+                        from ..obs.runtime import pull
+                        from ..ops import orset_planes_to_state
 
-                    from ..ops import orset_planes_to_state
-
-                    base_state = orset_planes_to_state(
-                        np.asarray(clock), np.asarray(add),
-                        np.asarray(rm), members, replicas,
+                        base_state = orset_planes_to_state(
+                            *pull(clock, add, rm), members, replicas
+                        )
+                with trace.span("delta.verify.apply"):
+                    plan["codec"].apply(base_state, plan["dobj"])
+                with trace.span("delta.verify.pack"):
+                    return (
+                        codec.pack(self.adapter.state_to_obj(base_state))
+                        == plan["new_bytes"]
                     )
-                plan["codec"].apply(base_state, plan["dobj"])
-                return (
-                    codec.pack(self.adapter.state_to_obj(base_state))
-                    == plan["new_bytes"]
-                )
             except Exception:
                 logger.warning("delta verify crashed", exc_info=True)
                 return False
@@ -2345,7 +2370,9 @@ class Core:
         if name == plan["base_name"]:
             return  # idempotent re-seal of the identical snapshot
         if plan["dobj"] is not None:
-            if len(codec.pack(plan["dobj"])) >= len(plan["new_bytes"]):
+            with trace.span("delta.size"):
+                delta_len = len(codec.pack(plan["dobj"]))
+            if delta_len >= len(plan["new_bytes"]):
                 # a delta no smaller than the state saves nothing
                 trace.add("delta_seal_skipped", 1)
                 plan["dobj"] = None
@@ -2428,16 +2455,21 @@ class Core:
         _read_remote_ops_bulk): decrypt+decode of chunk k+1 proceeds
         while chunk k folds, with per-stage ``stream.*`` trace spans —
         see docs/streaming_pipeline.md for how to read them."""
-        with trace.span("compact.ingest"):
-            await self.read_remote(_sample=False)
-        await self._compact_seal()
+        with trace.span("core.compact"):
+            with trace.span("compact.ingest"):
+                await self.read_remote(_sample=False)
+            record = await self._compact_seal(_sink=False)
+        # with core.compact closed: a sink record takes the registry's
+        # snapshot, and carries only what has ended
+        await self._sink_compact(*record)
 
     async def _compact_seal(
         self, *, _backlog: list | None = None,
         _packed_state: tuple | None = None,
         _state_obj: tuple | None = None,
         _delta_cut: dict | None = None,
-    ) -> None:
+        _sink: bool = True,
+    ) -> tuple:
         """The seal tail of :meth:`compact`: snapshot the CURRENT state +
         cursor, write-new-then-delete-old, reseal the warm-open
         checkpoint, sample replication, and append the sink record.
@@ -2459,7 +2491,9 @@ class Core:
         re-walking the state), used only when the state's mutation
         epoch still matches, else the live state is serialized here.
         The canonical packer re-sorts maps, so an equivalent obj seals
-        byte-identical payloads."""
+        byte-identical payloads.  Returns the sink record's ``(meta,
+        status)``; ``_sink=False`` leaves writing it to the caller
+        (:meth:`compact`, after its root span has closed)."""
         # lint: sync-section-begin (ASY001: the snapshot/cursor/delta-plan
         # cut below must come from ONE loop slice — an await here lets an
         # ingest interleave and seal a torn (state, cursor, delta) triple)
@@ -2469,7 +2503,8 @@ class Core:
         ):
             state_obj = _state_obj[0]
         else:
-            state_obj = self.adapter.state_to_obj(d.state)
+            with trace.span("seal.state_obj"):
+                state_obj = self.adapter.state_to_obj(d.state)
         cursor_obj = d.next_op_versions.to_obj()
         snap_mut = getattr(d.state, "_mut", None)
         # delta plan (diff + self-verify) in the SAME sync section: the
@@ -2477,9 +2512,10 @@ class Core:
         # ``_delta_cut`` is the serving layer's device-cut candidate —
         # validated (base name + mut epoch) inside the plan, never
         # trusted blindly
-        delta_plan = self._plan_delta_seal(
-            state_obj, cursor_obj, _cut=_delta_cut
-        )
+        with trace.span("delta.plan"):
+            delta_plan = self._plan_delta_seal(
+                state_obj, cursor_obj, _cut=_delta_cut
+            )
         payload = [
             state_obj,
             cursor_obj,
@@ -2561,32 +2597,42 @@ class Core:
         # zero by construction, staleness zero): the post-compaction
         # fixed point is what rides into the sink record below — the
         # per-device line the fleet aggregator reads.
-        status = await self._sample_replication(_backlog=_backlog)
-        # run-scoped metrics sink (CRDT_OBS_SINK / obs.sink.configure):
-        # every compaction appends its phase table + counters, so the
-        # streaming pipeline is auditable after the process is gone.
-        # Off the event loop: with events enabled the record can carry a
-        # full ring of timeline events, and json.dumps + the file append
-        # must not stall concurrent ingests (the registry is lock-backed,
-        # so snapshot/drain from a worker thread is safe).
+        status = await self._sample_replication("compact", _backlog=_backlog)
+        # ops_to_remove is (actor, covered-version-cursor) pairs — the
+        # GC prefix per actor, not a file count
+        record = (
+            {"gc_op_actors": len(ops_to_remove),
+             "gc_states": len(states_to_remove)},
+            status,
+        )
+        if _sink:
+            await self._sink_compact(*record)
+        return record
+
+    async def _sink_compact(self, meta: dict, status: dict | None) -> None:
+        """Run-scoped metrics sink (CRDT_OBS_SINK / obs.sink.configure):
+        every compaction appends its phase table + counters, so the
+        streaming pipeline is auditable after the process is gone.
+        Off the event loop: with events enabled the record can carry a
+        full ring of timeline events, and json.dumps + the file append
+        must not stall concurrent ingests (the registry is lock-backed,
+        so snapshot/drain from a worker thread is safe)."""
         from ..obs import sink as obs_sink
 
         if obs_sink.default_sink() is not None:
-            # ops_to_remove is (actor, covered-version-cursor) pairs —
-            # the GC prefix per actor, not a file count
             await asyncio.to_thread(
-                obs_sink.maybe_write,
-                "compact",
-                {"gc_op_actors": len(ops_to_remove),
-                 "gc_states": len(states_to_remove)},
-                status,
+                obs_sink.maybe_write, "compact", meta, status
             )
 
     # ------------------------------------------------- remote meta lifecycle
     async def _read_remote_meta(self, force_notify: bool = False) -> None:
-        names = await self.storage.list_remote_meta_names()
+        with trace.span("meta.list"):
+            names = await self.storage.list_remote_meta_names()
         new = [n for n in names if n not in self._data.read_metas]
-        loaded = await self.storage.load_remote_metas(new) if new else []
+        loaded = []
+        if new:
+            with trace.span("meta.load"):
+                loaded = await self.storage.load_remote_metas(new)
         # The merge and the KEY-cryptor fan-out hold the keys lock: a
         # key-register merge landing inside _install_new_key's
         # snapshot→write window would be silently superseded (lock order:

@@ -31,7 +31,7 @@ is golden-tested against the committed BENCH_LOCAL record.
 
 from __future__ import annotations
 
-from . import record, timeline
+from . import timeline
 
 #: canonical stage order — ties on the critical path resolve to the
 #: earliest stage, and reports render in this order.
@@ -41,8 +41,10 @@ STAGES = ("ingest", "decrypt", "decode", "h2d", "fold", "scatter", "seal")
 # streaming map covers the solo pipeline (ops/stream + session + the
 # bulk/legacy core paths); the serve map covers a FoldService cycle.
 _STREAM_STAGES: dict[str, tuple[tuple[str, ...], ...]] = {
-    "ingest": (("ops.list",), ("ops.load",), ("states.list",),
-               ("states.load",)),
+    # ops.chunk_load: the pipelined ingest's file loads (ops.load never
+    # fires on that path)
+    "ingest": (("ops.list",), ("ops.load",), ("ops.chunk_load",),
+               ("states.list",), ("states.load",)),
     "decrypt": (("stream.decrypt", "ops.bulk_decrypt",
                  "ops.chunk_decrypt"),),
     "decode": (("stream.decode", "session.decode", "fold.decode"),),
@@ -53,8 +55,12 @@ _STREAM_STAGES: dict[str, tuple[tuple[str, ...], ...]] = {
              ("session.sparse_fold",)),
     "scatter": (("session.writeback", "stream.finish",
                  "fold.writeback"), ("stream.d2h",)),
+    # seal.state_obj / delta.plan / delta.size: the O(state) walks of
+    # the seal tail's sync section and the delta size guard (leaves
+    # disjoint from the spans beside them)
     "seal": (("compact.seal",), ("compact.write",), ("compact.gc",),
-             ("checkpoint.save",), ("delta.seal",), ("delta.verify",)),
+             ("checkpoint.save",), ("delta.seal",), ("delta.verify",),
+             ("seal.state_obj",), ("delta.plan",), ("delta.size",)),
 }
 _SERVE_STAGES: dict[str, tuple[tuple[str, ...], ...]] = {
     "ingest": (("serve.ingest",), ("serve.plan",)),
@@ -109,72 +115,71 @@ def attribute_cycle(
     span.  ``ops`` enables the throughput half of the gap report.
     ``events`` (the record's event log) additionally yields the
     chunk-level overlap proof."""
-    with record.span("attribution.gap"):
-        spans = snapshot.get("spans", {})
-        # simulator harness spans (sim.run / sim.step / sim.check /
-        # sim.population) WRAP the serve spans a sim service cycle
-        # records — they are schedule bookkeeping, not cycle stages.
-        # Drop them explicitly: left in, they would dominate the
-        # event-extent wall inference and report a whole simulation as
-        # one impossibly slow cycle.
-        spans = {n: v for n, v in spans.items()
-                 if not n.startswith("sim.")}
-        pipe = pipeline or detect_pipeline(snapshot)
-        stage_map = _SERVE_STAGES if pipe == "serve" else _STREAM_STAGES
+    spans = snapshot.get("spans", {})
+    # simulator harness spans (sim.run / sim.step / sim.check /
+    # sim.population) WRAP the serve spans a sim service cycle
+    # records — they are schedule bookkeeping, not cycle stages.
+    # Drop them explicitly: left in, they would dominate the
+    # event-extent wall inference and report a whole simulation as
+    # one impossibly slow cycle.
+    spans = {n: v for n, v in spans.items()
+             if not n.startswith("sim.")}
+    pipe = pipeline or detect_pipeline(snapshot)
+    stage_map = _SERVE_STAGES if pipe == "serve" else _STREAM_STAGES
 
-        stages: dict[str, dict] = {}
-        serialized = 0.0
-        for stage in STAGES:
-            s, contributors = _stage_seconds(spans, stage_map.get(stage, ()))
-            stages[stage] = {"seconds": round(s, 6), "spans": contributors}
-            serialized += s
+    stages: dict[str, dict] = {}
+    serialized = 0.0
+    for stage in STAGES:
+        s, contributors = _stage_seconds(spans, stage_map.get(stage, ()))
+        stages[stage] = {"seconds": round(s, 6), "spans": contributors}
+        serialized += s
 
-        if wall_s is None and events:
-            span_events = [e for e in events
-                           if e.get("kind", "span") == "span"
-                           and not str(e.get("name", "")).startswith("sim.")]
-            if span_events:
-                wall_s = (max(e["t1"] for e in span_events)
-                          - min(e["t0"] for e in span_events))
-        if wall_s is None and pipe == "serve":
-            cyc = spans.get("serve.cycle")
-            if cyc:
-                wall_s = float(cyc["seconds"])
+    if wall_s is None and events:
+        span_events = [e for e in events
+                       if e.get("kind", "span") == "span"
+                       and not str(e.get("name", "")).startswith("sim.")]
+        if span_events:
+            wall_s = (max(e["t1"] for e in span_events)
+                      - min(e["t0"] for e in span_events))
+    if wall_s is None and pipe == "serve":
+        cyc = spans.get("serve.cycle")
+        if cyc:
+            wall_s = float(cyc["seconds"])
 
-        critical = max(
-            STAGES, key=lambda st: (stages[st]["seconds"],
-                                    -STAGES.index(st))
+    critical = max(
+        STAGES, key=lambda st: (stages[st]["seconds"],
+                                -STAGES.index(st))
+    )
+    report = {
+        "pipeline": pipe,
+        "stages": stages,
+        "serialized_s": round(serialized, 6),
+        "wall_s": round(wall_s, 6) if wall_s else None,
+        "critical_path": critical,
+        "critical_share": round(
+            stages[critical]["seconds"] / serialized, 4
+        ) if serialized > 0 else None,
+    }
+    if wall_s:
+        report["overlap_x"] = round(serialized / wall_s, 4)
+    if events:
+        chunks = timeline.chunk_overlaps(
+            timeline.to_chrome_trace(events)
         )
-        report = {
-            "pipeline": pipe,
-            "stages": stages,
-            "serialized_s": round(serialized, 6),
-            "wall_s": round(wall_s, 6) if wall_s else None,
-            "critical_path": critical,
-            "critical_share": round(
-                stages[critical]["seconds"] / serialized, 4
-            ) if serialized > 0 else None,
-        }
-        if wall_s:
-            report["overlap_x"] = round(serialized / wall_s, 4)
-        if events:
-            chunks = timeline.chunk_overlaps(
-                timeline.to_chrome_trace(events)
-            )
-            report["overlapped_chunks"] = len(chunks)
+        report["overlapped_chunks"] = len(chunks)
 
-        fold_s = stages["fold"]["seconds"]
-        if ops and wall_s:
-            gap = {
-                "ops": int(ops),
-                "e2e_ops_per_sec": round(ops / wall_s, 1),
-                "dominant_stage": critical,
-            }
-            if fold_s > 0:
-                gap["fold_marginal_ops_per_sec"] = round(ops / fold_s, 1)
-                gap["gap_x"] = round(wall_s / fold_s, 2)
-            report["gap"] = gap
-        return report
+    fold_s = stages["fold"]["seconds"]
+    if ops and wall_s:
+        gap = {
+            "ops": int(ops),
+            "e2e_ops_per_sec": round(ops / wall_s, 1),
+            "dominant_stage": critical,
+        }
+        if fold_s > 0:
+            gap["fold_marginal_ops_per_sec"] = round(ops / fold_s, 1)
+            gap["gap_x"] = round(wall_s / fold_s, 2)
+        report["gap"] = gap
+    return report
 
 
 def from_record(rec: dict) -> dict:

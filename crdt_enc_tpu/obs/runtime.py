@@ -14,6 +14,11 @@ module makes mechanical:
 * **H2D accounting**: the streaming paths count ``h2d_bytes`` at each
   ``jax.device_put`` issue (ops/stream.py, parallel/session.py); transfer
   issue latency is the ``stream.h2d`` span histogram.
+* **D2H accounting** (:func:`pull`): the pulls of device arrays back to
+  the host on the paths whose uploads ``h2d_bytes`` counts (the OR-Set
+  folds of accel.py and session.py, the service's buckets and device
+  cut, the delta verify) go through it and count ``d2h_bytes`` where
+  the pull is issued.
 * **Device memory** (:func:`sample_device_memory`): ``bytes_in_use`` /
   ``peak_bytes_in_use`` gauges sampled at fold boundaries — the
   bounded-device-memory claim of the donated-plane streaming fold,
@@ -108,6 +113,23 @@ def _set_recompiles(on: bool) -> None:
 def recompile_count() -> int:
     """The current ``jax_compiles`` counter (0 when tracking is off)."""
     return record.snapshot()["counters"].get("jax_compiles", 0)
+
+
+def pull(*arrays) -> tuple:
+    """``arrays`` as host numpy arrays, the device ones counted into
+    ``d2h_bytes`` where the pull is issued (the twin of the ``h2d_bytes``
+    accounting).  An array that is already numpy passes through
+    uncounted."""
+    import numpy as np
+
+    host = tuple(np.asarray(x) for x in arrays)
+    pulled = sum(
+        h.nbytes for h, x in zip(host, arrays)
+        if not isinstance(x, np.ndarray)
+    )
+    if pulled:
+        record.add("d2h_bytes", pulled)
+    return host
 
 
 def sample_device_memory(device=None) -> dict | None:

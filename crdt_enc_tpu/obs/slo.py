@@ -198,47 +198,46 @@ def burn_report(
     series — that is reported as 0 samples, not as compliance)."""
     if specs is None:
         specs = default_specs()
-    with record.span("slo.burn"):
-        out = {"window_s": window_s, "specs": []}
-        for spec in specs:
-            samples = _samples_for(spec, records)
-            entry = {
-                "name": spec.name,
-                "indicator": spec.indicator,
-                "target": spec.target,
-                "objective": spec.objective,
-                "samples": sum(g + b for _, g, b in samples),
-                "violations": sum(b for _, _, b in samples),
-                "windows": [],
-            }
-            if samples:
-                t0 = min(ts for ts, _, _ in samples)
-                buckets: dict[int, list[int]] = {}
-                for ts, g, b in samples:
-                    slot = buckets.setdefault(
-                        int((ts - t0) // window_s), [0, 0]
-                    )
-                    slot[0] += g
-                    slot[1] += b
-                for idx in sorted(buckets):
-                    g, b = buckets[idx]
-                    frac = b / (g + b) if (g + b) else 0.0
-                    entry["windows"].append({
-                        "window": idx,
-                        "start_s": round(idx * window_s, 3),
-                        "samples": g + b,
-                        "violations": b,
-                        "burn_rate": round(frac / spec.budget, 4),
-                    })
-                total = entry["samples"]
-                frac = entry["violations"] / total if total else 0.0
-                entry["bad_fraction"] = round(frac, 6)
-                entry["budget_burn"] = round(frac / spec.budget, 4)
-                entry["worst_window_burn"] = max(
-                    (w["burn_rate"] for w in entry["windows"]), default=0.0
+    out = {"window_s": window_s, "specs": []}
+    for spec in specs:
+        samples = _samples_for(spec, records)
+        entry = {
+            "name": spec.name,
+            "indicator": spec.indicator,
+            "target": spec.target,
+            "objective": spec.objective,
+            "samples": sum(g + b for _, g, b in samples),
+            "violations": sum(b for _, _, b in samples),
+            "windows": [],
+        }
+        if samples:
+            t0 = min(ts for ts, _, _ in samples)
+            buckets: dict[int, list[int]] = {}
+            for ts, g, b in samples:
+                slot = buckets.setdefault(
+                    int((ts - t0) // window_s), [0, 0]
                 )
-            out["specs"].append(entry)
-        return out
+                slot[0] += g
+                slot[1] += b
+            for idx in sorted(buckets):
+                g, b = buckets[idx]
+                frac = b / (g + b) if (g + b) else 0.0
+                entry["windows"].append({
+                    "window": idx,
+                    "start_s": round(idx * window_s, 3),
+                    "samples": g + b,
+                    "violations": b,
+                    "burn_rate": round(frac / spec.budget, 4),
+                })
+            total = entry["samples"]
+            frac = entry["violations"] / total if total else 0.0
+            entry["bad_fraction"] = round(frac, 6)
+            entry["budget_burn"] = round(frac / spec.budget, 4)
+            entry["worst_window_burn"] = max(
+                (w["burn_rate"] for w in entry["windows"]), default=0.0
+            )
+        out["specs"].append(entry)
+    return out
 
 
 def format_burn(report: dict) -> str:

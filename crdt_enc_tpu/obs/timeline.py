@@ -11,7 +11,8 @@ k+1's decrypt/decode/H2D riding under chunk k's fold).  This module turns
   the consumer's ``stream.reduce``;
 * spans as complete (``ph: "X"``) events with the span ``meta`` (chunk
   index) in ``args``, so overlap is also *programmatically* checkable —
-  :func:`chunk_overlaps` is what the acceptance tests assert on;
+  :func:`chunk_overlaps` is what the acceptance tests assert on — and
+  with the span's ``id`` and its ``parent``'s id, the span that caused it;
 * counter/gauge updates as counter-track (``ph: "C"``) events, so
   ``h2d_bytes`` or ``device_bytes_in_use`` plot as stepped graphs above
   the lanes.
@@ -76,6 +77,11 @@ def to_chrome_trace(events: list | None = None) -> dict:
         }
         if e.get("meta") is not None:
             ev["args"]["chunk"] = e["meta"]
+        if e.get("id") is not None:
+            # the span that caused this one, by id: the tree is readable
+            # from the export alone (None = a root)
+            ev["args"]["id"] = e["id"]
+            ev["args"]["parent"] = e.get("parent")
         out.append(ev)
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 

@@ -139,6 +139,13 @@ class OrsetColumns:
     members: Vocab = field(default_factory=Vocab)
     replicas: Vocab = field(default_factory=Vocab)
 
+    @property
+    def row_bytes(self) -> int:
+        """Bytes of the four row columns: what handing them to a jitted
+        fold uploads (``h2d_bytes``)."""
+        return (self.kind.nbytes + self.member.nbytes
+                + self.actor.nbytes + self.counter.nbytes)
+
 
 def orset_ops_to_columns(
     ops, members: Vocab | None = None, replicas: Vocab | None = None

@@ -53,6 +53,7 @@ tests pin the semantics at both extremes.
 
 from __future__ import annotations
 
+import contextvars
 import os
 import queue as _queue
 import threading
@@ -479,9 +480,12 @@ def run_ingest_pipeline(
             stop.set()  # first failure cancels the peers
             out_q.put(("error", k if k is not None else -1, e))
 
+    # each worker runs in a copy of the caller's context, so the spans it
+    # opens are parented on the span open here (and its counter taps see
+    # the workers' increments), as asyncio.to_thread does for its hops
     workers = [
         threading.Thread(
-            target=produce, args=(i,),
+            target=contextvars.copy_context().run, args=(produce, i),
             name=f"{thread_prefix}-{i}", daemon=True,
         )
         for i in range(producers)
@@ -696,9 +700,10 @@ def run_striped_ingest_pipeline(
             stop.set()
             out_q.put(("error", k if k is not None else -1, e))
 
+    # workers run in copies of the caller's context (run_ingest_pipeline)
     workers = [
         threading.Thread(
-            target=produce, args=(i,),
+            target=contextvars.copy_context().run, args=(produce, i),
             name=f"{thread_prefix}-{i}", daemon=True,
         )
         for i in range(producers)

@@ -363,9 +363,11 @@ class TpuAccelerator(HostAccelerator):
                 # exists only to bound jit recompilation, and this path
                 # never compiles anything.
                 trace.add("fold_rows_host", n_rows)
-                folded = K.orset_fold_sparse_host(
-                    state, kind, member, actor, counter, members, replicas
-                )
+                with trace.span("fold.host_sparse"):
+                    folded = K.orset_fold_sparse_host(
+                        state, kind, member, actor, counter, members,
+                        replicas,
+                    )
             c = self._plane_cache
             if c is not None and c.ref() is state:
                 self._plane_cache = None  # sparse writeback: planes stale
@@ -391,9 +393,7 @@ class TpuAccelerator(HostAccelerator):
                 if cache is not None:
                     # the blockwise stream stages planes from host (its
                     # own H2D rides under the first fold) — pull once
-                    clock0, add0, rm0 = (
-                        np.asarray(x) for x in (clock0, add0, rm0)
-                    )
+                    clock0, add0, rm0 = obs_runtime.pull(clock0, add0, rm0)
                 # blockwise fold with donated plane buffers: bounded device
                 # memory for arbitrarily large ingests (ops/stream.py).
                 # Chunks route through the Pallas MXU fold when eligible —
@@ -432,6 +432,9 @@ class TpuAccelerator(HostAccelerator):
                     )
                 cols = K.OrsetColumns(kind, member, actor, counter, members, replicas)
                 K.pad_orset_rows(cols, _bucket(len(cols.kind)), Rp)
+                # the padded row columns upload on every round, plane
+                # cache hit or miss: numpy handed to the jitted fold
+                trace.add("h2d_bytes", cols.row_bytes)
                 fold = self._pick_dense_fold(cols, Ep, Rp)
                 dev_planes = fold(
                     clock0,
@@ -442,7 +445,8 @@ class TpuAccelerator(HostAccelerator):
                     cols.actor,
                     cols.counter,
                 )
-            clock, add, rm = (np.asarray(x) for x in dev_planes)
+            # the O(state) pull-back of every dense round
+            clock, add, rm = obs_runtime.pull(*dev_planes)
             if (Ep, Rp) != (E, R):
                 clock, add, rm = clock[:R], add[:E, :R], rm[:E, :R]
         obs_runtime.sample_device_memory()  # fold boundary
@@ -549,13 +553,14 @@ class TpuAccelerator(HostAccelerator):
             replicas,
         )
         K.pad_orset_rows(cols, _bucket(len(cols.kind)), R)
+        trace.add("h2d_bytes", clock0.nbytes + cols.row_bytes)
         clock, skey, smax, is_max = K.orset_fold_coo(
             clock0, cols.kind, cols.member, cols.actor, cols.counter,
             num_members=E, num_replicas=R,
         )
         return K.orset_apply_coo(
-            state, np.asarray(clock), np.asarray(skey), np.asarray(smax),
-            np.asarray(is_max), members, replicas,
+            state, *obs_runtime.pull(clock, skey, smax, is_max),
+            members, replicas,
         )
 
     def _fold_orset_sharded(
@@ -605,13 +610,17 @@ class TpuAccelerator(HostAccelerator):
                 impl="pallas",
                 tile_cap=pmesh.sharded_fold_cap(cols.member, E_pad, dp, mp),
             )
+        trace.add(
+            "h2d_bytes",
+            clock0.nbytes + add0.nbytes + rm0.nbytes + cols.row_bytes,
+        )
         clock, add, rm = pmesh.orset_fold_sharded(
             mesh, clock0, add0, rm0,
             cols.kind, cols.member, cols.actor, cols.counter, **fold_kw,
         )
+        clock, add, rm = obs_runtime.pull(clock, add, rm)
         folded = K.orset_planes_to_state(
-            np.asarray(clock), np.asarray(add)[:E], np.asarray(rm)[:E],
-            members, replicas,
+            clock, add[:E], rm[:E], members, replicas
         )
         state.clock = folded.clock
         state.entries = folded.entries

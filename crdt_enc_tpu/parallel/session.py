@@ -492,7 +492,7 @@ class OrsetFoldSession:
             _, clock_s, plane_s = pmesh.stream_sharding(self.accel.mesh)
             import jax
 
-            clock, add, rm = (np.asarray(x) for x in self._d_planes)
+            clock, add, rm = obs_runtime.pull(*self._d_planes)
             z = np.zeros((E_new - self._d_E, add.shape[1]), np.int32)
             # the growth re-upload is a real transfer the plane gauges
             # would otherwise miss (OBS001)
@@ -688,7 +688,7 @@ class OrsetFoldSession:
                 # survivor rule would delete pre-existing entries the
                 # batch never touched (confirmed data loss; regression in
                 # tests/test_fold_session.py)
-                _, d_add, d_rm = (np.asarray(x) for x in self._d_planes)
+                d_add, d_rm = obs_runtime.pull(*self._d_planes[1:])
                 E_pad = max(self._d_E, _bucket(max(E, 1)))
                 clock0, add0, rm0 = self._state_planes(E_pad)
                 d_add = self._pad_batch(d_add, E_pad, R_final)

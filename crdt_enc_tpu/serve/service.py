@@ -69,6 +69,7 @@ import numpy as np
 from .. import ops as K
 from ..models import GCounter, ORSet
 from ..models.counters import POS
+from ..obs import runtime as obs_runtime
 from ..utils import codec, trace
 from . import bucketing
 from .bucketing import TenantShape, _bucket, plan_buckets
@@ -355,32 +356,53 @@ class FoldService:
     async def _run_cycle(self, tenants) -> list[TenantResult]:
         t0 = time.perf_counter()
         works = [_TenantWork(i, core) for i, core in enumerate(tenants)]
-        with trace.span("serve.cycle"):
-            await self._ingest_all(works)
-            await self._decrypt_all(works)
-            decodable = [w for w in works if w.ok and w.kind and w.payloads]
-            if decodable:
-                await asyncio.to_thread(self._decode_all, decodable)
-            self._fold_batched(works)
-            await self._fold_fallbacks(works)
-            await self._seal_all(works, t0)
-            self._stamp_continuations(works)
-        trace.add("serve_cycles", 1)
-        trace.add("serve_tenants", len(works))
-        results = [w.result for w in works]
-        await self._publish_cycle(tenants, results, time.perf_counter() - t0)
+        # serve.run_cycle is the whole call; serve.cycle inside it ends
+        # before the telemetry (obs_report gap reads it as the cycle's
+        # wall).  The seven phases run one after another and partition it:
+        # each phase span is the WALL of its phase (the spans inside
+        # them are summed over the tenants in flight)
+        with trace.span("serve.run_cycle"):
+            with trace.span("serve.cycle"):
+                with trace.span("serve.phase.ingest"):
+                    await self._ingest_all(works)
+                with trace.span("serve.phase.decrypt"):
+                    await self._decrypt_all(works)
+                with trace.span("serve.phase.decode"):
+                    decodable = [
+                        w for w in works if w.ok and w.kind and w.payloads
+                    ]
+                    if decodable:
+                        await asyncio.to_thread(self._decode_all, decodable)
+                with trace.span("serve.phase.fold"):
+                    self._fold_batched(works)
+                with trace.span("serve.phase.fallback"):
+                    await self._fold_fallbacks(works)
+                with trace.span("serve.phase.seal"):
+                    await self._seal_all(works, t0)
+                with trace.span("serve.phase.continue"):
+                    self._stamp_continuations(works)
+            trace.add("serve_cycles", 1)
+            trace.add("serve_tenants", len(works))
+            results = [w.result for w in works]
+            with trace.span("serve.publish"):
+                summary = self._publish_cycle(
+                    tenants, results, time.perf_counter() - t0
+                )
+        # with every span of the cycle closed: a record takes the
+        # registry's snapshot, and carries only what has ended
+        if summary is not None:
+            await self._sink_cycle(summary)
         return results
 
-    async def _publish_cycle(self, tenants, results, wall_s: float) -> None:
+    def _publish_cycle(self, tenants, results, wall_s: float) -> dict | None:
         """Post-cycle telemetry: the cycle summary (tenant paths, wall,
         per-tenant seal-latency SLO burn) goes to the live /healthz
-        endpoint and — when a sink is configured — into one
-        ``serve_cycle`` sink record; each sealed tenant's replication
-        status (sampled by its own ``_compact_seal``) feeds the live
-        health map.  Strictly after the fold/seal work, never on the
-        hot path, and never fatal to the cycle it describes."""
+        endpoint; each sealed tenant's replication status (sampled by
+        its own ``_compact_seal``) feeds the live health map.  Strictly
+        after the fold/seal work, never on the hot path, and never
+        fatal to the cycle it describes.  Returns the summary (None if
+        it could not be made) for the ``serve_cycle`` sink record."""
         from ..obs import live as obs_live
-        from ..obs import sink as obs_sink
         from ..obs import slo as obs_slo
 
         try:
@@ -412,13 +434,26 @@ class FoldService:
                     status = getattr(core, "last_replication_status", None)
                     if r.sealed and status is not None:
                         target.publish_health(status)
+            return summary
+        except Exception:  # telemetry must not fail the fleet cycle
+            logger.debug("cycle telemetry publication failed",
+                         exc_info=True)
+            return None
+
+    async def _sink_cycle(self, summary: dict) -> None:
+        """One ``serve_cycle`` sink record, when a sink is configured.
+        Written after ``serve.run_cycle`` has closed, so the k-th record
+        carries k of every span of a cycle, and its events are its own
+        cycle's."""
+        from ..obs import sink as obs_sink
+
+        try:
             if obs_sink.default_sink() is not None:
                 await asyncio.to_thread(
                     obs_sink.maybe_write, "serve_cycle", summary
                 )
         except Exception:  # telemetry must not fail the fleet cycle
-            logger.debug("cycle telemetry publication failed",
-                         exc_info=True)
+            logger.debug("cycle sink record failed", exc_info=True)
 
     # ------------------------------------------------------- strong reads
     async def read_strong(self, core, *, max_lag=None, min_cursor=None,
@@ -840,9 +875,7 @@ class FoldService:
                     num_members=E_b, num_replicas=R_b,
                 )
         with trace.span("serve.scatter", meta=bi):
-            clock_all = np.asarray(out[0])
-            add_all = np.asarray(out[1])
-            rm_all = np.asarray(out[2])
+            clock_all, add_all, rm_all = obs_runtime.pull(*out)
             for slot, key in enumerate(bucket.tenants):
                 w = by_idx[key]
                 _, _, _, _, members, replicas, entry = w.prepared
@@ -936,7 +969,7 @@ class FoldService:
                     code, counts = K.orset_plane_diff_tenants(
                         clock_s, add_s, rm_s, out[0], out[1], out[2]
                     )
-                counts = np.asarray(counts)  # one (T,) D2H per bucket
+                (counts,) = obs_runtime.pull(counts)  # one (T,) D2H per bucket
                 cells = E_b * R_b
                 for slot, key in cut_slots:
                     w = by_idx[key]
@@ -951,7 +984,7 @@ class FoldService:
                         )
                         # the ONLY per-tenant D2H of the cut: O(diff
                         # rows), not O(state)
-                        rows = tuple(np.asarray(r) for r in rows)
+                        rows = obs_runtime.pull(*rows)
                     else:
                         empty = np.zeros(0, np.int64)
                         rows = (empty, empty, empty, empty, empty)
@@ -960,7 +993,7 @@ class FoldService:
                         members=members.items,
                         replicas=replicas.items,
                         row_width=R_b,
-                        base_clock=np.asarray(clock_rows[slot]),
+                        base_clock=obs_runtime.pull(clock_rows[slot])[0],
                         new_clock=clock_all[slot],
                     )
                     # epoch-guarded candidate: _plan_delta_seal only
@@ -1007,7 +1040,7 @@ class FoldService:
                     clock0, actor, counter, num_replicas=R_b
                 )
         with trace.span("serve.scatter", meta=bi):
-            out_all = np.asarray(out)
+            (out_all,) = obs_runtime.pull(out)
             for slot, key in enumerate(bucket.tenants):
                 w = by_idx[key]
                 a, _, replicas, _ = w.prepared

@@ -1002,10 +1002,14 @@ def test_stream_counters_pinned_on_striped_path():
         len(b) for b in blobs
     )
     # tiny workload stays in the BUFFER regime; its one device hop is
-    # the dense fold's state-plane upload — exactly clock (R·4) +
-    # add/rm planes (2·E·R·4) for this E=12, R=5 shape.  A drift here
-    # means an unaccounted (or double-counted) device hop appeared.
-    assert snap["counters"].get("h2d_bytes", 0) == 5 * 4 + 2 * 12 * 5 * 4
+    # the dense fold: the state-plane upload — exactly clock (R·4) +
+    # add/rm planes (2·E·R·4) for this E=12, R=5 shape — plus the 240
+    # op rows' columns (13 B a row, padded to the 256-row class), and
+    # the same planes pulled back.  A drift here means an unaccounted
+    # (or double-counted) device hop appeared.
+    planes = 5 * 4 + 2 * 12 * 5 * 4
+    assert snap["counters"].get("h2d_bytes", 0) == planes + 13 * 256
+    assert snap["counters"].get("d2h_bytes", 0) == planes
     assert codec.pack(state.to_obj()) == codec.pack(host.to_obj())
     # a failed decrypt (wrong key) counts NOTHING
     trace.reset()
