@@ -1208,7 +1208,8 @@ def k_tenant_folds(ctx, kd, obs):
 
 def diff_vs_numpy(cb, ab, rb, cn, an, rn) -> int:
     """``orset_plane_diff_tenants`` over a bucket, and
-    ``orset_plane_diff_rows`` for its first tenant, against numpy.
+    ``orset_plane_diff_rows`` for its first tenant, against numpy; the
+    bucket's batched gather against that tenant's.
     Returns the first tenant's diff-cell count."""
     from crdt_enc_tpu import ops as K
     from crdt_enc_tpu.serve.bucketing import _bucket
@@ -1238,6 +1239,11 @@ def diff_vs_numpy(cb, ab, rb, cn, an, rn) -> int:
           and np.array_equal(v_an[:n_diff], an[0].ravel()[flat])
           and np.array_equal(v_rn[:n_diff], rn[0].ravel()[flat]),
           "orset_plane_diff_rows != numpy")
+    # the gather the service runs: every slot of the bucket at once
+    batched = K.orset_plane_diff_rows_tenants(code, ab, an, rn, size=size)
+    check(all(np.array_equal(np.asarray(b)[0], solo)
+              for b, solo in zip(batched, (idx, c, v_ab, v_an, v_rn))),
+          "orset_plane_diff_rows_tenants[0] != orset_plane_diff_rows")
     return n_diff
 
 

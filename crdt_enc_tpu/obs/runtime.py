@@ -17,8 +17,8 @@ module makes mechanical:
 * **D2H accounting** (:func:`pull`): the pulls of device arrays back to
   the host on the paths whose uploads ``h2d_bytes`` counts (the OR-Set
   folds of accel.py and session.py, the service's buckets and device
-  cut, the delta verify) go through it and count ``d2h_bytes`` where
-  the pull is issued.
+  cut, the delta verify) go through it and count ``d2h_bytes`` and
+  ``d2h_pulls`` (one per device array) where the pull is issued.
 * **Device memory** (:func:`sample_device_memory`): ``bytes_in_use`` /
   ``peak_bytes_in_use`` gauges sampled at fold boundaries — the
   bounded-device-memory claim of the donated-plane streaming fold,
@@ -117,18 +117,20 @@ def recompile_count() -> int:
 
 def pull(*arrays) -> tuple:
     """``arrays`` as host numpy arrays, the device ones counted into
-    ``d2h_bytes`` where the pull is issued (the twin of the ``h2d_bytes``
-    accounting).  An array that is already numpy passes through
-    uncounted."""
+    ``d2h_bytes`` (and one ``d2h_pulls`` each: every device array is a
+    blocking sync of its own, whatever its size) where the pull is
+    issued (the twin of the ``h2d_bytes`` accounting).  An array that is
+    already numpy passes through uncounted."""
     import numpy as np
 
     host = tuple(np.asarray(x) for x in arrays)
-    pulled = sum(
+    pulled = [
         h.nbytes for h, x in zip(host, arrays)
         if not isinstance(x, np.ndarray)
-    )
+    ]
     if pulled:
-        record.add("d2h_bytes", pulled)
+        record.add("d2h_bytes", sum(pulled))
+        record.add("d2h_pulls", len(pulled))
     return host
 
 

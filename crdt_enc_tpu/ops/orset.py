@@ -363,6 +363,98 @@ def orset_plane_diff_rows(code, add_b, add_n, rm_n, *, size):
     )
 
 
+@partial(jax.jit, static_argnames=("size",))
+def orset_plane_diff_rows_tenants(code, add_b, add_n, rm_n, *, size):
+    """The serving layer's batched twin of :func:`orset_plane_diff_rows`:
+    one dispatch gathers a whole bucket's diff rows (``vmap`` of the same
+    gather over the tenant axis) and the five ``(T, size)`` arrays come
+    home in one pull instead of five per tenant.  ``size`` is one static
+    capacity for the bucket — the caller quantizes the LARGEST phase-1
+    count it will read through ``_bucket`` — so a slot with fewer diffs
+    is padded past its count exactly as the solo gather pads (``idx ==
+    cells``, zero values), and a slot with more (one the caller never
+    reads) is truncated."""
+    return jax.vmap(partial(orset_plane_diff_rows, size=size))(
+        code, add_b, add_n, rm_n
+    )
+
+
+# Slots per stack / unstack program.  One program over a whole 1,024-slot
+# bucket takes 3,072 operands (or results): the chip's compiler needs 20 s
+# and 12 s for the pair and leaves 40 MB of code on the device per bucket
+# class; at 128 slots the pair compiles in 3 s to 5 MB, and a bucket of
+# the largest class costs sixteen more launches (PERF.md, PR 25).
+TENANT_CHUNK = 128
+
+
+@jax.jit
+def _stack_chunk(clock_rows, add_rows, rm_rows, live):
+    keep = jnp.arange(len(clock_rows)) < live
+
+    def stack(rows):
+        s = jnp.stack(rows)
+        return jnp.where(jnp.expand_dims(keep, range(1, s.ndim)), s, 0)
+
+    return stack(clock_rows), stack(add_rows), stack(rm_rows)
+
+
+@jax.jit
+def _concat_chunks(clock, add, rm):
+    return jnp.concatenate(clock), jnp.concatenate(add), jnp.concatenate(rm)
+
+
+def orset_stack_tenants(clock_rows, add_rows, rm_rows, slots: int):
+    """A bucket's pre-fold plane stacks from its tenants' rows: three
+    lists of equally shaped rows (device arrays and host arrays mixed
+    freely), one entry a tenant, become the ``(slots, R)`` /
+    ``(slots, E, R)`` stacks the mega-fold consumes.  One program per
+    ``TENANT_CHUNK`` slots and one concatenate, never one per row; the
+    dummy slots past the tenants are zeroed inside the program (its
+    lists fill up with a row already in hand, and the count of live
+    rows is a traced scalar), so the compile class is the bucket class
+    alone, whatever the number of tenants."""
+    live = len(clock_rows)
+    parts = []
+    for lo in range(0, slots, TENANT_CHUNK):
+        n = min(TENANT_CHUNK, slots - lo)
+
+        def fill(rows):
+            return (rows[lo : lo + n] + rows[:1] * n)[:n]
+
+        parts.append(
+            _stack_chunk(
+                fill(clock_rows), fill(add_rows), fill(rm_rows),
+                np.int32(min(max(live - lo, 0), n)),
+            )
+        )
+    if len(parts) == 1:
+        return parts[0]
+    return _concat_chunks(*zip(*parts))
+
+
+@partial(jax.jit, static_argnames=("n",))
+def _unstack_chunk(clock, add, rm, lo, *, n):
+    def rows(x):
+        return jnp.unstack(jax.lax.dynamic_slice_in_dim(x, lo, n))
+
+    return rows(clock), rows(add), rows(rm)
+
+
+def orset_unstack_tenants(clock, add, rm):
+    """The inverse of :func:`orset_stack_tenants` for the post-fold
+    stacks: three lists of per-slot arrays, ``TENANT_CHUNK`` slots a
+    program, each array an owned buffer (a warm-tier entry pins its
+    tenant's planes, never the bucket's stack)."""
+    slots = clock.shape[0]
+    out: tuple[list, list, list] = ([], [], [])
+    for lo in range(0, slots, TENANT_CHUNK):
+        n = min(TENANT_CHUNK, slots - lo)
+        part = _unstack_chunk(clock, add, rm, np.int32(lo), n=n)
+        for rows, more in zip(out, part):
+            rows.extend(more)
+    return out
+
+
 def merge_rule(clock_a, add_a, rm_a, clock_b, add_b, rm_b, clock_merged):
     """The clock-filter merge on raw arrays (clocks already row-broadcast
     ready, ``clock_merged = max(clock_a, clock_b)`` supplied by the

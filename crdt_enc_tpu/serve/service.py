@@ -774,7 +774,6 @@ class FoldService:
 
     def _fold_orset_bucket(self, bi: int, bucket, by_idx) -> None:
         import jax
-        import jax.numpy as jnp
 
         from ..core.core import CHECKPOINT_FMT_ORSET
         from ..parallel.accel import TpuAccelerator
@@ -831,10 +830,6 @@ class FoldService:
             clock_rows.append(clock0)
             add_rows.append(add0)
             rm_rows.append(rm0)
-        for _ in range(T - len(bucket.tenants)):
-            clock_rows.append(jnp.zeros(R_b, jnp.int32))
-            add_rows.append(jnp.zeros((E_b, R_b), jnp.int32))
-            rm_rows.append(jnp.zeros((E_b, R_b), jnp.int32))
         # every HOST-sourced plane row uploads here (cold scans always;
         # warm-tier rows too on the CPU backend, where the tier stores
         # host views) plus the op columns; device-resident rows re-wrap
@@ -849,12 +844,14 @@ class FoldService:
             )
             + kind.nbytes + member.nbytes + actor.nbytes + counter.nbytes,
         )
-        # stack the pre-fold planes ONCE: the fold consumes them and —
-        # when any slot is cut-eligible — the plane diff reuses the very
-        # same device stacks as its base side
-        clock_s = jnp.stack(clock_rows)
-        add_s = jnp.stack(add_rows)
-        rm_s = jnp.stack(rm_rows)
+        # stack the pre-fold planes ONCE, a program per 128 slots and
+        # never one per tenant: the fold consumes the stacks and — when
+        # any slot is cut-eligible — the plane diff reuses the very same
+        # device stacks as its base side.  The dummy lanes past the
+        # tenants are made inside the programs
+        clock_s, add_s, rm_s = K.orset_stack_tenants(
+            clock_rows, add_rows, rm_rows, slots=T
+        )
         if self._mesh_active:
             # SPMD mega-fold: tenant lanes over dp, member planes over
             # mp (parallel.mesh.orset_fold_tenants_sharded) — slot and
@@ -876,6 +873,12 @@ class FoldService:
                 )
         with trace.span("serve.scatter", meta=bi):
             clock_all, add_all, rm_all = obs_runtime.pull(*out)
+            if self.warm is not None and not cpu_backend:
+                # the tenants' next-cycle resume planes, a program per
+                # 128 slots: each an owned device buffer, so a warm
+                # entry pins its tenant's planes and not the bucket's
+                # stacks
+                resume = K.orset_unstack_tenants(*out)
             for slot, key in enumerate(bucket.tenants):
                 w = by_idx[key]
                 _, _, _, _, members, replicas, entry = w.prepared
@@ -941,7 +944,7 @@ class FoldService:
                             rm_all[slot].copy(),
                         )
                     else:
-                        planes = (out[0][slot], out[1][slot], out[2][slot])
+                        planes = tuple(rows[slot] for rows in resume)
                     self.warm.store(
                         state, members, replicas, planes,
                         canon=entry.canon if entry is not None else None,
@@ -950,12 +953,15 @@ class FoldService:
             # device-cut delta sealing (docs/delta.md): diff the bucket's
             # pre-fold stacks (for eligible slots, byte-identical to the
             # tenants' sealed diff bases) against the post-fold planes in
-            # ONE dispatch, then D2H only the diff rows per eligible
-            # tenant and build the Orswot wire form from them.  Slots
-            # that are not cut-eligible ride the same dispatch for free
-            # and their code rows are simply never read.  A separate
-            # span, deliberately outside serve.scatter: attribution
-            # groups both under the seal stage without double-counting.
+            # ONE dispatch, gather every slot's diff rows in a second
+            # one, and bring the counts, the rows and the base stacks
+            # home in one pull each: the device is spoken to per bucket,
+            # and the loop over the eligible tenants below is host work
+            # only.  Slots that are not
+            # cut-eligible ride the same dispatches for free and their
+            # rows are simply never read.  A separate span, deliberately
+            # outside serve.scatter: attribution groups both under the
+            # seal stage without double-counting.
             from ..delta.codec import orset_delta_from_rows
 
             with trace.span("delta.cut", meta=bi):
@@ -970,21 +976,34 @@ class FoldService:
                         clock_s, add_s, rm_s, out[0], out[1], out[2]
                     )
                 (counts,) = obs_runtime.pull(counts)  # one (T,) D2H per bucket
-                cells = E_b * R_b
+                most = max(int(counts[slot]) for slot, _ in cut_slots)
+                if most:
+                    # O(diff rows) a slot, not O(state): one static
+                    # capacity for the bucket, the largest count that
+                    # will be read, quantized and capped by the same law
+                    # the per-tenant gather used
+                    rows_all = obs_runtime.pull(
+                        *K.orset_plane_diff_rows_tenants(
+                            code, add_s, out[1], out[2],
+                            size=min(_bucket(most), E_b * R_b),
+                        )
+                    )
+                # the base sides on the host: the clocks for the wire
+                # form, the planes for the seal-time self-verify (numpy
+                # views of one pull, so the verify on the seal workers
+                # rebuilds the base without touching the device); a
+                # bucket in which no eligible tenant verifies leaves the
+                # planes where they are
+                (base_clocks,) = obs_runtime.pull(clock_s)
+                base_adds = base_rms = None
+                if any(by_idx[key].core._delta_verify for _, key in cut_slots):
+                    base_adds, base_rms = obs_runtime.pull(add_s, rm_s)
                 for slot, key in cut_slots:
                     w = by_idx[key]
                     _, _, _, _, members, replicas, entry = w.prepared
-                    state = w.core._data.state
                     n_diff = int(counts[slot])
                     if n_diff:
-                        size = min(_bucket(n_diff), cells)
-                        rows = K.orset_plane_diff_rows(
-                            code[slot], add_s[slot], out[1][slot],
-                            out[2][slot], size=size,
-                        )
-                        # the ONLY per-tenant D2H of the cut: O(diff
-                        # rows), not O(state)
-                        rows = obs_runtime.pull(*rows)
+                        rows = tuple(r[slot, :n_diff] for r in rows_all)
                     else:
                         empty = np.zeros(0, np.int64)
                         rows = (empty, empty, empty, empty, empty)
@@ -993,7 +1012,7 @@ class FoldService:
                         members=members.items,
                         replicas=replicas.items,
                         row_width=R_b,
-                        base_clock=obs_runtime.pull(clock_rows[slot])[0],
+                        base_clock=base_clocks[slot],
                         new_clock=clock_all[slot],
                     )
                     # epoch-guarded candidate: _plan_delta_seal only
@@ -1002,10 +1021,10 @@ class FoldService:
                     w.delta_cut = {
                         "dobj": dobj,
                         "base_name": entry.seal_name,
-                        "mut": state._mut,
-                        "base_planes": (
-                            clock_rows[slot], add_rows[slot],
-                            rm_rows[slot], members, replicas,
+                        "mut": w.core._data.state._mut,
+                        "base_planes": None if base_adds is None else (
+                            base_clocks[slot], base_adds[slot],
+                            base_rms[slot], members, replicas,
                         ),
                     }
 

@@ -228,6 +228,75 @@ def test_cut_pipeline_differential_through_mesh(use_mesh):
     assert ucodec.pack(orset_delta_diff(base, new)) == ucodec.pack(dev)
 
 
+def _seeded_bucket(seed, T=8, E=16, R=8):
+    """A bucket's base and post-fold stacks with every kind of slot the
+    service meets: random diffs, a slot with none (same clock and
+    horizons, no entries), and a dummy slot (all zero)."""
+    rng = np.random.default_rng(seed)
+    mk = lambda: np.where(
+        rng.random((T, E, R)) < 0.25, rng.integers(1, 9, (T, E, R)), 0
+    ).astype(np.int32)
+    cb = rng.integers(0, 5, (T, R)).astype(np.int32)
+    cn = cb + rng.integers(0, 3, (T, R)).astype(np.int32)
+    ab, rb, an, rn = mk(), mk(), mk(), mk()
+    cn[2], rn[2], ab[2], an[2] = cb[2], rb[2], 0, 0  # nothing to cut
+    for x in (cb, cn, ab, rb, an, rn):
+        x[T - 1] = 0  # dummy slot
+    return cb, ab, rb, cn, an, rn
+
+
+@pytest.mark.parametrize("size_of", ["largest_count", "bucket_class", "cells"])
+@pytest.mark.parametrize("seed", [3, 4])
+def test_batched_row_gather_equals_per_tenant_gather(seed, size_of):
+    """``orset_plane_diff_rows_tenants`` is the per-tenant gather slot by
+    slot, all five arrays, padding included: for a slot with no diff, a
+    dummy slot, and a slot whose count equals ``size`` exactly."""
+    cb, ab, rb, cn, an, rn = _seeded_bucket(seed)
+    code, counts = K.orset_plane_diff_tenants(cb, ab, rb, cn, an, rn)
+    counts = np.asarray(counts)
+    assert counts[2] == 0 and counts[-1] == 0 and counts.max() > 0
+    cells = ab.shape[1] * ab.shape[2]
+    size = {
+        "largest_count": int(counts.max()),  # one slot fills it exactly
+        "bucket_class": min(_bucket(int(counts.max())), cells),
+        "cells": cells,
+    }[size_of]
+    got = K.orset_plane_diff_rows_tenants(code, ab, an, rn, size=size)
+    assert all(np.asarray(g).shape == (len(counts), size) for g in got)
+    for t in range(len(counts)):
+        want = K.orset_plane_diff_rows(code[t], ab[t], an[t], rn[t], size=size)
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g)[t], np.asarray(w)), t
+
+
+@pytest.mark.parametrize("live,chunk", [(8, 128), (5, 128), (5, 4), (3, 2)])
+def test_stack_and_unstack_tenants_roundtrip(live, chunk, monkeypatch):
+    """The bucket's stacks from lists of host and device rows mixed, the
+    dummy slots zeroed inside the programs, in one chunk or several (a
+    whole chunk of dummies among them); and the per-slot arrays back,
+    each an array of its own."""
+    import jax.numpy as jnp
+
+    from crdt_enc_tpu.ops import orset as orset_ops
+
+    monkeypatch.setattr(orset_ops, "TENANT_CHUNK", chunk)
+    cb, ab, rb, *_ = _seeded_bucket(11)
+    T = len(cb)
+    mix = lambda x: [
+        jnp.asarray(r) if t % 2 else r for t, r in enumerate(x[:live])
+    ]
+    got = K.orset_stack_tenants(mix(cb), mix(ab), mix(rb), slots=T)
+    for g, want in zip(got, (cb, ab, rb)):
+        want = want.copy()
+        want[live:] = 0
+        assert np.array_equal(np.asarray(g), want)
+    rows = K.orset_unstack_tenants(*got)
+    assert [len(r) for r in rows] == [T, T, T]
+    for r, g in zip(rows, got):
+        for t in range(T):
+            assert np.array_equal(np.asarray(r[t]), np.asarray(g)[t])
+
+
 # --------------------------------------- service: device cut + no-op
 
 
@@ -341,6 +410,120 @@ def test_device_cut_matches_host_diff_arm(storage_factory):
             assert solo.with_state(canonical_bytes) == served.with_state(
                 canonical_bytes
             ), arm
+
+    run(go())
+
+
+async def _fleet_two_cycles(n, mesh=None):
+    """``n`` one-writer tenants of one bucket class through a stamping
+    cycle and a cutting cycle; returns the service, the served cores and
+    the second cycle's counters."""
+    remotes = [MemoryRemote() for _ in range(n)]
+    writers = [
+        await Core.open(make_opts(MemoryStorage(r))) for r in remotes
+    ]
+    served = [
+        await Core.open(make_opts(MemoryStorage(r), delta=True))
+        for r in remotes
+    ]
+    service = FoldService(served, ServeConfig(), mesh=mesh)
+    for rnd, count in enumerate((9, 5)):
+        for t, w in enumerate(writers):
+            await w.apply_ops([
+                w.with_state(
+                    lambda s, m=b"%d-%d-%d" % (rnd, t, i): s.add_ctx(
+                        w.actor_id, m
+                    )
+                )
+                for i in range(count)
+            ])
+        trace.reset()
+        res = await service.run_cycle()
+        assert all(r.sealed and r.path == "batched" for r in res)
+        assert gauges().get("serve_buckets") == 1
+    return service, served, counters()
+
+
+@pytest.mark.parametrize(
+    "rows_on,mesh_spec,chunk",
+    [
+        ("host", None, 128),
+        ("device", None, 128),
+        ("device", (8, 1), 128),
+        ("device", (8, 1), 4),
+    ],
+)
+def test_device_is_spoken_to_per_bucket_not_per_tenant(
+    rows_on, mesh_spec, chunk, monkeypatch
+):
+    """The per-bucket law (ISSUE 25): a one-bucket cycle issues the same
+    number of blocking pulls for 4 tenants as for 12, every tenant cut
+    on device.  ``device`` steers the service onto the accelerator's
+    branch (the warm tier keeps the unstack programs' device arrays and
+    next cycle's stack takes them as they are), which the CPU backend
+    otherwise never runs, alone and on the mesh, in one chunk of slots
+    and in several."""
+    import jax
+
+    from crdt_enc_tpu.ops import orset as orset_ops
+
+    monkeypatch.setattr(orset_ops, "TENANT_CHUNK", chunk)
+    mesh = pmesh.make_mesh(mesh_spec) if mesh_spec else None
+    if rows_on == "device":
+        monkeypatch.setattr(jax, "default_backend", lambda: "not-the-cpu")
+
+    async def go():
+        pulls = {}
+        for n in (4, 12):
+            service, served, c = await _fleet_two_cycles(n, mesh)
+            assert c.get("delta_device_cuts") == n
+            assert c.get("delta_files_sealed") == n
+            assert not c.get("delta_seal_divergence")
+            pulls[n] = c.get("d2h_pulls")
+            entry = service.warm.lookup(served[0]._data.state)
+            assert all(
+                isinstance(p, jax.Array) == (rows_on == "device")
+                for p in entry.planes
+            )
+            assert [p.shape for p in entry.planes] == [(8,), (16, 8), (16, 8)]
+        # scatter 3, counts 1, diff rows 5, base stacks 3
+        assert pulls == {4: 12, 12: 12}
+
+    run(go())
+
+
+def test_cut_hands_the_verify_host_views_and_tampering_is_refused(
+    monkeypatch,
+):
+    """``delta_cut["base_planes"]`` are numpy views of the bucket's one
+    pull: the seal-time self-verify rebuilds the base from them without
+    a device sync, passes the genuine delta and still refuses a
+    tampered one."""
+    plans = []
+    real = Core._verify_delta_plan
+
+    def spy(self, plan):
+        plans.append((self, plan))
+        return real(self, plan)
+
+    monkeypatch.setattr(Core, "_verify_delta_plan", spy)
+
+    async def go():
+        await _fleet_two_cycles(3)
+        assert len(plans) == 3
+        for core, plan in plans:
+            assert plan.get("device_cut") and plan["base_state"] is None
+            clock, add, rm, _, _ = plan["base_planes"]
+            assert all(type(x) is np.ndarray for x in (clock, add, rm))
+            assert add.base is not None  # a view of the bucket's stack
+            trace.reset()
+            assert real(core, plan)
+            assert not counters().get("d2h_pulls")
+            bad = copy.deepcopy(plan["dobj"])
+            member = next(iter(bad[b"e"]))
+            rep = next(iter(bad[b"e"][member]))
+            bad[b"e"][member][rep] += 1
+            assert not real(core, dict(plan, dobj=bad))
 
     run(go())
 
