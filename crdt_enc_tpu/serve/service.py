@@ -720,6 +720,7 @@ class FoldService:
                 del by_idx[key]
         trace.gauge("serve_buckets", len(buckets))
         for bi, bucket in enumerate(buckets):
+            trace.add("serve_buckets_folded", 1)
             try:
                 if bucket.kind == "orset":
                     self._fold_orset_bucket(bi, bucket, by_idx)
@@ -787,6 +788,9 @@ class FoldService:
         E_b = _bucket(bucket.members)
         R_b = _bucket(bucket.replicas)
         T = bucket.slots
+        # the bucket's class, on its spans: the event log then tells a
+        # 512-slot bucket of 128-member tenants from one slot of 65,536
+        shape = f"{bi}:{T}x{N_b}x{E_b}x{R_b}"
         kind = np.zeros((T, N_b), np.int8)
         member = np.zeros((T, N_b), np.int32)
         actor = np.full((T, N_b), R_b, np.int32)  # dummy lanes: all-pad
@@ -798,6 +802,7 @@ class FoldService:
         # from planes already in hand — no host dict walk, no retained
         # base bytes (docs/delta.md "device-cut deltas")
         cut_slots: list[tuple[int, object]] = []
+        tenant_cells = 0
         for slot, key in enumerate(bucket.tenants):
             w = by_idx[key]
             k, m, a, c, members, replicas, entry = w.prepared
@@ -815,6 +820,7 @@ class FoldService:
             actor[slot, :n] = a
             counter[slot, :n] = c
             E, R = len(members), len(replicas)
+            tenant_cells += E * R
             if entry is not None:
                 clock0, add0, rm0 = TpuAccelerator._cached_planes_padded(
                     entry, E_b, R_b
@@ -830,6 +836,9 @@ class FoldService:
             clock_rows.append(clock0)
             add_rows.append(add0)
             rm_rows.append(rm0)
+        # the padding share: what the stacks hold against what is a tenant's
+        trace.add("serve_stack_cells", T * E_b * R_b)
+        trace.add("serve_tenant_cells", tenant_cells)
         # every HOST-sourced plane row uploads here (cold scans always;
         # warm-tier rows too on the CPU backend, where the tier stores
         # host views) plus the op columns; device-resident rows re-wrap
@@ -859,19 +868,19 @@ class FoldService:
             from ..parallel import mesh as pmesh
 
             orset_step, _ = pmesh.tenant_fold_steps(self.mesh)
-            with trace.span("serve.shard", meta=bi):
+            with trace.span("serve.shard", meta=shape):
                 out = orset_step(
                     clock_s, add_s, rm_s, kind, member, actor, counter,
                 )
             trace.add("serve_sharded_folds", 1)
             trace.add("serve_sharded_tenants", len(bucket.tenants))
         else:
-            with trace.span("serve.fold", meta=bi):
+            with trace.span("serve.fold", meta=shape):
                 out = K.orset_fold_tenants(
                     clock_s, add_s, rm_s, kind, member, actor, counter,
                     num_members=E_b, num_replicas=R_b,
                 )
-        with trace.span("serve.scatter", meta=bi):
+        with trace.span("serve.scatter", meta=shape):
             clock_all, add_all, rm_all = obs_runtime.pull(*out)
             if self.warm is not None and not cpu_backend:
                 # the tenants' next-cycle resume planes, a program per
@@ -964,7 +973,7 @@ class FoldService:
             # seal stage without double-counting.
             from ..delta.codec import orset_delta_from_rows
 
-            with trace.span("delta.cut", meta=bi):
+            with trace.span("delta.cut", meta=shape):
                 if self._mesh_active:
                     from ..parallel import mesh as pmesh
 
@@ -1032,6 +1041,7 @@ class FoldService:
         N_b = _bucket(bucket.rows)
         R_b = _bucket(bucket.replicas)
         T = bucket.slots
+        shape = f"{bi}:{T}x{N_b}x0x{R_b}"
         actor = np.full((T, N_b), R_b, np.int32)
         counter = np.zeros((T, N_b), np.int32)
         clock0 = np.zeros((T, R_b), np.int32)
@@ -1049,16 +1059,16 @@ class FoldService:
             from ..parallel import mesh as pmesh
 
             _, gcounter_step = pmesh.tenant_fold_steps(self.mesh)
-            with trace.span("serve.shard", meta=bi):
+            with trace.span("serve.shard", meta=shape):
                 out = gcounter_step(clock0, actor, counter)
             trace.add("serve_sharded_folds", 1)
             trace.add("serve_sharded_tenants", len(bucket.tenants))
         else:
-            with trace.span("serve.fold", meta=bi):
+            with trace.span("serve.fold", meta=shape):
                 out = K.gcounter_fold_tenants(
                     clock0, actor, counter, num_replicas=R_b
                 )
-        with trace.span("serve.scatter", meta=bi):
+        with trace.span("serve.scatter", meta=shape):
             (out_all,) = obs_runtime.pull(out)
             for slot, key in enumerate(bucket.tenants):
                 w = by_idx[key]
@@ -1107,10 +1117,18 @@ class FoldService:
             )
             try:
                 if w.result.path == "solo":
-                    ok = spill_accel.fold_payloads(
-                        core._data.state, list(w.payloads),
-                        actors_hint=w.actors_sorted,
-                    )
+                    # a spilled tenant folds at power-of-two vocabulary
+                    # classes from here on, like every bucket: its
+                    # vocabulary grows by a few members a cycle, and the
+                    # solo dense fold otherwise compiles per exact
+                    # (members, replicas) — a compile a cycle, for ever
+                    if getattr(spill_accel, "bucket_vocab", None) is False:
+                        spill_accel.bucket_vocab = True
+                    with trace.span("serve.solo", meta=w.idx):
+                        ok = spill_accel.fold_payloads(
+                            core._data.state, list(w.payloads),
+                            actors_hint=w.actors_sorted,
+                        )
                     if ok:
                         core._advance_cursors(w.metas)
                     else:
