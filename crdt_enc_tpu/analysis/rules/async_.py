@@ -12,7 +12,8 @@ population runner (PR 18) and the serve tier depend on:
 * no ``await`` inside a declared *sync section* — a region bracketed by
   ``# lint: sync-section-begin`` / ``# lint: sync-section-end`` whose
   correctness depends on not yielding to the loop (the compaction
-  snapshot/cursor/delta-plan cut in ``core._compact_seal``).
+  snapshot/cursor/delta-plan cut was one until it became the plain
+  function ``core._plan_seal``).
 
 Findings carry the provenance chain: the call path from the async body
 down to the line that actually blocks.  When the effect arrives *via*

@@ -187,8 +187,14 @@ class FsStorage(Storage):
     async def load_local_meta(self) -> bytes | None:
         return await self._run(_read_file, self._local_meta_path())
 
+    # The seven calls of the seal tail exist as plain functions
+    # (``*_sync``, the port's optional sync twins: core/storage.py); the
+    # awaitables are the same functions handed to ``_run``.
+    def store_local_meta_sync(self, data: bytes) -> None:
+        _write_file_atomic(self._local_meta_path(), bytes(data))
+
     async def store_local_meta(self, data: bytes) -> None:
-        await self._run(_write_file_atomic, self._local_meta_path(), bytes(data))
+        await self._run(self.store_local_meta_sync, data)
 
     # -- local fold checkpoint ---------------------------------------------
     # Same durability discipline as the local meta: tmp + fsync + atomic
@@ -197,10 +203,11 @@ class FsStorage(Storage):
     async def load_local_checkpoint(self) -> bytes | None:
         return await self._run(_read_file, self._local_checkpoint_path())
 
+    def store_local_checkpoint_sync(self, data: bytes) -> None:
+        _write_file_atomic(self._local_checkpoint_path(), bytes(data))
+
     async def store_local_checkpoint(self, data: bytes) -> None:
-        await self._run(
-            _write_file_atomic, self._local_checkpoint_path(), bytes(data)
-        )
+        await self._run(self.store_local_checkpoint_sync, data)
 
     async def remove_local_checkpoint(self) -> None:
         await self._run(_remove_quiet, self._local_checkpoint_path())
@@ -217,15 +224,16 @@ class FsStorage(Storage):
         loaded = await asyncio.gather(*(one(n) for n in names))
         return [x for x in loaded if x is not None]
 
-    async def _store_ca(self, d: str, data: bytes) -> str:
+    @staticmethod
+    def _store_ca(d: str, data: bytes) -> str:
         name = content_name(data)
-        await self._run(_write_file_new, os.path.join(d, name), bytes(data))
+        _write_file_new(os.path.join(d, name), bytes(data))
         return name
 
-    async def _remove_ca(self, d: str, names: list[str]) -> None:
-        await asyncio.gather(
-            *(self._run(_remove_quiet, os.path.join(d, n)) for n in names)
-        )
+    @staticmethod
+    def _remove_ca(d: str, names: list[str]) -> None:
+        for n in names:
+            _remove_quiet(os.path.join(d, n))
 
     async def list_remote_meta_names(self) -> list[str]:
         return await self._list_ca(self._meta_dir())
@@ -234,10 +242,10 @@ class FsStorage(Storage):
         return await self._load_ca(self._meta_dir(), names)
 
     async def store_remote_meta(self, data: bytes) -> str:
-        return await self._store_ca(self._meta_dir(), data)
+        return await self._run(self._store_ca, self._meta_dir(), data)
 
     async def remove_remote_metas(self, names: list[str]) -> None:
-        await self._remove_ca(self._meta_dir(), names)
+        await self._run(self._remove_ca, self._meta_dir(), names)
 
     async def list_state_names(self) -> list[str]:
         return await self._list_ca(self._states_dir())
@@ -245,11 +253,17 @@ class FsStorage(Storage):
     async def load_states(self, names: list[str]) -> list[tuple[str, bytes]]:
         return await self._load_ca(self._states_dir(), names)
 
+    def store_state_sync(self, data: bytes) -> str:
+        return self._store_ca(self._states_dir(), data)
+
     async def store_state(self, data: bytes) -> str:
-        return await self._store_ca(self._states_dir(), data)
+        return await self._run(self.store_state_sync, data)
+
+    def remove_states_sync(self, names: list[str]) -> None:
+        self._remove_ca(self._states_dir(), names)
 
     async def remove_states(self, names: list[str]) -> None:
-        await self._remove_ca(self._states_dir(), names)
+        await self._run(self.remove_states_sync, names)
 
     # -- op logs -----------------------------------------------------------
     async def list_op_actors(self) -> list[Actor]:
@@ -625,35 +639,43 @@ class FsStorage(Storage):
         )
         return [item for chunk in per_actor for item in chunk]
 
-    async def store_ops(self, actor: Actor, version: int, data: bytes) -> None:
-        import functools
-
-        path = os.path.join(self._ops_dir(actor), str(version))
+    @staticmethod
+    def _store_versioned(d: str, version: int, data: bytes) -> None:
         # version-addressed: a vanished collider BURNS the version (the
         # caller probes forward) — see _write_file_new's contract
-        await self._run(
-            functools.partial(
-                _write_file_new, path, bytes(data),
-                relink_vanished_collider=False,
-            )
+        _write_file_new(
+            os.path.join(d, str(version)), bytes(data),
+            relink_vanished_collider=False,
         )
 
-    async def remove_ops(self, actor_last_versions: list[tuple[Actor, int]]) -> None:
-        def rm(actor: Actor, last: int) -> None:
-            d = self._ops_dir(actor)
-            for n in _list_dir(d):
-                try:
-                    v = int(n)
-                except ValueError:
-                    continue
-                if v <= last:
-                    _remove_quiet(os.path.join(d, n))
+    @staticmethod
+    def _remove_prefix(d: str, last: int) -> None:
+        """Every version ≤ ``last`` of one actor's log directory."""
+        for n in _list_dir(d):
             try:
-                os.rmdir(d)  # tidy an emptied actor dir; fails if ops remain
-            except OSError:
-                pass
+                v = int(n)
+            except ValueError:
+                continue
+            if v <= last:
+                _remove_quiet(os.path.join(d, n))
+        try:
+            os.rmdir(d)  # tidy an emptied actor dir; fails if files remain
+        except OSError:
+            pass
 
-        await asyncio.gather(*(self._run(rm, a, last) for a, last in actor_last_versions))
+    async def store_ops(self, actor: Actor, version: int, data: bytes) -> None:
+        await self._run(
+            self._store_versioned, self._ops_dir(actor), version, data
+        )
+
+    def remove_ops_sync(
+        self, actor_last_versions: list[tuple[Actor, int]]
+    ) -> None:
+        for actor, last in actor_last_versions:
+            self._remove_prefix(self._ops_dir(actor), last)
+
+    async def remove_ops(self, actor_last_versions: list[tuple[Actor, int]]) -> None:
+        await self._run(self.remove_ops_sync, actor_last_versions)
 
     # -- delta snapshots ---------------------------------------------------
     # Same layout idiom as the op logs (``remote/deltas/<actor-hex>/<N>``)
@@ -694,36 +716,20 @@ class FsStorage(Storage):
         )
         return [item for chunk in per_actor for item in chunk]
 
-    async def store_delta(self, actor: Actor, version: int, data: bytes) -> None:
-        import functools
+    def store_delta_sync(self, actor: Actor, version: int, data: bytes) -> None:
+        # version-addressed like op files (the producer probes forward)
+        self._store_versioned(self._deltas_dir(actor), version, data)
 
-        path = os.path.join(self._deltas_dir(actor), str(version))
-        # version-addressed like op files: a vanished collider burns the
-        # version (the producer probes forward) — _write_file_new's contract
-        await self._run(
-            functools.partial(
-                _write_file_new, path, bytes(data),
-                relink_vanished_collider=False,
-            )
-        )
+    async def store_delta(self, actor: Actor, version: int, data: bytes) -> None:
+        await self._run(self.store_delta_sync, actor, version, data)
+
+    def remove_deltas_sync(
+        self, actor_last_versions: list[tuple[Actor, int]]
+    ) -> None:
+        for actor, last in actor_last_versions:
+            self._remove_prefix(self._deltas_dir(actor), last)
 
     async def remove_deltas(
         self, actor_last_versions: list[tuple[Actor, int]]
     ) -> None:
-        def rm(actor: Actor, last: int) -> None:
-            d = self._deltas_dir(actor)
-            for n in _list_dir(d):
-                try:
-                    v = int(n)
-                except ValueError:
-                    continue
-                if v <= last:
-                    _remove_quiet(os.path.join(d, n))
-            try:
-                os.rmdir(d)
-            except OSError:
-                pass
-
-        await asyncio.gather(
-            *(self._run(rm, a, last) for a, last in actor_last_versions)
-        )
+        await self._run(self.remove_deltas_sync, actor_last_versions)

@@ -16,8 +16,11 @@ class IdentityCryptor(Cryptor):
         return VersionBytes(IDENTITY_KEY_VERSION_1, secrets.token_bytes(32))
 
     async def encrypt(self, key: VersionBytes, data: bytes) -> bytes:
+        return self.encrypt_fn(key)(data)
+
+    def encrypt_fn(self, key: VersionBytes):
         key.ensure_version(IDENTITY_KEY_VERSION_1)
-        return VersionBytes(IDENTITY_DATA_VERSION_1, data).serialize()
+        return lambda data: VersionBytes(IDENTITY_DATA_VERSION_1, data).serialize()
 
     async def decrypt(self, key: VersionBytes, data: bytes) -> bytes:
         key.ensure_version(IDENTITY_KEY_VERSION_1)

@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import base64
 import hashlib
+import threading
 from dataclasses import dataclass, field
 
 from ..core.storage import Storage
@@ -29,6 +30,24 @@ class MemoryRemote:
     deltas: dict = field(default_factory=dict)  # actor -> {version: bytes}
 
 
+# The seal tail's sync twins run on worker threads, several replicas of
+# one remote at once: a removal that walks a log and drops it when empty
+# must not interleave with a store into that log.  One lock for every
+# remote (a field would end ``copy.deepcopy(remote)``, which tests use).
+_LOG_LOCK = threading.Lock()
+
+
+def _remove_prefixes(logs: dict, actor_last_versions) -> None:
+    for actor, last in actor_last_versions:
+        log = logs.get(actor)
+        if not log:
+            continue
+        for v in [v for v in log if v <= last]:
+            del log[v]
+        if not log:
+            del logs[actor]
+
+
 class MemoryStorage(Storage):
     def __init__(self, remote: MemoryRemote | None = None):
         self.remote = remote if remote is not None else MemoryRemote()
@@ -39,15 +58,24 @@ class MemoryStorage(Storage):
     async def load_local_meta(self) -> bytes | None:
         return self._local_meta
 
-    async def store_local_meta(self, data: bytes) -> None:
+    # The seven calls of the seal tail are plain functions (``*_sync``,
+    # the port's optional sync twins: core/storage.py) with the
+    # awaitables written over them; nothing here ever awaited anything.
+    def store_local_meta_sync(self, data: bytes) -> None:
         self._local_meta = bytes(data)
+
+    async def store_local_meta(self, data: bytes) -> None:
+        self.store_local_meta_sync(data)
 
     # -- local fold checkpoint ---------------------------------------------
     async def load_local_checkpoint(self) -> bytes | None:
         return self._local_checkpoint
 
-    async def store_local_checkpoint(self, data: bytes) -> None:
+    def store_local_checkpoint_sync(self, data: bytes) -> None:
         self._local_checkpoint = bytes(data)
+
+    async def store_local_checkpoint(self, data: bytes) -> None:
+        self.store_local_checkpoint_sync(data)
 
     async def remove_local_checkpoint(self) -> None:
         self._local_checkpoint = None
@@ -75,14 +103,20 @@ class MemoryStorage(Storage):
     async def load_states(self, names: list[str]) -> list[tuple[str, bytes]]:
         return [(n, self.remote.states[n]) for n in names if n in self.remote.states]
 
-    async def store_state(self, data: bytes) -> str:
+    def store_state_sync(self, data: bytes) -> str:
         name = content_name(data)
         self.remote.states.setdefault(name, bytes(data))
         return name
 
-    async def remove_states(self, names: list[str]) -> None:
+    async def store_state(self, data: bytes) -> str:
+        return self.store_state_sync(data)
+
+    def remove_states_sync(self, names: list[str]) -> None:
         for n in names:
             self.remote.states.pop(n, None)
+
+    async def remove_states(self, names: list[str]) -> None:
+        self.remove_states_sync(names)
 
     # -- ops ---------------------------------------------------------------
     async def list_op_actors(self) -> list[Actor]:
@@ -95,8 +129,10 @@ class MemoryStorage(Storage):
         for actor, first in actor_first_versions:
             log = self.remote.ops.get(actor, {})
             v = first
-            while v in log:  # gap-free scan (crdt-enc-tokio lib.rs:254-269)
-                out.append((actor, v, log[v]))
+            # gap-free scan (crdt-enc-tokio lib.rs:254-269); a file a
+            # worker's GC takes between the probe and the read ends it
+            while (raw := log.get(v)) is not None:
+                out.append((actor, v, raw))
                 v += 1
         return out
 
@@ -107,26 +143,28 @@ class MemoryStorage(Storage):
         for actor, first in actor_first_versions:
             log = self.remote.ops.get(actor, {})
             v = first
-            while v in log:
-                out.append((actor, v, len(log[v])))
+            while (raw := log.get(v)) is not None:
+                out.append((actor, v, len(raw)))
                 v += 1
         return out
 
     async def store_ops(self, actor: Actor, version: int, data: bytes) -> None:
-        log = self.remote.ops.setdefault(actor, {})
-        if version in log:
-            raise FileExistsError(f"op v{version} already exists for this actor")
-        log[version] = bytes(data)
+        with _LOG_LOCK:
+            log = self.remote.ops.setdefault(actor, {})
+            if version in log:
+                raise FileExistsError(
+                    f"op v{version} already exists for this actor"
+                )
+            log[version] = bytes(data)
+
+    def remove_ops_sync(
+        self, actor_last_versions: list[tuple[Actor, int]]
+    ) -> None:
+        with _LOG_LOCK:
+            _remove_prefixes(self.remote.ops, actor_last_versions)
 
     async def remove_ops(self, actor_last_versions: list[tuple[Actor, int]]) -> None:
-        for actor, last in actor_last_versions:
-            log = self.remote.ops.get(actor)
-            if not log:
-                continue
-            for v in [v for v in log if v <= last]:
-                del log[v]
-            if not log:
-                del self.remote.ops[actor]
+        self.remove_ops_sync(actor_last_versions)
 
     # -- delta snapshots ---------------------------------------------------
     has_deltas = True
@@ -139,27 +177,33 @@ class MemoryStorage(Storage):
     ) -> list[tuple[Actor, int, bytes]]:
         out = []
         for actor, first in actor_first_versions:
-            log = self.remote.deltas.get(actor, {})
             # sorted, holes tolerated: density is not part of the delta
             # contract (chain validity comes from the base-name links)
+            with _LOG_LOCK:
+                log = dict(self.remote.deltas.get(actor, {}))
             for v in sorted(v for v in log if v >= first):
                 out.append((actor, v, log[v]))
         return out
 
+    def store_delta_sync(self, actor: Actor, version: int, data: bytes) -> None:
+        with _LOG_LOCK:
+            log = self.remote.deltas.setdefault(actor, {})
+            if version in log:
+                raise FileExistsError(
+                    f"delta v{version} already exists for this actor"
+                )
+            log[version] = bytes(data)
+
     async def store_delta(self, actor: Actor, version: int, data: bytes) -> None:
-        log = self.remote.deltas.setdefault(actor, {})
-        if version in log:
-            raise FileExistsError(f"delta v{version} already exists for this actor")
-        log[version] = bytes(data)
+        self.store_delta_sync(actor, version, data)
+
+    def remove_deltas_sync(
+        self, actor_last_versions: list[tuple[Actor, int]]
+    ) -> None:
+        with _LOG_LOCK:
+            _remove_prefixes(self.remote.deltas, actor_last_versions)
 
     async def remove_deltas(
         self, actor_last_versions: list[tuple[Actor, int]]
     ) -> None:
-        for actor, last in actor_last_versions:
-            log = self.remote.deltas.get(actor)
-            if not log:
-                continue
-            for v in [v for v in log if v <= last]:
-                del log[v]
-            if not log:
-                del self.remote.deltas[actor]
+        self.remove_deltas_sync(actor_last_versions)

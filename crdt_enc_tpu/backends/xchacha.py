@@ -12,6 +12,7 @@ holds no Python state so threads scale to the pool width.
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import os
 import secrets
@@ -382,8 +383,13 @@ class XChaChaCryptor(Cryptor):
         return VersionBytes(XCHACHA_KEY_VERSION_1, secrets.token_bytes(KEY_LEN))
 
     async def encrypt(self, key: VersionBytes, data: bytes) -> bytes:
+        return await asyncio.to_thread(self.encrypt_fn(key), data)
+
+    def encrypt_fn(self, key: VersionBytes):
+        """Sync seal twin for the seal tail's one worker job; the
+        envelope ``encrypt`` produces (it is written over this)."""
         key.ensure_version(XCHACHA_KEY_VERSION_1)
-        return await asyncio.to_thread(encrypt_blob, key.content, data)
+        return functools.partial(encrypt_blob, key.content)
 
     async def decrypt(self, key: VersionBytes, data: bytes) -> bytes:
         key.ensure_version(XCHACHA_KEY_VERSION_1)

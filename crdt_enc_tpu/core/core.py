@@ -43,10 +43,11 @@ from ..utils.versions import (
     CURRENT_CONTAINER_VERSION,
     SUPPORTED_CONTAINER_VERSIONS,
 )
+from . import twins
 from .adapters import CrdtAdapter, HostAccelerator
 from .cryptor import Cryptor
 from .key_cryptor import Key, KeyCryptor, Keys
-from .storage import Storage
+from .storage import SEAL_TAIL_TWINS, Storage
 
 IO_CONCURRENCY = 16  # bounded pipeline width (reference lib.rs:452,512)
 BULK_MIN_FILES = 16  # below this the per-file asyncio path is cheaper
@@ -329,6 +330,103 @@ class _MutData:
         # version already scanned (applied OR skipped) — the next read
         # loads only past it, and compaction GCs the consumed prefix
         self.read_deltas: dict[Actor, int] = {}
+
+
+@dataclass
+class _SealPlan:
+    """What one seal tail works from, cut from the live replica in one
+    loop slice (``Core._plan_seal``).  The steps own everything in it
+    but ``local_meta`` (see ``Core._seal_delta``)."""
+
+    key: Key  # the latest data key: all three blobs seal under it
+    snapshot: tuple  # the payload's items, packed: state, cursor, sealer
+    snap_mut: int | None  # the state's mutation epoch at the cut
+    delta: dict | None  # _plan_delta_seal's plan
+    clock: VClock  # ingest cursor and cursor matrix, for the
+    matrix: dict  # delta's stability watermark
+    local_meta: LocalMeta
+    last_delta_version: int
+    prior_names: frozenset  # snapshots folded into the state
+    states_to_remove: list
+    ops_to_remove: list
+    deltas_to_remove: list
+    checkpoint: dict | None  # _plan_checkpoint's payload, if enabled
+
+
+@dataclass
+class _SealOutcome:
+    """What the steps of one tail did, for ``Core._commit_seal``: a
+    field is set once its step is through, so a failed tail commits
+    exactly what it made durable."""
+
+    name: str | None = None  # the snapshot, durable
+    delta_version: int | None = None  # own delta published here
+    base: tuple | None = None  # (name, bytes | None, cursor) to retain
+    stale_states: list | None = None  # GC done; these snapshots removed
+    checkpoint_sig: tuple | None = None  # checkpoint durable
+    error: BaseException | None = None  # what stopped the steps
+
+
+class _LoopPorts:
+    """The seal tail's ports as the plugins give them: every call is
+    the plugin's own awaitable, awaited on the event loop."""
+
+    def __init__(self, core: "Core"):
+        self._storage = core.storage
+        self.encrypt = core.cryptor.encrypt
+
+    def __getattr__(self, name):
+        return getattr(self._storage, name)
+
+    offload = staticmethod(asyncio.to_thread)
+
+    @staticmethod
+    async def both(a, b) -> None:
+        await asyncio.gather(a, b)
+
+
+class _JobPorts:
+    """The same ports over their sync twins, for one worker-thread job:
+    each call has run to its end by the time its await is reached."""
+
+    def __init__(self, storage, encrypt_fn):
+        self._storage = storage
+        self._encrypt = encrypt_fn  # bound to the plan's key
+
+    async def encrypt(self, _material, data: bytes) -> bytes:
+        return self._encrypt(data)
+
+    def __getattr__(self, name):
+        twin = getattr(self._storage, name + "_sync")
+
+        async def call(*args):
+            return twin(*args)
+
+        return call
+
+    @staticmethod
+    async def offload(fn, *args):
+        return fn(*args)
+
+    @staticmethod
+    async def both(a, b) -> None:
+        # gather's contract without a loop: the second runs whatever the
+        # first did (there it was already started)
+        try:
+            await a
+        finally:
+            await b
+
+
+def _run_to_end(coro):
+    """Drive a coroutine none of whose awaits suspends (the seal tail
+    over :class:`_JobPorts`) to its result, with no event loop."""
+    try:
+        coro.send(None)
+    except StopIteration as stop:
+        return stop.value
+    coro.close()
+    raise RuntimeError("a sync port twin awaited the event loop")
 
 
 class Core:
@@ -924,9 +1022,63 @@ class Core:
     def _unpack_checkpoint_state(self, fmt: int, st):
         return unpack_checkpoint_state(self.adapter, fmt, st)
 
-    async def save_checkpoint(
-        self, *, _packed: tuple | None = None, _snap: tuple | None = None
-    ) -> bool:
+    def _plan_checkpoint(self, _packed: tuple | None = None) -> dict:
+        """The checkpoint payload, every mutable input materialized in
+        the calling loop slice so a concurrent apply cannot tear the
+        (state, cursor) pair.  Its state part owns what it holds
+        (packed row buffers, or a ``state_to_obj`` copy), so the
+        payload may be packed later, off the loop.
+
+        ``_packed`` is the fold service's pre-packed state payload,
+        ``(fmt, obj, mut_epoch)``: the service packs from the dense
+        planes it already holds (no sparse walk), and the epoch guards
+        staleness — if the state mutated since packing (a concurrent
+        apply), the live state is re-packed here instead."""
+        d = self._data
+        if (
+            _packed is not None
+            and _packed[2] == getattr(d.state, "_mut", None)
+        ):
+            fmt, st = _packed[0], _packed[1]
+        else:
+            fmt, st = self._pack_checkpoint_state()
+        payload = {
+            b"fmt": fmt,
+            b"state": st,
+            b"cursor": d.next_op_versions.to_obj(),
+            b"rs": sorted(d.read_states),
+            b"fp": self._checkpoint_fingerprint(),
+            # the cursor matrix rides along so a warm open keeps its
+            # replication view (stability watermark continuity);
+            # observational only — never part of the fingerprint
+            b"cm": {
+                a: c.to_obj() for a, c in sorted(d.cursor_matrix.items())
+            },
+            # delta-chain continuity (observational): the per-sealer
+            # delta consumption cursor
+            b"rd": dict(sorted(d.read_deltas.items())),
+        }
+        if self._stable is not None and self._stable.cursor.counters:
+            # the stable prefix only grows, so it is checkpointable
+            # as-is (docs/strong_reads.md): a warm reopen resumes
+            # the exposed strong-read frontier instead of
+            # restarting the session guarantee from bottom.
+            # Observational — never fingerprinted; a malformed slot
+            # costs a cold prefix rebuild, never a wrong read.
+            payload[b"sp"] = self._stable.to_obj()
+        return payload
+
+    async def _store_checkpoint(self, payload: dict, key: Key, ports) -> tuple:
+        """Seal and store a planned checkpoint payload; returns the
+        signature that gates no-op reseals once the store is durable."""
+        blob = await self._seal_packed(
+            key, codec.pack(payload), ports.encrypt
+        )
+        await ports.store_local_checkpoint(blob)
+        trace.add("checkpoint_bytes", len(blob))
+        return (dict(payload[b"cursor"]), frozenset(payload[b"rs"]))
+
+    async def save_checkpoint(self) -> bool:
         """Seal the materialized state + ingest cursor + read-states set
         as this replica's local warm-open checkpoint (sealed with the
         normal data-key cryptor, stored through the storage port's
@@ -936,76 +1088,16 @@ class Core:
         cursor is a complete, safe resume point.  Returns False when
         checkpointing is disabled on this core.
 
-        ``_packed`` is the fold service's pre-packed state payload,
-        ``(fmt, obj, mut_epoch)``: the service packs from the dense
-        planes it already holds (no sparse walk), and the epoch guards
-        staleness — if the state mutated since packing (a concurrent
-        apply), the live state is re-packed here instead, so the sealed
-        (state, cursor) pair can never tear.
-
-        ``_snap`` is ``(snapshot_name, mut_epoch)`` from the compaction
-        seal tail: when the live state PROVABLY still equals the just-
-        sealed snapshot (same mutation epoch), the checkpoint records
-        the snapshot's name (``b"snap"``), so a warm reopen can restore
-        the delta-sealing base and keep its delta chain unbroken
-        (docs/delta.md).  States without a mutation epoch never record
-        it — a wrong base would seal wrong deltas, a missing one only
-        costs consumers one full snapshot read."""
+        A compaction writes its own checkpoint as the last step of its
+        seal tail (:meth:`_seal_steps`), from the same two halves."""
         if not self._checkpoint_enabled:
             return False
         with trace.span("checkpoint.save"):
-            # sync section: every mutable input is materialized before
-            # the first await, so a concurrent apply cannot tear the
-            # (state, cursor) pair
-            d = self._data
-            if (
-                _packed is not None
-                and _packed[2] == getattr(d.state, "_mut", None)
-            ):
-                fmt, st = _packed[0], _packed[1]
-            else:
-                fmt, st = self._pack_checkpoint_state()
-            sig = (
-                dict(d.next_op_versions.counters), frozenset(d.read_states)
+            payload = self._plan_checkpoint()
+            # only a DURABLE seal gates skips
+            self._checkpoint_sig = await self._store_checkpoint(
+                payload, self._latest_key(), _LoopPorts(self)
             )
-            payload = {
-                b"fmt": fmt,
-                b"state": st,
-                b"cursor": d.next_op_versions.to_obj(),
-                b"rs": sorted(d.read_states),
-                b"fp": self._checkpoint_fingerprint(),
-                # the cursor matrix rides along so a warm open keeps its
-                # replication view (stability watermark continuity);
-                # observational only — never part of the fingerprint
-                b"cm": {
-                    a: c.to_obj() for a, c in sorted(d.cursor_matrix.items())
-                },
-                # delta-chain continuity (both observational): the
-                # per-sealer delta consumption cursor, and — only when
-                # the epoch proves state == sealed snapshot — its name
-                b"rd": dict(sorted(d.read_deltas.items())),
-            }
-            if (
-                self._stable is not None
-                and self._stable.cursor.counters
-            ):
-                # the stable prefix only grows, so it is checkpointable
-                # as-is (docs/strong_reads.md): a warm reopen resumes
-                # the exposed strong-read frontier instead of
-                # restarting the session guarantee from bottom.
-                # Observational — never fingerprinted; a malformed slot
-                # costs a cold prefix rebuild, never a wrong read.
-                payload[b"sp"] = self._stable.to_obj()
-            if (
-                _snap is not None
-                and _snap[1] is not None
-                and _snap[1] == getattr(d.state, "_mut", None)
-            ):
-                payload[b"snap"] = _snap[0].encode()
-            blob = await self._seal(payload)
-            await self.storage.store_local_checkpoint(blob)
-            self._checkpoint_sig = sig  # only a DURABLE seal gates skips
-            trace.add("checkpoint_bytes", len(blob))
         return True
 
     async def _checkpoint_fallback(self, reason: str) -> bool:
@@ -1145,14 +1237,20 @@ class Core:
         return key
 
     async def _seal(self, payload_obj) -> bytes:
+        return await self._seal_packed(
+            self._latest_key(), codec.pack(payload_obj), self.cryptor.encrypt
+        )
+
+    async def _seal_packed(self, key: Key, content: bytes, encrypt) -> bytes:
         """inner(data version) → cipher middle → outer(container), with the
         sealing key's id recorded in the outer layer so readers can select
         the right key after rotation or concurrent bootstrap (the reference
         decrypts everything with the current latest key, lib.rs:437-441,
-        which loses data once two keys exist — deliberately fixed here)."""
-        inner = VersionBytes(self.current_data_version, codec.pack(payload_obj))
-        key = self._latest_key()
-        middle = await self.cryptor.encrypt(key.material, inner.serialize())
+        which loses data once two keys exist — deliberately fixed here).
+        ``content`` is the canonically packed payload; ``encrypt`` is the
+        cryptor port's, or the seal job's twin of it."""
+        inner = VersionBytes(self.current_data_version, content)
+        middle = await encrypt(key.material, inner.serialize())
         return VersionBytes(
             CURRENT_CONTAINER_VERSION, codec.pack([key.id, middle])
         ).serialize()
@@ -2354,76 +2452,80 @@ class Core:
                 logger.warning("delta verify crashed", exc_info=True)
                 return False
 
-    async def _seal_delta(self, plan, name: str) -> None:
-        """Await half of the delta seal: wire-build, seal with the data
-        key, publish at the next own-log version (FileExistsError
-        probes forward — the op-file discipline), persist the bumped
-        local-meta cursor, and retain the new base.  A delta-less round
-        (``dobj`` None) wipes the own log instead: a chain that cannot
-        extend to the new snapshot is dead weight every consumer would
-        scan and fall back on."""
+    async def _seal_delta(self, plan, name: str, ports, out) -> None:
+        """The delta steps of the seal tail (:meth:`_seal_steps`):
+        wire-build, seal with the data key, publish at the next own-log
+        version (FileExistsError probes forward — the op-file
+        discipline), persist the bumped local-meta cursor, and name the
+        new base to retain.  A delta-less round (``dobj`` None) wipes
+        the own log instead: a chain that cannot extend to the new
+        snapshot is dead weight every consumer would scan and fall back
+        on.  Bookkeeping is reported on ``out``, never written here."""
         from ..delta import wire
         from ..obs.replication import stability_watermark
 
-        d = self._data
-        assert self._local_meta is not None
-        if name == plan["base_name"]:
+        dp = plan.delta
+        if name == dp["base_name"]:
             return  # idempotent re-seal of the identical snapshot
-        if plan["dobj"] is not None:
+        if dp["dobj"] is not None:
             with trace.span("delta.size"):
-                delta_len = len(codec.pack(plan["dobj"]))
-            if delta_len >= len(plan["new_bytes"]):
+                delta_len = len(codec.pack(dp["dobj"]))
+            if delta_len >= len(dp["new_bytes"]):
                 # a delta no smaller than the state saves nothing
                 trace.add("delta_seal_skipped", 1)
-                plan["dobj"] = None
-            elif self._delta_verify and not await asyncio.to_thread(
-                self._verify_delta_plan, plan
+                dp["dobj"] = None
+            elif self._delta_verify and not await ports.offload(
+                self._verify_delta_plan, dp
             ):
                 logger.warning(
                     "delta diff does not refold to the sealed state; "
                     "refusing to publish it (snapshot only)"
                 )
                 trace.add("delta_seal_divergence", 1)
-                plan["dobj"] = None
-        if plan["dobj"] is None:
-            self._set_delta_base(name, plan["new_bytes"], plan["cursor"])
-            last = self._local_meta.last_delta_version
+                dp["dobj"] = None
+        if dp["dobj"] is None:
+            out.base = (name, dp["new_bytes"], dp["cursor"])
+            last = plan.last_delta_version
             if last:
                 trace.add("delta_pruned", 1)
-                await self.storage.remove_deltas([(self.actor_id, last)])
+                await ports.remove_deltas([(self.actor_id, last)])
             return
         with trace.span("delta.seal"):
-            union = d.next_op_versions.copy()
-            for clock in d.cursor_matrix.values():
+            union = plan.clock.copy()
+            for clock in plan.matrix.values():
                 union.merge(clock)
             rec = wire.DeltaRecord(
-                base_name=plan["base_name"],
+                base_name=dp["base_name"],
                 new_name=name,
-                base_cursor=VClock.from_obj(plan["base_cursor"]),
-                new_cursor=VClock.from_obj(plan["cursor"]),
+                base_cursor=VClock.from_obj(dp["base_cursor"]),
+                new_cursor=VClock.from_obj(dp["cursor"]),
                 sealer=self.actor_id,
                 adapter=self.adapter.name,
                 watermark=stability_watermark(
-                    self.actor_id, d.next_op_versions, d.cursor_matrix, union
+                    self.actor_id, plan.clock, plan.matrix, union
                 ),
-                delta_obj=plan["dobj"],
+                delta_obj=dp["dobj"],
             )
-            blob = await self._seal(wire.build_delta_obj(rec))
-            version = self._local_meta.last_delta_version + 1
+            blob = await self._seal_packed(
+                plan.key, codec.pack(wire.build_delta_obj(rec)), ports.encrypt
+            )
+            version = plan.last_delta_version + 1
             while True:
                 try:
-                    await self.storage.store_delta(
-                        self.actor_id, version, blob
-                    )
+                    await ports.store_delta(self.actor_id, version, blob)
                     break
                 except FileExistsError:
                     version += 1
-            self._local_meta.last_delta_version = version
-            vb = VersionBytes(
-                CURRENT_CONTAINER_VERSION,
-                codec.pack(self._local_meta.to_obj()),
-            )
-            await self.storage.store_local_meta(vb.serialize())
+            out.delta_version = version
+            # the one live object the tail reads: the producer cursors
+            # beside ``last_delta`` are taken as they stand at the write,
+            # as before, so an ``apply_ops`` that persisted a newer
+            # ``last_op`` while this tail ran is never written back stale
+            # (three monotone counters; a torn read is a valid past)
+            meta_obj = plan.local_meta.to_obj()
+            meta_obj[b"last_delta"] = version
+            vb = VersionBytes(CURRENT_CONTAINER_VERSION, codec.pack(meta_obj))
+            await ports.store_local_meta(vb.serialize())
             trace.add("delta_files_sealed", 1)
             trace.add("delta_bytes_sealed", len(blob))
             # own-log bound: consumers further than MAX_CHAIN behind
@@ -2432,17 +2534,17 @@ class Core:
 
             if version > MAX_CHAIN:
                 trace.add("delta_pruned", 1)
-                await self.storage.remove_deltas(
+                await ports.remove_deltas(
                     [(self.actor_id, version - MAX_CHAIN)]
                 )
         # a published device-cut proves the warm planes ARE this
         # snapshot: drop the host base copy (the planes take over as
         # the base; _plan_delta_seal's bytes-None branch covers any
         # future cycle where they no longer line up)
-        self._set_delta_base(
+        out.base = (
             name,
-            None if plan.get("device_cut") else plan["new_bytes"],
-            plan["cursor"],
+            None if dp.get("device_cut") else dp["new_bytes"],
+            dp["cursor"],
         )
 
     # --------------------------------------------------------------- compact
@@ -2479,25 +2581,76 @@ class Core:
         run the EXACT solo sealing path — one implementation of the
         snapshot wire form, the GC ordering, and the checkpoint reseal,
         so a service-compacted remote can never drift from a solo
-        ``compact()``.  ``_backlog`` is forwarded to the replication
-        sample: the service passes ``[]`` because its own ingest just
-        folded everything its listing found (same contract as
-        ``read_remote``'s post-ingest sample) — a batch of N tenants
-        must not pay N per-actor storage probes per dispatch.
-        ``_packed_state`` forwards to :meth:`save_checkpoint` (the
-        service's planes-packed checkpoint payload); ``_state_obj`` is
-        ``(obj, mut_epoch)`` — a pre-built snapshot state object (the
-        service derives it from the canonical fold writeback instead of
-        re-walking the state), used only when the state's mutation
-        epoch still matches, else the live state is serialized here.
-        The canonical packer re-sorts maps, so an equivalent obj seals
-        byte-identical payloads.  Returns the sink record's ``(meta,
-        status)``; ``_sink=False`` leaves writing it to the caller
-        (:meth:`compact`, after its root span has closed)."""
-        # lint: sync-section-begin (ASY001: the snapshot/cursor/delta-plan
-        # cut below must come from ONE loop slice — an await here lets an
-        # ingest interleave and seal a torn (state, cursor, delta) triple)
+        ``compact()``.
+
+        Three parts.  **Plan** (:meth:`_plan_seal`, one synchronous
+        slice of the loop): everything the tail needs from the live
+        replica, copied.  **Steps** (:meth:`_seal_steps`, the one
+        ordered body: snapshot durable → delta → local meta → GC →
+        checkpoint): every byte sealed and every file step made, live
+        state untouched.  When the storage and the cryptor both offer
+        sync twins (core/twins.py) the steps run to their end as ONE
+        worker-thread job (``seal_jobs``); otherwise each port call is
+        awaited here on the loop as the plugin gives it
+        (``seal_stepwise``) — same body, same order, same crash points.
+        **Commit** (:meth:`_commit_seal`, on the loop): the bookkeeping
+        of the steps that completed, then a failed step's error.  A
+        mutation landing while the steps run is kept apart by the
+        epochs, and the files written are the plan-time triple.
+
+        ``_backlog`` is forwarded to the replication sample: the service
+        passes ``[]`` because its own ingest just folded everything its
+        listing found (same contract as ``read_remote``'s post-ingest
+        sample) — a batch of N tenants must not pay N per-actor storage
+        probes per dispatch.  ``_packed_state`` is the service's
+        planes-packed checkpoint payload (:meth:`_plan_checkpoint`);
+        ``_state_obj`` is ``(obj, mut_epoch)`` — a pre-built snapshot
+        state object (the service derives it from the canonical fold
+        writeback instead of re-walking the state), used only when the
+        state's mutation epoch still matches, else the live state is
+        serialized here.  The canonical packer re-sorts maps, so an
+        equivalent obj seals byte-identical payloads.  Returns the sink
+        record's ``(meta, status)``; ``_sink=False`` leaves writing it
+        to the caller (:meth:`compact`, after its root span has
+        closed)."""
+        plan = self._plan_seal(_packed_state, _state_obj, _delta_cut)
+        ports = self._job_ports(plan)
+        if ports is not None:
+            trace.add("seal_jobs", 1)
+            out = await asyncio.to_thread(
+                _run_to_end, self._seal_tail(plan, ports)
+            )
+        else:
+            trace.add("seal_stepwise", 1)
+            out = await self._seal_tail(plan, _LoopPorts(self))
+        self._commit_seal(plan, out)
+        if out.error is not None:
+            raise out.error
+        # local ops are now folded into the snapshot; reset the producer
+        # cursor bookkeeping is unnecessary — versions only grow.
+        # replication status AFTER the GC + checkpoint seal (backlog is
+        # zero by construction, staleness zero): the post-compaction
+        # fixed point is what rides into the sink record below — the
+        # per-device line the fleet aggregator reads.
+        status = await self._sample_replication("compact", _backlog=_backlog)
+        # ops_to_remove is (actor, covered-version-cursor) pairs — the
+        # GC prefix per actor, not a file count
+        record = (
+            {"gc_op_actors": len(plan.ops_to_remove),
+             "gc_states": len(plan.states_to_remove)},
+            status,
+        )
+        if _sink:
+            await self._sink_compact(*record)
+        return record
+
+    def _plan_seal(self, _packed_state, _state_obj, _delta_cut) -> "_SealPlan":
+        """Part one of the seal tail.  A plain function: the snapshot,
+        cursor, delta plan, GC lists, key and checkpoint payload are cut
+        from ONE loop slice — an await in here would let an ingest
+        interleave and seal a torn (state, cursor, delta) triple."""
         d = self._data
+        key = self._latest_key()
         if _state_obj is not None and _state_obj[1] == getattr(
             d.state, "_mut", None
         ):
@@ -2507,7 +2660,7 @@ class Core:
                 state_obj = self.adapter.state_to_obj(d.state)
         cursor_obj = d.next_op_versions.to_obj()
         snap_mut = getattr(d.state, "_mut", None)
-        # delta plan (diff + self-verify) in the SAME sync section: the
+        # delta plan (diff + self-verify) in the SAME slice: the
         # (base, new, delta) triple must be cut from one stable state.
         # ``_delta_cut`` is the serving layer's device-cut candidate —
         # validated (base name + mut epoch) inside the plan, never
@@ -2516,35 +2669,94 @@ class Core:
             delta_plan = self._plan_delta_seal(
                 state_obj, cursor_obj, _cut=_delta_cut
             )
-        payload = [
-            state_obj,
-            cursor_obj,
-            # sealer id: readers attribute the cursor to this replica in
-            # their cursor matrix (StateWrapper's wire note) — old
-            # readers index [0]/[1] and never see it
-            self.actor_id,
-        ]
-        states_to_remove = sorted(d.read_states)
-        ops_to_remove = sorted(d.next_op_versions.counters.items())
-        prior_names = frozenset(d.read_states)
-        # consumed-prefix GC covers FOREIGN logs only: the own log is
-        # governed by _seal_delta's MAX_CHAIN bound — a stale reopen
-        # that re-scanned its own chain must not wipe links steady
-        # consumers are still walking
-        deltas_to_remove = sorted(
-            (a, v) for a, v in d.read_deltas.items() if a != self.actor_id
+        if delta_plan is not None:
+            state_bytes = delta_plan["new_bytes"]
+        else:
+            with trace.span("seal.state_obj"):
+                state_bytes = codec.pack(state_obj)
+        checkpoint = None
+        if self._checkpoint_enabled:
+            # the freshly compacted state is the ideal warm-open resume
+            # point: everything folded, op logs GC'd to the cursor
+            with trace.span("checkpoint.save"):
+                checkpoint = self._plan_checkpoint(_packed_state)
+        assert self._local_meta is not None
+        return _SealPlan(
+            key=key,
+            # as bytes, packed in this slice: the service's ``state_obj``
+            # aliases the live entry dicts and is valid only at this epoch
+            snapshot=(
+                state_bytes,
+                codec.pack(cursor_obj),
+                # sealer id: readers attribute the cursor to this replica
+                # in their cursor matrix (StateWrapper's wire note) — old
+                # readers index [0]/[1] and never see it
+                codec.pack(self.actor_id),
+            ),
+            snap_mut=snap_mut,
+            delta=delta_plan,
+            clock=d.next_op_versions.copy(),
+            matrix={a: c.copy() for a, c in d.cursor_matrix.items()},
+            local_meta=self._local_meta,
+            last_delta_version=self._local_meta.last_delta_version,
+            prior_names=frozenset(d.read_states),
+            states_to_remove=sorted(d.read_states),
+            ops_to_remove=sorted(d.next_op_versions.counters.items()),
+            # consumed-prefix GC covers FOREIGN logs only: the own log is
+            # governed by _seal_delta's MAX_CHAIN bound — a stale reopen
+            # that re-scanned its own chain must not wipe links steady
+            # consumers are still walking
+            deltas_to_remove=sorted(
+                (a, v) for a, v in d.read_deltas.items()
+                if a != self.actor_id
+            ),
+            checkpoint=checkpoint,
         )
-        # lint: sync-section-end
+
+    def _job_ports(self, plan: "_SealPlan"):
+        """The two plugin ports over their sync twins, or None when
+        either offers none (core/twins.py): what decides between one
+        worker job and the stepwise drive, and nothing else does."""
+        if not (
+            twins.offers(self.storage, SEAL_TAIL_TWINS)
+            and twins.offers(self.cryptor, (("encrypt", "encrypt_fn"),))
+        ):
+            return None
+        encrypt = self.cryptor.encrypt_fn(plan.key.material)
+        if encrypt is None:
+            return None
+        return _JobPorts(self.storage, encrypt)
+
+    async def _seal_tail(self, plan: "_SealPlan", ports) -> "_SealOutcome":
+        out = _SealOutcome()
+        try:
+            await self._seal_steps(plan, ports, out)
+        except BaseException as e:
+            # the commit still records the steps that completed (a
+            # published delta's version, a durable snapshot's name), as
+            # the bookkeeping between the awaits always did
+            out.error = e
+        return out
+
+    async def _seal_steps(self, plan: "_SealPlan", ports, out) -> None:
+        """Part two: THE order of the tail's durable steps, the only
+        place it is written.  ``ports`` is either the plugins themselves
+        (:class:`_LoopPorts`) or their sync twins (:class:`_JobPorts`,
+        whose awaits complete without suspending, so one ``send`` runs
+        this body to its end on a worker thread).  Reads the plan,
+        reports on ``out``, touches nothing live."""
         with trace.span("compact.seal"):
-            blob = await self._seal(payload)
+            blob = await self._seal_packed(
+                plan.key, codec.pack_array(plan.snapshot), ports.encrypt
+            )
         # crash safety: the new snapshot is durable before anything vanishes
         with trace.span("compact.write"):
-            name = await self.storage.store_state(blob)
-        if delta_plan is not None:
+            name = out.name = await ports.store_state(blob)
+        if plan.delta is not None:
             # the delta lands AFTER its target snapshot is durable (a
             # crash between the two leaves a snapshot consumers simply
             # full-read) and BEFORE the GC below
-            await self._seal_delta(delta_plan, name)
+            await self._seal_delta(plan, name, ports, out)
         # snapshot-GC guard: foreign snapshots may only be removed when
         # the justifying snapshot ``name`` has never been published
         # before.  A re-seal of unchanged state reproduces its previous
@@ -2561,53 +2773,60 @@ class Core:
         # ordering _ensure_own_history's cross-check assumes.  Deferred
         # names stay in read_states and are GC'd by the next
         # genuinely-new seal.
-        if name in prior_names:
+        if name in plan.prior_names:
             stale_states: list[str] = []
             trace.add("seal_gc_deferred", 1)
         else:
-            stale_states = states_to_remove
+            stale_states = plan.states_to_remove
         with trace.span("compact.gc"):
-            if deltas_to_remove and self._delta_enabled:
+            if plan.deltas_to_remove and self._delta_enabled:
                 # consumed delta prefixes go FIRST: the new snapshot
                 # covers them, and removing them before their target
                 # snapshots keeps any crash window free of dangling
                 # chain heads (docs/delta.md GC ordering)
-                await self.storage.remove_deltas(deltas_to_remove)
-            await asyncio.gather(
-                self.storage.remove_states(stale_states),
-                self.storage.remove_ops(ops_to_remove),
+                await ports.remove_deltas(plan.deltas_to_remove)
+            await ports.both(
+                ports.remove_states(stale_states),
+                ports.remove_ops(plan.ops_to_remove),
             )
-        # sync bookkeeping section
-        d.read_states.difference_update(stale_states)
-        d.read_states.add(name)
-        # record what this seal depended on, AT the snapshot epoch: the
-        # serving layer skips the next seal iff the signature has not
-        # moved (a mutation landing mid-seal keeps the epochs apart, so
-        # the skip can never alias it away)
-        self._last_seal_sig = self._seal_signature(_mut=snap_mut)
-        if self._checkpoint_enabled:
-            # the freshly compacted state is the ideal warm-open resume
-            # point: everything folded, op logs GC'd to the cursor
-            await self.save_checkpoint(
-                _packed=_packed_state, _snap=(name, snap_mut)
-            )
-        # local ops are now folded into the snapshot; reset the producer
-        # cursor bookkeeping is unnecessary — versions only grow.
-        # replication status AFTER the GC + checkpoint seal (backlog is
-        # zero by construction, staleness zero): the post-compaction
-        # fixed point is what rides into the sink record below — the
-        # per-device line the fleet aggregator reads.
-        status = await self._sample_replication("compact", _backlog=_backlog)
-        # ops_to_remove is (actor, covered-version-cursor) pairs — the
-        # GC prefix per actor, not a file count
-        record = (
-            {"gc_op_actors": len(ops_to_remove),
-             "gc_states": len(states_to_remove)},
-            status,
-        )
-        if _sink:
-            await self._sink_compact(*record)
-        return record
+        out.stale_states = stale_states
+        if plan.checkpoint is not None:
+            with trace.span("checkpoint.save"):
+                payload = plan.checkpoint
+                # functions of the plan and of the snapshot's name: the
+                # read set once the GC above is accounted, and the name
+                # itself, which the plan-time state equals by
+                # construction (docs/delta.md: a warm reopen restores
+                # the delta-sealing base and keeps its chain unbroken).
+                # States without a mutation epoch never record it, as
+                # before — a missing name only costs one full read
+                payload[b"rs"] = sorted(
+                    (plan.prior_names - set(stale_states)) | {name}
+                )
+                if plan.snap_mut is not None:
+                    payload[b"snap"] = name.encode()
+                out.checkpoint_sig = await self._store_checkpoint(
+                    payload, plan.key, ports
+                )
+
+    def _commit_seal(self, plan: "_SealPlan", out: "_SealOutcome") -> None:
+        """Part three, on the loop: the bookkeeping of every step that
+        completed, in the order the steps made it."""
+        d = self._data
+        if out.delta_version is not None:
+            self._local_meta.last_delta_version = out.delta_version
+        if out.base is not None:
+            self._set_delta_base(*out.base)
+        if out.stale_states is not None:  # the GC ran to its end
+            d.read_states.difference_update(out.stale_states)
+            d.read_states.add(out.name)
+            # record what this seal depended on, AT the snapshot epoch:
+            # the serving layer skips the next seal iff the signature has
+            # not moved (a mutation landing mid-seal keeps the epochs
+            # apart, so the skip can never alias it away)
+            self._last_seal_sig = self._seal_signature(_mut=plan.snap_mut)
+        if out.checkpoint_sig is not None:
+            self._checkpoint_sig = out.checkpoint_sig
 
     async def _sink_compact(self, meta: dict, status: dict | None) -> None:
         """Run-scoped metrics sink (CRDT_OBS_SINK / obs.sink.configure):

@@ -43,6 +43,19 @@ class Cryptor(ABC):
         backends that override one must keep the other in step."""
         return None
 
+    def encrypt_fn(self, key: VersionBytes):
+        """Optional SYNC twin of :meth:`encrypt`: a plain callable
+        ``(data) -> envelope`` bound to ``key``, or None.  The seal tail
+        (``Core._compact_seal``) seals a tenant's snapshot, delta and
+        checkpoint inside ONE worker-thread job when the cryptor and the
+        storage both offer sync twins (:mod:`.twins`), instead of one
+        ``asyncio.to_thread`` round-trip per blob.  Must produce what
+        ``encrypt`` produces; a backend keeps the two in step by writing
+        ``encrypt`` over this callable.  A cryptor without it, or a
+        subclass that overrides ``encrypt`` alone, is awaited on the
+        loop as before."""
+        return None
+
     async def init(self, core) -> None: ...
 
     async def set_remote_meta(self, meta) -> None:

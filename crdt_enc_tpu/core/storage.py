@@ -16,6 +16,18 @@ Contracts carried over:
 Missing directories/objects are treated as empty/None, never as errors
 (crdt-enc-tokio/src/lib.rs:376-401) — a replica may simply not have synced
 yet.
+
+Sync twins (optional).  The seal tail of a compaction makes seven calls
+(:data:`SEAL_TAIL_TWINS`).  A backend whose work is a plain function
+anyway may offer each as ``<name>_sync`` with the same arguments, result
+and exceptions (``store_delta_sync`` raises ``FileExistsError`` too), and
+write the awaitable over it, so that the two cannot drift.  When the
+storage and the cryptor both offer twins (:mod:`.twins`), a tenant's whole
+tail runs as ONE worker-thread job instead of one thread round-trip a
+call; a twin is therefore called from a worker thread, with other
+replicas' twins in flight on other threads.  A storage without them, or a
+wrapper around one, has every call awaited on the loop, in the same
+order.  ``FsStorage`` and ``MemoryStorage`` offer all seven.
 """
 
 from __future__ import annotations
@@ -23,6 +35,16 @@ from __future__ import annotations
 from abc import ABC, abstractmethod
 
 from ..models.vclock import Actor
+
+# (awaitable, sync twin) for each storage call of Core's seal tail
+SEAL_TAIL_TWINS = tuple(
+    (name, name + "_sync")
+    for name in (
+        "store_state", "store_delta", "store_local_meta",
+        "store_local_checkpoint", "remove_states", "remove_ops",
+        "remove_deltas",
+    )
+)
 
 
 class Storage(ABC):

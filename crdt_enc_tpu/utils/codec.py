@@ -54,6 +54,17 @@ def pack(obj) -> bytes:
     return msgpack.packb(_canon(obj), use_bin_type=True)
 
 
+def pack_array(packed_items) -> bytes:
+    """``pack([a, b, …])`` from the items' own ``pack`` bytes: an array
+    is its header followed by its elements, and canonical form is
+    per-element, so a large element packed once (the state, which the
+    delta plan already holds as bytes) is never walked again."""
+    items = list(packed_items)
+    if len(items) > 15:
+        raise ValueError("pack_array: fixarray only")
+    return b"".join([bytes([0x90 | len(items)])] + items)
+
+
 def unpack(data: bytes):
     """Decode canonical msgpack.  Arrays come back as tuples (use_list=False)
     so that composite map keys — e.g. (replica, counter) dots — stay hashable."""

@@ -8,6 +8,9 @@ jax, so a plain ``pytest tests/`` behaves like the tier-1 command
 """
 
 import os
+import sys
+
+import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -40,3 +43,14 @@ def pytest_collection_finish(session):
     files = {item.location[0] for item in session.items}
     COLLECT_INFO["n_items"] = len(session.items)
     COLLECT_INFO["n_files"] = len(files)
+
+
+@pytest.fixture(autouse=True)
+def _no_metrics_sink_left_configured():
+    """A metrics sink a test configured and did not take down would record
+    every later compaction of its worker process, and drain the event log
+    that other tests read."""
+    yield
+    sink = sys.modules.get("crdt_enc_tpu.obs.sink")
+    if sink is not None:
+        sink._configured = False

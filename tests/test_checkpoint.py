@@ -604,3 +604,107 @@ def test_streaming_compact_checkpoints_from_rows(storage_factory, monkeypatch):
         )
 
     run(go())
+
+
+# ------------------------------------- the seal tail's one worker job
+
+
+def test_mutation_while_the_seal_job_is_parked(tmp_path):
+    """A local write lands while a tenant's seal job sits in a blocking
+    storage twin.  The files the job goes on to write are the plan-time
+    triple; the bookkeeping keeps the epochs apart, so the next cycle seals
+    again; the checkpoint's ``snap`` names a snapshot its own state equals;
+    and the producer cursor the write persisted is not written back stale."""
+    import threading
+
+    from crdt_enc_tpu.core.core import LocalMeta, unpack_checkpoint_state
+    from crdt_enc_tpu.serve import FoldService
+    from crdt_enc_tpu.utils import VersionBytes
+
+    parked, release = threading.Event(), threading.Event()
+
+    class Parking(MemoryStorage):
+        def store_state_sync(self, data):
+            name = super().store_state_sync(data)
+            parked.set()
+            assert release.wait(30)
+            return name
+
+    async def go():
+        remote = MemoryRemote()
+        adapter = orset_adapter()
+        writer = await Core.open(make_opts(MemoryStorage(remote), adapter))
+        for i in range(9):
+            await writer.update(
+                lambda s, i=i: s.add_ctx(writer.actor_id, b"m%d" % i)
+            )
+        storage = Parking(remote)
+        served = await Core.open(make_opts(storage, adapter))
+        service = FoldService([served])
+        release.set()
+        (res,) = await service.run_cycle()
+        assert res.sealed
+        for i in range(9, 14):
+            await writer.update(
+                lambda s, i=i: s.add_ctx(writer.actor_id, b"m%d" % i)
+            )
+        parked.clear()
+        release.clear()
+        trace.reset()
+        cycle = asyncio.ensure_future(service.run_cycle())
+        assert await asyncio.to_thread(parked.wait, 30)
+        plan_time = served.with_state(canonical_bytes)
+        await served.update(lambda s: s.add_ctx(served.actor_id, b"late"))
+        late_version = served._local_meta.last_op_version
+        release.set()
+        (res,) = await cycle
+        assert res.sealed and res.error is None
+        assert trace.snapshot()["counters"].get("seal_jobs") == 1
+
+        name = served.delta_base_name
+        (_, blob), = await storage.load_states([name])
+        snapshot = await served._open_sealed(blob)
+        assert codec.pack(snapshot[0]) == plan_time
+        ckpt = await served._open_sealed(await storage.load_local_checkpoint())
+        assert bytes(ckpt[b"snap"]).decode() == name
+        assert name in ckpt[b"rs"]
+        assert canonical_bytes(unpack_checkpoint_state(
+            adapter, int(ckpt[b"fmt"]), ckpt[b"state"]
+        )) == plan_time
+        assert ckpt[b"cursor"] == snapshot[1]
+        # every delta of the chain refolds to a published snapshot
+        consumer = await Core.open(make_opts(MemoryStorage(remote), adapter))
+        await consumer.read_remote()
+        assert consumer.with_state(canonical_bytes) == served.with_state(
+            canonical_bytes
+        )
+        # the write's durable cursor survived the job's local-meta write
+        meta = LocalMeta.from_obj(codec.unpack(
+            VersionBytes.deserialize(await storage.load_local_meta()).content
+        ))
+        assert meta.last_op_version == late_version > 0
+        assert meta.last_delta_version == served._local_meta.last_delta_version
+        # the seal is not mistaken for one of the state as it is now
+        assert served._seal_signature() != served._last_seal_sig
+        trace.reset()
+        (res,) = await service.run_cycle()
+        assert res.sealed and not trace.snapshot()["counters"].get(
+            "serve_noop_cycles"
+        )
+        (_, blob), = await storage.load_states([served.delta_base_name])
+        assert b"late" in (await served._open_sealed(blob))[0][b"e"]
+        service.close()
+        # a warm reopen restores a state equal to the snapshot it names
+        storage2 = Parking(remote)
+        storage2._local_meta = storage._local_meta
+        storage2._local_checkpoint = storage._local_checkpoint
+        warm = await Core.open(make_opts(storage2, adapter, create=False))
+        assert warm.opened_from_checkpoint
+        assert warm.delta_base_name == served.delta_base_name
+        assert warm.with_state(canonical_bytes) == served.with_state(
+            canonical_bytes
+        )
+
+    release.set()
+    run(go())
+    trace.reset()

@@ -198,3 +198,75 @@ def test_store_ops_collision_is_detected(tmp_path):
         assert data == b"first writer wins"
 
     asyncio.run(go())
+
+
+# ------------------------------------------------ sync twins of the ports
+
+
+def test_sync_twins_are_offered_by_the_class_that_defines_them():
+    """What sends a seal tail to one worker job is what the ports' classes
+    define (core/twins.py): a forwarding wrapper offers nothing of its
+    inner storage's, and a subclass that overrides an awaitable alone has
+    left the inherited twin behind."""
+    from crdt_enc_tpu.backends import FsStorage, XChaChaCryptor
+    from crdt_enc_tpu.core import twins
+    from crdt_enc_tpu.core.storage import SEAL_TAIL_TWINS
+    from crdt_enc_tpu.sim.faults import FaultConfig, FaultyStorage
+    from crdt_enc_tpu.sim.runner import DeterministicCryptor, _TapStorage
+
+    seal = (("encrypt", "encrypt_fn"),)
+    memory = MemoryStorage(MemoryRemote())
+    assert twins.offers(memory, SEAL_TAIL_TWINS)
+    assert twins.offers(FsStorage("/nowhere/l", "/nowhere/r"), SEAL_TAIL_TWINS)
+    assert twins.offers(IdentityCryptor(), seal)
+    assert twins.offers(XChaChaCryptor(), seal)
+    assert twins.offers(DeterministicCryptor("k"), seal)  # gen_key only
+
+    class Forwarding:  # tools/daemon.py's _FlakyStorage, the sim's tap
+        def __init__(self, inner):
+            self.inner = inner
+
+        def __getattr__(self, name):
+            return getattr(self.inner, name)
+
+    assert Forwarding(memory).store_state_sync  # reachable on the instance
+    assert not twins.offers(Forwarding(memory), SEAL_TAIL_TWINS)
+    assert not twins.offers(_TapStorage(memory, {}), SEAL_TAIL_TWINS)
+    faulty = FaultyStorage(memory, FaultConfig.none(), seed=1, name="r")
+    assert not twins.offers(faulty, SEAL_TAIL_TWINS)
+    assert not twins.offers(CheckedCryptor(), seal)  # overrides encrypt alone
+
+    class CountedStore(MemoryStorage):
+        async def store_state(self, data):
+            return await super().store_state(data)
+
+    class SlowDisk(MemoryStorage):
+        def store_state_sync(self, data):
+            return super().store_state_sync(data)
+
+    assert not twins.offers(CountedStore(MemoryRemote()), SEAL_TAIL_TWINS)
+    assert twins.offers(SlowDisk(MemoryRemote()), SEAL_TAIL_TWINS)
+
+
+def test_checked_cryptor_compaction_stays_readable():
+    """A cryptor that overrides ``encrypt`` alone seals through its own
+    ``encrypt`` (stepwise), never through the base class's twin: what it
+    seals, it opens."""
+    from crdt_enc_tpu.utils import trace
+
+    async def go():
+        remote = MemoryRemote()
+        w = await Core.open(make_opts(MemoryStorage(remote)))
+        await w.update(lambda s: s.inc(w.actor_id))
+        trace.reset()
+        await w.compact()
+        counted = trace.snapshot()["counters"]
+        assert counted.get("seal_stepwise") == 1 and not counted.get("seal_jobs")
+        r = await Core.open(make_opts(MemoryStorage(remote)))
+        await r.read_remote()
+        assert r.with_state(lambda s: s.to_obj()) == w.with_state(
+            lambda s: s.to_obj()
+        )
+
+    asyncio.run(go())
+    trace.reset()

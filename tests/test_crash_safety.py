@@ -340,3 +340,143 @@ def test_interrupted_compact_is_idempotent_under_retry(fs_factory):
         assert await clean.list_op_actors() == []
 
     run(go())
+
+
+# ------------------------------------- the seal tail's one worker job
+# A failure at each step of the tail, with the tail run as one job (the
+# ports' sync twins) and call by call on the loop (tests/_seal_drive.py):
+# the same failure must leave the same remote, the same local files and the
+# same bookkeeping, and in both the order holds.
+
+_STEPS = [
+    ("host", "encrypt:1"), ("host", "store_state"),
+    ("host", "encrypt:2"), ("host", "store_delta"),
+    ("host", "store_local_meta"), ("host", "remove_states"),
+    ("host", "remove_ops"), ("host", "encrypt:3"),
+    ("host", "store_local_checkpoint"), ("skipped", "remove_deltas"),
+]
+
+
+async def _fail_one_step(fleet, case, step):
+    from _seal_drive import (
+        FailingCryptor, Injected, add_members, bookkeeping, published,
+        remove_members,
+    )
+
+    writer = await fleet.open("w")
+    await add_members(writer, [b"a%d" % i for i in range(12)])
+    cryptor = FailingCryptor("seal-drive")
+    sealer = await fleet.open("s", cryptor=cryptor)
+    await sealer.compact()  # snapshot only: the base of the next delta
+    await add_members(writer, [b"b%d" % i for i in range(5)])
+    if case == "skipped":
+        await sealer.compact()  # delta v1, which the failing seal prunes
+        await remove_members(
+            writer, [b"a%d" % i for i in range(12)]
+            + [b"b%d" % i for i in range(5)]
+        )
+    storage = fleet.inner["s"]
+    before = await published(storage)
+    if step.startswith("encrypt:"):
+        cryptor.nth = int(step.split(":")[1])
+    else:
+        storage.fail = step
+    with pytest.raises(Injected):
+        await sealer.compact()
+    storage.fail = None
+    after = await published(storage)
+    book = bookkeeping(sealer)
+    checkpoint = (
+        await sealer._open_sealed(after["checkpoint"])
+        if after["checkpoint"] else None
+    )
+    # the retry cleans up, and a cold replica converges on it
+    await sealer.compact()
+    cold = await fleet.open("cold")
+    await cold.read_remote()
+    assert cold.with_state(canonical_bytes) == sealer.with_state(
+        canonical_bytes
+    ) == writer.with_state(canonical_bytes)
+    return before, after, book, checkpoint
+
+
+@pytest.mark.parametrize("case, step", _STEPS, ids=[s for _, s in _STEPS])
+@pytest.mark.parametrize("kind", ["memory", "fs"])
+def test_failed_seal_step_leaves_the_same_remote_in_both_drives(
+    kind, case, step, tmp_path
+):
+    from _seal_drive import DRIVES, Fleet, failing, run_pinned
+    from crdt_enc_tpu.backends import MemoryStorage
+
+    cls = failing(MemoryStorage if kind == "memory" else FsStorage)
+    seen = {}
+    for drive in DRIVES:
+        fleet = Fleet(kind, drive, tmp_path / drive, cls=cls)
+        seen[drive] = run_pinned(lambda: _fail_one_step(fleet, case, step))
+    before, after, book, checkpoint = seen["job"]
+    assert (before, after, book, checkpoint) == seen["stepwise"]
+    new_states = set(after["states"]) - set(before["states"])
+    gone = (
+        (set(before["ops"]) - set(after["ops"]))
+        | (set(before["states"]) - set(after["states"]))
+    )
+    # the snapshot is durable before anything is removed
+    assert not gone or new_states
+    assert bool(new_states) == (step not in ("encrypt:1", "store_state"))
+    assert bool(gone) == (step in (
+        "remove_states", "remove_ops", "encrypt:3", "store_local_checkpoint",
+    )), "GC runs after the delta and the local meta, and as a pair"
+    # no delta without its snapshot
+    new_deltas = set(after["deltas"]) - set(before["deltas"])
+    assert not new_deltas or new_states
+    assert bool(new_deltas) == (case == "host" and step not in (
+        "encrypt:1", "store_state", "encrypt:2", "store_delta",
+    ))
+    # no checkpoint naming a snapshot that was never published (the one
+    # that stands may name the snapshot this seal's GC has just collected)
+    if checkpoint is not None and checkpoint.get(b"snap") is not None:
+        assert bytes(checkpoint[b"snap"]).decode() in (
+            set(before["states"]) | set(after["states"])
+        )
+    assert after["checkpoint"] == before["checkpoint"]
+    # bookkeeping follows the steps that completed
+    assert (book["local_meta"][b"last_delta"] > 0) == bool(
+        new_deltas or case == "skipped"
+    )
+
+
+@pytest.mark.parametrize("step", ["store_state", "remove_ops",
+                                  "store_local_checkpoint"])
+@pytest.mark.parametrize("drive", ["job", "stepwise"])
+def test_failed_seal_job_is_one_tenants_error(drive, step, tmp_path):
+    """Through the service: the tenant whose job fails reports ``error``,
+    the others seal."""
+    from _seal_drive import Fleet, add_members, failing
+    from crdt_enc_tpu.backends import MemoryStorage
+    from crdt_enc_tpu.serve import FoldService
+    from crdt_enc_tpu.utils import trace
+
+    async def go():
+        fleets = [
+            Fleet("memory", drive, tmp_path / f"t{t}",
+                  cls=failing(MemoryStorage))
+            for t in range(3)
+        ]
+        for t, fleet in enumerate(fleets):
+            await add_members(
+                await fleet.open("w"), [b"t%d-%d" % (t, i) for i in range(9)]
+            )
+        served = [await fleet.open("s") for fleet in fleets]
+        fleets[1].inner["s"].fail = step
+        trace.reset()
+        results = await FoldService(served).run_cycle()
+        assert "Injected" in results[1].error and results[1].path == "error"
+        assert not results[1].sealed
+        assert results[0].sealed and results[2].sealed
+        counted = trace.snapshot()["counters"]
+        key = "seal_jobs" if drive == "job" else "seal_stepwise"
+        assert counted.get(key) == 3 and counted.get("serve_tenant_errors") == 1
+
+    run(go())
+    from crdt_enc_tpu.utils import trace
+    trace.reset()

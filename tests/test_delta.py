@@ -1009,3 +1009,129 @@ def test_schedule_deltas_roundtrip_and_default_off():
     assert [s.to_obj() for s in old.steps] == [
         s.to_obj() for s in generate(5, 3, 40, FaultConfig.none()).steps
     ]
+
+
+# ------------------------------------- seal tail: one job vs stepwise
+# The same history sealed with the tail as ONE worker-thread job (the
+# ports' sync twins) and call by call on the loop (tests/_seal_drive.py):
+# one ordered body serves both, so every published byte and every piece of
+# the Core's bookkeeping must agree.
+
+
+async def _seal_history(fleet, case):
+    """Seal a scripted history in ``fleet``'s drive; the last seal is the
+    case under test.  Returns (files, bookkeeping, counters of that seal)."""
+    from _seal_drive import add_members, bookkeeping, published, remove_members
+
+    from crdt_enc_tpu.parallel import TpuAccelerator
+    from crdt_enc_tpu.serve import FoldService, ServeConfig
+
+    writer = await fleet.open("w")
+    await add_members(writer, [b"a%d" % i for i in range(12)])
+    if case in ("device", "host_served"):
+        served = await fleet.open(
+            "s", accelerator=TpuAccelerator(min_device_batch=1)
+        )
+        cfg = ServeConfig() if case == "device" else ServeConfig(warm=False)
+        service = FoldService([served], cfg)
+        (res,) = await service.run_cycle()
+        assert res.sealed
+        await add_members(writer, [b"b%d" % i for i in range(5)])
+        await remove_members(writer, [b"a3"])
+        trace.reset()
+        (res,) = await service.run_cycle()
+        assert res.sealed and res.error is None
+        service.close()
+        sealer = served
+    else:
+        sealer = await fleet.open("s")
+        await sealer.compact()  # no base yet: a snapshot-only link
+        if case == "prior":
+            trace.reset()
+            await sealer.compact()  # unchanged state: the same name again
+        else:
+            await add_members(writer, [b"b%d" % i for i in range(5)])
+            await remove_members(writer, [b"a3"])
+            if case == "exists":
+                # a file a crashed incarnation left at the next version
+                await fleet.inner["s"].store_delta(
+                    sealer.actor_id, 1, b"left behind"
+                )
+            if case == "skipped":
+                await sealer.compact()  # publishes delta v1
+                await remove_members(
+                    writer, [b"a%d" % i for i in range(12) if i != 3]
+                    + [b"b%d" % i for i in range(5)]
+                )
+            trace.reset()
+            await sealer.compact()
+    counted = dict(trace.snapshot()["counters"])
+    return await published(fleet.inner["s"]), bookkeeping(sealer), counted
+
+
+@pytest.mark.parametrize("case", [
+    "host", "device", "host_served", "skipped", "exists", "prior",
+])
+@pytest.mark.parametrize("kind", ["memory", "fs"])
+def test_seal_job_and_stepwise_publish_identical_bytes(kind, case, tmp_path):
+    from _seal_drive import DRIVES, Fleet, run_pinned
+
+    seen = {}
+    for drive in DRIVES:
+        fleet = Fleet(kind, drive, tmp_path / drive)
+        seen[drive] = run_pinned(lambda: _seal_history(fleet, case))
+        trace.reset()
+    (files, book, counted), (files_s, book_s, counted_s) = (
+        seen["job"], seen["stepwise"]
+    )
+    assert counted.get("seal_jobs") == 1 and not counted.get("seal_stepwise")
+    assert counted_s.get("seal_stepwise") == 1
+    assert not counted_s.get("seal_jobs")
+    # the case is the one its name says
+    want = {
+        "host": {"delta_files_sealed": 1},
+        "device": {"delta_files_sealed": 1, "delta_device_cuts": 1},
+        "host_served": {"delta_files_sealed": 1},
+        "skipped": {"delta_seal_skipped": 1, "delta_pruned": 1},
+        "exists": {"delta_files_sealed": 1},
+        "prior": {"seal_gc_deferred": 1},
+    }[case]
+    for name, n in want.items():
+        assert counted.get(name) == n == counted_s.get(name), name
+    if case == "host_served":
+        assert not counted.get("delta_device_cuts")
+    if case == "exists":
+        actor = book["local_meta"][b"actor"]
+        assert files["deltas"][(actor, 1)] == b"left behind"
+        assert (actor, 2) in files["deltas"]
+        assert book["local_meta"][b"last_delta"] == 2
+    assert files["states"] and files["checkpoint"] and files["local_meta"]
+    for family, got in files.items():
+        assert got == files_s[family], family
+    assert book == book_s
+    drift = {
+        k for k in set(counted) | set(counted_s)
+        # the second drive finds the first one's programs compiled
+        if k not in ("seal_jobs", "seal_stepwise", "jax_compiles")
+        and counted.get(k) != counted_s.get(k)
+    }
+    assert not drift, drift
+
+
+def test_pack_array_is_pack_of_the_list():
+    """The snapshot payload is spliced from its items' own packed bytes (the
+    state is held once, as the delta plan's bytes)."""
+    rng = random.Random(5)
+    state = {
+        b"c": {rng.randbytes(16): rng.randrange(1, 99) for _ in range(20)},
+        b"e": {b"m%d" % i: {rng.randbytes(16): i + 1} for i in range(40)},
+        b"d": {},
+    }
+    cursor = {rng.randbytes(16): 7 for _ in range(5)}
+    sealer = rng.randbytes(16)
+    assert codec.pack([state, cursor, sealer]) == codec.pack_array(
+        [codec.pack(state), codec.pack(cursor), codec.pack(sealer)]
+    )
+    assert codec.pack([]) == codec.pack_array([])
+    with pytest.raises(ValueError):
+        codec.pack_array([b"\x00"] * 16)

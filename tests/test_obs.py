@@ -505,6 +505,9 @@ def test_compact_appends_sink_snapshot(tmp_path, monkeypatch):
     from crdt_enc_tpu.core import Core
 
     path = tmp_path / "compact.jsonl"
+    # before the configure: monkeypatch restores what it found, and a
+    # setattr made after it would restore the configured sink
+    monkeypatch.setattr(sink, "_configured", False)
     sink.configure(str(path))
     try:
         async def go():

@@ -393,6 +393,8 @@ def test_repl_sample_opt_out(monkeypatch):
 
 def test_compact_sink_record_carries_replication(tmp_path, monkeypatch):
     path = tmp_path / "dev.jsonl"
+    # before the configure: monkeypatch restores what it found
+    monkeypatch.setattr(sink, "_configured", False)
     sink.configure(str(path))
     try:
         async def go():
@@ -592,6 +594,8 @@ def test_fleet_cli_end_to_end_two_real_devices(tmp_path, capsys,
 
     remote = MemoryRemote()
     pa, pb = tmp_path / "deva.jsonl", tmp_path / "devb.jsonl"
+    # before any configure: monkeypatch restores what it found
+    monkeypatch.setattr(sink, "_configured", False)
 
     async def device(path, n_ops, read_first):
         sink.configure(str(path))

@@ -770,3 +770,128 @@ def test_tenant_failure_is_isolated():
         assert results[1].path == "batched" and results[1].sealed
 
     run(scenario())
+
+
+# ------------------------------------- seal tail: one worker job a tenant
+
+
+class _SealPhaseProbe:
+    """Counts ``asyncio.to_thread`` hops and ``os.fsync`` calls made while
+    ``FoldService._seal_all`` is running."""
+
+    def __init__(self, monkeypatch):
+        import os
+
+        from crdt_enc_tpu.obs import sink
+
+        # no metrics sink: its record would be a second hop a tenant
+        monkeypatch.setattr(sink, "_configured", None)
+        self.hops = self.fsyncs = 0
+        self.inside = False
+        real_hop, real_fsync = asyncio.to_thread, os.fsync
+        real_seal_all = FoldService._seal_all
+
+        async def hop(fn, *args, **kw):
+            self.hops += self.inside
+            return await real_hop(fn, *args, **kw)
+
+        def fsync(fd):
+            self.fsyncs += self.inside
+            return real_fsync(fd)
+
+        async def seal_all(service, works, t0):
+            self.inside = True
+            try:
+                return await real_seal_all(service, works, t0)
+            finally:
+                self.inside = False
+
+        monkeypatch.setattr(asyncio, "to_thread", hop)
+        monkeypatch.setattr(os, "fsync", fsync)
+        monkeypatch.setattr(FoldService, "_seal_all", seal_all)
+
+
+@pytest.mark.parametrize("kind", ["memory", "fs"])
+def test_busy_cycle_seals_each_tenant_in_one_job(kind, tmp_path, monkeypatch):
+    """A toy busy cycle: every tenant has new files, and each tenant's
+    whole seal tail is ONE hop to a worker thread (snapshot, delta with its
+    verify, local meta, GC, checkpoint), with the flushes it always made:
+    file and directory for each of the four files it publishes."""
+    tenants = 5
+
+    def storage(t, name):
+        if kind == "memory":
+            return MemoryStorage(remotes[t])
+        base = tmp_path / f"t{t}"
+        return FsStorage(str(base / name), str(base / "remote"))
+
+    remotes = [MemoryRemote() for _ in range(tenants)]
+
+    async def go():
+        served = [
+            await Core.open(make_opts(storage(t, "s"))) for t in range(tenants)
+        ]
+        service = FoldService(served)
+        for t in range(tenants):
+            await write_orset(storage(t, "w1"), 12, b"a%d" % t)
+        await service.run_cycle()  # snapshot-only links: no base yet
+        for t in range(tenants):
+            await write_orset(storage(t, "w2"), 6, b"b%d" % t)
+        probe = _SealPhaseProbe(monkeypatch)
+        trace.reset()
+        results = await service.run_cycle()
+        assert all(r.sealed and r.error is None for r in results)
+        counted = trace.snapshot()["counters"]
+        assert counted.get("seal_jobs") == tenants
+        assert not counted.get("seal_stepwise")
+        assert counted.get("delta_files_sealed") == tenants
+        assert probe.hops == tenants
+        if kind == "fs":
+            assert probe.fsyncs == 8 * tenants
+        service.close()
+
+    run(go())
+    trace.reset()
+
+
+def test_seal_tail_behind_a_fault_wrapper_stays_on_the_loop():
+    """``FaultyStorage`` defines no sync twin, so its tenants' tails are
+    awaited call by call, through the wrapper's own awaitables (where the
+    faults are rolled), and are counted as such."""
+    from crdt_enc_tpu.sim.faults import FaultConfig, FaultyStorage
+
+    tenants = 3
+
+    async def go():
+        remotes = [MemoryRemote() for _ in range(tenants)]
+        for t, r in enumerate(remotes):
+            await write_orset(MemoryStorage(r), 12, b"f%d" % t)
+        wrapped = [
+            FaultyStorage(
+                MemoryStorage(r), FaultConfig.none(), seed=27, name=f"t{t}"
+            )
+            for t, r in enumerate(remotes)
+        ]
+        seen: list = []
+        for w in wrapped:
+            real = w._write
+
+            async def write(family, thunk, landed=None, real=real):
+                seen.append(family)
+                return await real(family, thunk, landed)
+
+            w._write = write
+        served = [await Core.open(make_opts(w)) for w in wrapped]
+        del seen[:]
+        trace.reset()
+        results = await FoldService(served).run_cycle()
+        assert all(r.sealed for r in results)
+        counted = trace.snapshot()["counters"]
+        assert counted.get("seal_stepwise") == tenants
+        assert not counted.get("seal_jobs")
+        for call in ("store_state", "remove_states", "remove_ops",
+                     "store_local_checkpoint"):
+            assert seen.count(call) == tenants, call
+
+    run(go())
+    trace.reset()

@@ -173,3 +173,75 @@ def test_second_dense_fold_counts_exactly_its_columns_up_and_its_planes_back():
         int(np.prod(p.shape)) * p.dtype.itemsize for p in planes
     )
     assert counters["fold_rows_device"] == len(ops)
+
+
+# --------------------------------------------- the seal tail's worker job
+TAIL_SPANS = {
+    "compact.seal", "compact.write", "delta.size", "delta.verify",
+    "delta.verify.apply", "delta.verify.pack", "delta.seal", "compact.gc",
+    "checkpoint.save",
+}
+
+
+@pytest.mark.parametrize("root", ["serve.seal", "core.compact"])
+def test_seal_job_spans_keep_their_ancestor_across_the_thread(
+    root, tmp_path, monkeypatch
+):
+    """A tenant's seal tail runs as one job on a worker thread; every span
+    it records there still hangs under ``serve.seal`` (solo: under
+    ``core.compact``), by name and event by event."""
+    import threading
+
+    from crdt_enc_tpu.obs import sink
+
+    # a sink record would drain the event log this test reads
+    monkeypatch.setattr(sink, "_configured", None)
+
+    from test_serve import make_opts, write_orset
+
+    from crdt_enc_tpu.backends import FsStorage
+    from crdt_enc_tpu.core import Core
+    from crdt_enc_tpu.serve import FoldService
+
+    def storage(name):
+        return FsStorage(str(tmp_path / name), str(tmp_path / "remote"))
+
+    async def go():
+        await write_orset(storage("w1"), 12, b"a")
+        core = await Core.open(make_opts(storage("s")))
+        service = FoldService([core])
+
+        async def call():
+            if root == "core.compact":
+                return await core.compact()
+            (res,) = await service.run_cycle()
+            assert res.sealed
+
+        await call()  # a base for the delta
+        await write_orset(storage("w2"), 6, b"b")
+        trace.reset()
+        trace.enable_events()
+        await call()
+        service.close()
+        return threading.get_ident(), trace.events()
+
+    loop_tid, events = asyncio.run(go())
+    assert trace.snapshot()["counters"].get("seal_jobs") == 1
+    spans = {e["id"]: e for e in events if e["kind"] == "span"}
+    on_worker = [
+        e for e in spans.values()
+        if e["tid"] != loop_tid and e["name"] in TAIL_SPANS
+    ]
+    assert {e["name"] for e in on_worker} == TAIL_SPANS
+    assert len({e["tid"] for e in on_worker}) == 1, "one job, one thread"
+    for e in on_worker:
+        chain, at = [], e
+        while at["parent"] is not None:
+            at = spans[at["parent"]]
+            chain.append(at["name"])
+        assert root in chain, (e["name"], chain)
+    direct = {
+        name for name in TAIL_SPANS if not name.startswith("delta.verify.")
+    }
+    tree = trace.tree()
+    assert direct <= set(tree[root])
