@@ -242,329 +242,6 @@ def host_fold(kind, member, actor, counter, R: int):
     return state, time.perf_counter() - t0
 
 
-def _producers_arg() -> list:
-    """The ``--producers`` sweep list: a comma-separated count list after
-    the flag (e.g. ``--producers 1,2,4``), a single N, or [1] when the
-    flag is absent (the historical single-producer pipeline)."""
-    if "--producers" in sys.argv:
-        i = sys.argv.index("--producers")
-        if i + 1 < len(sys.argv):
-            try:
-                ns = [int(x) for x in sys.argv[i + 1].split(",") if x.strip()]
-            except ValueError:
-                raise SystemExit(
-                    f"--producers wants N or N,N,... got {sys.argv[i + 1]!r}"
-                )
-            if ns and all(n > 0 for n in ns):
-                return ns
-        raise SystemExit("--producers wants a positive count list")
-    return [1]
-
-
-def e2e_streaming(smoke: bool):
-    """BASELINE config #5 END-TO-END: encrypted op-file blobs in →
-    byte-identical compacted OR-Set state out, measuring the overlapped
-    streaming-compaction pipeline (ops/stream.py; N producer threads run
-    threaded native decrypt + decode for upcoming chunks while the
-    consumer columnarizes and folds the current one, a sequencer keeping
-    chunk order deterministic) against the NON-overlapped
-    single-dispatch front end (every stage sequential) on the identical
-    workload.  ``--producers 1,2,4`` sweeps the fan-out width; every N
-    is byte-equality-checked against the sequential state and records
-    its marginal + obs snapshot.  Prints one JSON line and appends the
-    full record — with the per-stage marginals from the trace spans and
-    the per-N sweep table — to BENCH_LOCAL.jsonl.
-
-    Env knobs: BENCH_E2E_OPS (200_000), BENCH_E2E_REPLICAS (100_000),
-    BENCH_E2E_MEMBERS (1024), BENCH_E2E_OPF (48, ops per file),
-    BENCH_E2E_CHUNKS (8), BENCH_E2E_ITERS (3).
-    """
-    import secrets
-
-    N = int(os.environ.get("BENCH_E2E_OPS", 10_000 if smoke else 200_000))
-    R = int(os.environ.get("BENCH_E2E_REPLICAS", 500 if smoke else 100_000))
-    E = int(os.environ.get("BENCH_E2E_MEMBERS", 128 if smoke else 1024))
-    OPF = int(os.environ.get("BENCH_E2E_OPF", 48))
-    N_CHUNKS = int(os.environ.get("BENCH_E2E_CHUNKS", 8))
-    ITERS = int(os.environ.get("BENCH_E2E_ITERS", 3))
-
-    jax, dev = init_jax(expects_tpu(smoke))
-
-    import crdt_enc_tpu
-    from benchmarks.suite import _build_encrypted_files
-    from crdt_enc_tpu.backends.xchacha import decrypt_blobs_packed
-    from crdt_enc_tpu.models import ORSet
-    from crdt_enc_tpu.parallel import TpuAccelerator
-    from crdt_enc_tpu.utils import codec, trace
-
-    crdt_enc_tpu.enable_compilation_cache()
-    key = secrets.token_bytes(32)
-    payloads, plain, _headers, actors = _build_encrypted_files(
-        N, R, E, OPF, key, n_headers=0
-    )
-    total_ops = sum(len(codec.unpack(p)) for p in plain)
-    accel = TpuAccelerator()
-    actors_sorted = sorted(actors)
-    log(
-        f"e2e_streaming: device {dev.platform}; {len(payloads)} files, "
-        f"{total_ops} ops, R={R} E={E}"
-    )
-
-    # ---- non-overlapped single-dispatch front end: every stage runs to
-    # completion before the next starts (ONE decrypt batch, then decode,
-    # then fold+writeback) — the exact serial sum the pipeline hides
-    def sequential():
-        state = ORSet()
-        session = accel.open_fold_session(state, actors_hint=actors_sorted)
-        packed = decrypt_blobs_packed(key, payloads)
-        session.reduce_chunk(session.decode_chunk(packed))
-        session.finish()
-        return state
-
-    # ---- overlapped pipeline (the product path, accel front door),
-    # swept over the --producers fan-out widths
-    producer_list = _producers_arg()
-
-    def overlapped(n_producers: int):
-        state = ORSet()
-        ok = accel.fold_encrypted_stream(
-            state, key, payloads, actors_hint=actors_sorted,
-            n_chunks=N_CHUNKS, n_producers=n_producers,
-        )
-        assert ok, "accelerator declined the streaming fold"
-        return state
-
-    seq_state = sequential()  # warmup + compile + equality witness
-    seq_bytes = codec.pack(seq_state.to_obj())
-
-    t_seq = min(_timed_host(sequential) for _ in range(ITERS))
-    # per-N: byte equality vs the sequential state, then the best-of-ITERS
-    # wall with the per-stage marginals + full obs snapshot (stage
-    # histograms with p50/p95/p99, recompile + transfer counters,
-    # device-memory gauges) of the best pass.  The accelerator wired
-    # jax_compiles tracking at construction (obs.runtime); a non-zero
-    # count on a post-warmup pass is the ADVICE-r5 recompile bug class.
-    sweep = {}
-    raw_times = {}  # unrounded best wall per N — ratios use these
-    full_batch_equal = True
-    for n_prod in producer_list:
-        ovl_state = overlapped(n_prod)  # warmup + equality witness
-        equal = codec.pack(ovl_state.to_obj()) == seq_bytes
-        full_batch_equal = full_batch_equal and equal
-        log(f"overlapped[N={n_prod}] ≡ sequential (full batch): {equal}")
-        t_best = float("inf")
-        obs_snapshot = {}
-        stage_marginals = {}
-        for _ in range(ITERS):
-            trace.reset()
-            t = _timed_host(lambda: overlapped(n_prod))
-            if t < t_best:
-                t_best = t
-                obs_snapshot = trace.snapshot()
-                stage_marginals = {
-                    name: round(v["seconds"], 4)
-                    for name, v in obs_snapshot["spans"].items()
-                    if name.startswith(("stream.", "session."))
-                }
-        trace.reset()
-        raw_times[str(n_prod)] = t_best
-        sweep[str(n_prod)] = {
-            "e2e_s": round(t_best, 4),
-            "ops_per_sec": round(total_ops / t_best, 1),
-            "speedup_vs_sequential": round(t_seq / t_best, 2),
-            "full_batch_equal": bool(equal),
-            "stage_marginals_s": stage_marginals,
-            "obs": obs_snapshot,
-        }
-        log(
-            f"e2e[N={n_prod}]: overlapped {t_best:.3f}s "
-            f"({total_ops / t_best:,.0f} ops/s) vs sequential {t_seq:.3f}s "
-            f"→ {t_seq / t_best:.2f}x overlap win"
-        )
-    best_n = min(raw_times, key=raw_times.get)
-    t_ovl = raw_times[best_n]  # unrounded — display rounding must not
-    rate = total_ops / t_ovl   # leak into the recorded rate/ratios
-    # machine-checked critical-path attribution of the best pass: the
-    # ROADMAP-item-1 "where did the time go" claim as a number with a
-    # trend trajectory (obs.attribution; render with `obs_report gap`)
-    from crdt_enc_tpu.obs import attribution
-
-    gap_report = attribution.attribute_cycle(
-        sweep[best_n]["obs"], pipeline="streaming", wall_s=t_ovl,
-        ops=total_ops,
-    )
-    if not full_batch_equal:
-        # byte divergence from the sequential scalar path: the number is
-        # meaningless and a record would poison the trend ratchet —
-        # refuse loudly (same contract as --e2e-delta/--e2e-multitenant)
-        log("REFUSING to record: overlapped state diverged from sequential")
-        raise SystemExit(1)
-    result = {
-        "metric": "orset_e2e_streaming_ops_per_sec",
-        "config": "mixed_streaming_100k_e2e",
-        "value": round(rate, 1),
-        "unit": "ops/s",
-        "e2e_overlapped_s": round(t_ovl, 4),
-        "e2e_sequential_s": round(t_seq, 4),
-        "overlap_speedup": sweep[best_n]["speedup_vs_sequential"],
-        "producers_best": int(best_n),
-        # per-N marginal table WITHOUT the obs payloads (those go in the
-        # full BENCH_LOCAL record below) — stdout stays one short line
-        "producer_sweep": {
-            n: {k: v for k, v in rec.items() if k != "obs"}
-            for n, rec in sweep.items()
-        },
-        "stage_marginals_s": sweep[best_n]["stage_marginals_s"],
-        "gap_report": gap_report,
-        "full_batch_equal": bool(full_batch_equal),
-        "backend": dev.platform,
-    }
-    if "1" in raw_times and best_n != "1":
-        result["producer_speedup_vs_1"] = round(
-            raw_times["1"] / t_ovl, 2
-        )
-    print(json.dumps(result))
-    if os.environ.get("BENCH_LOCAL_DISABLE") == "1":
-        return
-    if dev.platform != "tpu" and os.environ.get("BENCH_LOCAL_ALL") != "1":
-        return
-    _append_local({
-        **result,
-        "ts": datetime.datetime.now(datetime.timezone.utc).isoformat(
-            timespec="seconds"),
-        "device_kind": dev.device_kind,
-        # host_cpus contextualizes the overlap number: with ≤2 cores the
-        # producers, the consumer, and the decrypt pool share the same
-        # silicon, so fan-out cannot beat the serial sum — the win
-        # needs a device fold or idle host cores (the TPU configuration)
-        "host_cpus": os.cpu_count(),
-        "shape": {"N": N, "R": R, "E": E, "ops_per_file": OPF,
-                  "files": len(payloads), "n_chunks": N_CHUNKS,
-                  "total_ops": total_ops},
-        # full per-N registry snapshots: per-stage histograms
-        # (p50/p95/p99/max), jax_compiles / h2d_bytes counters, device
-        # memory gauges, the stream_producers gauge — render with
-        # `python -m crdt_enc_tpu.tools.obs_report report BENCH_LOCAL.jsonl`
-        "producer_sweep_obs": {n: rec["obs"] for n, rec in sweep.items()},
-        "obs": sweep[best_n]["obs"],
-    })
-
-
-def device_decode_exp(smoke: bool):
-    """The CRDT_DEVICE_DECODE experiment, measured honestly (ISSUE 13
-    layer 4): decode the fixed-stride add-op framing (a) on device
-    (jnp strided gathers after bulk AEAD, ops/device_decode.py), (b)
-    with the same vectorized extraction on host numpy (the control arm
-    — isolates WHERE the gather runs), and (c) through the production
-    native C decoder (the incumbent).  All three must produce identical
-    columns; the record carries all three walls and names the winner.
-    Runs on an ALL-ADDS corpus — the device kernel's best case by
-    construction; mixed corpora fall back to (c) in production.
-
-    Env knobs: BENCH_DD_OPS (200_000), BENCH_DD_REPLICAS (100_000),
-    BENCH_DD_OPF (48), BENCH_DD_ITERS (5).
-    """
-    import secrets
-
-    N = int(os.environ.get("BENCH_DD_OPS", 10_000 if smoke else 200_000))
-    R = int(os.environ.get("BENCH_DD_REPLICAS", 500 if smoke else 100_000))
-    OPF = int(os.environ.get("BENCH_DD_OPF", 48))
-    ITERS = int(os.environ.get("BENCH_DD_ITERS", 5))
-    jax, dev = init_jax(expects_tpu(smoke))
-
-    import numpy as np
-
-    from crdt_enc_tpu.ops.device_decode import (
-        decode_adds_device, decode_adds_host,
-    )
-    from crdt_enc_tpu.ops.native_decode import decode_orset_payload_batch
-    from crdt_enc_tpu.utils import codec
-
-    rng = np.random.default_rng(7)
-    actors = sorted(secrets.token_bytes(16) for _ in range(R))
-    payloads = []
-    for lo in range(0, N, OPF):
-        ops = [
-            [0, int(rng.integers(0, 128)),
-             [actors[int(rng.integers(0, R))], int(rng.integers(1, 128))]]
-            for _ in range(min(OPF, N - lo))
-        ]
-        payloads.append(codec.pack(ops))
-    lens = np.array([len(p) for p in payloads], np.uint64)
-    offs = np.zeros(len(payloads) + 1, np.uint64)
-    np.cumsum(lens, out=offs[1:])
-    buf = np.frombuffer(b"".join(payloads), np.uint8)
-    packed = (buf, offs)
-    log(
-        f"device_decode: device {dev.platform}; {len(payloads)} payloads, "
-        f"{N} add ops, R={R}"
-    )
-
-    dd = decode_adds_device(packed, actors)
-    assert dd is not None, "all-adds corpus must qualify for the device path"
-    hh = decode_adds_host(packed, actors)
-    nn = decode_orset_payload_batch(list(payloads), actors)
-    # identical columns across all three arms — refuse to record otherwise
-    for name, got in (("host_vectorized", hh), ("native", nn)):
-        assert got is not None, name
-        k2, m2, a2, c2 = got[0], got[1], got[2], got[3]
-        mobj = got[4]
-        assert (np.asarray(k2) == np.asarray(dd[0])).all(), name
-        assert (np.asarray(a2) == np.asarray(dd[2])).all(), name
-        assert (np.asarray(c2) == np.asarray(dd[3])).all(), name
-        # member identity via resolved objects (intern order differs)
-        got_members = [mobj[int(i)] for i in np.asarray(m2)[:64].tolist()]
-        dd_members = [dd[4][int(i)] for i in np.asarray(dd[1])[:64].tolist()]
-        assert got_members == dd_members, name
-
-    def best(fn):
-        t = float("inf")
-        for _ in range(ITERS):
-            t0 = time.perf_counter()
-            r = fn()
-            assert r is not None  # arms validated identical above
-            t = min(t, time.perf_counter() - t0)
-        return t
-
-    t_dev = best(lambda: decode_adds_device(packed, actors))
-    t_host = best(lambda: decode_adds_host(packed, actors))
-    t_native = best(lambda: decode_orset_payload_batch(list(payloads), actors))
-    arms = {"device": t_dev, "host_vectorized": t_host, "native": t_native}
-    winner = min(arms, key=arms.get)
-    result = {
-        "metric": "orset_device_decode_ops_per_sec",
-        "config": f"device_decode_adds_{N // 1000}k",
-        "value": round(N / arms[winner], 1),
-        "unit": "ops/s",
-        "winner": winner,
-        "arms_s": {k: round(v, 5) for k, v in arms.items()},
-        "device_vs_native_x": round(t_dev / t_native, 2),
-        "shape": {"N": N, "R": R, "ops_per_file": OPF,
-                  "files": len(payloads)},
-        "backend": dev.platform,
-    }
-    print(json.dumps(result))
-    if os.environ.get("BENCH_LOCAL_DISABLE") == "1":
-        return
-    if dev.platform != "tpu" and os.environ.get("BENCH_LOCAL_ALL") != "1":
-        return
-    _append_local({
-        **result,
-        "ts": datetime.datetime.now(datetime.timezone.utc).isoformat(
-            timespec="seconds"),
-        "device_kind": dev.device_kind,
-        "host_cpus": os.cpu_count(),
-    })
-
-
-def _timed_host(fn):
-    """Wall-clock one end-to-end pass (host stages dominate; there is no
-    chained-marginal trick to play — the honest number is the wall)."""
-    t0 = time.perf_counter()
-    fn()
-    return time.perf_counter() - t0
-
-
 def _mesh_arg():
     """``--mesh dp=N[,mp=M]`` → ``(dp, mp)`` for the sharded-service
     arm of the multitenant sweep, else None (single-chip only)."""
@@ -2466,12 +2143,6 @@ def main():
         return
     if "--e2e-delta" in sys.argv:
         e2e_delta(smoke)
-        return
-    if "--e2e-streaming" in sys.argv:
-        e2e_streaming(smoke)
-        return
-    if "--device-decode" in sys.argv:
-        device_decode_exp(smoke)
         return
     if "--e2e-warm-open" in sys.argv:
         e2e_warm_open(smoke)

@@ -2,9 +2,10 @@
 
 VERDICT r4 weak items 1/6: same-run host rates swing 1.5× with machine
 weather, so published speedups need ONE committed idle-box denominator
-per config.  This tool runs ONLY the host loops of the five suite
-configs (exact same generators and subsamples — the ``host_only`` mode
-of each ``bench_*``) under the median-of-N protocol and writes
+per config.  This tool runs ONLY the host loops of the four suite
+configs and the daemon fleet (exact same generators and subsamples —
+the ``host_only`` mode of each ``bench_*``) under the median-of-N
+protocol and writes
 ``benchmarks/pinned_baselines.json`` with raw samples.
 
 Run it on an otherwise-idle box:
@@ -69,7 +70,7 @@ def main():
     ap.add_argument("--runs", type=int, default=0,
                     help="host runs per config (default BENCH_HOST_RUNS)")
     ap.add_argument("--config", type=int, default=0,
-                    help="re-pin one config (1-6) only")
+                    help="re-pin one config (1-4, 6) only")
     ap.add_argument("--force", action="store_true",
                     help="write pins even past the spread gate (warns)")
     args = ap.parse_args()
@@ -84,7 +85,6 @@ def main():
     from bench import PINNED_PATH, e2e_daemon_host
     from benchmarks.suite import (
         bench_gcounter, bench_lwwmap, bench_orset, bench_pncounter,
-        bench_streaming,
     )
 
     runners = {
@@ -94,9 +94,6 @@ def main():
                                iters=0, host_only=True),
         4: lambda: bench_lwwmap(1_000_000, 1_000_000, 10_000,
                                 n_host=50_000, iters=0, host_only=True),
-        5: lambda: bench_streaming(200_000, 100_000, 1024, ops_per_file=48,
-                                   n_host_files=300, iters=0,
-                                   host_only=True),
         # the daemon family (ISSUE 12): sequential solo compacts over
         # the default --e2e-daemon fleet head shape — the denominator
         # the daemon's aggregate ops/s is ratioed against, so the

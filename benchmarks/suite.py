@@ -1,4 +1,6 @@
-"""The five BASELINE.json benchmark configs, host-reference vs device.
+"""Four of the five BASELINE.json benchmark configs, host-reference vs
+device (config 5, the encrypted streaming pipeline, went with the
+bench-only front door that it drove: PR 28).
 
 Each config measures: single-core host-reference fold rate (the per-op
 loop the reference runs, capped to a subsample for the big configs — the
@@ -6,11 +8,9 @@ loop is O(n) so per-op rate transfers), device fold rate, and a
 byte-equality check of the folded state against the host reference on a
 common subsample.
 
-Configs 1-4 time the fold as the MARGINAL cost inside a chained
+Every config times the fold as the MARGINAL cost inside a chained
 ``lax.scan`` (``timeit_marginal``) so the fixed per-dispatch cost
-cancels; config 5 is an end-to-end streaming pipeline (decrypt → decode →
-fold) timed wall-clock, dispatch latency included — there the host-side
-crypto/decode dominates and end-to-end is the honest number.
+cancels.
 
 Run:  python benchmarks/suite.py [--smoke] [--config N] [--cpu]
 Prints one JSON line per config and a trailing summary line.
@@ -579,157 +579,13 @@ def bench_lwwmap(N: int, K_keys: int, R: int, n_host: int, iters: int,
     )
 
 
-# ----------------------------------------------------------------- config 5
-
-
-def _build_encrypted_files(N, R, E, ops_per_file, key, n_headers):
-    """Columns → per-(actor)-ordered op files, sealed with the native AEAD,
-    plus a few header-CRDT (Keys-style MVReg) blobs mixed in."""
-    import bench as north
-
-    from crdt_enc_tpu.backends.xchacha import encrypt_blob
-    from crdt_enc_tpu.models import MVReg
-    from crdt_enc_tpu.utils import codec
-
-    kind, member, actor, counter = north.gen_columns(N, R, E, seed=5)
-    actors = actor_bytes_table(R)
-    live = actor < R
-    order = np.argsort(actor[live], kind="stable")
-    k_l = kind[live][order]
-    m_l = member[live][order]
-    a_l = actor[live][order]
-    c_l = counter[live][order]
-
-    payloads, plain_payloads = [], []
-    i, n = 0, len(k_l)
-    while i < n:
-        j = min(i + ops_per_file, n)
-        # keep a file within one actor (files are per (actor, version))
-        j = i + int(np.searchsorted(a_l[i:j], a_l[i], side="right"))
-        ops = []
-        for t in range(i, j):
-            ab = actors[int(a_l[t])]
-            if k_l[t] == 0:
-                ops.append([0, int(m_l[t]), [ab, int(c_l[t])]])
-            else:
-                ops.append([1, int(m_l[t]), {ab: int(c_l[t])}])
-        raw = codec.pack(ops)
-        plain_payloads.append(raw)
-        payloads.append(encrypt_blob(key, raw))
-        i = j
-
-    headers = []
-    for h in range(n_headers):
-        reg = MVReg()
-        reg.apply(reg.write_ctx(actors[h % R], [b"hdr", h]))
-        headers.append(encrypt_blob(key, codec.pack(reg.to_obj())))
-    return payloads, plain_payloads, headers, actors
-
-
-def bench_streaming(N, R, E, ops_per_file, n_host_files, iters,
-                    host_only: bool = False) -> dict:
-    """Config 5: mixed header-CRDT + OR-Set, 100k replicas, streaming
-    compaction with the XChaCha20-Poly1305 decrypt front end."""
-    import secrets
-
-    from crdt_enc_tpu.backends.xchacha import decrypt_blob, decrypt_blobs
-    from crdt_enc_tpu.models import MVReg, ORSet
-    from crdt_enc_tpu.models.orset import AddOp, RmOp
-    from crdt_enc_tpu.models.vclock import Dot, VClock
-    from crdt_enc_tpu.utils import codec
-
-    key = secrets.token_bytes(32)
-    payloads, plain, headers, actors = _build_encrypted_files(
-        N, R, E, ops_per_file, key, n_headers=max(1, len(str(N)))
-    )
-    n_files = len(payloads)
-    n_ops = sum(len(codec.unpack(p)) for p in plain[:n_host_files])
-    log(f"  streaming: {n_files} files, {len(headers)} headers")
-
-    # ---- single-core host baseline: sequential decrypt → decode → apply,
-    # median-of-HOST_RUNS passes with raw samples recorded (the pinned
-    # protocol — single-pass timing showed 3x run-to-run variance)
-    def host_once():
-        state = ORSet()
-        t0 = time.perf_counter()
-        for blob in payloads[:n_host_files]:
-            raw = decrypt_blob(key, blob)
-            for o in codec.unpack(raw):
-                if o[0] == 0:
-                    state.apply(AddOp(o[1], Dot.from_obj(o[2])))
-                else:
-                    state.apply(RmOp(o[1], VClock.from_obj(o[2])))
-        for h in headers:
-            MVReg.from_obj(codec.unpack(decrypt_blob(key, h)))
-        return time.perf_counter() - t0, state
-
-    t_host, host_times, state = host_median(host_once)
-    host_rate = n_ops / t_host
-    if host_only:
-        return _host_only_record(
-            "mixed_streaming_100k", n_ops,
-            dict(R=R, E=E, ops_per_file=ops_per_file,
-                 n_host_files=n_host_files), t_host, host_times)
-
-    # ---- streaming pipeline: chunked threaded batch decrypt overlapping
-    # the native columnar decode (fold_payload_stream), then one sparse
-    # fold at this replica scale.  This is the same machinery the product
-    # ingest runs: Core's bulk path feeds open_payload_stream under a
-    # decrypt lookahead (core.py _read_remote_ops_bulk), and the pipelined
-    # session's BUFFER mode finishes through the identical
-    # _fold_orset_columns tail; the full product path on a real remote is
-    # measured separately in benchmarks/compaction_e2e.py.  Headers
-    # decoded host-side, they are tiny.
-    from crdt_enc_tpu.backends.xchacha import decrypt_blobs_chunked
-    from crdt_enc_tpu.parallel import TpuAccelerator
-
-    accel = TpuAccelerator()
-    actors_sorted = sorted(actors)
-
-    def pipeline():
-        folded = ORSet()
-        chunks = decrypt_blobs_chunked(key, payloads, n_chunks=8)
-        for h in decrypt_blobs(key, headers):
-            MVReg.from_obj(codec.unpack(h))
-        ok = accel.fold_payload_stream(folded, chunks, actors_hint=actors_sorted)
-        assert ok, "accelerator declined the bulk payload batch"
-        return folded
-
-    total_ops = sum(len(codec.unpack(p)) for p in plain)
-    pipeline()  # warmup + compile
-    t_dev = float("inf")
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        folded = pipeline()
-        t_dev = min(t_dev, time.perf_counter() - t0)
-    dev_rate = total_ops / t_dev
-
-    # ---- byte equality: same product path over the host subsample files
-    sub = ORSet()
-    ok = accel.fold_payloads(
-        sub, decrypt_blobs(key, payloads[:n_host_files]), actors_hint=actors_sorted
-    )
-    equal = bool(ok) and codec.pack(sub.to_obj()) == codec.pack(state.to_obj())
-    return dict(
-        config="mixed_streaming_100k", metric="ops_streamed_per_sec",
-        _pin_shape=dict(R=R, E=E, ops_per_file=ops_per_file,
-                        n_host_files=n_host_files),
-        N=total_ops, R=R, E=E, files=n_files,
-        host_rate=host_rate, device_rate=dev_rate, byte_equal=bool(equal),
-        **host_stats(host_times),
-        # end-to-end host pipeline (AEAD + decode dominate): the HBM
-        # roofline is not the binding resource, so no pct is reported
-        timing="end_to_end", bytes_model=None,
-    )
-
-
 # --------------------------------------------------------------------- main
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--config", type=int, default=0, help="run one config (1-5)")
+    ap.add_argument("--config", type=int, default=0, help="run one config (1-4)")
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument(
         "--cpu", action="store_true",
@@ -762,10 +618,6 @@ def main():
         4: lambda: bench_lwwmap(
             S(1_000_000), min(1_000_000, S(1_000_000)), min(10_000, S(10_000)),
             n_host=S(50_000, lo=2_000), iters=args.iters, cmul=cmul,
-        ),
-        5: lambda: bench_streaming(
-            S(200_000), min(100_000, S(100_000)), min(1024, S(1024)),
-            ops_per_file=48, n_host_files=S(300, lo=20), iters=args.iters,
         ),
     }
     from bench import roofline_pct

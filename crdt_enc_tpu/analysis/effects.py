@@ -132,7 +132,7 @@ _LAUNDER_ARG = {"to_thread": 0, "run_in_executor": 1}
 #: named seams whose *implementation* is the sanctioned producer pool —
 #: blocks effects do not propagate across a call to them (the blocking
 #: work runs on pool threads; the entry point itself stays loop-safe).
-_LAUNDER_CALLEES = {"run_ingest_pipeline", "run_striped_ingest_pipeline"}
+_LAUNDER_CALLEES = {"run_ingest_pipeline"}
 
 _LOCK_CTORS = {
     "threading.Lock": "threading",

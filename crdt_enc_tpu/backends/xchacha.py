@@ -334,50 +334,6 @@ def decrypt_blobs(key: bytes, blobs: list, n_threads: int = 0) -> list:
     return res
 
 
-def decrypt_blobs_chunked(
-    key: bytes, blobs: list, *, chunk_blobs: int = 0, n_chunks: int = 8,
-    n_threads: int = 0,
-):
-    """Yield decrypted chunks with one-chunk lookahead: chunk i+1 decrypts
-    on a worker thread (the native batch call releases the GIL) while the
-    consumer decodes/folds chunk i.  Feeds
-    ``TpuAccelerator.fold_payload_stream``; same error semantics as
-    ``decrypt_blobs``, surfaced at the failing chunk."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    n = len(blobs)
-    if n == 0:
-        return
-    if chunk_blobs <= 0:
-        chunk_blobs = max(1, -(-n // max(n_chunks, 1)))
-    spans = [blobs[i : i + chunk_blobs] for i in range(0, n, chunk_blobs)]
-
-    def open_chunk(span):
-        packed = decrypt_blobs_packed(key, span, n_threads)
-        return packed if packed is not None else decrypt_blobs(
-            key, span, n_threads
-        )
-
-    if (os.cpu_count() or 1) <= 1:
-        # one core: the lookahead thread cannot overlap anything real —
-        # it only adds executor/context-switch overhead (~8ms at the
-        # config-5 shape, measured round 5) — so decrypt synchronously
-        for span in spans:
-            yield open_chunk(span)
-        return
-
-    with ThreadPoolExecutor(1) as ex:
-        fut = ex.submit(open_chunk, spans[0])
-        for i in range(len(spans)):
-            nxt = (
-                ex.submit(open_chunk, spans[i + 1])
-                if i + 1 < len(spans)
-                else None
-            )
-            yield fut.result()
-            fut = nxt
-
-
 class XChaChaCryptor(Cryptor):
     async def gen_key(self) -> VersionBytes:
         return VersionBytes(XCHACHA_KEY_VERSION_1, secrets.token_bytes(KEY_LEN))

@@ -51,7 +51,6 @@ from .storage import SEAL_TAIL_TWINS, Storage
 
 IO_CONCURRENCY = 16  # bounded pipeline width (reference lib.rs:452,512)
 BULK_MIN_FILES = 16  # below this the per-file asyncio path is cheaper
-BULK_STREAM_CHUNK = 16384  # files per decrypt-lookahead chunk (bulk ingest)
 
 # local fold-checkpoint payload formats (docs/checkpointing.md)
 CHECKPOINT_FMT_OBJ = 0  # adapter.state_to_obj (any CRDT type)
@@ -1868,52 +1867,26 @@ class Core:
                         except StopAsyncIteration:
                             break
                     with trace.span("ops.chunk_unwrap", meta=ci):
-                        kept, key_ids, middles = [], [], []
-                        for f in files:
-                            actor, version, raw = f
-                            if actor in cut:
-                                continue
-                            try:
-                                outer = VersionBytes.deserialize(
-                                    raw
-                                ).ensure_versions(SUPPORTED_CONTAINER_VERSIONS)
-                                kid, middle = codec.unpack(outer.content)
-                            except Exception as e:
-                                # torn outer envelope: quarantine the
-                                # file + end this actor's dense run
-                                # (the cursor holds at the hole)
-                                self._note_quarantine(
-                                    "op", f"{actor.hex()}:v{version}", e
-                                )
-                                cut.add(actor)
-                                continue
-                            kept.append(f)
-                            key_ids.append(bytes(kid))
-                            middles.append(bytes(middle))
-                    files = kept
-                    groups: dict[bytes, list[int]] = {}
-                    for i, kid in enumerate(key_ids):
-                        groups.setdefault(kid, []).append(i)
+                        files, groups = self._unwrap_op_files(files, cut)
                     clears: list = [None] * len(files)
                     with trace.span("ops.chunk_decrypt", meta=ci):
-                        for kid, idxs in groups.items():
-                            key = self._data.keys.get_key(kid)
-                            if key is None:
-                                raise MissingKeyError(
-                                    "ops sealed with unknown key "
-                                    f"{uuid.UUID(bytes=kid)}; key metadata "
-                                    "may not have synced yet"
-                                )
+                        # each key resolves just before its own group
+                        # opens: an unsynced key raises only after the
+                        # groups ahead of it noted their quarantines
+                        for kid, idxs, mids in groups:
                             outs = await self._decrypt_tolerant(
-                                key,
+                                self._sealing_key(kid),
                                 [files[i] for i in idxs],
-                                [middles[i] for i in idxs],
+                                mids,
                             )
                             for i, clear in zip(idxs, outs):
                                 clears[i] = clear
-                    trace.add("bytes_decrypted", sum(len(m) for m in middles))
+                    trace.add(
+                        "bytes_decrypted",
+                        sum(len(m) for _, _, mids in groups for m in mids),
+                    )
                     if files:
-                        await q.put(("chunk", files, clears))
+                        await q.put(("chunk", ci, files, clears))
                         ci += 1
                 await q.put(("end",))
             except Exception as e:
@@ -1943,7 +1916,7 @@ class Core:
             return False
         session_done = False
         python_mode = False
-        pending: list[tuple[list, list]] = []  # buffered below BULK_MIN_FILES
+        pending: list[tuple] = []  # chunks buffered below BULK_MIN_FILES
         pending_files = 0
         session_started = False
         fed_files = 0
@@ -1957,7 +1930,7 @@ class Core:
         # configured fan-out, else the cpu-count auto-tune.
         from ..ops.stream import stream_producer_count
 
-        inflight: list[tuple] = []  # (decode_task, metas, files, clears)
+        inflight: list[tuple] = []  # (decode_task, metas, ci, files, clears)
         n_producers = stream_producer_count(
             getattr(self.accel, "stream_producers", 0)
         )
@@ -1985,12 +1958,14 @@ class Core:
             reduce it (serialized), advance its cursors.  A decline flips
             to per-op python folds for it and everything after."""
             nonlocal python_mode, fed_files
-            task, metas, files, clears = inflight.pop(0)
+            task, metas, ci, files, clears = inflight.pop(0)
             try:
                 decoded = await task
                 if python_mode:
                     raise SessionDeclined("session already degraded")
-                with trace.span("ops.chunk_fold"):
+                # the chunk index pairs this fold with the producer's
+                # ops.chunk_* spans, so the overlap is event-auditable
+                with trace.span("ops.chunk_fold", meta=ci):
                     await asyncio.to_thread(session.reduce_chunk, decoded)
             except SessionDeclined:
                 if not python_mode:
@@ -2001,7 +1976,7 @@ class Core:
                 # this one — fold them NOW, in order, or a newer chunk
                 # would fold first and trip the version-gap check
                 while inflight:
-                    t2, _m2, f2, c2 = inflight.pop(0)
+                    t2, _m2, _ci2, f2, c2 = inflight.pop(0)
                     t2.cancel()
                     try:
                         await t2
@@ -2012,7 +1987,7 @@ class Core:
             self._advance_cursors(metas)
             fed_files += len(files)
 
-        async def dispatch(files, clears) -> None:
+        async def dispatch(ci, files, clears) -> None:
             nonlocal python_mode
             if python_mode:
                 await self._fold_chunk_python(files, clears, blocked)
@@ -2025,7 +2000,7 @@ class Core:
             task = asyncio.create_task(
                 asyncio.to_thread(session.decode_chunk, payloads)
             )
-            inflight.append((task, metas, files, clears))
+            inflight.append((task, metas, ci, files, clears))
             if len(inflight) >= MAX_DECODES:
                 await drain_one()
 
@@ -2039,25 +2014,25 @@ class Core:
                     break
                 if tag == "error":
                     raise item[1]
-                _, files, clears = item
+                _, ci, files, clears = item
                 if not session_started and not python_mode:
-                    pending.append((files, clears))
+                    pending.append((ci, files, clears))
                     pending_files += len(files)
                     if pending_files < BULK_MIN_FILES:
                         continue
                     session_started = True
                     backlog, pending = pending, []
-                    for f, c in backlog:
-                        await dispatch(f, c)
+                    for chunk in backlog:
+                        await dispatch(*chunk)
                     continue
-                await dispatch(files, clears)
+                await dispatch(ci, files, clears)
             # stream fully consumed; a never-promoted tiny ingest folds
             # per-op, the same shape as the legacy small path (decrypt
             # already happened, batched)
             while inflight:
                 await drain_one()
             await finish_session()
-            for files, clears in pending:
+            for _, files, clears in pending:
                 await self._fold_chunk_python(files, clears, blocked)
             pending = []
             return True
@@ -2078,111 +2053,28 @@ class Core:
         :class:`_Quarantined`) instead of surprising the ingest; key-auth
         and op-order violations raise exactly as the per-file path would
         (lib.rs:519-531 semantics preserved)."""
-        files, groups = self._unwrap_op_files(files)
+        files, groups = self._unwrap_resolved(files)
         if not files:
             return True  # every file quarantined: consumed, cursors held
-
-        # Single sealing key (the overwhelmingly common case) + a stream-
-        # capable accelerator: chunked decrypt with one-chunk lookahead —
-        # the worker thread decrypts chunk i+1 (native, GIL released)
-        # while this thread validates and span-decodes chunk i; one
-        # combined fold at the end.  The same pipeline benchmarks/suite.py
-        # config 5 measures.
-        open_stream = getattr(self.accel, "open_payload_stream", None)
-        stream = (
-            open_stream(self._data.state, actors_hint=actors)
-            if open_stream is not None and len(groups) == 1
-            else None
-        )
-        payload_chunks: list[list] = []
-        metas: list = []
-        overlay: dict[Actor, int] = {}
-        barred: set[Actor] = set()  # actors cut at a quarantined file
-        streamed_ok = stream is not None
         with trace.span("ops.bulk_decrypt"):
-            if stream is not None:
-                (key, idxs, mids), = groups
-                CH = BULK_STREAM_CHUNK
-                slices = [idxs[i : i + CH] for i in range(0, len(idxs), CH)]
-                mid_slices = [
-                    mids[i : i + CH] for i in range(0, len(mids), CH)
-                ]
-
-                async def decrypt_chunk(si):
-                    # per-chunk producer stage, span-tagged with the chunk
-                    # index so the overlap with the consumer's decode below
-                    # is auditable from the trace event log (the same
-                    # stream.* stage names the ops/stream.py pipeline and
-                    # bench.py --e2e-streaming use)
-                    with trace.span("stream.decrypt", meta=si):
-                        return await self._decrypt_tolerant(
-                            key,
-                            [files[i] for i in slices[si]],
-                            mid_slices[si],
-                        )
-
-                nxt = asyncio.create_task(decrypt_chunk(0))
-                try:
-                    for si, sl in enumerate(slices):
-                        clears = await nxt
-                        nxt = (
-                            asyncio.create_task(decrypt_chunk(si + 1))
-                            if si + 1 < len(slices)
-                            else None
-                        )
-                        if nxt is not None:
-                            # a created task has not executed yet: one tick
-                            # steps it into its to_thread so the worker
-                            # decrypts WHILE this thread validates+decodes
-                            # (without this the "lookahead" is serialized)
-                            await asyncio.sleep(0)
-                        # sync: inner version checks WITHOUT cursor advance
-                        # — cursors move only after the fold lands (same
-                        # discipline as the pipelined path; an OpOrderError
-                        # mid-batch must not strand validated-but-unfolded
-                        # ops behind advanced cursors)
-                        with trace.span("stream.validate", meta=si):
-                            p, m = self._validate_chunk(
-                                [files[i] for i in sl], clears, overlay,
-                                barred,
-                            )
-                        metas.extend(m)
-                        payload_chunks.append(p)
-                        if streamed_ok:
-                            with trace.span("stream.decode", meta=si):
-                                streamed_ok = stream.feed(p)
-                finally:
-                    if nxt is not None:
-                        nxt.cancel()
-                        try:
-                            await nxt
-                        except (asyncio.CancelledError, Exception):
-                            pass
-            else:
-                clears: list = [None] * len(files)
-                for key, idxs, mids in groups:
-                    outs = await self._decrypt_tolerant(
-                        key, [files[i] for i in idxs], mids
-                    )
-                    for i, clear in zip(idxs, outs):
-                        clears[i] = clear
-                p, m = self._validate_chunk(files, clears, overlay, barred)
-                metas.extend(m)
-                payload_chunks.append(p)
+            clears: list = [None] * len(files)
+            for key, idxs, mids in groups:
+                outs = await self._decrypt_tolerant(
+                    key, [files[i] for i in idxs], mids
+                )
+                for i, clear in zip(idxs, outs):
+                    clears[i] = clear
+            # cursors move only after the fold lands: an OpOrderError
+            # mid-batch must not strand validated ops behind them
+            payloads, metas = self._validate_chunk(files, clears)
         trace.add(
             "bytes_decrypted",
             sum(len(m) for _, _, mids in groups for m in mids),
         )
-
-        payloads = [p for chunk in payload_chunks for p in chunk]
         if not payloads:
             return True
         with trace.span("ops.bulk_fold"):
-            if streamed_ok and stream.finish():
-                self._advance_cursors(metas)
-                trace.add("op_files_bulk_folded", len(payloads))
-                return True
-            if stream is None and self.accel.fold_payloads(
+            if self.accel.fold_payloads(
                 self._data.state, payloads, actors_hint=actors
             ):
                 self._advance_cursors(metas)
@@ -2200,53 +2092,64 @@ class Core:
             trace.add("ops_folded", len(batch))
         return True
 
-    # -------------------------------------------------- serving front end
-    def _unwrap_op_files(self, files: list):
+    # ------------------------------------- the unwrap rule (every ingest door)
+    def _unwrap_op_files(self, files: list, cut: set | None = None):
         """Outer-envelope unwrap of loaded op files, grouped by sealing
-        key: ``(kept, [(key, idxs, middles)])`` — ONE implementation of
-        the unwrap → group → key-resolve sequence shared by the
-        whole-batch bulk ingest and the serving front end (a wire or
-        error-message change must have one home).  A file whose outer
-        framing does not parse is QUARANTINED (counter + warning, the
-        actor's dense run ends there, cursor held — see
+        key id: ``(kept, [(kid, idxs, middles)])`` — ONE implementation
+        of the unwrap → group sequence for every ingest door (solo
+        pipelined, solo whole-batch, serve); with :meth:`_sealing_key`
+        a wire or error-message change has one home.  A file whose
+        outer framing does not parse is QUARANTINED (counter + warning,
+        the actor's dense run ends there, cursor held — see
         :class:`_Quarantined`), so ``kept`` may be shorter than
-        ``files``; ``idxs`` index into ``kept``.  An unsynced sealing
-        key raises :class:`MissingKeyError` — loud, not damage."""
-        with trace.span("ops.bulk_unwrap"):
-            kept, key_ids, middles = [], [], []
-            cut: set = set()
-            for f in files:
-                actor, version, raw = f
-                if actor in cut:
-                    continue
-                try:
-                    outer = VersionBytes.deserialize(raw).ensure_versions(
-                        SUPPORTED_CONTAINER_VERSIONS
-                    )
-                    kid, middle = codec.unpack(outer.content)
-                except Exception as e:
-                    self._note_quarantine(
-                        "op", f"{actor.hex()}:v{version}", e
-                    )
-                    cut.add(actor)
-                    continue
-                kept.append(f)
-                key_ids.append(bytes(kid))
-                middles.append(bytes(middle))
-        by_kid: dict[bytes, list[int]] = {}
-        for i, kid in enumerate(key_ids):
-            by_kid.setdefault(kid, []).append(i)
-        groups = []
-        for kid, idxs in by_kid.items():
-            key = self._data.keys.get_key(kid)
-            if key is None:
-                raise MissingKeyError(
-                    f"ops sealed with unknown key {uuid.UUID(bytes=kid)}; "
-                    "key metadata may not have synced yet"
+        ``files``; ``idxs`` index into ``kept``.  ``cut`` is the set of
+        actors already ended by a quarantine: the pipelined door hands
+        in the one it carries across chunks, and it is added to."""
+        if cut is None:
+            cut = set()
+        kept: list = []
+        by_kid: dict[bytes, tuple[list, list]] = {}
+        for f in files:
+            actor, version, raw = f
+            if actor in cut:
+                continue
+            try:
+                outer = VersionBytes.deserialize(raw).ensure_versions(
+                    SUPPORTED_CONTAINER_VERSIONS
                 )
-            groups.append((key, idxs, [middles[i] for i in idxs]))
-        return kept, groups
+                kid, middle = codec.unpack(outer.content)
+            except Exception as e:
+                self._note_quarantine("op", f"{actor.hex()}:v{version}", e)
+                cut.add(actor)
+                continue
+            idxs, mids = by_kid.setdefault(bytes(kid), ([], []))
+            idxs.append(len(kept))
+            mids.append(bytes(middle))
+            kept.append(f)
+        return kept, [(kid, *group) for kid, group in by_kid.items()]
 
+    def _sealing_key(self, kid: bytes) -> Key:
+        """The key an op file names as its sealer.  An unsynced key
+        raises :class:`MissingKeyError` — loud, not damage."""
+        key = self._data.keys.get_key(kid)
+        if key is None:
+            raise MissingKeyError(
+                f"ops sealed with unknown key {uuid.UUID(bytes=kid)}; "
+                "key metadata may not have synced yet"
+            )
+        return key
+
+    def _unwrap_resolved(self, files: list):
+        """:meth:`_unwrap_op_files` with every sealing key resolved
+        before anything opens — the whole-batch doors (solo bulk,
+        serve): ``(kept, [(key, idxs, middles)])``."""
+        with trace.span("ops.bulk_unwrap"):
+            files, groups = self._unwrap_op_files(files)
+        return files, [
+            (self._sealing_key(kid), idxs, mids) for kid, idxs, mids in groups
+        ]
+
+    # -------------------------------------------------- serving front end
     async def load_sealed_ops(self):
         """The multi-tenant serving layer's ingest front end
         (crdt_enc_tpu/serve/service.py): list + load + outer-unwrap
@@ -2274,7 +2177,7 @@ class Core:
         trace.add("op_files_loaded", len(files))
         if not files:
             return actors, [], []
-        files, groups = self._unwrap_op_files(files)
+        files, groups = self._unwrap_resolved(files)
         return actors, files, groups
 
     # --------------------------------------------------------- delta sealing

@@ -16,9 +16,10 @@ names the **critical-path stage**, and emits the **gap report**:
 end-to-end ops/s vs the fold-marginal ops/s (what throughput would be
 if only the fold stage existed), with the dominant stage named — the
 number ROADMAP item 1 closes, now with a trend trajectory because
-``bench.py`` attaches it to every ``--e2e-streaming`` /
-``--e2e-multitenant`` record and ``obs_report gap`` reads both sink
-files and the committed BENCH_LOCAL records.
+``bench.py`` attaches it to every ``--e2e-multitenant`` record (and
+attached it to the committed ``--e2e-streaming`` records, a mode since
+deleted) and ``obs_report gap`` reads both sink files and the committed
+BENCH_LOCAL records.
 
 Span aggregates nest (``stream.ingest`` wraps ``stream.decrypt`` +
 ``stream.decode``; ``session.decode`` runs inside ``stream.decode``),
@@ -40,6 +41,9 @@ STAGES = ("ingest", "decrypt", "decode", "h2d", "fold", "scatter", "seal")
 # stage -> groups of alternative span names (module docs).  The
 # streaming map covers the solo pipeline (ops/stream + session + the
 # bulk/legacy core paths); the serve map covers a FoldService cycle.
+# stream.decrypt, stream.decode and stream.finish have no emitter left:
+# they stay as alternatives so the committed BENCH_LOCAL records of the
+# deleted bench-only pipeline still read (the gap rendering's golden).
 _STREAM_STAGES: dict[str, tuple[tuple[str, ...], ...]] = {
     # ops.chunk_load: the pipelined ingest's file loads (ops.load never
     # fires on that path)

@@ -24,7 +24,7 @@ fleet aggregator merges.
 
 Wiring: set ``CRDT_OBS_SINK=/path/run.jsonl`` and every ``Core.compact``
 (and every ``tools/fsck --obs`` run) appends a snapshot automatically
-(:func:`maybe_write`); ``bench.py --e2e-streaming`` embeds the same
+(:func:`maybe_write`); ``bench.py --e2e-multitenant`` embeds the same
 snapshot shape in its BENCH_LOCAL record; :func:`configure` sets the
 sink programmatically.  ``python -m crdt_enc_tpu.tools.obs_report``
 consumes the files.

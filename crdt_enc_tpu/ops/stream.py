@@ -33,15 +33,14 @@ here runs it CONCURRENTLY with the device fold:
   (:class:`ChunkPool`) instead of allocating per chunk — the host buffer
   for chunk k is recycled the moment its transfer lands.
 
-Every stage is timed through ``utils.trace`` spans (``stream.decrypt``,
-``stream.decode``, ``stream.ingest``, ``stream.h2d``, ``stream.fold``,
+Every stage is timed through ``utils.trace`` spans (``stream.ingest``,
+``stream.columnarize``, ``stream.h2d``, ``stream.fold``,
 ``stream.reduce``, ``stream.d2h``, plus ``stream.producer.wait`` /
 ``stream.sequence`` and the ``stream_producers`` gauge for the fan-out
 stage) with the chunk index as span ``meta``,
 so the overlap is auditable from the event log
 (``trace.enable_events()``) — tests/test_streaming_pipeline.py pins that
-chunk k+1's ingest starts before chunk k's fold completes, and
-``bench.py --e2e-streaming`` publishes the per-stage marginals.
+chunk k+1's ingest starts before chunk k's fold completes.
 
 Exactness: chunked ≡ whole-batch under the causal-delivery contract the
 core guarantees (per-actor op files apply in version order, core.py
@@ -73,14 +72,11 @@ def stream_producer_count(requested: int = 0) -> int:
     ``os.cpu_count()``.
 
     Auto-tune policy: **one producer per core, minus one core reserved
-    for the consumer** (columnarize + fold dispatch), floor 1.  The old
-    cap of 4 predated file-granular stripe claiming — with producers
-    cooperating on one chunk's stripes through the unified work queue
-    (:func:`run_striped_ingest_pipeline`) the decrypt front end scales
-    with the cores actually present, and an idle 32-core host should
-    not be throttled to 4 lanes.  Boxes where wide fan-out genuinely
-    thrashes (shared/throttled cgroups) pin ``CRDT_STREAM_PRODUCERS``
-    instead of everyone paying a global ceiling."""
+    for the consumer** (columnarize + fold dispatch), floor 1 — an idle
+    32-core host should not be throttled to a fixed handful of lanes.
+    Boxes where wide fan-out genuinely thrashes (shared/throttled
+    cgroups) pin ``CRDT_STREAM_PRODUCERS`` instead of everyone paying a
+    global ceiling."""
     if requested > 0:
         return int(requested)
     env = os.environ.get("CRDT_STREAM_PRODUCERS", "")
@@ -516,217 +512,6 @@ def run_ingest_pipeline(
                         stash[k] = item  # holds its slot until reduced
                 if tag == "error":
                     continue  # drain the pre-failure prefix, then raise
-            try:
-                with trace.span("stream.reduce", meta=expected):
-                    reduce_fn(item, expected)
-            finally:
-                slots.release()
-            expected += 1
-    finally:
-        stop.set()
-        for w in workers:
-            w.join(timeout=30.0)
-
-
-class _ChunkWork:
-    """One claimed chunk on the unified work queue: its stripe list, the
-    claim cursor, the landed parts, and the remaining-stripe count."""
-
-    __slots__ = ("span", "stripes", "next_stripe", "remaining", "parts")
-
-    def __init__(self, span, stripes):
-        self.span = span
-        self.stripes = stripes
-        self.next_stripe = 0
-        self.remaining = len(stripes)
-        self.parts = [None] * len(stripes)
-
-
-def run_striped_ingest_pipeline(
-    spans, split_fn, stripe_fn, assemble_fn, reduce_fn, *,
-    depth: int = 0, producers: int = 1, inline: bool | None = None,
-    thread_prefix: str = "crdt-ingest-producer",
-):
-    """File-granular fan-out over ``spans``: the unified work queue.
-
-    The chunk-granular pipeline above assigns each producer a WHOLE
-    chunk — one oversized op file then serializes its lane while its
-    peers idle, and the only recourse was a nested native decrypt pool
-    inside the chunk (threads × threads oversubscription).  Here the
-    work unit is a **stripe** (a file subrange of one chunk,
-    ``split_fn(span, k) -> [stripe, ...]``): producers claim stripes
-    from a single shared queue — preferring the OLDEST in-flight
-    chunk's unclaimed stripes, opening a new chunk (in index order,
-    after a backpressure-slot acquire, exactly the chunk-pipeline
-    discipline) only when none are left — so a giant file occupies one
-    worker while the rest of the pool keeps the pipeline full, and the
-    in-chunk thread pool is gone.
-
-    ``stripe_fn(stripe, k, s) -> part`` runs concurrently (decrypt —
-    native, GIL released).  The worker that lands a chunk's LAST stripe
-    runs ``assemble_fn(parts, span, k) -> item`` (decode) and emits it;
-    the calling thread reduces items in STRICT chunk order via the same
-    sequencer as :func:`run_ingest_pipeline`, so the folded bytes are
-    identical at any producer count and any stripe split.  Backpressure
-    bounds live chunks to ``depth`` (0 = ``producers + 1``).
-
-    ``inline`` (None = auto): with one producer on a single-core host
-    the worker thread cannot overlap anything real — it only adds
-    queue/GIL handoffs — so the whole pipeline runs inline on the
-    calling thread, byte-identically.  Explicit ``inline=False`` forces
-    the threaded path (tests exercise the seams on any box).
-
-    Error contract: the first stripe/assemble failure stops the pool
-    and raises :class:`PipelineError` (original as ``__cause__``)
-    WITHOUT draining earlier chunks — a stopped pool may have orphaned
-    their unclaimed stripes, so unlike the chunk pipeline no pre-failure
-    prefix is guaranteed reduced.  The only caller
-    (``TpuAccelerator.fold_encrypted_stream``) feeds a fold session
-    that mutates nothing until ``finish``, so a raise discards cleanly.
-    A consumer (reduce) exception re-raises unchanged; workers are
-    always joined before returning."""
-    spans = list(spans)
-    n_spans = len(spans)
-    producers = max(1, int(producers))
-    if depth <= 0:
-        depth = max(2, producers + 1)
-    trace.gauge("stream_producers", producers)
-    if n_spans == 0:
-        return
-    if inline is None:
-        inline = producers == 1 and (os.cpu_count() or 1) <= 1
-    if inline:
-        for k, span in enumerate(spans):
-            stripes = split_fn(span, k)
-            with trace.span("stream.ingest", meta=k):
-                parts = [
-                    stripe_fn(stripe, k, s)
-                    for s, stripe in enumerate(stripes)
-                ]
-                item = assemble_fn(parts, span, k)
-            with trace.span("stream.reduce", meta=k):
-                reduce_fn(item, k)
-        return
-
-    slots = threading.BoundedSemaphore(depth)
-    out_q: _queue.Queue = _queue.Queue()
-    stop = threading.Event()
-    lock = threading.Lock()
-    next_chunk = [0]
-    active: dict[int, _ChunkWork] = {}  # insertion order = chunk order
-
-    def claim():
-        """The next (work, k, s) stripe claim, preferring the oldest
-        in-flight chunk, or ``"new"`` when a fresh chunk must be opened
-        (slot acquire happens OUTSIDE the lock), or ``None`` when no
-        work remains."""
-        with lock:
-            for k, work in active.items():
-                if work.next_stripe < len(work.stripes):
-                    s = work.next_stripe
-                    work.next_stripe += 1
-                    return work, k, s
-            if next_chunk[0] < n_spans:
-                return "new"
-        return None
-
-    def open_chunk():
-        """Claim the next chunk index and register its stripes; returns
-        a stripe claim from it, ``"raced"`` when another worker took the
-        last index, or ``None`` when exhausted.  The caller already
-        holds a backpressure slot; it is returned on non-claims."""
-        with lock:
-            k = next_chunk[0]
-            if k >= n_spans:
-                return None
-            next_chunk[0] += 1
-        stripes = split_fn(spans[k], k)
-        with lock:
-            work = _ChunkWork(spans[k], stripes)
-            if not stripes:
-                # empty chunk: complete immediately (no stripe will land)
-                pass
-            else:
-                work.next_stripe = 1
-                active[k] = work
-                return work, k, 0
-        out_q.put(("chunk", k, assemble_fn([], spans[k], k)))
-        return "empty"
-
-    def finish_stripe(work, k, s, part):
-        with lock:
-            work.parts[s] = part
-            work.remaining -= 1
-            done = work.remaining == 0
-            if done:
-                active.pop(k, None)
-        if done:
-            with trace.span("stream.ingest", meta=k):
-                item = assemble_fn(work.parts, work.span, k)
-            out_q.put(("chunk", k, item))
-
-    def produce(pid: int):
-        k = None
-        try:
-            while True:
-                if stop.is_set():
-                    return
-                got = claim()
-                if got is None:
-                    return
-                if got == "new":
-                    # backpressure BEFORE opening a chunk (poll so a dead
-                    # consumer can't strand this thread); stripes of
-                    # already-open chunks need no slot — their chunk holds one
-                    with trace.span("stream.producer.wait", meta=pid):
-                        while not slots.acquire(timeout=0.1):
-                            if stop.is_set():
-                                return
-                    if stop.is_set():
-                        slots.release()
-                        return
-                    got = open_chunk()
-                    if got is None:
-                        slots.release()
-                        return
-                    if got == "empty":
-                        continue  # slot rides with the emitted chunk
-                work, k, s = got
-                with trace.span("stream.stripe", meta=k):
-                    part = stripe_fn(work.stripes[s], k, s)
-                finish_stripe(work, k, s, part)
-                k = None
-        except BaseException as e:  # noqa: BLE001 — relayed to consumer
-            stop.set()
-            out_q.put(("error", k if k is not None else -1, e))
-
-    # workers run in copies of the caller's context (run_ingest_pipeline)
-    workers = [
-        threading.Thread(
-            target=contextvars.copy_context().run, args=(produce, i),
-            name=f"{thread_prefix}-{i}", daemon=True,
-        )
-        for i in range(producers)
-    ]
-    for w in workers:
-        w.start()
-    stash: dict[int, object] = {}
-    expected = 0
-    try:
-        while expected < n_spans:
-            if expected in stash:
-                item = stash.pop(expected)
-            else:
-                with trace.span("stream.sequence", meta=expected):
-                    while True:
-                        tag, k, item = out_q.get()
-                        if tag == "error":
-                            raise PipelineError(
-                                f"striped ingest failed at chunk {k}"
-                            ) from item
-                        if k == expected:
-                            break
-                        stash[k] = item  # holds its slot until reduced
             try:
                 with trace.span("stream.reduce", meta=expected):
                     reduce_fn(item, expected)

@@ -130,9 +130,9 @@ class TpuAccelerator(HostAccelerator):
                 )
                 sharded_stream = True
         self.sharded_stream = bool(sharded_stream) and self._mesh_active()
-        # ingest fan-out width for fold_encrypted_stream and the core's
-        # pipelined bulk ingest: 0 = auto (ops.stream.stream_producer_count
-        # — env CRDT_STREAM_PRODUCERS, else cpu_count-derived)
+        # ingest fan-out width for the core's pipelined bulk ingest:
+        # 0 = auto (ops.stream.stream_producer_count — env
+        # CRDT_STREAM_PRODUCERS, else cpu_count-derived)
         self.stream_producers = stream_producers
         # every XLA backend compile around the jitted/Pallas folds bumps
         # the jax_compiles counter — steady-state growth is the ADVICE-r5
@@ -679,185 +679,6 @@ class TpuAccelerator(HostAccelerator):
         if decoded is None:
             return False
         return self._fold_orset_decoded(state, decoded, actors_sorted)
-
-    def fold_encrypted_stream(
-        self, state, key: bytes, blobs: list, *, actors_hint=(),
-        chunk_blobs: int = 0, n_chunks: int = 8, depth: int = 0,
-        n_threads: int = 0, n_producers: int = 0,
-    ) -> bool:
-        """The full overlapped streaming-compaction front end (BASELINE
-        config #5 shape): encrypted op-file blobs in → folded ``state``
-        out, with the host stages running CONCURRENTLY with the fold.
-
-        ``n_producers`` worker threads (0 = the accelerator's configured
-        ``stream_producers``, itself 0 = auto from the core count) claim
-        **file-granular stripes** off one unified work queue
-        (ops/stream.py ``run_striped_ingest_pipeline``): each stripe is
-        a byte-bounded file subrange of a chunk, decrypted natively
-        single-threaded (the old per-chunk decrypt thread pool is gone —
-        parallelism lives entirely in the pool, never threads ×
-        threads), and the worker landing a chunk's last stripe runs its
-        columnar decode, while this thread columnarizes and folds
-        completed chunks through a fold session (parallel/session.py —
-        BUFFER / HOST_REDUCE / DEVICE_STREAM by regime; the device mode
-        issues chunk H2D under the in-flight donated fold, mesh-sharded
-        when the accelerator's ``sharded_stream`` route is active).  A
-        sequencer re-emits chunks in chunk-index order, so the folded
-        bytes are identical at any producer count and any stripe split.
-        Backpressure bounds live host memory to ``depth`` chunks (0 =
-        producers + 1).  On a single-core host with one producer the
-        pipeline runs inline (no threads — byte-identical, minus the
-        queue overhead).  Per-stage trace spans (``stream.decrypt`` /
-        ``stream.decode`` / ``stream.stripe`` / ``stream.ingest`` /
-        ``stream.reduce`` / ``stream.finish``, plus the fan-out's
-        ``stream.producer.wait`` / ``stream.sequence`` and the
-        ``stream_producers`` gauge) make the overlap auditable;
-        ``bench.py --e2e-streaming`` publishes them.
-
-        Returns False — with ``state`` untouched (sessions mutate only
-        at finish) — when no session exists for this CRDT type or the
-        native decoder declines; the caller replays its own copy of the
-        blobs down another path.  Crypto failures (AeadError) and
-        pipeline faults raise.
-        """
-        from ..backends.xchacha import decrypt_blobs, decrypt_blobs_packed
-        from ..ops.stream import (
-            run_striped_ingest_pipeline, stream_producer_count,
-        )
-        from .session import SessionDeclined
-
-        session = self.open_fold_session(state, actors_hint=actors_hint)
-        if session is None:
-            return False
-        n = len(blobs)
-        if n == 0:
-            return True
-        if chunk_blobs <= 0:
-            chunk_blobs = max(1, -(-n // max(n_chunks, 1)))
-        spans = [blobs[i : i + chunk_blobs] for i in range(0, n, chunk_blobs)]
-
-        producers = stream_producer_count(
-            n_producers if n_producers > 0 else self.stream_producers
-        )
-        # with N > 1 every decrypt call is single-threaded: the
-        # parallelism lives entirely in the producer pool's
-        # file-granular stripe claiming — N cooperating decrypt lanes
-        # on one unified queue, never threads × threads.  A SINGLE
-        # producer keeps the native batch call's own thread pool (0 =
-        # auto from the core count) — one whole-chunk stripe with no
-        # pool of its own would strand a multicore box's idle cores.
-        stripe_threads = n_threads if n_threads else (
-            0 if producers == 1 else 1
-        )
-
-        accepts_packed = getattr(session, "accepts_packed", False)
-
-        def split(span, k):
-            """File-granular stripes: with several producers a chunk
-            splits at byte boundaries so one giant op file forms its own
-            stripe (one worker) while its peers decrypt the rest — a
-            whole-chunk lane can no longer serialize behind it."""
-            if producers == 1 or len(span) <= 1:
-                return [span] if span else []
-            budget = max(1, sum(len(b) for b in span) // producers)
-            stripes, cur, cur_bytes = [], [], 0
-            for b in span:
-                cur.append(b)
-                cur_bytes += len(b)
-                if cur_bytes >= budget:
-                    stripes.append(cur)
-                    cur, cur_bytes = [], 0
-            if cur:
-                stripes.append(cur)
-            return stripes
-
-        def stripe(files, k, s):
-            with trace.span("stream.decrypt", meta=k):
-                packed = decrypt_blobs_packed(key, files, stripe_threads)
-                if packed is None:
-                    packed = decrypt_blobs(key, files, stripe_threads)
-                # counted only AFTER the stripe's decrypt succeeded
-                # (AeadError raises above) — the attribution marginals
-                # must never claim bytes a failed batch never opened
-                trace.add(
-                    "bytes_decrypted", sum(len(b) for b in files)
-                )
-                return packed
-
-        def assemble(parts, span, k):
-            if not accepts_packed:
-                # span-decoder-less sessions (counters, maps) take
-                # per-blob views of the shared cleartext buffers
-                payloads: list = []
-                for part in parts:
-                    if isinstance(part, tuple):
-                        out, offs = part
-                        view = memoryview(out)
-                        lo_hi = offs.tolist()
-                        payloads.extend(
-                            view[int(lo_hi[i]) : int(lo_hi[i + 1])]
-                            for i in range(len(lo_hi) - 1)
-                        )
-                    else:
-                        payloads.extend(part)
-                with trace.span("stream.decode", meta=k):
-                    return session.decode_chunk(payloads)
-            with trace.span("stream.decode", meta=k):
-                # thread-safe by contract: decode never mutates the
-                # session (parallel/session.py); multi-part decode
-                # combines the per-stripe cleartext buffers zero-copy
-                return session.decode_chunk_parts(parts)
-
-        def reduce(decoded, k):
-            session.reduce_chunk(decoded)
-
-        try:
-            run_striped_ingest_pipeline(
-                spans, split, stripe, assemble, reduce,
-                depth=depth, producers=producers,
-            )
-            with trace.span("stream.finish"):
-                session.finish()
-        except SessionDeclined:
-            return False
-        except K.PipelineError as e:
-            if isinstance(e.__cause__, SessionDeclined):
-                return False
-            raise e.__cause__ from None
-        return True
-
-    def fold_payload_stream(self, state, chunks, actors_hint=()) -> bool:
-        """ORSet bulk front end over an *iterator* of decrypted-payload
-        chunks (e.g. ``xchacha.decrypt_blobs_chunked``): each chunk
-        decodes while the producer decrypts the next, then all rows fold
-        once.  On False the stream is closed (a generator's pending
-        lookahead is cancelled at its next yield) and the caller replays
-        its own copy of the payloads down the per-op path."""
-        stream = self.open_payload_stream(state, actors_hint=actors_hint)
-        if stream is None:
-            return False
-        try:
-            for chunk in chunks:
-                if not stream.feed(chunk):
-                    return False
-        finally:
-            close = getattr(chunks, "close", None)
-            if close is not None:
-                close()
-        return stream.finish()
-
-    def open_payload_stream(self, state, actors_hint=()):
-        """Incremental bulk front end: returns a stream with
-        ``feed(payloads) -> bool`` (decodes one chunk; False = declined,
-        nothing folded) and ``finish() -> bool`` (one combined fold into
-        ``state``), or None when ``state`` has no columnar bulk path.
-        ``feed`` only decodes — callers overlap it with their own decrypt
-        of the next chunk (the native calls release the GIL); ``state``
-        mutates only inside ``finish``.  Caller-serialized, like the fold
-        sessions (parallel/session.py)."""
-        if not isinstance(state, ORSet):
-            return None
-        return _OrsetPayloadStream(self, state, actors_hint)
 
     def _orset_actor_table(self, state: ORSet, actors_hint) -> list:
         """Sorted actor table for the native decoder (it binary-searches):
@@ -1410,61 +1231,3 @@ class TpuAccelerator(HostAccelerator):
         state.deferred = merged.deferred
         self._note_orset_writeback(state)
         return state
-
-
-class _OrsetPayloadStream:
-    """Incremental ORSet bulk front end (``TpuAccelerator.open_payload_
-    stream``): per-chunk native span decode, one combined intern + fold at
-    ``finish``.  The product's bulk ingest feeds chunks as its decrypt
-    lookahead lands (core.py ``_read_remote_ops_bulk``); the state is
-    untouched until ``finish`` returns True, so a declined or abandoned
-    stream leaves the replica exactly as it was."""
-
-    def __init__(self, accel: TpuAccelerator, state: ORSet, actors_hint=()):
-        self.accel = accel
-        self.state = state
-        self.actors_sorted = accel._orset_actor_table(state, actors_hint)
-        self.parts: list = []
-        self.declined = False
-        self._finished = False
-        # actor-table + native hash index, built once per stream (the
-        # table is fixed for the stream's life) and reused across feeds
-        self._decode_cache: dict = {}
-
-    def feed(self, payloads: list) -> bool:
-        """Decode one chunk of decrypted payloads.  False = the native
-        decoder declined (unknown actor, non-canonical encoding); the
-        stream is dead and the caller replays through the per-op path."""
-        from ..ops.native_decode import decode_orset_payload_spans
-
-        assert not self._finished, "stream already finished"
-        if self.declined:
-            return False
-        if not payloads:
-            return True
-        with trace.span("fold.decode"):
-            part = decode_orset_payload_spans(
-                payloads, self.actors_sorted, cache=self._decode_cache
-            )
-        if part is None:
-            self.declined = True
-            return False
-        self.parts.append(part)
-        return True
-
-    def finish(self) -> bool:
-        """Combine every fed chunk and fold into the state (the only
-        mutation).  False = vocab collision; state untouched."""
-        from ..ops.native_decode import combine_orset_spans
-
-        assert not self._finished, "stream already finished"
-        assert not self.declined, "stream was declined"
-        self._finished = True
-        if not self.parts:
-            return True
-        with trace.span("fold.decode"):
-            decoded = combine_orset_spans(self.parts)
-        self.parts = []
-        return self.accel._fold_orset_decoded(
-            self.state, decoded, self.actors_sorted
-        )

@@ -106,11 +106,6 @@ class OrsetFoldSession:
     ``finish()`` exactly once — only finish mutates ``state``.
     """
 
-    # decode_chunk accepts the packed ``(buffer, offsets)`` cleartext pair
-    # straight from decrypt_blobs_packed (the zero-object-materialization
-    # shape); sessions without span decoders take per-blob payload lists
-    accepts_packed = True
-
     def __init__(self, accel, state: ORSet, actors_hint=()):
         from ..ops.columnar import strictly_sorted
 
@@ -190,45 +185,17 @@ class OrsetFoldSession:
         """Stage 1, thread-safe (no session mutation): native columnar
         decode of one chunk's payloads.  The ctypes call releases the GIL,
         so the core decodes chunk i+1 while chunk i reduces."""
-        return self.decode_chunk_parts([payloads])
-
-    def decode_chunk_parts(self, parts: list):
-        """Multi-part twin of :meth:`decode_chunk`: each element of
-        ``parts`` is one stripe's cleartext — a packed ``(buffer,
-        offsets)`` pair or a payload list — decoded in place and
-        combined zero-copy (the striped pipeline's per-stripe decrypt
-        buffers never re-join).  Thread-safe like ``decode_chunk``."""
         from ..ops.native_decode import (
             combine_orset_spans, decode_orset_payload_spans,
         )
 
-        if len(parts) == 1 and isinstance(parts[0], tuple):
-            from ..ops.device_decode import (
-                decode_adds_device, device_decode_enabled,
-            )
-
-            if device_decode_enabled():
-                # the CRDT_DEVICE_DECODE=1 experiment: fixed-stride
-                # add-only chunks bit-twiddle on device after bulk AEAD;
-                # anything else (removes, wide ints) falls through to
-                # the native host decoder below (ops/device_decode.py)
-                dd = decode_adds_device(parts[0], self.actors_sorted)
-                if dd is not None:
-                    return dd
-
         with trace.span("session.decode"):
-            decoded_parts = []
-            for payloads in parts:
-                part = decode_orset_payload_spans(
-                    payloads, self.actors_sorted, cache=self._decode_cache
-                )
-                if part is None:
-                    raise SessionDeclined(
-                        "native decoder declined the chunk"
-                    )
-                decoded_parts.append(part)
-            decoded = combine_orset_spans(decoded_parts, with_bytes=True)
-        return decoded
+            part = decode_orset_payload_spans(
+                payloads, self.actors_sorted, cache=self._decode_cache
+            )
+            if part is None:
+                raise SessionDeclined("native decoder declined the chunk")
+            return combine_orset_spans([part], with_bytes=True)
 
     def reduce_chunk(self, decoded) -> None:
         """Stage 2, serialized by the caller (mutates vocab + planes)."""

@@ -241,76 +241,6 @@ def test_decrypt_blobs_matches_sequential_and_detects_tamper():
         decrypt_blobs(key, blobs[:7] + [bytes(bad)] + blobs[8:])
 
 
-def test_fold_payload_stream_matches_batch_and_host():
-    """The chunked streaming front end (decrypt lookahead → per-chunk span
-    decode → one combined fold) must equal both the one-shot bulk path and
-    the per-op host fold, at every chunking."""
-    from crdt_enc_tpu.backends.xchacha import decrypt_blobs_chunked
-    from crdt_enc_tpu.models.orset import op_from_obj
-
-    key = secrets.token_bytes(32)
-    actors = sorted(uuid.UUID(int=i + 1).bytes for i in range(5))
-    state = ORSet()
-    payloads, all_ops = [], []
-    for f in range(30):
-        ops = []
-        for i in range(7):
-            a = actors[(f + i) % 5]
-            if (f + i) % 6 == 5:
-                op = state.rm_ctx((f * 7 + i) % 11)
-                if op.ctx.is_empty():
-                    continue
-            else:
-                op = state.add_ctx(a, (f * 7 + i) % 11)
-            state.apply(op)
-            ops.append(op)
-        payloads.append(encrypt_blob(key, codec.pack([op.to_obj() for op in ops])))
-        all_ops.extend(ops)
-
-    host = ORSet()
-    for op in all_ops:
-        host.apply(op)
-
-    accel = TpuAccelerator(min_device_batch=1)
-    batch = ORSet()
-    assert accel.fold_payloads(batch, decrypt_blobs(key, payloads), actors_hint=actors)
-    assert canonical_bytes(batch) == canonical_bytes(host)
-
-    for kwargs in ({"n_chunks": 4}, {"n_chunks": 64}, {"chunk_blobs": 1}):
-        streamed = ORSet()
-        chunks = decrypt_blobs_chunked(key, payloads, **kwargs)
-        assert accel.fold_payload_stream(streamed, chunks, actors_hint=actors)
-        assert canonical_bytes(streamed) == canonical_bytes(host), kwargs
-
-    # empty stream is a no-op success
-    untouched = ORSet()
-    assert accel.fold_payload_stream(untouched, iter([]), actors_hint=actors)
-    assert canonical_bytes(untouched) == canonical_bytes(ORSet())
-
-
-def test_fold_payload_stream_declines_unknown_actor_mid_stream():
-    """A chunk the native decoder can't handle declines the whole stream,
-    leaving the state untouched for the caller's per-op replay."""
-    from crdt_enc_tpu.backends.xchacha import decrypt_blobs_chunked
-
-    key = secrets.token_bytes(32)
-    known = uuid.UUID(int=1).bytes
-    stranger = uuid.UUID(int=99).bytes
-    s = ORSet()
-    ok_op = s.add_ctx(known, "m")
-    s.apply(ok_op)
-    bad_op = s.add_ctx(stranger, "n")
-    payloads = [
-        encrypt_blob(key, codec.pack([ok_op.to_obj()])),
-        encrypt_blob(key, codec.pack([bad_op.to_obj()])),
-    ]
-    accel = TpuAccelerator(min_device_batch=1)
-    state = ORSet()
-    chunks = decrypt_blobs_chunked(key, payloads, chunk_blobs=1)
-    assert accel.fold_payload_stream(state, chunks, actors_hint=[known]) is False
-    assert canonical_bytes(state) == canonical_bytes(ORSet())
-
-
 def test_bulk_gap_leaves_cursors_consistent(monkeypatch):
     """An op file arriving beyond the expected version (a GC'd hole with
     stranded files) must raise OpOrderError WITHOUT advancing cursors past
@@ -354,46 +284,6 @@ def test_bulk_gap_leaves_cursors_consistent(monkeypatch):
     run(go())
 
 
-def test_bulk_stream_path_matches_per_file(monkeypatch):
-    """The chunked-decrypt streaming bulk ingest (single sealing key +
-    open_payload_stream, multiple lookahead chunks) must equal the
-    per-file reference reader."""
-    import crdt_enc_tpu.core.core as core_mod_
-
-    class NoSessionTpu(TpuAccelerator):
-        """Force the legacy bulk path (no fold session) while keeping the
-        payload-stream front end."""
-
-        def open_fold_session(self, state, actors_hint=()):
-            return None
-
-    async def go():
-        remote = MemoryRemote()
-        writer = await Core.open(make_opts(MemoryStorage(remote), orset_adapter()))
-        await _write_history(writer, n_files=40)
-
-        monkeypatch.setattr(core_mod_, "BULK_STREAM_CHUNK", 7)  # many chunks
-        reader = await Core.open(
-            make_opts(
-                MemoryStorage(remote), orset_adapter(),
-                accel=NoSessionTpu(min_device_batch=1),
-            )
-        )
-        await reader.read_remote()
-
-        monkeypatch.setattr(core_mod_, "BULK_MIN_FILES", 10**9)
-        ref = await Core.open(make_opts(MemoryStorage(remote), orset_adapter()))
-        await ref.read_remote()
-
-        assert reader.with_state(canonical_bytes) == ref.with_state(canonical_bytes)
-        assert (
-            reader.info().next_op_versions.to_obj()
-            == ref.info().next_op_versions.to_obj()
-        )
-
-    run(go())
-
-
 # ---- ISSUE 13 acceptance: streaming ≡ sequential scalar, adapters × backends
 
 
@@ -402,8 +292,8 @@ def test_bulk_stream_path_matches_per_file(monkeypatch):
 def test_streaming_ingest_matches_scalar_adapters_backends(
     kind, backend, tmp_path, monkeypatch
 ):
-    """The striped streaming front end (pipelined fold sessions, unified
-    work queue, bytes-keyed remap, split sparse fold) must produce
+    """The pipelined ingest (fold sessions, bytes-keyed remap, split
+    sparse fold) must produce
     byte-identical state AND cursors to the sequential per-file scalar
     path, for ≥3 adapters on BOTH storage backends."""
     from crdt_enc_tpu.backends import FsStorage

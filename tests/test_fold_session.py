@@ -208,17 +208,7 @@ def make_opts(remote, adapter=None, accel=None):
     )
 
 
-def _chunked_storage(remote, files_per_chunk):
-    """MemoryStorage that yields op chunks of a few files — exercises the
-    pipeline's chunk boundaries without a real fs."""
-
-    class ChunkedMemoryStorage(MemoryStorage):
-        async def iter_op_chunks(self, wanted, max_bytes=1 << 30):
-            files = await self.load_ops(wanted)
-            for lo in range(0, len(files), files_per_chunk):
-                yield files[lo : lo + files_per_chunk]
-
-    return ChunkedMemoryStorage(remote)
+from _ingest_doors import chunked as _chunked_storage  # noqa: E402
 
 
 @pytest.mark.parametrize("files_per_chunk", [1, 5, 64])
@@ -468,15 +458,13 @@ def test_session_keeps_untouched_preexisting_members(force_mode):
 
 
 def test_encrypted_stream_device_mode_matches_host(monkeypatch):
-    """ISSUE 1 differential: the full overlapped pipeline (threaded
-    decrypt + decode producer → session consumer) forced through the
-    DEVICE_STREAM donated-fold mode lands byte-identical to the per-op
-    host loop — streaming ≡ whole-batch on the device path too."""
-    import secrets
-
+    """ISSUE 1 differential: the full overlapped pipeline (decrypting
+    producer → session consumer) forced through the DEVICE_STREAM
+    donated-fold mode lands byte-identical to the per-op host loop —
+    streaming ≡ whole-batch on the device path too."""
     import crdt_enc_tpu.parallel.session as S
+    from _ingest_doors import read_pipelined, seed_remote
     from crdt_enc_tpu import native
-    from crdt_enc_tpu.backends import xchacha
 
     try:
         native.load()
@@ -485,12 +473,27 @@ def test_encrypted_stream_device_mode_matches_host(monkeypatch):
     monkeypatch.setattr(S, "BUFFER_BYTES", 0)  # promote on first chunk
     monkeypatch.setattr(S, "HOST_PLANE_CELLS", -1)  # ... to device planes
     host, ops = _history(300, 17, seed=6)
-    key = secrets.token_bytes(32)
-    blobs = [xchacha.encrypt_blob(key, p) for p in _payloads(ops)]
-    accel = TpuAccelerator(min_device_batch=1)
-    streamed = ORSet()
-    ok = accel.fold_encrypted_stream(
-        streamed, key, blobs, actors_hint=ACTORS, n_chunks=5
-    )
-    assert ok
-    assert canonical_bytes(streamed) == canonical_bytes(host)
+    # every actor writes its own adds (removes ride with the first): the
+    # session's actor table is the storage listing
+    by_actor = {a: [] for a in ACTORS}
+    for op in ops:
+        by_actor[op.dot.actor if isinstance(op, AddOp) else ACTORS[0]].append(op)
+    files = [
+        (a, codec.unpack(p)) for a in ACTORS for p in _payloads(by_actor[a])
+    ]
+    modes = []
+    real_finish = OrsetFoldSession.finish
+
+    def spy_finish(self):
+        modes.append(self.mode)
+        return real_finish(self)
+
+    monkeypatch.setattr(OrsetFoldSession, "finish", spy_finish)
+
+    async def go():
+        remote, _ = await seed_remote(files)
+        return await read_pipelined(remote, 6)
+
+    reader = run(go())
+    assert modes == ["device_stream"]
+    assert reader.with_state(canonical_bytes) == canonical_bytes(host)

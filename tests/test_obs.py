@@ -253,30 +253,23 @@ def test_export_trace_cli_proves_overlap_on_streaming_run(
     tmp_path, capsys, monkeypatch
 ):
     """ISSUE 2 acceptance: obs_report export-trace on a recorded
-    streaming run (the --e2e-streaming smoke shape: encrypted blobs →
-    fold_encrypted_stream) emits valid Chrome-trace JSON whose events
-    prove chunk k+1's ingest overlaps chunk k's fold/reduce."""
+    streaming run (encrypted op files → the core's pipelined ingest)
+    emits valid Chrome-trace JSON whose events prove chunk k+1's decrypt
+    overlaps chunk k's fold."""
     _native_crypto_or_skip()
+    import asyncio
     import time as _time
 
-    from crdt_enc_tpu.models import ORSet
+    from _ingest_doors import orset_workload, read_pipelined, seed_remote
     from crdt_enc_tpu.parallel import TpuAccelerator
     from crdt_enc_tpu.parallel import session as psession
     from crdt_enc_tpu.tools import obs_report
-    from tests.test_streaming_pipeline import _encrypted_orset_workload
 
-    key, blobs, actors, host = _encrypted_orset_workload(
-        n_files=60, ops_per_file=8
-    )
-    accel = TpuAccelerator()
-    streamed = ORSet()
-    trace.enable_events()
-    # two producers force the threaded pipeline (on a 1-core box the
-    # auto-tuned single producer runs INLINE — no lookahead to prove),
-    # and a slowed consumer widens the overlap window so the proof is
+    files, _, host = orset_workload(n_files=60, ops_per_file=8)
+    # a slowed consumer widens the overlap window so the proof is
     # deterministic on one core: a PIPELINED run shows chunk k+1's
-    # ingest starting inside the slow reduce k; a serial run would not,
-    # however slow the reduce — same discipline as the seam tests'
+    # decrypt starting inside the slow fold k; a serial run would not,
+    # however slow the fold — same discipline as the seam tests'
     # injected delays
     real_reduce = psession.OrsetFoldSession.reduce_chunk
 
@@ -287,12 +280,16 @@ def test_export_trace_cli_proves_overlap_on_streaming_run(
     monkeypatch.setattr(
         psession.OrsetFoldSession, "reduce_chunk", slow_reduce
     )
-    ok = accel.fold_encrypted_stream(
-        streamed, key, blobs, actors_hint=sorted(actors), n_chunks=6,
-        n_producers=2,
-    )
-    assert ok
-    assert codec.pack(streamed.to_obj()) == codec.pack(host.to_obj())
+
+    async def go():
+        remote, _ = await seed_remote(files)
+        trace.enable_events()
+        return await read_pipelined(remote, 6, accel=TpuAccelerator())
+
+    reader = asyncio.run(go())
+    assert reader.with_state(
+        lambda s: codec.pack(s.to_obj())
+    ) == codec.pack(host.to_obj())
     # record the run through the sink (events attach automatically)
     run_path = tmp_path / "run.jsonl"
     rec = sink.MetricsSink(str(run_path)).write("e2e-streaming-smoke")
@@ -300,14 +297,14 @@ def test_export_trace_cli_proves_overlap_on_streaming_run(
     out_path = tmp_path / "trace.json"
     rc = obs_report.main([
         "export-trace", str(run_path), "-o", str(out_path),
-        "--check-overlap", "stream.ingest:stream.reduce",
+        "--check-overlap", "ops.chunk_decrypt:ops.chunk_fold",
     ])
     assert rc == 0, capsys.readouterr()
     with open(out_path) as f:
         obj = json.load(f)
     assert obj["traceEvents"]
-    ks = timeline.chunk_overlaps(obj, "stream.ingest", "stream.reduce")
-    assert ks, "recorded streaming run shows no ingest/fold overlap"
+    ks = timeline.chunk_overlaps(obj, "ops.chunk_decrypt", "ops.chunk_fold")
+    assert ks, "recorded streaming run shows no decrypt/fold overlap"
     out = capsys.readouterr().out
     assert "overlap proof" in out
 

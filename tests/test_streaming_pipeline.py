@@ -16,7 +16,7 @@ Three contracts pinned here:
 
 from __future__ import annotations
 
-import secrets
+import asyncio
 import threading
 import time
 
@@ -240,124 +240,98 @@ def test_overlapped_stream_fold_matches_whole_batch():
 
 
 # ------------------------------------------------- end-to-end differential
+# Through the served door: Core.read_remote() on a TpuAccelerator over a
+# remote whose iter_op_chunks yields several chunks (tests/_ingest_doors.py).
 
 
-def _encrypted_orset_workload(n_files=40, ops_per_file=6, R=5, E=12, seed=2):
-    """Per-actor op files sealed with the native AEAD + the per-op host
-    truth (apply order == file order, per-actor version order)."""
-    from crdt_enc_tpu.backends.xchacha import encrypt_blob
-    from crdt_enc_tpu.models import ORSet
-    from crdt_enc_tpu.models.orset import AddOp, RmOp
-    from crdt_enc_tpu.models.vclock import Dot, VClock
-
-    rng = np.random.default_rng(seed)
-    key = secrets.token_bytes(32)
-    actors = [bytes([a]) * 16 for a in range(1, R + 1)]
-    counters = {a: 0 for a in range(R)}
-    host = ORSet()
-    blobs = []
-    for f in range(n_files):
-        a = f % R
-        ops = []
-        for _ in range(ops_per_file):
-            m = int(rng.integers(0, E))
-            if rng.random() < 0.75 or counters[a] == 0:
-                counters[a] += 1
-                ops.append([0, m, [actors[a], counters[a]]])
-                host.apply(AddOp(m, Dot(actors[a], counters[a])))
-            else:
-                ops.append([1, m, {actors[a]: counters[a]}])
-                host.apply(RmOp(m, VClock({actors[a]: counters[a]})))
-        blobs.append(encrypt_blob(key, codec.pack(ops)))
-    return key, blobs, actors, host
+_run = asyncio.run
 
 
 def test_streaming_pipeline_byte_identical_to_host():
-    """ISSUE 1 acceptance: encrypted blobs → streaming pipeline → state is
+    """ISSUE 1 acceptance: encrypted op files → pipelined ingest → state is
     BYTE-identical to the per-op host reference AND to the whole-batch
     bulk fold, across chunking geometries."""
     _native_crypto_or_skip()
-    from crdt_enc_tpu.backends.xchacha import decrypt_blobs
+    from _ingest_doors import orset_workload, read_pipelined, seed_remote
     from crdt_enc_tpu.models import ORSet
     from crdt_enc_tpu.parallel import TpuAccelerator
 
-    key, blobs, actors, host = _encrypted_orset_workload()
+    files, actors, host = orset_workload()
     host_bytes = codec.pack(host.to_obj())
-    accel = TpuAccelerator()
-    hint = sorted(actors)
 
     # whole-batch bulk fold (the previously-pinned path)
     whole = ORSet()
-    assert accel.fold_payloads(
-        whole, decrypt_blobs(key, blobs), actors_hint=hint
+    assert TpuAccelerator().fold_payloads(
+        whole, [codec.pack(ops) for _, ops in files],
+        actors_hint=sorted(actors),
     )
     assert codec.pack(whole.to_obj()) == host_bytes
 
-    for n_chunks in (1, 3, 8, len(blobs)):
-        streamed = ORSet()
-        ok = accel.fold_encrypted_stream(
-            streamed, key, blobs, actors_hint=hint, n_chunks=n_chunks,
-        )
-        assert ok, f"pipeline declined at n_chunks={n_chunks}"
-        assert codec.pack(streamed.to_obj()) == host_bytes, (
-            f"divergence at n_chunks={n_chunks}"
-        )
+    async def go():
+        remote, _ = await seed_remote(files)
+        for files_per_chunk in (len(files), 14, 5, 1):
+            trace.reset()
+            reader = await read_pipelined(
+                remote, files_per_chunk, accel=TpuAccelerator()
+            )
+            # every file went through the fold session, none per op
+            counters = trace.snapshot()["counters"]
+            assert counters["op_files_bulk_folded"] == len(files)
+            assert "ops_folded" not in counters
+            assert reader.with_state(
+                lambda s: codec.pack(s.to_obj())
+            ) == host_bytes, f"divergence at {files_per_chunk} files a chunk"
+
+    _run(go())
 
 
 def test_streaming_pipeline_into_existing_state():
     """The pipeline folds INTO a non-empty replica exactly as the per-op
     path does (stale dots rejected, pre-existing entries honored)."""
     _native_crypto_or_skip()
+    from _ingest_doors import (
+        apply_files, chunked, make_opts, orset_workload, seed_remote,
+    )
+    from crdt_enc_tpu.core import Core
     from crdt_enc_tpu.models import ORSet
-    from crdt_enc_tpu.models.orset import AddOp
-    from crdt_enc_tpu.models.vclock import Dot
     from crdt_enc_tpu.parallel import TpuAccelerator
 
-    key, blobs, actors, host = _encrypted_orset_workload(seed=9)
-    pre = [(b"\x77" * 16, 1, 99), (b"\x78" * 16, 2, 5)]
-    streamed = ORSet()
-    for a, c, m in pre:
-        host_op = AddOp(m, Dot(a, c))
-        streamed.apply(host_op)
-        host.apply(host_op)  # same op applied before the stream in both
-    # NB: host had the stream's ops applied already in the builder, so
-    # rebuild host truth in the right order: pre-ops THEN stream ops
-    host2 = ORSet()
-    for a, c, m in pre:
-        host2.apply(AddOp(m, Dot(a, c)))
-    from crdt_enc_tpu.backends.xchacha import decrypt_blobs
-    from crdt_enc_tpu.models.orset import RmOp
-    from crdt_enc_tpu.models.vclock import VClock
+    files, actors, _ = orset_workload(seed=9)
 
-    for raw in decrypt_blobs(key, blobs):
-        for o in codec.unpack(raw):
-            if o[0] == 0:
-                host2.apply(AddOp(o[1], Dot.from_obj(o[2])))
-            else:
-                host2.apply(RmOp(o[1], VClock.from_obj(o[2])))
+    async def go():
+        remote, _ = await seed_remote(files)
+        reader = await Core.open(
+            make_opts(chunked(remote, 10), accel=TpuAccelerator())
+        )
+        for m in (99, 5):  # entries the stream never mentions or re-adds
+            await reader.update(
+                lambda s, m=m: s.add_ctx(reader.actor_id, m)
+            )
+        # host truth in the right order: pre-ops THEN stream ops
+        host = reader.with_state(lambda s: ORSet.from_obj(s.to_obj()))
+        apply_files(host, files)
+        await reader.read_remote()
+        assert reader.with_state(
+            lambda s: codec.pack(s.to_obj())
+        ) == codec.pack(host.to_obj())
 
-    accel = TpuAccelerator()
-    ok = accel.fold_encrypted_stream(
-        streamed, key, blobs, actors_hint=sorted(actors), n_chunks=4,
-    )
-    assert ok
-    assert codec.pack(streamed.to_obj()) == codec.pack(host2.to_obj())
+    _run(go())
 
 
 def test_streaming_pipeline_counter_session():
-    """fold_encrypted_stream is generic over session types: a PN-Counter
+    """The pipelined door is generic over session types: a PN-Counter
     ingest runs the same pipeline and equals the per-op reference."""
     _native_crypto_or_skip()
-    from crdt_enc_tpu.backends.xchacha import encrypt_blob
+    from _ingest_doors import read_pipelined, seed_remote
+    from crdt_enc_tpu.core.adapters import pncounter_adapter
     from crdt_enc_tpu.models import PNCounter
     from crdt_enc_tpu.parallel import TpuAccelerator
 
-    key = secrets.token_bytes(32)
     actors = [bytes([a]) * 16 for a in range(1, 4)]
     host = PNCounter()
-    blobs = []
+    files = []
     rng = np.random.default_rng(4)
-    for f in range(12):
+    for f in range(24):
         a = f % 3
         ops = []
         for _ in range(5):
@@ -367,44 +341,54 @@ def test_streaming_pipeline_counter_session():
             )
             ops.append([int(sign), [dot.actor, dot.counter]])
             host.apply((sign, dot))
-        blobs.append(encrypt_blob(key, codec.pack(ops)))
-    streamed = PNCounter()
-    accel = TpuAccelerator()
-    ok = accel.fold_encrypted_stream(
-        streamed, key, blobs, actors_hint=sorted(actors), n_chunks=3,
-    )
-    assert ok
-    assert codec.pack(streamed.to_obj()) == codec.pack(host.to_obj())
-    assert streamed.read() == host.read()
+        files.append((actors[a], ops))
+
+    async def go():
+        remote, _ = await seed_remote(files, adapter=pncounter_adapter())
+        reader = await read_pipelined(
+            remote, 7, accel=TpuAccelerator(), adapter=pncounter_adapter()
+        )
+        assert reader.with_state(
+            lambda s: codec.pack(s.to_obj())
+        ) == codec.pack(host.to_obj())
+        assert reader.with_state(lambda s: s.read()) == host.read()
+
+    _run(go())
 
 
 def test_streaming_pipeline_seam_on_real_path():
-    """The real pipeline (native decrypt + decode in the producer) emits
-    the stage spans the docs promise, and its ingest of some chunk k+1
-    starts before reduce k completes once reduces are non-trivial."""
+    """The real pipeline (native decrypt in the producer, native decode
+    in a worker thread) emits the stage spans the docs promise, each
+    chunk's under its own index."""
     _native_crypto_or_skip()
-    from crdt_enc_tpu.models import ORSet
+    from _ingest_doors import orset_workload, read_pipelined, seed_remote
     from crdt_enc_tpu.parallel import TpuAccelerator
 
-    key, blobs, actors, host = _encrypted_orset_workload(
-        n_files=60, ops_per_file=8
-    )
-    accel = TpuAccelerator()
-    streamed = ORSet()
-    trace.reset()
-    trace.enable_events()
-    try:
-        ok = accel.fold_encrypted_stream(
-            streamed, key, blobs, actors_hint=sorted(actors), n_chunks=6,
-        )
-    finally:
-        trace.enable_events(False)
-    assert ok
+    files, _, host = orset_workload(n_files=60, ops_per_file=8)
+
+    async def go():
+        remote, _ = await seed_remote(files)
+        trace.reset()
+        trace.enable_events()
+        try:
+            return await read_pipelined(remote, 10, accel=TpuAccelerator())
+        finally:
+            trace.enable_events(False)
+
+    reader = _run(go())
     names = {e["name"] for e in trace.events()}
-    for required in ("stream.decrypt", "stream.decode", "stream.ingest",
-                     "stream.reduce", "stream.finish"):
+    for required in ("ops.chunk_load", "ops.chunk_unwrap",
+                     "ops.chunk_decrypt", "ops.chunk_wait",
+                     "ops.chunk_fold", "session.decode",
+                     "ops.session_finish"):
         assert required in names, f"missing stage span {required}"
-    assert codec.pack(streamed.to_obj()) == codec.pack(host.to_obj())
+    for per_chunk in ("ops.chunk_unwrap", "ops.chunk_decrypt"):
+        assert [e["meta"] for e in _events_by_name(per_chunk)] == list(
+            range(6)
+        )
+    assert reader.with_state(
+        lambda s: codec.pack(s.to_obj())
+    ) == codec.pack(host.to_obj())
 
 
 # ------------------------------------------------- multi-producer fan-out
@@ -583,53 +567,45 @@ def test_multi_producer_consumer_error_cancels_pool():
     _assert_no_producer_threads()
 
 
-def test_multi_producer_byte_identical_to_single():
-    """ISSUE 3 acceptance (differential): the SAME encrypted span set
-    folded with 1, 2, and 4 producers — with randomized producer delays
-    injected ahead of the real decrypt — produces byte-identical states,
-    all equal to the per-op host reference."""
+def test_multi_producer_byte_identical_to_single(monkeypatch):
+    """ISSUE 3 acceptance (differential): the SAME encrypted op files
+    ingested at ``stream_producers`` 1 and 4 — with randomized delays
+    injected ahead of the real decode — produce byte-identical states,
+    both equal to the per-op host reference."""
     _native_crypto_or_skip()
-    from crdt_enc_tpu.models import ORSet
+    from _ingest_doors import orset_workload, read_pipelined, seed_remote
     from crdt_enc_tpu.parallel import TpuAccelerator
+    from crdt_enc_tpu.parallel import session as psession
 
-    key, blobs, actors, host = _encrypted_orset_workload(
-        n_files=48, ops_per_file=7, seed=21
-    )
+    files, _, host = orset_workload(n_files=48, ops_per_file=7, seed=21)
     host_bytes = codec.pack(host.to_obj())
-    accel = TpuAccelerator()
-    hint = sorted(actors)
-    rng = np.random.default_rng(9)
-    delays = rng.random(12) * 0.01
+    delays = iter(np.random.default_rng(9).random(64) * 0.01)
+    real_decode = psession.OrsetFoldSession.decode_chunk
 
-    from crdt_enc_tpu.ops import stream as stream_mod
+    def jittered_decode(self, payloads):
+        time.sleep(next(delays))
+        return real_decode(self, payloads)
 
-    real_pipeline = stream_mod.run_striped_ingest_pipeline
+    monkeypatch.setattr(
+        psession.OrsetFoldSession, "decode_chunk", jittered_decode
+    )
 
-    def jittered_pipeline(spans, split_fn, stripe_fn, assemble_fn,
-                          reduce_fn, **kw):
-        def slow_stripe(stripe, k, s):
-            time.sleep(delays[(k + s) % len(delays)])
-            return stripe_fn(stripe, k, s)
-
-        return real_pipeline(
-            spans, split_fn, slow_stripe, assemble_fn, reduce_fn, **kw
-        )
-
-    results = {}
-    for n_producers in (1, 2, 4):
-        streamed = ORSet()
-        stream_mod.run_striped_ingest_pipeline = jittered_pipeline
-        try:
-            ok = accel.fold_encrypted_stream(
-                streamed, key, blobs, actors_hint=hint, n_chunks=8,
-                n_producers=n_producers,
+    async def go():
+        remote, _ = await seed_remote(files)
+        results = {}
+        for n in (1, 4):
+            trace.reset()
+            reader = await read_pipelined(
+                remote, 6, accel=TpuAccelerator(stream_producers=n)
             )
-        finally:
-            stream_mod.run_striped_ingest_pipeline = real_pipeline
-        assert ok, f"pipeline declined at n_producers={n_producers}"
-        results[n_producers] = codec.pack(streamed.to_obj())
-    for n_producers, got in results.items():
-        assert got == host_bytes, f"divergence at n_producers={n_producers}"
+            assert trace.snapshot()["gauges"]["stream_producers"] == n
+            results[n] = reader.with_state(
+                lambda s: codec.pack(s.to_obj())
+            )
+        return results
+
+    for n, got in _run(go()).items():
+        assert got == host_bytes, f"divergence at stream_producers={n}"
 
 
 # ------------------------------------------------- mesh-sharded streaming
@@ -652,7 +628,7 @@ def test_sharded_stream_byte_identical_to_single_chip(monkeypatch):
     mp-sharded, chunks dp-sharded) and through the single-chip stream is
     byte-identical — both equal to the per-op host reference."""
     _native_crypto_or_skip()
-    from crdt_enc_tpu.models import ORSet
+    from _ingest_doors import orset_workload, read_pipelined, seed_remote
     from crdt_enc_tpu.parallel import TpuAccelerator, mesh as pmesh
     from crdt_enc_tpu.parallel import session as psession
 
@@ -660,14 +636,12 @@ def test_sharded_stream_byte_identical_to_single_chip(monkeypatch):
     # tiny promotion threshold so the small workload leaves BUFFER mode
     monkeypatch.setattr(psession, "BUFFER_BYTES", 256)
 
-    key, blobs, actors, host = _encrypted_orset_workload(
+    files, _, host = orset_workload(
         n_files=60, ops_per_file=8, R=5, E=24, seed=13
     )
     host_bytes = codec.pack(host.to_obj())
-    hint = sorted(actors)
 
-    accel = TpuAccelerator(mesh=mesh)
-    assert accel.sharded_stream  # auto-on with an active mesh
+    assert TpuAccelerator(mesh=mesh).sharded_stream  # auto-on with a mesh
 
     # spy: the sharded fold step must actually run (not a silent
     # fallback to the single-chip or buffered route)
@@ -685,19 +659,19 @@ def test_sharded_stream_byte_identical_to_single_chip(monkeypatch):
 
     monkeypatch.setattr(pmesh, "sharded_stream_fold_step", spy_step)
 
-    sharded = ORSet()
-    ok = accel.fold_encrypted_stream(
-        sharded, key, blobs, actors_hint=hint, n_chunks=6, n_producers=2,
-    )
-    assert ok and calls, "sharded streaming fold did not engage"
-    assert codec.pack(sharded.to_obj()) == host_bytes
+    async def go():
+        remote, _ = await seed_remote(files)
+        sharded = await read_pipelined(
+            remote, 10, accel=TpuAccelerator(mesh=mesh, stream_producers=2)
+        )
+        assert calls, "sharded streaming fold did not engage"
+        single = await read_pipelined(remote, 10, accel=TpuAccelerator())
+        return sharded, single
 
-    single = ORSet()
-    ok = TpuAccelerator().fold_encrypted_stream(
-        single, key, blobs, actors_hint=hint, n_chunks=6,
-    )
-    assert ok
-    assert codec.pack(single.to_obj()) == host_bytes
+    for reader in _run(go()):
+        assert reader.with_state(
+            lambda s: codec.pack(s.to_obj())
+        ) == host_bytes
 
 
 def test_sharded_stream_into_existing_state(monkeypatch):
@@ -706,39 +680,38 @@ def test_sharded_stream_into_existing_state(monkeypatch):
     horizons streamed through the mesh still kill pre-existing entries,
     and stale dots are still rejected."""
     _native_crypto_or_skip()
-    from crdt_enc_tpu.backends.xchacha import decrypt_blobs
+    from _ingest_doors import (
+        apply_files, chunked, make_opts, orset_workload, seed_remote,
+    )
+    from crdt_enc_tpu.core import Core
     from crdt_enc_tpu.models import ORSet
-    from crdt_enc_tpu.models.orset import AddOp, RmOp
-    from crdt_enc_tpu.models.vclock import Dot, VClock
     from crdt_enc_tpu.parallel import TpuAccelerator
     from crdt_enc_tpu.parallel import session as psession
 
     mesh = _mesh_or_skip()
     monkeypatch.setattr(psession, "BUFFER_BYTES", 256)
 
-    key, blobs, actors, _ = _encrypted_orset_workload(
+    files, _, _ = orset_workload(
         n_files=48, ops_per_file=8, R=4, E=16, seed=29
     )
-    pre = [(b"\x77" * 16, 1, 3), (b"\x78" * 16, 2, 5)]
-    streamed = ORSet()
-    host = ORSet()
-    for a, c, m in pre:
-        op = AddOp(m, Dot(a, c))
-        streamed.apply(op)
-        host.apply(op)
-    for raw in decrypt_blobs(key, blobs):
-        for o in codec.unpack(raw):
-            if o[0] == 0:
-                host.apply(AddOp(o[1], Dot.from_obj(o[2])))
-            else:
-                host.apply(RmOp(o[1], VClock.from_obj(o[2])))
 
-    accel = TpuAccelerator(mesh=mesh)
-    ok = accel.fold_encrypted_stream(
-        streamed, key, blobs, actors_hint=sorted(actors), n_chunks=5,
-    )
-    assert ok
-    assert codec.pack(streamed.to_obj()) == codec.pack(host.to_obj())
+    async def go():
+        remote, _ = await seed_remote(files)
+        reader = await Core.open(
+            make_opts(chunked(remote, 10), accel=TpuAccelerator(mesh=mesh))
+        )
+        for m in (3, 5):  # members the stream's removes reach
+            await reader.update(
+                lambda s, m=m: s.add_ctx(reader.actor_id, m)
+            )
+        host = reader.with_state(lambda s: ORSet.from_obj(s.to_obj()))
+        apply_files(host, files)
+        await reader.read_remote()
+        assert reader.with_state(
+            lambda s: codec.pack(s.to_obj())
+        ) == codec.pack(host.to_obj())
+
+    _run(go())
 
 
 def test_sharded_stream_gated_off_multiprocess(monkeypatch):
@@ -810,217 +783,63 @@ def test_sharded_stream_toggle_off_stays_buffered(monkeypatch):
     assert not env_off.sharded_stream
 
 
-# ------------------------------------------- unified work queue (stripes)
+# ------------------------------------------- counters + session seams
 
 
-def test_striped_order_deterministic_with_random_delays():
-    """Stripes claimed by 1/2/4 producers with randomized stripe delays
-    still reduce in strict chunk order, with each chunk's parts
-    assembled in stripe order."""
-    rng = np.random.default_rng(3)
-    delays = rng.random(40) * 0.004
-
-    for producers in (1, 2, 4):
-        order = []
-
-        def split(span, k):
-            return [(k, s) for s in range(1 + k % 3)]
-
-        def stripe(item, k, s):
-            time.sleep(delays[(k * 3 + s) % len(delays)])
-            assert item == (k, s)
-            return ("part", k, s)
-
-        def assemble(parts, span, k):
-            assert parts == [("part", k, s) for s in range(1 + k % 3)]
-            return ("chunk", k)
-
-        def reduce(item, k):
-            assert item == ("chunk", k)
-            order.append(k)
-
-        K.run_striped_ingest_pipeline(
-            list(range(18)), split, stripe, assemble, reduce,
-            producers=producers, inline=False,
-        )
-        assert order == list(range(18)), (producers, order)
-
-
-def test_striped_giant_stripe_does_not_block_peers():
-    """One slow stripe occupies one worker while a second worker keeps
-    claiming OTHER stripes — the file-granular claim contract (the old
-    chunk-granular pool serialized everything behind the giant)."""
-    started = []
-    release = threading.Event()
-
-    def split(span, k):
-        return [0, 1] if k == 0 else [0]
-
-    def stripe(item, k, s):
-        started.append((k, s))
-        if (k, s) == (0, 0):
-            assert release.wait(10.0)
-        return (k, s)
-
-    def assemble(parts, span, k):
-        return k
-
-    done = []
-
-    def reduce(item, k):
-        done.append(k)
-
-    t = threading.Thread(
-        target=lambda: K.run_striped_ingest_pipeline(
-            list(range(4)), split, stripe, assemble, reduce,
-            producers=2, inline=False,
-        )
-    )
-    t.start()
-    deadline = time.monotonic() + 10.0
-    # the second worker must make progress past the stalled stripe
-    while len(started) < 4 and time.monotonic() < deadline:
-        time.sleep(0.005)
-    assert len(started) >= 4, started
-    assert not done  # chunk order: nothing reduces before chunk 0
-    release.set()
-    t.join(10.0)
-    assert done == [0, 1, 2, 3]
-
-
-def test_striped_fault_propagates_and_joins_workers():
-    before = threading.active_count()
-
-    def split(span, k):
-        return [0, 1]
-
-    def stripe(item, k, s):
-        if (k, s) == (2, 1):
-            raise ValueError("boom at (2,1)")
-        return 0
-
-    with pytest.raises(K.PipelineError) as ei:
-        K.run_striped_ingest_pipeline(
-            list(range(8)), split, stripe, lambda p, sp, k: 0,
-            lambda i, k: None, producers=3, inline=False,
-        )
-    assert isinstance(ei.value.__cause__, ValueError)
-    deadline = time.monotonic() + 5.0
-    while threading.active_count() > before and time.monotonic() < deadline:
-        time.sleep(0.01)
-    assert threading.active_count() <= before
-
-
-def test_striped_consumer_error_cancels_pool():
-    before = threading.active_count()
-
-    def reduce(item, k):
-        if k == 1:
-            raise RuntimeError("consumer dies")
-
-    with pytest.raises(RuntimeError):
-        K.run_striped_ingest_pipeline(
-            list(range(30)), lambda sp, k: [0], lambda it, k, s: 0,
-            lambda p, sp, k: 0, reduce, producers=3, inline=False,
-        )
-    deadline = time.monotonic() + 5.0
-    while threading.active_count() > before and time.monotonic() < deadline:
-        time.sleep(0.01)
-    assert threading.active_count() <= before
-
-
-def test_striped_empty_chunks_and_empty_split():
-    """Zero spans is a no-op; a split returning [] still emits the chunk
-    (assemble sees no parts) and order holds."""
-    K.run_striped_ingest_pipeline(
-        [], lambda sp, k: [0], lambda it, k, s: 0, lambda p, sp, k: 0,
-        lambda i, k: None, producers=2, inline=False,
-    )
-    order = []
-    K.run_striped_ingest_pipeline(
-        list(range(5)),
-        lambda sp, k: [] if k % 2 else [0],
-        lambda it, k, s: "p",
-        lambda parts, sp, k: (k, parts),
-        lambda item, k: order.append(item),
-        producers=2, inline=False,
-    )
-    assert order == [(k, ["p"] if k % 2 == 0 else []) for k in range(5)]
-
-
-def test_striped_inline_auto_on_single_core(monkeypatch):
-    """producers==1 on a 1-core host runs the whole pipeline inline —
-    no worker threads — and still byte-identically (order + parts)."""
-    import crdt_enc_tpu.ops.stream as stream_mod
-
-    monkeypatch.setattr(stream_mod.os, "cpu_count", lambda: 1)
-    spawned = []
-    real_thread = threading.Thread
-
-    class SpyThread(real_thread):
-        def __init__(self, *a, **kw):
-            spawned.append(kw.get("name"))
-            super().__init__(*a, **kw)
-
-    monkeypatch.setattr(stream_mod.threading, "Thread", SpyThread)
-    order = []
-    K.run_striped_ingest_pipeline(
-        list(range(6)), lambda sp, k: [0, 1],
-        lambda it, k, s: (k, s),
-        lambda parts, sp, k: (k, parts),
-        lambda item, k: order.append(item),
-        producers=1,
-    )
-    assert order == [(k, [(k, 0), (k, 1)]) for k in range(6)]
-    assert spawned == []  # inline: not a single worker thread
-    # explicit inline=False still threads even on one core
-    K.run_striped_ingest_pipeline(
-        list(range(2)), lambda sp, k: [0], lambda it, k, s: 0,
-        lambda p, sp, k: 0, lambda i, k: None, producers=1, inline=False,
-    )
-    assert spawned  # the forced path spawned its worker
-
-
-def test_stream_counters_pinned_on_striped_path():
-    """bytes_decrypted on the accel streaming front door equals EXACTLY
-    the byte sum of the encrypted blobs (counted only after a stripe's
-    decrypt succeeds), and the host/buffer regime issues zero h2d — the
-    attribution marginals' inputs stay trustworthy (ISSUE 13 audit)."""
+def test_stream_counters_pinned_on_pipelined_path():
+    """bytes_decrypted on the pipelined door equals EXACTLY the byte sum
+    of the sealed ciphertexts (counted only after a chunk's decrypt
+    succeeds), and the host/buffer regime issues zero h2d beyond the one
+    dense fold — the attribution marginals' inputs stay trustworthy
+    (ISSUE 13 audit)."""
     _native_crypto_or_skip()
-    from crdt_enc_tpu.models import ORSet
+    from _ingest_doors import orset_workload, read_pipelined, seed_remote
+    from crdt_enc_tpu.backends.xchacha import AeadError, XChaChaCryptor
+    from crdt_enc_tpu.core.core import IngestDecryptError
     from crdt_enc_tpu.parallel import TpuAccelerator
+    from crdt_enc_tpu.utils import VersionBytes
 
-    key, blobs, actors, host = _encrypted_orset_workload(seed=5)
-    accel = TpuAccelerator()
-    trace.reset()
-    state = ORSet()
-    assert accel.fold_encrypted_stream(
-        state, key, blobs, actors_hint=sorted(actors), n_chunks=4,
-    )
-    snap = trace.snapshot()
-    assert snap["counters"].get("bytes_decrypted", 0) == sum(
-        len(b) for b in blobs
-    )
-    # tiny workload stays in the BUFFER regime; its one device hop is
-    # the dense fold: the state-plane upload — exactly clock (R·4) +
-    # add/rm planes (2·E·R·4) for this E=12, R=5 shape — plus the 240
-    # op rows' columns (13 B a row, padded to the 256-row class), and
-    # the same planes pulled back.  A drift here means an unaccounted
-    # (or double-counted) device hop appeared.
-    planes = 5 * 4 + 2 * 12 * 5 * 4
-    assert snap["counters"].get("h2d_bytes", 0) == planes + 13 * 256
-    assert snap["counters"].get("d2h_bytes", 0) == planes
-    assert codec.pack(state.to_obj()) == codec.pack(host.to_obj())
-    # a failed decrypt (wrong key) counts NOTHING
-    trace.reset()
-    from crdt_enc_tpu.backends.xchacha import AeadError
+    files, actors, host = orset_workload(seed=5)
 
-    with pytest.raises(AeadError):
-        accel.fold_encrypted_stream(
-            ORSet(), secrets.token_bytes(32), blobs,
-            actors_hint=sorted(actors), n_chunks=4,
+    class DeadCryptor(XChaChaCryptor):
+        async def decrypt_batch(self, key, blobs):
+            raise AeadError("dead")
+
+        async def decrypt(self, key, data):
+            raise AeadError("dead")
+
+    async def go():
+        remote, writer = await seed_remote(files)
+        sealed = await writer.storage.load_ops([(a, 1) for a in actors])
+        ciphertext_bytes = sum(
+            len(codec.unpack(VersionBytes.deserialize(raw).content)[1])
+            for _, _, raw in sealed
         )
-    assert trace.snapshot()["counters"].get("bytes_decrypted", 0) == 0
+        trace.reset()
+        reader = await read_pipelined(remote, 10, accel=TpuAccelerator())
+        snap = trace.snapshot()
+        assert snap["counters"]["bytes_decrypted"] == ciphertext_bytes
+        # tiny workload stays in the BUFFER regime; its one device hop is
+        # the dense fold: the state-plane upload — exactly clock (R·4) +
+        # add/rm planes (2·E·R·4) for this E=12, R=5 shape — plus the 240
+        # op rows' columns (13 B a row, padded to the 256-row class), and
+        # the same planes pulled back.  A drift here means an unaccounted
+        # (or double-counted) device hop appeared.
+        planes = 5 * 4 + 2 * 12 * 5 * 4
+        assert snap["counters"].get("h2d_bytes", 0) == planes + 13 * 256
+        assert snap["counters"].get("d2h_bytes", 0) == planes
+        assert reader.with_state(
+            lambda s: codec.pack(s.to_obj())
+        ) == codec.pack(host.to_obj())
+        # a failed decrypt (dead cryptor) counts NOTHING
+        trace.reset()
+        with pytest.raises(IngestDecryptError):
+            await read_pipelined(
+                remote, 10, accel=TpuAccelerator(), cryptor=DeadCryptor()
+            )
+        assert trace.snapshot()["counters"].get("bytes_decrypted", 0) == 0
+
+    _run(go())
 
 
 def test_session_fresh_fast_init_matches_general_path():
@@ -1028,11 +847,14 @@ def test_session_fresh_fast_init_matches_general_path():
     construction (actor table, R, clock0) and fold byte-identically when
     the hint arrives UNSORTED (general path) vs sorted (fast path)."""
     _native_crypto_or_skip()
+    from _ingest_doors import (
+        ChunkedMemoryStorage, orset_workload, read_pipelined, seed_remote,
+    )
     from crdt_enc_tpu.models import ORSet
     from crdt_enc_tpu.parallel import TpuAccelerator
     from crdt_enc_tpu.parallel.session import OrsetFoldSession
 
-    key, blobs, actors, host = _encrypted_orset_workload(seed=11)
+    files, actors, host = orset_workload(seed=11)
     accel = TpuAccelerator()
     fast = OrsetFoldSession(accel, ORSet(), sorted(actors))
     slow = OrsetFoldSession(accel, ORSet(), list(reversed(sorted(actors))))
@@ -1050,36 +872,58 @@ def test_session_fresh_fast_init_matches_general_path():
     pos = sess.actors_sorted.index(actors[1])
     assert sess._clock0[pos] == 7
 
-    results = {}
-    for hint in (sorted(actors), list(reversed(sorted(actors)))):
-        state = ORSet()
-        assert accel.fold_encrypted_stream(
-            state, key, blobs, actors_hint=hint, n_chunks=4
-        )
-        results[tuple(hint)] = codec.pack(state.to_obj())
-    assert len(set(results.values())) == 1
-    assert next(iter(results.values())) == codec.pack(host.to_obj())
+    # and folds byte-identically through the served door whichever way
+    # the storage lists the actors (the listing IS the session's hint)
+    class ReversedListing(ChunkedMemoryStorage):
+        async def list_op_actors(self):
+            return list(reversed(sorted(await super().list_op_actors())))
+
+    async def go():
+        remote, _ = await seed_remote(files)
+        return [
+            (await read_pipelined(
+                remote, 10, accel=accel, base=base
+            )).with_state(lambda s: codec.pack(s.to_obj()))
+            for base in (ChunkedMemoryStorage, ReversedListing)
+        ]
+
+    results = _run(go())
+    assert len(set(results)) == 1
+    assert results[0] == codec.pack(host.to_obj())
 
 
 def test_session_member_collision_declines_on_bytes_path():
     """1 == True as members: the bytes-keyed remap must decline exactly
     like the legacy object remap (the dense planes cannot represent the
-    collision), and the caller's fallback still folds correctly."""
+    collision), and the core's per-op fallback still folds correctly."""
     _native_crypto_or_skip()
-    from crdt_enc_tpu.backends.xchacha import encrypt_blob
+    from _ingest_doors import read_pipelined, seed_remote
     from crdt_enc_tpu.models import ORSet
+    from crdt_enc_tpu.models.orset import AddOp
+    from crdt_enc_tpu.models.vclock import Dot
     from crdt_enc_tpu.parallel import TpuAccelerator
 
-    key = secrets.token_bytes(32)
     actor = b"\x01" * 16
-    blobs = [
-        encrypt_blob(key, codec.pack([[0, 1, [actor, 1]]])),
-        encrypt_blob(key, codec.pack([[0, True, [actor, 2]]])),
-    ]
-    accel = TpuAccelerator()
-    state = ORSet()
-    ok = accel.fold_encrypted_stream(
-        state, key, blobs, actors_hint=[actor], n_chunks=1
-    )
-    assert not ok  # declined, state untouched — caller replays per-op
-    assert not state.entries
+    # enough files to promote the ingest into the session, the last two
+    # colliding as Python values
+    members = [1] * 17 + [True]
+    files = [(actor, [[0, m, [actor, c]]]) for c, m in enumerate(members, 1)]
+    host = ORSet()
+    for c, m in enumerate(members, 1):
+        host.apply(AddOp(m, Dot(actor, c)))
+
+    async def go():
+        remote, _ = await seed_remote(files)
+        trace.reset()
+        reader = await read_pipelined(remote, 6, accel=TpuAccelerator())
+        counters = trace.snapshot()["counters"]
+        # the chunks ahead of the collision went through the session; the
+        # declined chunk (and nothing ahead of it) replayed per op
+        assert counters["op_files_bulk_folded"] == 12
+        assert counters["ops_folded"] == 6
+        assert reader.with_state(
+            lambda s: codec.pack(s.to_obj())
+        ) == codec.pack(host.to_obj())
+        assert reader.info().next_op_versions.get(actor) == len(members)
+
+    _run(go())
