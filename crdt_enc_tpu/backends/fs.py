@@ -30,6 +30,7 @@ import uuid
 
 from ..core.storage import Storage
 from ..models.vclock import Actor
+from ..utils import trace
 from .memory import content_name
 
 FS_CONCURRENCY = 32  # reference buffer_unordered(32), crdt-enc-tokio lib.rs:112
@@ -152,6 +153,58 @@ def _remove_quiet(path: str) -> None:
         pass
 
 
+def _native_step(step: str, *args) -> bool:
+    """One file step as ONE call into ``native/io.cpp`` (interpreter lock
+    released, paths relative to a directory opened once; the protocol is
+    in that file's header).  True iff the step ran clean (status 0): the
+    caller is done.  On any other status — the name exists, a directory
+    vanished, any errno, or no library at all — the caller runs its Python
+    helper from the start, so every rule about a surprise (identical
+    replay, burned version, the retry of a vanishing directory) is
+    written once, there.  The one exception is a NEGATIVE status: the
+    name is in place and the directory's flush failed, which a replay
+    would read back as an identical-content success; it is raised as
+    ``_fsync_dir`` would have raised it."""
+    from .. import native
+
+    try:
+        lib = native.load()
+    except Exception:
+        FsStorage._warn_native_unavailable()
+    else:
+        status = getattr(lib, step)(*args)
+        if status == 0:
+            trace.add("fs_steps_native", 1)
+            return True
+        if status < 0:
+            raise OSError(-status, os.strerror(-status), os.fsdecode(args[0]))
+    trace.add("fs_steps_python", 1)
+    return False
+
+
+def _publish_native(step: str, path: str, data: bytes) -> bool:
+    d, name = os.path.split(path)
+    return _native_step(
+        step, os.fsencode(d), os.fsencode(name), data, len(data)
+    )
+
+
+def _remove_prefixes_native(
+    base: str, actor_last_versions: list[tuple[Actor, int]]
+) -> bool:
+    import ctypes
+
+    n = len(actor_last_versions)
+    # clamped into int64: the C loop judges names of at most 18 digits, so
+    # a bound past either end compares as the bound itself would
+    lasts = (max(-1, min(last, (1 << 63) - 1)) for _, last in actor_last_versions)
+    return _native_step(
+        "remove_log_prefixes", os.fsencode(base), n,
+        b"".join(actor.hex().encode() + b"\0" for actor, _ in actor_last_versions),
+        (ctypes.c_int64 * n)(*lasts),
+    )
+
+
 class FsStorage(Storage):
     def __init__(self, local_path: str, remote_path: str):
         self.local = os.fspath(local_path)
@@ -191,7 +244,12 @@ class FsStorage(Storage):
     # (``*_sync``, the port's optional sync twins: core/storage.py); the
     # awaitables are the same functions handed to ``_run``.
     def store_local_meta_sync(self, data: bytes) -> None:
-        _write_file_atomic(self._local_meta_path(), bytes(data))
+        self._store_local(self._local_meta_path(), bytes(data))
+
+    @staticmethod
+    def _store_local(path: str, data: bytes) -> None:
+        if not _publish_native("write_file_atomic", path, data):
+            _write_file_atomic(path, data)
 
     async def store_local_meta(self, data: bytes) -> None:
         await self._run(self.store_local_meta_sync, data)
@@ -204,7 +262,7 @@ class FsStorage(Storage):
         return await self._run(_read_file, self._local_checkpoint_path())
 
     def store_local_checkpoint_sync(self, data: bytes) -> None:
-        _write_file_atomic(self._local_checkpoint_path(), bytes(data))
+        self._store_local(self._local_checkpoint_path(), bytes(data))
 
     async def store_local_checkpoint(self, data: bytes) -> None:
         await self._run(self.store_local_checkpoint_sync, data)
@@ -227,7 +285,9 @@ class FsStorage(Storage):
     @staticmethod
     def _store_ca(d: str, data: bytes) -> str:
         name = content_name(data)
-        _write_file_new(os.path.join(d, name), bytes(data))
+        path, data = os.path.join(d, name), bytes(data)
+        if not _publish_native("publish_file_new", path, data):
+            _write_file_new(path, data)
         return name
 
     @staticmethod
@@ -260,7 +320,12 @@ class FsStorage(Storage):
         return await self._run(self.store_state_sync, data)
 
     def remove_states_sync(self, names: list[str]) -> None:
-        self._remove_ca(self._states_dir(), names)
+        d = self._states_dir()
+        if names and not _native_step(
+            "remove_names", os.fsencode(d), len(names),
+            b"".join(os.fsencode(n) + b"\0" for n in names),
+        ):
+            self._remove_ca(d, names)
 
     async def remove_states(self, names: list[str]) -> None:
         await self._run(self.remove_states_sync, names)
@@ -374,11 +439,11 @@ class FsStorage(Storage):
         if not _warned_native_scan:
             _warned_native_scan = True
             logger.warning(
-                "native op scan unavailable; using per-file scans "
-                "(logged once)", exc_info=True,
+                "native library unavailable; using the per-file Python "
+                "paths (logged once)", exc_info=True,
             )
         else:
-            logger.debug("native op scan failed", exc_info=True)
+            logger.debug("native call failed", exc_info=True)
 
     def _scan_native(self, actor: Actor, first: int):
         """Dense scan via the native reader.
@@ -643,10 +708,9 @@ class FsStorage(Storage):
     def _store_versioned(d: str, version: int, data: bytes) -> None:
         # version-addressed: a vanished collider BURNS the version (the
         # caller probes forward) — see _write_file_new's contract
-        _write_file_new(
-            os.path.join(d, str(version)), bytes(data),
-            relink_vanished_collider=False,
-        )
+        path, data = os.path.join(d, str(version)), bytes(data)
+        if not _publish_native("publish_file_new", path, data):
+            _write_file_new(path, data, relink_vanished_collider=False)
 
     @staticmethod
     def _remove_prefix(d: str, last: int) -> None:
@@ -668,11 +732,21 @@ class FsStorage(Storage):
             self._store_versioned, self._ops_dir(actor), version, data
         )
 
+    def _remove_logs(
+        self, dir_of, actor_last_versions: list[tuple[Actor, int]]
+    ) -> None:
+        """Prefix GC of one log family (``dir_of``: ``_ops_dir`` or
+        ``_deltas_dir``), the whole list in one native step."""
+        if actor_last_versions and not _remove_prefixes_native(
+            dir_of(), actor_last_versions
+        ):
+            for actor, last in actor_last_versions:
+                self._remove_prefix(dir_of(actor), last)
+
     def remove_ops_sync(
         self, actor_last_versions: list[tuple[Actor, int]]
     ) -> None:
-        for actor, last in actor_last_versions:
-            self._remove_prefix(self._ops_dir(actor), last)
+        self._remove_logs(self._ops_dir, actor_last_versions)
 
     async def remove_ops(self, actor_last_versions: list[tuple[Actor, int]]) -> None:
         await self._run(self.remove_ops_sync, actor_last_versions)
@@ -726,8 +800,7 @@ class FsStorage(Storage):
     def remove_deltas_sync(
         self, actor_last_versions: list[tuple[Actor, int]]
     ) -> None:
-        for actor, last in actor_last_versions:
-            self._remove_prefix(self._deltas_dir(actor), last)
+        self._remove_logs(self._deltas_dir, actor_last_versions)
 
     async def remove_deltas(
         self, actor_last_versions: list[tuple[Actor, int]]

@@ -247,6 +247,23 @@ def _bind(lib) -> None:
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, u8p
     ]
     lib.probe_op_files.restype = ctypes.c_int64
+    # the writers (io.cpp "file steps"): status 0, or the Python body runs
+    for name in ("publish_file_new", "write_file_atomic"):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64
+        ]
+        fn.restype = ctypes.c_int32
+    lib.remove_log_prefixes.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, i64p
+    ]
+    lib.remove_log_prefixes.restype = ctypes.c_int32
+    lib.remove_names.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p
+    ]
+    lib.remove_names.restype = ctypes.c_int32
+    lib.file_step_flushes.argtypes = []
+    lib.file_step_flushes.restype = ctypes.c_int64
     # (the two-pass count+decode batch protocol still exists in C —
     # orset_count_rows_batch / orset_decode_batch[_h] — but the Python
     # span decoder moved to the single-pass grow/take protocol below, so

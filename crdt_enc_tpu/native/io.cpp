@@ -13,12 +13,52 @@
 // A file that shrinks/vanishes between passes returns -1 and the caller
 // falls back to the per-file Python path (the sync tool may race us; op
 // files themselves are immutable once published).
+//
+// File steps: the writers (backends/fs.py is the only caller).
+//
+// Every file step FsStorage makes — an immutable publish, a replace of a
+// mutable local file, one GC list — is ONE call below.  ctypes.CDLL
+// releases the interpreter lock for its length, so sixteen seal tails on
+// sixteen worker threads do not hand the lock round once per system
+// call; and every path is relative to a directory opened once, so a step
+// walks the long <tenant>/remote/<family>/ prefix once, not eight times
+// (on the chip machine's kernel a path walk costs more than a flush).
+//
+//   publish new    open(dir) [ENOENT only: mkdir -p, open again] ->
+//                  openat(.tmp-<32 hex>, O_CREAT|O_EXCL) -> write all ->
+//                  fsync(file) -> close -> linkat(tmp, name) ->
+//                  unlinkat(tmp) -> fsync(dir) -> close
+//   write atomic   the same with renameat for linkat + unlinkat
+//   remove lists   open(base) once; per actor openat, readdir,
+//                  unlinkat each all-digit name <= last, then
+//                  unlinkat(actor, AT_REMOVEDIR), failure ignored
+//
+// The flushes are the Python helpers' own, in their places: the file
+// before its name appears, the directory after, both before the call
+// returns.  What a listing can observe is unchanged: final names, the
+// `.tmp-` prefix of a file in flight, an emptied actor directory gone.
+//
+// A surprise is never handled here.  The name exists (an identical
+// replay? a burned version?), a directory vanished under a step (the
+// compactor of another replica), a directory entry int() might read as a
+// number though it is not all digits, any other errno: the step removes
+// its tmp, returns a non-zero status, and fs.py runs the Python helper
+// from its start.  Those rules (`_write_file_new`'s docstring) decide
+// whether a write is lost or doubled; they stay written once, where the
+// crash and fault tests patch and count them.  One status is negative:
+// the name is published and the directory's flush failed.  A replay
+// would find identical content and call it success, so fs.py raises it.
 
+#include <atomic>
 #include <cerrno>
 #include <cstdint>
 #include <cstdio>
+#include <cstdlib>
 #include <cstring>
+#include <dirent.h>
 #include <fcntl.h>
+#include <pthread.h>
+#include <sys/random.h>
 #include <sys/stat.h>
 #include <unistd.h>
 
@@ -27,6 +67,121 @@ namespace {
 int path_join(char* out, size_t cap, const char* dir, int64_t version) {
   int n = snprintf(out, cap, "%s/%lld", dir, (long long)version);
   return (n > 0 && (size_t)n < cap) ? 0 : -1;
+}
+
+// ---- file steps (the writers; protocol in the header comment) ----------
+
+// `.tmp-<32 hex>`: a salt drawn once a process (again in a forked child,
+// which would otherwise repeat its parent's names) and a counter, so a
+// name costs no system call.  O_EXCL still guards the create.
+std::atomic<uint64_t> g_tmp_salt{0};
+std::atomic<uint64_t> g_tmp_counter{0};
+
+void draw_tmp_salt() {
+  uint64_t salt = 0;
+  if (getrandom(&salt, sizeof salt, 0) != (ssize_t)sizeof salt)
+    salt = ((uint64_t)getpid() << 32) ^ (uint64_t)(uintptr_t)&salt;
+  g_tmp_salt.store(salt);
+}
+
+void tmp_name(char out[40]) {
+  static const int registered =
+      (draw_tmp_salt(), pthread_atfork(nullptr, nullptr, draw_tmp_salt));
+  (void)registered;
+  snprintf(out, 40, ".tmp-%016llx%016llx",
+           (unsigned long long)g_tmp_salt.load(),
+           (unsigned long long)g_tmp_counter.fetch_add(1));
+}
+
+// Every flush the writers make, counted: a test holds a seal tail to its
+// eight, which no Python-side probe can see once the steps run here.
+std::atomic<int64_t> g_flushes{0};
+
+int flush(int fd) {
+  g_flushes.fetch_add(1, std::memory_order_relaxed);
+  return fsync(fd);
+}
+
+int open_dir(const char* dir) {
+  return open(dir, O_RDONLY | O_DIRECTORY | O_CLOEXEC);
+}
+
+// `mkdir -p dir`, reached only after open_dir said ENOENT: the seal tail
+// never probes for a directory that is there.
+void make_dirs(const char* dir) {
+  char path[4096];
+  size_t n = strlen(dir);
+  if (n == 0 || n >= sizeof path) return;
+  memcpy(path, dir, n + 1);
+  for (size_t i = 1; i <= n; i++) {
+    if (path[i] != '/' && path[i] != '\0') continue;
+    char keep = path[i];
+    path[i] = '\0';
+    mkdir(path, 0777);  // EEXIST and every other failure: the open tells
+    path[i] = keep;
+  }
+}
+
+int write_all(int fd, const uint8_t* data, int64_t len) {
+  int64_t done = 0;
+  while (done < len) {
+    ssize_t w = write(fd, data + done, (size_t)(len - done));
+    if (w < 0 && errno == EINTR) continue;
+    if (w < 0) return errno;
+    if (w == 0) return EIO;
+    done += w;
+  }
+  return 0;
+}
+
+// The shared body of the two publishes: tmp + fsync in `dir`, then
+// linkat (publish new: EEXIST if the name is there) or renameat (write
+// atomic: last writer wins), then fsync of the directory.  Returns 0, a
+// positive errno when nothing was published (the tmp is gone again), or
+// a NEGATIVE errno when the name is in place but the directory's flush
+// failed: the one failure a replay from the start would paper over.
+int publish(const char* dir, const char* name, const uint8_t* data,
+            int64_t len, bool exclusive) {
+  int dfd = open_dir(dir);
+  if (dfd < 0 && errno == ENOENT) {
+    make_dirs(dir);
+    dfd = open_dir(dir);
+  }
+  if (dfd < 0) return errno;
+  char tmp[40];
+  tmp_name(tmp);
+  int fd = openat(dfd, tmp, O_WRONLY | O_CREAT | O_EXCL | O_CLOEXEC, 0644);
+  if (fd < 0) {
+    int err = errno;
+    close(dfd);
+    return err;
+  }
+  int err = write_all(fd, data, len);
+  if (err == 0 && flush(fd) != 0) err = errno;
+  if (close(fd) != 0 && err == 0) err = errno;
+  if (err == 0) {
+    if (exclusive) {
+      if (linkat(dfd, tmp, dfd, name, 0) != 0) err = errno;
+      unlinkat(dfd, tmp, 0);
+    } else if (renameat(dfd, tmp, dfd, name) != 0) {
+      err = errno;
+      unlinkat(dfd, tmp, 0);
+    }
+    if (err == 0 && flush(dfd) != 0) err = -errno;
+  } else {
+    unlinkat(dfd, tmp, 0);
+  }
+  close(dfd);
+  return err;
+}
+
+// Would Python's int() take a name that is not all ASCII digits?  Only
+// if it holds a digit at all: an ASCII one, or (above 0x7f) perhaps a
+// Unicode one.  Such a name is a surprise and goes back to Python.
+bool maybe_python_int(const char* name) {
+  for (const unsigned char* p = (const unsigned char*)name; *p; p++)
+    if ((*p >= '0' && *p <= '9') || *p >= 0x80) return true;
+  return false;
 }
 
 }  // namespace
@@ -99,6 +254,98 @@ int64_t probe_op_files(const char* base_dir, int64_t n,
   }
   close(dfd);
   return n;
+}
+
+// ---- the writers: one call a file step --------------------------------
+//
+// Each returns 0 for the clean path and a non-zero status for everything
+// else; the caller (backends/fs.py) then runs the Python helper from its
+// start.  Nothing below is left behind by a non-zero return but, at most,
+// the published name itself.
+
+int64_t file_step_flushes() { return g_flushes.load(); }
+
+// Immutable publish of dir/name (content- or version-addressed).
+int32_t publish_file_new(const char* dir, const char* name,
+                         const uint8_t* data, int64_t len) {
+  return publish(dir, name, data, len, true);
+}
+
+// Last-writer-wins replace of dir/name (local meta, local checkpoint).
+int32_t write_file_atomic(const char* dir, const char* name,
+                          const uint8_t* data, int64_t len) {
+  return publish(dir, name, data, len, false);
+}
+
+// Every version <= lasts[i] of base_dir/<actors[i]>/, for n actors in one
+// call (`actors`: flat NUL-separated directory names), then rmdir of the
+// actor directory, which fails harmlessly while files remain.  An absent
+// base or actor directory has nothing to remove.  `.tmp-` files, `.`,
+// `..` and names int() cannot read are skipped, as the Python body skips
+// them; a name int() MIGHT read differently from strtoll ("+7", "1_0",
+// twenty digits, non-ASCII) is left in place and reported as EINVAL.
+int32_t remove_log_prefixes(const char* base_dir, int64_t n,
+                            const char* actors, const int64_t* lasts) {
+  int bfd = open_dir(base_dir);
+  if (bfd < 0) return errno == ENOENT ? 0 : errno;
+  int status = 0;
+  const char* actor = actors;
+  for (int64_t i = 0; i < n && status == 0; i++, actor += strlen(actor) + 1) {
+    int afd = openat(bfd, actor, O_RDONLY | O_DIRECTORY | O_CLOEXEC);
+    if (afd < 0) {
+      if (errno != ENOENT) status = errno;
+      continue;
+    }
+    DIR* listing = fdopendir(afd);  // owns afd from here
+    if (listing == nullptr) {
+      status = errno;
+      close(afd);
+      continue;
+    }
+    for (;;) {
+      errno = 0;
+      struct dirent* e = readdir(listing);
+      if (e == nullptr) {
+        if (errno != 0) status = errno;
+        break;
+      }
+      const char* name = e->d_name;
+      if (strncmp(name, ".tmp-", 5) == 0 || strcmp(name, ".") == 0 ||
+          strcmp(name, "..") == 0)
+        continue;
+      size_t digits = strspn(name, "0123456789");
+      if (name[digits] != '\0' || digits == 0 || digits > 18) {
+        if (maybe_python_int(name)) status = EINVAL;
+        continue;
+      }
+      if (strtoll(name, nullptr, 10) > lasts[i]) continue;
+      if (unlinkat(afd, name, 0) != 0 && errno != ENOENT) {
+        status = errno;
+        break;
+      }
+    }
+    closedir(listing);
+    unlinkat(bfd, actor, AT_REMOVEDIR);
+  }
+  close(bfd);
+  return status;
+}
+
+// dir/<names[i]> for n names (flat NUL-separated), already-gone files
+// tolerated: the content-addressed families' GC.
+int32_t remove_names(const char* dir, int64_t n, const char* names) {
+  int dfd = open_dir(dir);
+  if (dfd < 0) return errno == ENOENT ? 0 : errno;
+  int status = 0;
+  const char* name = names;
+  for (int64_t i = 0; i < n; i++, name += strlen(name) + 1) {
+    if (unlinkat(dfd, name, 0) != 0 && errno != ENOENT) {
+      status = errno;
+      break;
+    }
+  }
+  close(dfd);
+  return status;
 }
 
 }  // extern "C"

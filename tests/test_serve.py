@@ -776,8 +776,9 @@ def test_tenant_failure_is_isolated():
 
 
 class _SealPhaseProbe:
-    """Counts ``asyncio.to_thread`` hops and ``os.fsync`` calls made while
-    ``FoldService._seal_all`` is running."""
+    """Counts ``asyncio.to_thread`` hops and flushes made while
+    ``FoldService._seal_all`` is running: ``os.fsync`` from the Python
+    file helpers, ``file_step_flushes`` from the native file steps."""
 
     def __init__(self, monkeypatch):
         import os
@@ -799,12 +800,18 @@ class _SealPhaseProbe:
             self.fsyncs += self.inside
             return real_fsync(fd)
 
+        from crdt_enc_tpu import native
+
+        native_flushes = native.load().file_step_flushes
+
         async def seal_all(service, works, t0):
             self.inside = True
+            before = native_flushes()
             try:
                 return await real_seal_all(service, works, t0)
             finally:
                 self.inside = False
+                self.fsyncs += native_flushes() - before
 
         monkeypatch.setattr(asyncio, "to_thread", hop)
         monkeypatch.setattr(os, "fsync", fsync)
@@ -816,7 +823,8 @@ def test_busy_cycle_seals_each_tenant_in_one_job(kind, tmp_path, monkeypatch):
     """A toy busy cycle: every tenant has new files, and each tenant's
     whole seal tail is ONE hop to a worker thread (snapshot, delta with its
     verify, local meta, GC, checkpoint), with the flushes it always made:
-    file and directory for each of the four files it publishes."""
+    file and directory for each of the four files it publishes, whichever
+    of the two (native step, Python helper) made them."""
     tenants = 5
 
     def storage(t, name):
