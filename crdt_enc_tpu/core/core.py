@@ -270,13 +270,15 @@ class OpenOptions:
 
 
 async def open_sealed_blob(
-    keys: Keys, cryptor: Cryptor, raw: bytes, supported_data_versions=None
+    keys: Keys, cryptor: Cryptor, raw: bytes, supported_data_versions=None,
+    *, count_clear: str | None = None,
 ):
     """Unwrap one three-layer sealed blob (the single implementation of
     the wire contract — the core and the fsck tool both go through here,
     so the two can never drift).  ``supported_data_versions=None`` skips
     the inner app-version check (diagnostic callers that do not know the
-    application's version set)."""
+    application's version set).  ``count_clear`` names a counter that
+    grows by the blob's cleartext length (what the decode below walks)."""
     outer = VersionBytes.deserialize(raw).ensure_versions(
         SUPPORTED_CONTAINER_VERSIONS
     )
@@ -288,6 +290,8 @@ async def open_sealed_blob(
             "key metadata may not have synced yet"
         )
     clear = await cryptor.decrypt(key.material, bytes(middle))
+    if count_clear is not None:
+        trace.add(count_clear, len(clear))
     inner = VersionBytes.deserialize(clear)
     if supported_data_versions is not None:
         inner.ensure_versions(supported_data_versions)
@@ -1254,9 +1258,10 @@ class Core:
             CURRENT_CONTAINER_VERSION, codec.pack([key.id, middle])
         ).serialize()
 
-    async def _open_sealed(self, raw: bytes):
+    async def _open_sealed(self, raw: bytes, *, count_clear: str | None = None):
         return await open_sealed_blob(
-            self._data.keys, self.cryptor, raw, self.supported_data_versions
+            self._data.keys, self.cryptor, raw, self.supported_data_versions,
+            count_clear=count_clear,
         )
 
     def _note_quarantine(self, family: str, ident: str, exc: Exception) -> None:
@@ -1539,7 +1544,9 @@ class Core:
         async def decode(name: str, raw: bytes):
             async with sem:
                 try:
-                    obj = await self._open_sealed(raw)
+                    obj = await self._open_sealed(
+                        raw, count_clear="snapshot_bytes_opened"
+                    )
                     # [state, cursor] or [state, cursor, sealer] — see
                     # StateWrapper's wire note; a malformed sealer id is
                     # ignored (observational), never a read failure

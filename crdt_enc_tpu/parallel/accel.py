@@ -213,6 +213,7 @@ class TpuAccelerator(HostAccelerator):
             return None
         if c.token != getattr(state, "_mut", None):
             self._plane_cache = None  # stale: free the device planes
+            trace.add("plane_cache_drops", 1)
             return None
         return c
 
@@ -301,6 +302,7 @@ class TpuAccelerator(HostAccelerator):
         c = self._plane_cache
         if c is not None and c.ref() is state:
             self._plane_cache = None
+            trace.add("plane_cache_drops", 1)
 
     def _fold_orset_columns(
         self, state: ORSet, kind, member, actor, counter, members, replicas
@@ -1197,37 +1199,48 @@ class TpuAccelerator(HostAccelerator):
     def _merge_orsets(self, state: ORSet, others: list) -> ORSet:
         members, replicas = K.Vocab(), K.Vocab()
         all_states = [state] + list(others)
-        for s in all_states:
-            K.orset_scan_vocab(s, members, replicas)  # cheap vocab-only pass
+        with trace.span("states.merge.scan"):
+            for s in all_states:
+                K.orset_scan_vocab(s, members, replicas)  # vocab-only pass
         if len(members) == 0 or len(replicas) == 0:
             return state
-        planes = [
-            K.orset_state_to_planes(s, members, replicas, scanned=True)
-            for s in all_states
-        ]
-        clocks = np.stack([p[0] for p in planes])
-        adds = np.stack([p[1] for p in planes])
-        rms = np.stack([p[2] for p in planes])
-        E, R = len(members), len(replicas)
-        if self.bucket_vocab:
-            # merge at power-of-two (S, E, R) classes: all-zero states are
-            # the merge identity and zero vocab lanes are inert, so the
-            # padded tree merge is byte-equal after the slice back — and a
-            # population of small states shares one compiled merge set
-            S = len(all_states)
-            Sp, Ep, Rp = _bucket(S, 2), _bucket(E), _bucket(R)
-            if (Sp, Ep, Rp) != (S, E, R):
-                pad = ((0, Sp - S), (0, Ep - E), (0, Rp - R))
-                clocks = np.pad(clocks, (pad[0], pad[2]))
-                adds = np.pad(adds, pad)
-                rms = np.pad(rms, pad)
-        clock, add, rm = K.orset_merge_many(clocks, adds, rms)
-        clock = np.asarray(clock)[:R]
-        add = np.asarray(add)[:E, :R]
-        rm = np.asarray(rm)[:E, :R]
-        merged = K.orset_planes_to_state(clock, add, rm, members, replicas)
-        state.clock = merged.clock
-        state.entries = merged.entries
-        state.deferred = merged.deferred
-        self._note_orset_writeback(state)
+        planes = []
+        for i, s in enumerate(all_states):
+            with trace.span("states.merge.to_planes", i):
+                planes.append(
+                    K.orset_state_to_planes(s, members, replicas, scanned=True)
+                )
+        S, E, R = len(all_states), len(members), len(replicas)
+        with trace.span("states.merge.stack"):
+            clocks = np.stack([p[0] for p in planes])
+            adds = np.stack([p[1] for p in planes])
+            rms = np.stack([p[2] for p in planes])
+            if self.bucket_vocab:
+                # merge at power-of-two (S, E, R) classes: all-zero states
+                # are the merge identity and zero vocab lanes are inert, so
+                # the padded tree merge is byte-equal after the slice back —
+                # and a population of small states shares one compiled
+                # merge set
+                Sp, Ep, Rp = _bucket(S, 2), _bucket(E), _bucket(R)
+                if (Sp, Ep, Rp) != (S, E, R):
+                    pad = ((0, Sp - S), (0, Ep - E), (0, Rp - R))
+                    clocks = np.pad(clocks, (pad[0], pad[2]))
+                    adds = np.pad(adds, pad)
+                    rms = np.pad(rms, pad)
+        trace.add("snapshot_merges", 1)
+        trace.add("merge_state_cells", S * E * R)
+        trace.add("merge_out_cells", E * R)
+        trace.add("merge_clock_cells", (S + 1) * R)
+        with trace.span("states.merge.device"):  # dispatch to the last byte
+            clock, add, rm = obs_runtime.pull(
+                *K.orset_merge_many(clocks, adds, rms)
+            )
+        with trace.span("states.merge.writeback"):
+            merged = K.orset_planes_to_state(
+                clock[:R], add[:E, :R], rm[:E, :R], members, replicas
+            )
+            state.clock = merged.clock
+            state.entries = merged.entries
+            state.deferred = merged.deferred
+            self._note_orset_writeback(state)
         return state
