@@ -153,7 +153,7 @@ def _remove_quiet(path: str) -> None:
         pass
 
 
-def _native_step(step: str, *args) -> bool:
+def _native_step(step: str, *args, counted: str = "fs_steps") -> bool:
     """One file step as ONE call into ``native/io.cpp`` (interpreter lock
     released, paths relative to a directory opened once; the protocol is
     in that file's header).  True iff the step ran clean (status 0): the
@@ -164,7 +164,9 @@ def _native_step(step: str, *args) -> bool:
     written once, there.  The one exception is a NEGATIVE status: the
     name is in place and the directory's flush failed, which a replay
     would read back as an identical-content success; it is raised as
-    ``_fsync_dir`` would have raised it."""
+    ``_fsync_dir`` would have raised it.  ``counted`` names the counter
+    pair: the seal tail's writes are ``fs_steps_*``, a poll's reads
+    ``fs_reads_*``."""
     from .. import native
 
     try:
@@ -174,11 +176,11 @@ def _native_step(step: str, *args) -> bool:
     else:
         status = getattr(lib, step)(*args)
         if status == 0:
-            trace.add("fs_steps_native", 1)
+            trace.add(counted + "_native", 1)
             return True
         if status < 0:
             raise OSError(-status, os.strerror(-status), os.fsdecode(args[0]))
-    trace.add("fs_steps_python", 1)
+    trace.add(counted + "_python", 1)
     return False
 
 
@@ -203,6 +205,38 @@ def _remove_prefixes_native(
         b"".join(actor.hex().encode() + b"\0" for actor, _ in actor_last_versions),
         (ctypes.c_int64 * n)(*lasts),
     )
+
+
+LIST_NAMES_BYTES = 1 << 16  # one listing's names; a longer one is Python's
+
+
+def _list_names(path: str) -> list[str]:
+    """A poll's listing of one directory: what ``_list_dir`` gives (an
+    absent directory is empty, ``.tmp-`` files in flight are left out)
+    from ONE native call, or from ``_list_dir`` itself on any other
+    status (``fs_reads_*``)."""
+    import ctypes
+
+    buf = ctypes.create_string_buffer(LIST_NAMES_BYTES)
+    n, used = ctypes.c_int64(), ctypes.c_int64()
+    if not _native_step(
+        "list_dir_names", os.fsencode(path), buf, len(buf),
+        ctypes.byref(n), ctypes.byref(used), counted="fs_reads",
+    ):
+        return _list_dir(path)
+    names = os.fsdecode(buf[: used.value]).split("\0")[: n.value]
+    return [name for name in names if not name.startswith(".tmp-")]
+
+
+def _op_actors(names: list[str]) -> list[Actor]:
+    """The actors among the names of a log family's directory."""
+    actors = []
+    for n in names:
+        try:
+            actors.append(bytes.fromhex(n))
+        except ValueError:
+            continue  # foreign junk in the synced dir is not ours to judge
+    return sorted(a for a in actors if len(a) == 16)
 
 
 class FsStorage(Storage):
@@ -271,9 +305,6 @@ class FsStorage(Storage):
         await self._run(_remove_quiet, self._local_checkpoint_path())
 
     # -- content-addressed families ---------------------------------------
-    async def _list_ca(self, d: str) -> list[str]:
-        return sorted(await self._run(_list_dir, d))
-
     async def _load_ca(self, d: str, names: list[str]) -> list[tuple[str, bytes]]:
         async def one(n):
             raw = await self._run(_read_file, os.path.join(d, n))
@@ -295,8 +326,15 @@ class FsStorage(Storage):
         for n in names:
             _remove_quiet(os.path.join(d, n))
 
+    # The four reads of a poll exist as plain functions too (``*_sync``,
+    # the port's optional sync twins: core/storage.py INGEST_TWINS), each
+    # ONE native call; the awaitables are the same functions handed to
+    # ``_run``.
+    def list_remote_meta_names_sync(self) -> list[str]:
+        return sorted(_list_names(self._meta_dir()))
+
     async def list_remote_meta_names(self) -> list[str]:
-        return await self._list_ca(self._meta_dir())
+        return await self._run(self.list_remote_meta_names_sync)
 
     async def load_remote_metas(self, names: list[str]) -> list[tuple[str, bytes]]:
         return await self._load_ca(self._meta_dir(), names)
@@ -307,8 +345,11 @@ class FsStorage(Storage):
     async def remove_remote_metas(self, names: list[str]) -> None:
         await self._run(self._remove_ca, self._meta_dir(), names)
 
+    def list_state_names_sync(self) -> list[str]:
+        return sorted(_list_names(self._states_dir()))
+
     async def list_state_names(self) -> list[str]:
-        return await self._list_ca(self._states_dir())
+        return await self._run(self.list_state_names_sync)
 
     async def load_states(self, names: list[str]) -> list[tuple[str, bytes]]:
         return await self._load_ca(self._states_dir(), names)
@@ -331,15 +372,11 @@ class FsStorage(Storage):
         await self._run(self.remove_states_sync, names)
 
     # -- op logs -----------------------------------------------------------
+    def list_op_actors_sync(self) -> list[Actor]:
+        return _op_actors(_list_names(self._ops_dir()))
+
     async def list_op_actors(self) -> list[Actor]:
-        names = await self._run(_list_dir, self._ops_dir())
-        actors = []
-        for n in names:
-            try:
-                actors.append(bytes.fromhex(n))
-            except ValueError:
-                continue  # foreign junk in the synced dir is not ours to judge
-        return sorted(a for a in actors if len(a) == 16)
+        return await self._run(self.list_op_actors_sync)
 
     # One C++ call scans/reads a whole dense per-actor run (SURVEY.md §2.2:
     # the bulk load path gets a native reader) — per-file Python open/read
@@ -629,33 +666,83 @@ class FsStorage(Storage):
             for t in tasks:
                 t.cancel()
 
+    # what ONE ``load_op_runs`` call may bring back; a larger load is the
+    # bounded rounds' of ``_scan_native``
+    LOAD_RUNS_FILES = 1024
+    LOAD_RUNS_BYTES = 1 << 20
+
+    def _load_op_runs(
+        self, actor_first_versions: list[tuple[Actor, int]]
+    ) -> list[tuple[Actor, int, bytes]] | None:
+        """The probe, the dense scan and the reads of every wanted actor
+        as ONE native call under one ``ops/`` descriptor; None on any
+        status but 0 (``fs_reads_*``)."""
+        import ctypes
+
+        import numpy as np
+
+        from .. import native
+
+        n = len(actor_first_versions)
+        i64 = ctypes.c_int64
+        counts = (i64 * n)()
+        sizes = (i64 * self.LOAD_RUNS_FILES)()
+        buf = np.empty(self.LOAD_RUNS_BYTES, np.uint8)
+        n_files, n_bytes = i64(), i64()
+        if not _native_step(
+            "load_op_runs", os.fsencode(self._ops_dir()), n,
+            b"".join(a.hex().encode() + b"\0" for a, _ in actor_first_versions),
+            (i64 * n)(*(first for _, first in actor_first_versions)),
+            len(sizes), len(buf), counts, sizes,
+            buf.ctypes.data_as(native.u8p),
+            ctypes.byref(n_files), ctypes.byref(n_bytes), counted="fs_reads",
+        ):
+            return None
+        raw = buf[: n_bytes.value].tobytes()
+        each = iter(sizes[: n_files.value])
+        out, end = [], 0
+        for (actor, first), count in zip(actor_first_versions, counts):
+            for v in range(first, first + count):
+                start, end = end, end + next(each)
+                out.append((actor, v, raw[start:end]))
+        return out
+
+    def _scan_actor(self, actor: Actor, first: int) -> list[tuple[Actor, int, bytes]]:
+        """One actor's dense run from ``first``: the native rounds, then
+        per file from wherever they stopped."""
+        res = self._scan_native(actor, first)
+        if res is None:
+            out, v = [], first
+        else:
+            out, v = res
+            if v is None:
+                return out
+        d = self._ops_dir(actor)
+        while True:
+            raw = _read_file(os.path.join(d, str(v)))
+            if raw is None:
+                return out
+            out.append((actor, v, raw))
+            v += 1
+
+    def load_ops_sync(
+        self, actor_first_versions: list[tuple[Actor, int]]
+    ) -> list[tuple[Actor, int, bytes]]:
+        if not actor_first_versions:
+            return []
+        files = self._load_op_runs(actor_first_versions)
+        if files is None:
+            files = [
+                item
+                for actor, first in self._probe_actors(actor_first_versions)
+                for item in self._scan_actor(actor, first)
+            ]
+        return files
+
     async def load_ops(
         self, actor_first_versions: list[tuple[Actor, int]]
     ) -> list[tuple[Actor, int, bytes]]:
-        actor_first_versions = await self._run(
-            self._probe_actors, actor_first_versions
-        )
-
-        def scan(actor: Actor, first: int) -> list[tuple[Actor, int, bytes]]:
-            res = self._scan_native(actor, first)
-            if res is None:
-                out, v = [], first
-            else:
-                out, v = res
-                if v is None:
-                    return out
-            d = self._ops_dir(actor)
-            while True:
-                raw = _read_file(os.path.join(d, str(v)))
-                if raw is None:
-                    return out
-                out.append((actor, v, raw))
-                v += 1
-
-        per_actor = await asyncio.gather(
-            *(self._run(scan, a, f) for a, f in actor_first_versions)
-        )
-        return [item for chunk in per_actor for item in chunk]
+        return await self._run(self.load_ops_sync, actor_first_versions)
 
     async def stat_ops(
         self, actor_first_versions: list[tuple[Actor, int]]
@@ -759,14 +846,7 @@ class FsStorage(Storage):
     has_deltas = True
 
     async def list_delta_actors(self) -> list[Actor]:
-        names = await self._run(_list_dir, self._deltas_dir())
-        actors = []
-        for n in names:
-            try:
-                actors.append(bytes.fromhex(n))
-            except ValueError:
-                continue  # foreign junk in the synced dir is not ours to judge
-        return sorted(a for a in actors if len(a) == 16)
+        return _op_actors(await self._run(_list_dir, self._deltas_dir()))
 
     async def load_deltas(
         self, actor_first_versions: list[tuple[Actor, int]]

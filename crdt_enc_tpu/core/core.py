@@ -47,7 +47,7 @@ from . import twins
 from .adapters import CrdtAdapter, HostAccelerator
 from .cryptor import Cryptor
 from .key_cryptor import Key, KeyCryptor, Keys
-from .storage import SEAL_TAIL_TWINS, Storage
+from .storage import INGEST_TWINS, SEAL_TAIL_TWINS, Storage
 
 IO_CONCURRENCY = 16  # bounded pipeline width (reference lib.rs:452,512)
 BULK_MIN_FILES = 16  # below this the per-file asyncio path is cheaper
@@ -370,6 +370,19 @@ class _SealOutcome:
     error: BaseException | None = None  # what stopped the steps
 
 
+@dataclass
+class _Poll:
+    """What the storage reads of one poll of the remote found
+    (``Core._poll_steps``).  A listing that shows an unread name is the
+    last read made: what follows it depends on the merge of what the
+    name holds, which is the loop's to do."""
+
+    metas: list  # names in meta/
+    states: list | None = None  # names in states/
+    # past the cursor: (actors, files, [(key id, idxs, middles)])
+    ops: tuple | None = None
+
+
 class _LoopPorts:
     """The seal tail's ports as the plugins give them: every call is
     the plugin's own awaitable, awaited on the event loop."""
@@ -392,9 +405,9 @@ class _JobPorts:
     """The same ports over their sync twins, for one worker-thread job:
     each call has run to its end by the time its await is reached."""
 
-    def __init__(self, storage, encrypt_fn):
+    def __init__(self, storage, encrypt_fn=None):
         self._storage = storage
-        self._encrypt = encrypt_fn  # bound to the plan's key
+        self._encrypt = encrypt_fn  # bound to the plan's key (seal tail)
 
     async def encrypt(self, _material, data: bytes) -> bytes:
         return self._encrypt(data)
@@ -1517,9 +1530,12 @@ class Core:
             # second per-actor storage probe on the polling hot path
             await self._sample_replication("read_remote", _backlog=[])
 
-    async def _read_remote_states(self) -> None:
-        with trace.span("states.list"):
-            names = await self.storage.list_state_names()
+    async def _read_remote_states(self, names: list | None = None) -> None:
+        """``names``: the listing, where the caller has just made it
+        (:meth:`poll_sealed_ops`)."""
+        if names is None:
+            with trace.span("states.list"):
+                names = await self.storage.list_state_names()
         new = [n for n in names if n not in self._data.read_states]
         if not new:
             # a quiet poll pays NO delta machinery: deltas are sealed
@@ -2146,15 +2162,21 @@ class Core:
             )
         return key
 
+    def _unwrap_grouped(self, files: list):
+        with trace.span("ops.bulk_unwrap"):
+            return self._unwrap_op_files(files)
+
+    def _resolve_groups(self, groups: list) -> list:
+        return [
+            (self._sealing_key(kid), idxs, mids) for kid, idxs, mids in groups
+        ]
+
     def _unwrap_resolved(self, files: list):
         """:meth:`_unwrap_op_files` with every sealing key resolved
         before anything opens — the whole-batch doors (solo bulk,
         serve): ``(kept, [(key, idxs, middles)])``."""
-        with trace.span("ops.bulk_unwrap"):
-            files, groups = self._unwrap_op_files(files)
-        return files, [
-            (self._sealing_key(kid), idxs, mids) for kid, idxs, mids in groups
-        ]
+        files, groups = self._unwrap_grouped(files)
+        return files, self._resolve_groups(groups)
 
     # -------------------------------------------------- serving front end
     async def load_sealed_ops(self):
@@ -2172,20 +2194,88 @@ class Core:
         factored so the two cannot drift.  No ``bytes_decrypted``
         counting here: nothing is decrypted yet — the caller counts
         after its decrypt phase actually succeeds."""
+        actors, files, groups = await self._sealed_op_steps(
+            self._data.next_op_versions, self.storage
+        )
+        return actors, files, self._resolve_groups(groups)
+
+    async def _sealed_op_steps(self, cursor: VClock, ports):
+        """The reads of :meth:`load_sealed_ops`, written once: list →
+        load past ``cursor`` → outer unwrap, the groups still under
+        their sealing key's id.  ``ports`` is the storage itself or its
+        sync twins (:class:`_JobPorts`, inside the ingest job, where
+        ``cursor`` is a copy and no key is looked up)."""
         with trace.span("ops.list"):
-            actors = await self.storage.list_op_actors()
-        wanted = [
-            (a, self._data.next_op_versions.get(a) + 1) for a in sorted(actors)
-        ]
+            actors = await ports.list_op_actors()
+        wanted = [(a, cursor.get(a) + 1) for a in sorted(actors)]
         if not wanted:
             return [], [], []
         with trace.span("ops.load"):
-            files = await self.storage.load_ops(wanted)
+            files = await ports.load_ops(wanted)
         trace.add("op_files_loaded", len(files))
         if not files:
             return actors, [], []
-        files, groups = self._unwrap_resolved(files)
+        files, groups = self._unwrap_grouped(files)
         return actors, files, groups
+
+    async def _poll_steps(
+        self, read_metas: frozenset, read_states: frozenset, cursor: VClock,
+        ports,
+    ) -> "_Poll":
+        """The storage reads of one poll in their order, each under the
+        span it has when awaited.  The body of the ingest job: ``ports``
+        are the storage's sync twins, the three arguments before it
+        copies cut on the loop; nothing live is read, no cursor moves,
+        nothing is decrypted, validated or folded."""
+        with trace.span("meta.list"):
+            poll = _Poll(await ports.list_remote_meta_names())
+        if not read_metas.issuperset(poll.metas):
+            return poll  # may bring a key: merged before anything is read
+        with trace.span("states.list"):
+            poll.states = await ports.list_state_names()
+        if not read_states.issuperset(poll.states):
+            return poll  # a snapshot merge moves the cursor the load is planned from
+        poll.ops = await self._sealed_op_steps(cursor, ports)
+        return poll
+
+    async def poll_sealed_ops(self):
+        """One poll of the remote for the serving layer: remote meta,
+        then snapshots, then :meth:`load_sealed_ops`, whose result it
+        returns.
+
+        Where the storage offers sync twins of the four reads
+        (core/twins.py, ``INGEST_TWINS``) they leave the loop as ONE
+        worker-thread job (:meth:`_poll_steps`) and not one thread
+        round-trip each; key resolution (loud on an unsynced key) and
+        every bookkeeping step stay here on the loop (``ingest_jobs``).
+        A job that meets an unread name in ``meta/`` or ``states/``
+        stops at that listing, and the poll goes on from it call by
+        call, as it does from its start over a storage without twins
+        (``ingest_stepwise``): same reads, same order, same spans."""
+        metas = states = None
+        how = "ingest_stepwise"
+        try:
+            if twins.offers(self.storage, INGEST_TWINS):
+                how = "ingest_jobs"
+                d = self._data
+                poll = await asyncio.to_thread(
+                    _run_to_end,
+                    self._poll_steps(
+                        frozenset(d.read_metas), frozenset(d.read_states),
+                        d.next_op_versions.copy(), _JobPorts(self.storage),
+                    ),
+                )
+                if poll.ops is not None:
+                    actors, files, groups = poll.ops
+                    return actors, files, self._resolve_groups(groups)
+                how = "ingest_stepwise"
+                metas, states = poll.metas, poll.states
+            if states is None:  # else the job found nothing unread in meta/
+                await self._read_remote_meta(names=metas)
+            await self._read_remote_states(names=states)
+            return await self.load_sealed_ops()
+        finally:
+            trace.add(how, 1)  # one a poll, however it ended
 
     # --------------------------------------------------------- delta sealing
     @property
@@ -2754,9 +2844,13 @@ class Core:
             )
 
     # ------------------------------------------------- remote meta lifecycle
-    async def _read_remote_meta(self, force_notify: bool = False) -> None:
-        with trace.span("meta.list"):
-            names = await self.storage.list_remote_meta_names()
+    async def _read_remote_meta(
+        self, force_notify: bool = False, names: list | None = None
+    ) -> None:
+        """``names``: as for :meth:`_read_remote_states`."""
+        if names is None:
+            with trace.span("meta.list"):
+                names = await self.storage.list_remote_meta_names()
         new = [n for n in names if n not in self._data.read_metas]
         loaded = []
         if new:

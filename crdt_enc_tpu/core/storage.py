@@ -28,6 +28,12 @@ call; a twin is therefore called from a worker thread, with other
 replicas' twins in flight on other threads.  A storage without them, or a
 wrapper around one, has every call awaited on the loop, in the same
 order.  ``FsStorage`` and ``MemoryStorage`` offer all seven.
+
+A poll of the remote makes four reads (:data:`INGEST_TWINS`), and the
+same holds of them: a storage that offers all four as plain functions has
+the serving layer's poll of a tenant run as ONE worker-thread job
+(``Core.poll_sealed_ops``), any other has each read awaited in turn.
+``FsStorage`` offers them.
 """
 
 from __future__ import annotations
@@ -43,6 +49,15 @@ SEAL_TAIL_TWINS = tuple(
         "store_state", "store_delta", "store_local_meta",
         "store_local_checkpoint", "remove_states", "remove_ops",
         "remove_deltas",
+    )
+)
+
+# (awaitable, sync twin) for each storage read of a poll of the remote
+INGEST_TWINS = tuple(
+    (name, name + "_sync")
+    for name in (
+        "list_remote_meta_names", "list_state_names", "list_op_actors",
+        "load_ops",
     )
 )
 

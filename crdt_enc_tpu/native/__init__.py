@@ -264,6 +264,16 @@ def _bind(lib) -> None:
     lib.remove_names.restype = ctypes.c_int32
     lib.file_step_flushes.argtypes = []
     lib.file_step_flushes.restype = ctypes.c_int64
+    # a poll's reads (io.cpp "a poll's reads"): status 0, or today's path
+    lib.list_dir_names.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64, i64p, i64p
+    ]
+    lib.list_dir_names.restype = ctypes.c_int32
+    lib.load_op_runs.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, i64p,
+        ctypes.c_int64, ctypes.c_int64, i64p, i64p, u8p, i64p, i64p,
+    ]
+    lib.load_op_runs.restype = ctypes.c_int32
     # (the two-pass count+decode batch protocol still exists in C —
     # orset_count_rows_batch / orset_decode_batch[_h] — but the Python
     # span decoder moved to the single-pass grow/take protocol below, so

@@ -14,6 +14,34 @@
 // falls back to the per-file Python path (the sync tool may race us; op
 // files themselves are immutable once published).
 //
+// A poll's reads (backends/fs.py is the only caller).
+//
+// A replica polling its remote makes four reads: the names in meta/, in
+// states/ and in ops/, and the dense runs of the actors with a new file.
+// Each is ONE call below (`list_dir_names`, `load_op_runs`), so that a
+// worker job making all four (Core's ingest job) hands the interpreter
+// lock round four times and not once a system call, and walks the long
+// <tenant>/remote/ prefix four times and not once a file:
+//
+//   list names     open(dir) -> readdir to the end -> close; names back
+//                  NUL-separated in the caller's buffer, `.` and `..`
+//                  left out, nothing else judged (the caller filters and
+//                  sorts).  An absent directory has no names.
+//   load op runs   open(ops) once; per wanted (actor, first version), for
+//                  v = first, first+1, ...: openat("<actor>/<v>") [absent:
+//                  the run ends; for v = first this is the probe] ->
+//                  fstat [not a regular file: the run ends] -> read
+//                  st_size bytes -> read again, which must say end of
+//                  file -> close.  All bytes land in one buffer, in the
+//                  order asked.
+//
+// As with the writers below, a surprise is never handled here: a file
+// that is there and cannot be opened or read, one that ends before or
+// after its size, a listing or a load the caller's buffers do not hold,
+// any other errno, is a non-zero status, and fs.py runs today's Python
+// path from its start, which tells a benign race (file gone: the dense
+// run ends) from a defect (file present but unreadable: loud).
+//
 // File steps: the writers (backends/fs.py is the only caller).
 //
 // Every file step FsStorage makes — an immutable publish, a replace of a
@@ -254,6 +282,133 @@ int64_t probe_op_files(const char* base_dir, int64_t n,
   }
   close(dfd);
   return n;
+}
+
+// ---- a poll's reads: one call a read (protocol in the header) ----------
+
+// Names of the entries of `dir`, NUL-separated into `buf` (`cap` bytes);
+// their count in *n_out, the bytes used in *used_out.  An absent
+// directory is an empty one.  ERANGE when `buf` does not hold them.
+int32_t list_dir_names(const char* dir, char* buf, int64_t cap,
+                       int64_t* n_out, int64_t* used_out) {
+  *n_out = *used_out = 0;
+  int dfd = open_dir(dir);
+  if (dfd < 0) return errno == ENOENT ? 0 : errno;
+  DIR* listing = fdopendir(dfd);  // owns dfd from here
+  if (listing == nullptr) {
+    int err = errno;
+    close(dfd);
+    return err;
+  }
+  int status = 0;
+  int64_t n = 0, used = 0;
+  for (;;) {
+    errno = 0;
+    struct dirent* e = readdir(listing);
+    if (e == nullptr) {
+      status = errno;
+      break;
+    }
+    const char* name = e->d_name;
+    if (strcmp(name, ".") == 0 || strcmp(name, "..") == 0) continue;
+    int64_t len = (int64_t)strlen(name) + 1;
+    if (used + len > cap) {
+      status = ERANGE;
+      break;
+    }
+    memcpy(buf + used, name, (size_t)len);
+    used += len;
+    n++;
+  }
+  closedir(listing);
+  *n_out = n;
+  *used_out = used;
+  return status;
+}
+
+// The dense runs of n wanted (actor, first version) pairs under
+// `ops_dir` (`actors`: flat NUL-separated directory names), every path
+// relative to the one descriptor.  counts_out[i] is the length of pair
+// i's run (0: nothing new, the probe's answer); sizes_out holds the file
+// sizes of all runs in order (at most max_files), `buf` their bytes back
+// to back (at most cap); *files_out and *bytes_out the totals.  An
+// absent ops_dir, actor directory or first file is an empty run.  ERANGE
+// when the buffers do not hold the runs; any file that is there and does
+// not read back whole at its size is EIO or its errno.
+int32_t load_op_runs(const char* ops_dir, int64_t n, const char* actors,
+                     const int64_t* firsts, int64_t max_files, int64_t cap,
+                     int64_t* counts_out, int64_t* sizes_out, uint8_t* buf,
+                     int64_t* files_out, int64_t* bytes_out) {
+  *files_out = *bytes_out = 0;
+  for (int64_t i = 0; i < n; i++) counts_out[i] = 0;
+  int ofd = open_dir(ops_dir);
+  if (ofd < 0) return errno == ENOENT ? 0 : errno;
+  int status = 0;
+  int64_t files = 0, used = 0;
+  const char* actor = actors;
+  char rel[320];
+  for (int64_t i = 0; i < n && status == 0; i++, actor += strlen(actor) + 1) {
+    for (int64_t v = firsts[i];; v++) {
+      int len = snprintf(rel, sizeof rel, "%s/%lld", actor, (long long)v);
+      if (len <= 0 || (size_t)len >= sizeof rel) {
+        status = ENAMETOOLONG;
+        break;
+      }
+      // O_NONBLOCK: a FIFO where a version should be must not hang the
+      // open; it is not a regular file and ends the run below
+      int fd = openat(ofd, rel, O_RDONLY | O_NONBLOCK | O_CLOEXEC);
+      if (fd < 0) {
+        // no such file, or no such actor directory (or a junk file of
+        // its name): the dense run ends here
+        if (errno != ENOENT && errno != ENOTDIR) status = errno;
+        break;
+      }
+      struct stat st;
+      if (fstat(fd, &st) != 0) {
+        status = errno;
+        close(fd);
+        break;
+      }
+      if (!S_ISREG(st.st_mode)) {
+        close(fd);
+        break;
+      }
+      int64_t want = (int64_t)st.st_size;
+      if (files >= max_files || used + want > cap) {
+        status = ERANGE;
+        close(fd);
+        break;
+      }
+      int64_t got = 0;
+      while (got < want) {
+        ssize_t r = read(fd, buf + used + got, (size_t)(want - got));
+        if (r < 0 && errno == EINTR) continue;
+        if (r < 0) status = errno;
+        if (r == 0) status = EIO;  // shorter than its size
+        if (r <= 0) break;
+        got += r;
+      }
+      if (status == 0) {
+        // an op file is immutable once published: it ends where its
+        // size says
+        uint8_t extra;
+        ssize_t tail;
+        do {
+          tail = read(fd, &extra, 1);
+        } while (tail < 0 && errno == EINTR);
+        if (tail != 0) status = tail < 0 ? errno : EIO;
+      }
+      close(fd);
+      if (status != 0) break;
+      sizes_out[files++] = want;
+      used += want;
+      counts_out[i]++;
+    }
+  }
+  close(ofd);
+  *files_out = files;
+  *bytes_out = used;
+  return status;
 }
 
 // ---- the writers: one call a file step --------------------------------

@@ -10,7 +10,9 @@ across a fleet of open cores:
    through the tenant's normal paths and pulls the pending op tail
    through ``Core.load_sealed_ops`` (list → load → outer unwrap,
    ciphertexts grouped by sealing key, decrypt deferred to the
-   cycle-wide phase below), then validates versions with the core's
+   cycle-wide phase below) — ``Core.poll_sealed_ops``: the storage
+   reads of all three as ONE worker-thread job where the storage offers
+   sync twins — then validates versions with the core's
    own ``_validate_chunk`` — cursors do NOT advance until the fold
    lands, exactly the solo bulk-ingest discipline.  Tenants ingest
    concurrently under a bounded semaphore.
@@ -484,14 +486,14 @@ class FoldService:
             async with sem:
                 try:
                     with trace.span("serve.ingest", meta=w.idx):
-                        core = w.core
-                        await core._read_remote_meta()
-                        await core._read_remote_states()
-                        # decrypt-deferred ops load: ciphertexts grouped
-                        # by sealing key; the cycle-wide decrypt phase
-                        # below opens every tenant's in ONE thread hop
+                        # remote meta, snapshots, then the decrypt-
+                        # deferred ops load (ciphertexts grouped by
+                        # sealing key; the cycle-wide decrypt phase below
+                        # opens every tenant's in ONE thread hop): one
+                        # worker job a tenant where the storage offers
+                        # sync twins of the reads, awaited in turn else
                         w.actors, w.files, w.groups = (
-                            await core.load_sealed_ops()
+                            await w.core.poll_sealed_ops()
                         )
                 except Exception as e:  # tenant isolation, never fleet-fatal
                     w.result.error = repr(e)

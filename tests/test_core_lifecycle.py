@@ -393,8 +393,9 @@ def test_unreadable_op_file_raises_loudly(tmp_path, monkeypatch):
     """A present-but-unreadable op file is a real defect, not a race: the
     scan must raise, not silently truncate the log (reviewer finding).
     Unreadability is simulated by monkeypatching (chmod 0 would not bind
-    when tests run as root): the native bulk round fails, and the per-file
-    re-probe hits the open error — the exact production sequence."""
+    when tests run as root): the one native call reports the errno and
+    reads nothing, the native bulk round of the Python path fails, and the
+    per-file re-probe hits the open error — the exact production sequence."""
     import os as _os
 
     import pytest
@@ -424,6 +425,7 @@ def test_unreadable_op_file_raises_loudly(tmp_path, monkeypatch):
                 raise PermissionError(path)
             return real_rf(path)
 
+        monkeypatch.setattr(lib, "load_op_runs", lambda *a: 13)  # EACCES
         monkeypatch.setattr(lib, "read_op_files", failing_read)
         monkeypatch.setattr(fsmod, "_read_file", failing_rf)
         with pytest.raises(PermissionError):

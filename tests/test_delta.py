@@ -1112,7 +1112,8 @@ def test_seal_job_and_stepwise_publish_identical_bytes(kind, case, tmp_path):
     drift = {
         k for k in set(counted) | set(counted_s)
         # the second drive finds the first one's programs compiled
-        if k not in ("seal_jobs", "seal_stepwise", "jax_compiles")
+        if k not in ("seal_jobs", "seal_stepwise", "ingest_jobs",
+                     "ingest_stepwise", "jax_compiles")
         and counted.get(k) != counted_s.get(k)
     }
     assert not drift, drift

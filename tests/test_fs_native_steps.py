@@ -355,3 +355,314 @@ def test_threads_racing_for_one_version_one_wins(mode, tmp_path):
     assert tree(str(tmp_path)) == {
         os.path.normpath(f"r/deltas/{A.hex()}/1"): b"writer %d" % winners[0]
     }
+
+
+# ---- a poll's reads (ISSUE 31): ``list_dir_names`` and ``load_op_runs``
+# against the Python path, each case run both ways on the same tree ----
+
+
+def reads() -> tuple:
+    counted = trace.snapshot()["counters"]
+    return counted.get("fs_reads_native", 0), counted.get("fs_reads_python", 0)
+
+
+def storage_at(root) -> FsStorage:
+    return FsStorage(os.path.join(root, "l"), os.path.join(root, "r"))
+
+
+def absent_directories_list_empty(s, root):
+    got = (s.list_remote_meta_names_sync(), s.list_state_names_sync(),
+           s.list_op_actors_sync(), s.load_ops_sync([(A, 1), (B, 7)]))
+    return got, ([], [], [], [])
+
+
+def junk_names_are_not_ours_to_judge(s, root):
+    for name in ("ZZ", "AA", ".tmp-0123", "MM"):
+        put(root, f"r/meta/{name}")
+        put(root, f"r/states/{name}")
+    put(root, f"r/ops/{B.hex()}/1")
+    put(root, f"r/ops/{A.hex()}/1")
+    put(root, "r/ops/.tmp-feed/1")
+    put(root, "r/ops/not-hex/1")
+    put(root, "r/ops/abcd/1")  # hex, and no actor's length
+    put(root, f"r/ops/{C.hex()}")  # a file where an actor's directory is
+    put(root, "r/ops/caf\udce9".encode("utf-8", "surrogateescape").decode(
+        "utf-8", "surrogateescape"))
+    got = (s.list_remote_meta_names_sync(), s.list_state_names_sync(),
+           s.list_op_actors_sync(), s.load_ops_sync([(A, 1), (B, 1), (C, 1)]))
+    names = ["AA", "MM", "ZZ"]
+    return got, (names, names, [A, B, C], [(A, 1, b"x"), (B, 1, b"x")])
+
+
+def runs_are_dense_and_in_the_order_asked(s, root):
+    for v, data in ((1, b"one"), (2, b""), (3, b"three" * 400), (5, b"five")):
+        put(root, f"r/ops/{B.hex()}/{v}", data)
+    put(root, f"r/ops/{A.hex()}/7", b"seven")
+    put(root, f"r/ops/{A.hex()}/8", b"eight")
+    os.makedirs(os.path.join(root, f"r/ops/{C.hex()}"))  # nothing in it
+    got = (
+        s.load_ops_sync([(B, 1), (C, 1), (A, 7)]),  # the gap at 4 ends B's
+        s.load_ops_sync([(A, 9), (B, 4)]),  # nothing new for either
+        s.load_ops_sync([(A, 8), (B, 5)]),
+        s.load_ops_sync([]),
+    )
+    return got, (
+        [(B, 1, b"one"), (B, 2, b""), (B, 3, b"three" * 400),
+         (A, 7, b"seven"), (A, 8, b"eight")],
+        [],
+        [(A, 8, b"eight"), (B, 5, b"five")],
+        [],
+    )
+
+
+def a_fifo_where_an_actors_directory_is(s, root):
+    put(root, f"r/ops/{A.hex()}/1", b"one")
+    os.mkfifo(os.path.join(root, f"r/ops/{C.hex()}"))
+    got = s.load_ops_sync([(A, 1), (B, 1), (C, 1)])
+    return got, [(A, 1, b"one")]
+
+
+# case, the reads it makes (each ONE native call, or one Python body)
+READ_CASES = [
+    (absent_directories_list_empty, 4),
+    (junk_names_are_not_ours_to_judge, 4),
+    (runs_are_dense_and_in_the_order_asked, 3),
+    (a_fifo_where_an_actors_directory_is, 1),
+]
+
+
+@pytest.mark.parametrize(
+    "case,calls", READ_CASES, ids=[c[0].__name__ for c in READ_CASES]
+)
+def test_a_read_gives_one_answer_either_way(mode, case, calls, tmp_path):
+    got, expected = case(storage_at(str(tmp_path)), str(tmp_path))
+    assert got == expected
+    assert reads() == ((calls, 0) if mode == "native" else (0, calls))
+    assert steps() == (0, 0)  # the tail's counters are not the reads'
+
+
+@pytest.mark.parametrize("forced", [5, 34])  # EIO; ERANGE, as a full buffer
+def test_a_nonzero_read_status_runs_the_python_path(forced, tmp_path, monkeypatch):
+    """Status 0 or today's path from its start: the probe, the native
+    rounds of ``_scan_native`` and ``_list_dir``, untouched."""
+    lib = native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    _, expected = junk_names_are_not_ours_to_judge(s, root)
+    seen = []
+    real_probe = FsStorage._probe_actors
+
+    def probe(self, wanted):
+        seen.append(list(wanted))
+        return real_probe(self, wanted)
+
+    monkeypatch.setattr(lib, "list_dir_names", lambda *a: forced)
+    monkeypatch.setattr(lib, "load_op_runs", lambda *a: forced)
+    monkeypatch.setattr(FsStorage, "_probe_actors", probe)
+    trace.reset()
+    got = (s.list_remote_meta_names_sync(), s.list_state_names_sync(),
+           s.list_op_actors_sync(), s.load_ops_sync([(A, 1), (B, 1), (C, 1)]))
+    assert got == expected
+    assert seen == [[(A, 1), (B, 1), (C, 1)]]
+    assert reads() == (0, 4)
+    trace.reset()
+
+
+def test_what_is_no_regular_file_ends_the_run_as_the_native_rounds_end_it(
+    tmp_path, monkeypatch
+):
+    """A directory or a FIFO where a version should be: ``scan_op_sizes``
+    has always ended the dense run there (the per-file Python path, which
+    a machine without a toolchain runs, raises), and the one call ends it
+    there too, without opening the FIFO for good."""
+    lib = native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    put(root, f"r/ops/{A.hex()}/1", b"one")
+    os.makedirs(os.path.join(root, f"r/ops/{A.hex()}/2"))
+    put(root, f"r/ops/{A.hex()}/3", b"three")
+    os.makedirs(os.path.join(root, f"r/ops/{B.hex()}/1"))
+    put(root, f"r/ops/{C.hex()}/4", b"four")
+    os.mkfifo(os.path.join(root, f"r/ops/{C.hex()}/5"))
+    wanted = [(A, 1), (B, 1), (C, 4)]
+    expected = [(A, 1, b"one"), (C, 4, b"four")]
+    trace.reset()
+    assert s.load_ops_sync(wanted) == expected
+    assert reads() == (1, 0)
+    monkeypatch.setattr(lib, "load_op_runs", lambda *a: 5)
+    assert s.load_ops_sync(wanted) == expected
+    assert reads() == (1, 1)
+    trace.reset()
+
+
+def test_reads_the_buffers_do_not_hold_are_pythons(tmp_path, monkeypatch):
+    """A listing or a load larger than ONE call brings back is ERANGE
+    from the library itself, and reads the same through the rounds."""
+    native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    _, expected = runs_are_dense_and_in_the_order_asked(s, root)
+    monkeypatch.setattr(fs_mod, "LIST_NAMES_BYTES", 40)  # one actor's name
+    trace.reset()
+    assert s.list_op_actors_sync() == [A, B, C]
+    assert reads() == (0, 1)
+    wanted = [(B, 1), (C, 1), (A, 7)]
+    monkeypatch.setattr(FsStorage, "LOAD_RUNS_BYTES", 2000)  # B's third: 2,000
+    assert s.load_ops_sync(wanted) == expected[0]
+    assert reads() == (0, 2)
+    monkeypatch.setattr(FsStorage, "LOAD_RUNS_BYTES", 1 << 20)
+    monkeypatch.setattr(FsStorage, "LOAD_RUNS_FILES", 4)
+    assert s.load_ops_sync(wanted) == expected[0]
+    assert reads() == (0, 3)
+    monkeypatch.setattr(FsStorage, "LOAD_RUNS_FILES", 5)
+    assert s.load_ops_sync(wanted) == expected[0]
+    assert reads() == (1, 3)
+    trace.reset()
+
+
+def test_a_file_removed_between_the_two_passes_ends_the_run(tmp_path, monkeypatch):
+    """The Python path sizes a run and then reads it; a file the sync
+    tool takes away in between is today's ``_ScanRace``: the round is
+    read again file by file, and the run ends where the file was.  The
+    one native call opens a file before it sizes it, and a surprise it
+    does meet (here: a status) lands on this same path."""
+    lib = native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    for v in (1, 2, 3):
+        put(root, f"r/ops/{A.hex()}/{v}", b"v%d" % v)
+    real_read = lib.read_op_files
+
+    def raced(*args):
+        os.remove(os.path.join(root, f"r/ops/{A.hex()}/2"))
+        return real_read(*args)
+
+    monkeypatch.setattr(lib, "load_op_runs", lambda *a: 11)  # EAGAIN, say
+    monkeypatch.setattr(lib, "read_op_files", raced)
+    trace.reset()
+    assert s.load_ops_sync([(A, 1)]) == [(A, 1, b"v1")]
+    assert reads() == (0, 1)
+    monkeypatch.undo()
+    assert s.load_ops_sync([(A, 1)]) == [(A, 1, b"v1")]
+    assert s.load_ops_sync([(A, 3)]) == [(A, 3, b"v3")]
+    trace.reset()
+
+
+def test_a_file_that_does_not_end_at_its_size_is_a_status(tmp_path):
+    """``load_op_runs`` holds an op file to the size it had when opened:
+    a ``/proc`` file says 0 and holds more, as a file still growing
+    would, and is a status with nothing brought back."""
+    import ctypes
+
+    lib = native.load()
+    i64 = ctypes.c_int64
+    os.makedirs(tmp_path / "ops" / "aa")
+    os.symlink("/proc/self/status", tmp_path / "ops" / "aa" / "1")
+    counts, sizes, buf = (i64 * 1)(), (i64 * 8)(), (ctypes.c_uint8 * 64)()
+    files, nbytes = i64(7), i64(7)
+    status = lib.load_op_runs(
+        os.fsencode(tmp_path / "ops"), 1, b"aa\0", (i64 * 1)(1), 8, 64,
+        counts, sizes, buf, ctypes.byref(files), ctypes.byref(nbytes),
+    )
+    assert status != 0
+    assert (files.value, nbytes.value, counts[0]) == (0, 0, 0)
+
+
+def test_sixteen_threads_poll_sixteen_remotes(mode, tmp_path):
+    root = str(tmp_path)
+    stores = [
+        FsStorage(os.path.join(root, f"t{t}", "l"), os.path.join(root, f"t{t}", "r"))
+        for t in range(16)
+    ]
+    for t in range(16):
+        put(root, f"t{t}/r/meta/M{t}")
+        put(root, f"t{t}/r/states/S{t}")
+        for v in (1, 2):
+            put(root, f"t{t}/r/ops/{A.hex()}/{v}", b"%d:%d" % (t, v))
+    errors, polled = [], {}
+    start = threading.Barrier(16)
+
+    def work(t):
+        try:
+            start.wait(timeout=30)
+            for _ in range(20):
+                polled[t] = (
+                    stores[t].list_remote_meta_names_sync(),
+                    stores[t].list_state_names_sync(),
+                    stores[t].list_op_actors_sync(),
+                    stores[t].load_ops_sync([(A, 1), (B, 1)]),
+                )
+        except BaseException as e:  # surfaced below, on the test's thread
+            errors.append(e)
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in range(16)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=60)
+    assert not any(th.is_alive() for th in threads) and not errors
+    assert polled == {
+        t: ([f"M{t}"], [f"S{t}"], [A],
+            [(A, 1, b"%d:1" % t), (A, 2, b"%d:2" % t)])
+        for t in range(16)
+    }
+    calls = 16 * 20 * 4
+    assert reads() == ((calls, 0) if mode == "native" else (0, calls))
+
+
+POLL_SPANS = {"meta.list", "states.list", "ops.list", "ops.load", "ops.bulk_unwrap"}
+
+
+def test_a_polls_spans_fire_once_a_tenant_under_serve_ingest(
+    mode, tmp_path, monkeypatch
+):
+    """The job's reads keep their span names, fire once a tenant, and hang
+    under that tenant's ``serve.ingest`` across the hop to the worker
+    thread (so ``listing_ms.fleet`` and the idle gaps keep their
+    meaning), whichever body made them."""
+    import asyncio
+
+    from test_serve import make_opts, write_orset
+
+    from crdt_enc_tpu.core import Core
+    from crdt_enc_tpu.obs import sink
+    from crdt_enc_tpu.serve import FoldService
+
+    # a sink record would drain the event log this test reads
+    monkeypatch.setattr(sink, "_configured", None)
+    tenants = 3
+
+    def storage(t, name):
+        base = tmp_path / f"t{t}"
+        return FsStorage(str(base / name), str(base / "remote"))
+
+    async def go():
+        cores = []
+        for t in range(tenants):
+            await write_orset(storage(t, "w1"), 6, b"a%d" % t)
+            cores.append(await Core.open(make_opts(storage(t, "s"))))
+        service = FoldService(cores)
+        trace.reset()
+        trace.enable_events()
+        results = await service.run_cycle()
+        service.close()
+        assert all(r.sealed for r in results)
+        return threading.get_ident(), trace.events()
+
+    loop_tid, events = asyncio.run(go())
+    snap = trace.snapshot()
+    assert snap["counters"].get("ingest_jobs") == tenants
+    spans = {e["id"]: e for e in events if e["kind"] == "span"}
+    for name in POLL_SPANS:
+        fired = [e for e in spans.values() if e["name"] == name]
+        assert len(fired) == tenants, name
+        ingests = set()
+        for e in fired:
+            assert e["tid"] != loop_tid, "made by the job, off the loop"
+            parent = spans[e["parent"]]
+            assert parent["name"] == "serve.ingest"
+            ingests.add(parent["id"])
+        assert len(ingests) == tenants, "one under each tenant's serve.ingest"
+    assert POLL_SPANS <= set(trace.tree()["serve.ingest"])
+    calls = 4 * tenants
+    assert reads() == ((calls, 0) if mode == "native" else (0, calls))

@@ -903,3 +903,215 @@ def test_seal_tail_behind_a_fault_wrapper_stays_on_the_loop():
 
     run(go())
     trace.reset()
+
+
+# ---- the ingest job (ISSUE 31): a tenant's poll of its remote is one
+# worker job over the storage's sync twins, or today's awaited calls ----
+
+
+class _AwaitedOnly(FsStorage):
+    """A storage without the twins, as ``twins.offers`` judges it: this
+    class overrides one awaitable, and so left the inherited twins
+    behind.  Its polls are awaited call by call."""
+
+    async def load_ops(self, actor_first_versions):
+        return await super().load_ops(actor_first_versions)
+
+
+def _fleet_storage(cls, root, t: int, name: str):
+    base = root / f"t{t}"
+    return cls(str(base / name), str(base / "remote"))
+
+
+async def _toy_fleet(root, tenants: int) -> None:
+    """``tenants`` remotes a service has sealed once, each with new op
+    files of two writers since, but the last, which has none."""
+    served = [
+        await Core.open(make_opts(_fleet_storage(FsStorage, root, t, "s")))
+        for t in range(tenants)
+    ]
+    for t in range(tenants):
+        await write_orset(_fleet_storage(FsStorage, root, t, "w1"), 12, b"a%d" % t)
+    service = FoldService(served)
+    assert all(r.error is None for r in await service.run_cycle())
+    service.close()
+    for t in range(tenants - 1):
+        await write_orset(_fleet_storage(FsStorage, root, t, "w2"), 6, b"b%d" % t)
+        await write_orset(_fleet_storage(FsStorage, root, t, "w3"), 3, b"c%d" % t)
+
+
+async def _serve_once(root, cls, tenants: int, monkeypatch):
+    """One cycle of a service reopened over ``root``: every tenant's
+    ``(actors, files, groups)``, its result less the clock, the tree
+    left behind, and the counters."""
+    from test_fs_native_steps import tree
+
+    cores = [
+        await Core.open(make_opts(_fleet_storage(cls, root, t, "s")))
+        for t in range(tenants)
+    ]
+    polled = {}
+    real = Core.poll_sealed_ops
+
+    async def poll(core):
+        actors, files, groups = await real(core)
+        polled[cores.index(core)] = (
+            actors, files,
+            [(key.id, key.material, idxs, mids) for key, idxs, mids in groups],
+        )
+        return actors, files, groups
+
+    monkeypatch.setattr(Core, "poll_sealed_ops", poll)
+    trace.reset()
+    service = FoldService(cores)
+    results = await service.run_cycle()
+    service.close()
+    monkeypatch.setattr(Core, "poll_sealed_ops", real)
+    counted = trace.snapshot()["counters"]
+    trace.reset()
+    return (
+        [polled[t] for t in range(tenants)],
+        [(r.path, r.rows, r.sealed, r.error) for r in results],
+        tree(str(root)), counted,
+    )
+
+
+def _read_body(body: str, monkeypatch) -> None:
+    """How ``FsStorage`` makes a poll's reads: ``native`` (one call
+    each), ``python`` (no library: what a machine without a toolchain
+    runs) or ``status`` (the library answers with a non-zero status)."""
+    from test_fs_native_steps import no_toolchain
+
+    from crdt_enc_tpu import native
+
+    lib = native.load()
+    if body == "python":
+        no_toolchain(monkeypatch)
+    elif body == "status":
+        monkeypatch.setattr(lib, "list_dir_names", lambda *a: 5)  # EIO, say
+        monkeypatch.setattr(lib, "load_op_runs", lambda *a: 5)
+
+
+@pytest.mark.parametrize("storage", ["twins", "awaited"])
+@pytest.mark.parametrize("body", ["native", "python", "status"])
+def test_ingest_job_and_awaited_poll_agree(body, storage, tmp_path, monkeypatch):
+    """The job and the call-by-call poll, over the native reads, the
+    Python ones and a library that reports a surprise, are held to ONE
+    outcome: what ``poll_sealed_ops`` hands the cycle, each tenant's
+    result, and every byte on disk afterwards (sealed snapshots, deltas,
+    checkpoints), against the awaited calls over the Python bodies."""
+    import shutil
+
+    tenants = 4
+
+    async def go():
+        await _toy_fleet(tmp_path / "a", tenants)
+        shutil.copytree(tmp_path / "a", tmp_path / "b")
+        with monkeypatch.context() as m:
+            _read_body("python", m)
+            expected = await _serve_once(tmp_path / "a", _AwaitedOnly, tenants, m)
+        assert expected[3].get("ingest_stepwise") == tenants
+        with monkeypatch.context() as m:
+            _read_body(body, m)
+            cls = FsStorage if storage == "twins" else _AwaitedOnly
+            got = await _serve_once(tmp_path / "b", cls, tenants, m)
+        assert got[:3] == expected[:3]
+        assert [r for r in got[1] if r[0] == "batched"] and all(
+            r[3] is None for r in got[1]
+        )
+        counted = got[3]
+        jobs = tenants if storage == "twins" else 0
+        assert counted.get("ingest_jobs", 0) == jobs
+        assert counted.get("ingest_stepwise", 0) == tenants - jobs
+        # three listings a tenant, and a load where ops/ holds an actor
+        reads = 4 * tenants - 1
+        native_reads = reads if body == "native" else 0
+        assert counted.get("fs_reads_native", 0) == native_reads
+        assert counted.get("fs_reads_python", 0) == reads - native_reads
+
+    run(go())
+
+
+@pytest.mark.parametrize("unread", ["meta", "states"])
+def test_an_unread_name_stops_the_job_and_the_tenant_converges(
+    unread, tmp_path
+):
+    """A name the tenant has not read, in ``meta/`` (a rotated key: the
+    ops behind it cannot be unwrapped before it is merged) or in
+    ``states/`` (a foreign snapshot: its merge moves the cursor the load
+    is planned from), ends the job at that listing; the poll goes on
+    call by call with the listing it has, is counted as stepwise, and
+    lists nothing twice."""
+    tenants = 3
+
+    async def go():
+        await _toy_fleet(tmp_path, tenants)
+        other = await Core.open(
+            make_opts(_fleet_storage(FsStorage, tmp_path, 0, "other"))
+        )
+        await other.read_remote()
+        if unread == "meta":
+            await other.rotate_key()
+            await other.apply_ops(
+                [other.with_state(lambda s: s.add_ctx(other.actor_id, b"late"))]
+            )
+        else:
+            await other.compact()
+        cores = [
+            await Core.open(make_opts(_fleet_storage(FsStorage, tmp_path, t, "s")))
+            for t in range(tenants)
+        ]
+        trace.reset()
+        service = FoldService(cores)
+        results = await service.run_cycle()
+        service.close()
+        assert all(r.error is None for r in results), results
+        snap = trace.snapshot()
+        assert snap["counters"].get("ingest_stepwise") == 1
+        assert snap["counters"].get("ingest_jobs") == tenants - 1
+        for listing in ("meta.list", "states.list", "ops.list"):
+            assert snap["spans"][listing]["count"] == tenants, listing
+        await other.read_remote()
+        cold = await Core.open(
+            make_opts(_fleet_storage(FsStorage, tmp_path, 0, "cold"))
+        )
+        await cold.read_remote()
+        want = other.with_state(canonical_bytes)
+        assert cores[0].with_state(canonical_bytes) == want
+        assert cold.with_state(canonical_bytes) == want
+        if unread == "meta":
+            assert cores[0].with_state(lambda s: b"late" in s.entries)
+
+    run(go())
+    trace.reset()
+
+
+def test_one_tenants_job_raising_is_that_tenants_error_alone(tmp_path):
+    tenants = 3
+
+    async def go():
+        await _toy_fleet(tmp_path, tenants)
+        cores = [
+            await Core.open(make_opts(_fleet_storage(FsStorage, tmp_path, t, "s")))
+            for t in range(tenants)
+        ]
+
+        def broken(actor_first_versions):
+            raise PermissionError("ops/ is not to be read (test)")
+
+        cores[1].storage.load_ops_sync = broken
+        trace.reset()
+        service = FoldService(cores)
+        results = await service.run_cycle()
+        service.close()
+        assert results[1].path == "error" and "PermissionError" in results[1].error
+        assert not results[1].sealed
+        assert results[0].error is None and results[0].sealed
+        assert results[2].error is None
+        counted = trace.snapshot()["counters"]
+        # one a tenant polled, the one whose job raised among them
+        assert counted.get("ingest_jobs") == tenants
+        assert not counted.get("ingest_stepwise")
+
+    run(go())
+    trace.reset()
