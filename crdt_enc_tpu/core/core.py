@@ -333,6 +333,15 @@ class _MutData:
         # version already scanned (applied OR skipped) — the next read
         # loads only past it, and compaction GCs the consumed prefix
         self.read_deltas: dict[Actor, int] = {}
+        # delta bases that outlive their file: per foreign sealer, the
+        # name of the newest snapshot of that sealer this replica has
+        # merged (loaded whole, or reached through a link).  compact()
+        # GCs a merged snapshot and drops its name from ``read_states``,
+        # and the sealer's NEXT link is based on exactly that snapshot:
+        # "has merged it" stays true after the file is gone
+        # (docs/delta.md "A consumer of several sealers").  In memory
+        # only: after a reopen one link a sealer falls back
+        self.merged_bases: dict[Actor, str] = {}
 
 
 @dataclass
@@ -1605,15 +1614,25 @@ class Core:
                 self._data.state, [sw.state for sw in wrappers]
             )
         trace.add("states_merged", len(wrappers))
-        for _, sealer, sw in decoded:
+        for name, sealer, sw in decoded:
             self._data.next_op_versions.merge(sw.next_op_versions)
-            if sealer is not None and sealer != self.actor_id:
-                # learn the sealing replica's published ingest cursor —
-                # the matrix row the stability watermark mins over
-                self._data.cursor_matrix.setdefault(
-                    sealer, VClock()
-                ).merge(sw.next_op_versions)
+            if sealer is not None:
+                self._note_sealer(sealer, name, sw.next_op_versions)
         self._data.read_states.update(name for name, _, _ in decoded)
+
+    def _note_sealer(self, sealer: Actor, name: str, cursor: VClock) -> None:
+        """What merging a foreign sealer's snapshot ``name`` (whole, or
+        through a link) teaches about that sealer: its published ingest
+        cursor — the matrix row the stability watermark mins over — and,
+        where that cursor is the newest seen from it, the base its next
+        link will name (``_MutData.merged_bases``)."""
+        if sealer == self.actor_id:
+            return
+        d = self._data
+        row = d.cursor_matrix.setdefault(sealer, VClock())
+        if cursor.descends(row):
+            d.merged_bases[sealer] = name
+        row.merge(cursor)
 
     # ------------------------------------------------------- delta chains
     def _delta_fallback(self, actor: Actor, version: int, reason: str) -> None:
@@ -1631,28 +1650,45 @@ class Core:
     async def _read_remote_deltas(self) -> int:
         """Walk every sealer's delta log past the consumed cursor and
         apply each link whose base snapshot this replica has already
-        merged (base NAME ∈ ``read_states`` — the content address is
-        the fingerprint, so an unknown or renamed base is doubt and
-        falls back).  Applying a link is byte-equal to merging its
-        target snapshot (delta/codec.py contract), so the target name
-        is marked read, its cursor merged, and the sealer's
-        cursor-matrix row advanced — exactly the full-snapshot
+        merged (base NAME ∈ ``read_states``, or the sealer's newest
+        merged snapshot, whose name outlives its GC in ``merged_bases``
+        — the content address is the fingerprint, so an unknown or
+        renamed base is doubt and falls back).  Applying a link is
+        byte-equal to merging its target snapshot (delta/codec.py
+        contract), so the target name is marked read, its cursor
+        merged, and the sealer's cursor-matrix row advanced and next
+        base noted (:meth:`_note_sealer`) — exactly the full-snapshot
         bookkeeping.  Returns the number of links applied."""
         from ..delta import codec_for, wire
 
         d = self._data
         codec_cls = codec_for(self.adapter.name)
         with trace.span("delta.read"):
-            actors = await self.storage.list_delta_actors()
+            with trace.span("delta.read.list"):
+                actors = await self.storage.list_delta_actors()
             wanted = [
                 (a, d.read_deltas.get(a, 0) + 1) for a in sorted(actors)
             ]
             if not wanted:
                 return 0
-            files = await self.storage.load_deltas(wanted)
+            with trace.span("delta.read.load"):
+                files = await self.storage.load_deltas(wanted)
             if not files:
                 return 0
+            trace.add("delta_passes", 1)
+            trace.add("delta_links_read", len(files))
             trace.add("delta_bytes_read", sum(len(raw) for _, _, raw in files))
+            # a log that starts past the cursor lost a link (never
+            # synced, or GC'd under a consumer this far behind): its
+            # target came, or comes, by the snapshot path
+            first_loaded: dict[Actor, int] = {}
+            for actor, version, _ in files:
+                first_loaded[actor] = min(
+                    version, first_loaded.get(actor, version)
+                )
+            for actor, version in wanted:
+                if first_loaded.get(actor, version) > version:
+                    self._delta_fallback(actor, version, "gap")
             applied = 0
             chain = 0  # longest contiguous applied run this pass
             run: dict[Actor, int] = {}
@@ -1662,9 +1698,11 @@ class Core:
                 # snapshot listing regardless — see the caller's note)
                 if version > d.read_deltas.get(actor, 0):
                     d.read_deltas[actor] = version
+                link = f"{actor.hex()}:{version}"
                 try:
-                    obj = await self._open_sealed(raw)
-                    rec = wire.parse_delta_obj(obj)
+                    with trace.span("delta.read.open", link):
+                        obj = await self._open_sealed(raw)
+                        rec = wire.parse_delta_obj(obj)
                 except MissingKeyError:
                     # unlike op ingest this is NOT loud: the full
                     # snapshot (sealed with the same key register) will
@@ -1683,17 +1721,20 @@ class Core:
                 if codec_cls is None:
                     self._delta_fallback(actor, version, "no_codec")
                     continue
-                if not rec.base_name or rec.base_name not in d.read_states:
+                if not rec.base_name or (
+                    rec.base_name not in d.read_states
+                    and d.merged_bases.get(rec.sealer) != rec.base_name
+                ):
                     self._delta_fallback(actor, version, "base_missing")
                     continue
                 # sync section: fold the link + full snapshot bookkeeping
-                codec_cls.apply(d.state, rec.delta_obj)
+                with trace.span("delta.apply", link):
+                    walked = codec_cls.apply(d.state, rec.delta_obj)
+                if walked:
+                    trace.add("delta_apply_slots", walked)
                 d.next_op_versions.merge(rec.new_cursor)
                 d.read_states.add(rec.new_name)
-                if rec.sealer != self.actor_id:
-                    d.cursor_matrix.setdefault(
-                        rec.sealer, VClock()
-                    ).merge(rec.new_cursor)
+                self._note_sealer(rec.sealer, rec.new_name, rec.new_cursor)
                 applied += 1
                 run[actor] = run.get(actor, 0) + 1
                 chain = max(chain, run[actor])

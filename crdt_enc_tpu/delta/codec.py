@@ -6,7 +6,9 @@ A codec provides two pure functions over a CRDT type's state:
   to ``new`` as a msgpack-able object, or ``None`` when no delta
   smaller than the full state can be cut (the caller then seals no
   delta and consumers fall back to the snapshot path).
-* ``apply(state, obj) -> None`` — fold the delta into ``state``.
+* ``apply(state, obj) -> int | None`` — fold the delta into ``state``;
+  a codec whose apply walks the consumer's state says how many of its
+  slots it walked (the consumer counts them, ``delta_apply_slots``).
 
 **Correctness contract** (the differential tests and the adversarial
 simulator both pin it byte-exactly): for any consumer state ``X`` that
@@ -138,8 +140,10 @@ def orset_delta_from_rows(
     }
 
 
-def orset_delta_apply(state: ORSet, obj) -> None:
-    """Fold one Orswot window delta into ``state`` (module docs)."""
+def orset_delta_apply(state: ORSet, obj) -> int:
+    """Fold one Orswot window delta into ``state`` (module docs).
+    Returns the live slots the kill pass walked: every slot of the
+    state where the delta has a causal window, none where it has not."""
     bc = VClock.from_obj(obj.get(b"bc"))
     nc = VClock.from_obj(obj.get(b"c"))
     adds = {m: {bytes(r): int(c) for r, c in v.items()}
@@ -158,6 +162,7 @@ def orset_delta_apply(state: ORSet, obj) -> None:
     scan = list(state.entries) if window else [
         m for m in removed if m in state.entries
     ]
+    walked = sum(map(len, state.entries.values())) if window else 0
     for member in scan:
         slots = state.entries.get(member)
         if not slots:
@@ -195,6 +200,7 @@ def orset_delta_apply(state: ORSet, obj) -> None:
     state.clock.merge(nc)
     for member in touched:
         state._normalize_member(member)
+    return walked
 
 
 class _OrsetCodec:

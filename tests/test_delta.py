@@ -397,6 +397,61 @@ def test_fallback_on_gc_mid_chain(storage_factory):
     run(go())
 
 
+def test_compacting_consumer_keeps_a_base_it_has_gcd_and_counts_a_gap(
+    storage_factory,
+):
+    """Two compactors on one remote (ISSUE 32).  B's compact() GCs the
+    snapshot of A it merged and drops the name from ``read_states``; A's
+    next link names exactly that snapshot as its base.  The base
+    outlives its file (``merged_bases``), so B applies the link and
+    loads no snapshot.  A link that never arrives costs one snapshot
+    load, and is counted (reason ``gap``) when A's next link is read."""
+
+    async def go():
+        a = await Core.open(make_opts(storage_factory("a"), orset_adapter()))
+        b = await Core.open(make_opts(storage_factory("b"), orset_adapter()))
+
+        async def a_round(tag):
+            await a.update(lambda s: s.add_ctx(a.actor_id, tag))
+            await a.compact()
+
+        async def b_round():
+            trace.reset()
+            await b.compact()
+            assert b.with_state(canonical_bytes) == a.with_state(
+                canonical_bytes
+            )
+            return counters()
+
+        for i in range(80):
+            await a.update(lambda s, m=b"m%d" % i: s.add_ctx(a.actor_id, m))
+        await a.compact()
+        c = await b_round()  # the baseless head: a whole snapshot
+        assert c.get("states_merged") == 1 and not c.get("delta_applied")
+        head = b._data.merged_bases[a.actor_id]
+        assert head not in b._data.read_states, "merged, GC'd and forgotten"
+
+        await a_round(b"t1")
+        c = await b_round()
+        assert c.get("delta_applied") == 1 and not c.get("states_merged")
+        assert not c.get("delta_fallbacks")
+        assert c.get("delta_passes") == 1 and c.get("delta_apply_slots") >= 80
+        assert b._data.merged_bases[a.actor_id] != head
+
+        # A's next link never arrives; its snapshot does
+        await a_round(b"t2")
+        await a.storage.remove_deltas([(a.actor_id, 1 << 62)])
+        c = await b_round()
+        assert c.get("states_merged") == 1 and not c.get("delta_applied")
+        await a_round(b"t3")
+        c = await b_round()
+        assert c.get("delta_applied") == 1 and not c.get("states_merged")
+        assert c.get("delta_fallbacks") == 1
+        assert b.last_delta_fallback_reason == "gap"
+
+    run(go())
+
+
 def test_fallback_on_torn_delta_and_base_doubt(storage_factory):
     async def go():
         producer = await Core.open(
