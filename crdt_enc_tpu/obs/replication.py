@@ -21,7 +21,12 @@ replication status:
   replica with no published cursor contributes only its *implied
   self-knowledge* (it has certainly seen its own sealed ops), so one
   silent replica collapses the watermark for every other actor's entries
-  — silence is indistinguishable from lag, and the math says so.
+  — silence is indistinguishable from lag, and the math says so.  The
+  same fact is what the computation costs: two silent replicas make the
+  watermark ``{}`` after one pass over the replicas, one leaves a single
+  entry, and with none the min runs over what was published — never
+  replicas × actors (:func:`stability_watermark`, span
+  ``repl.watermark``).
 * **per-actor op backlog** — sealed-but-unfolded op files past the local
   cursor, in files and bytes (from ``Storage.stat_ops``, which sizes the
   tail without reading it).
@@ -72,26 +77,51 @@ def stability_watermark(
     passes an explicit ``replicas`` denominator instead — its
     membership policy may pin an expected set or quarantine silent
     replicas out of the min (crdt_enc_tpu/read/policy.py); the math
-    here stays one implementation either way."""
-    if replicas is None:
-        replicas = set(cursor_matrix) | set(union.counters) | {actor_id}
-    watermark: dict[Actor, int] = {}
-    for a in union.counters:
-        lo = None
+    here stays one implementation either way.
+
+    Cost: what was published, not replicas × actors.  A *silent*
+    replica (in the denominator, but neither this one nor the owner of
+    a published cursor) holds 0 for every actor but itself, so the min
+    is read off the denominator's structure: two silent replicas give
+    ``{}`` after one pass over ``replicas``; one silent replica leaves
+    only its own entry to compute; with none, an actor's min stops at
+    the first row that lacks it — O(replicas + actors + published
+    entries) row lookups in every case (counters are non-negative, as
+    ``VClock`` keeps them)."""
+    with record.span("repl.watermark"):
+        if replicas is None:
+            replicas = set(cursor_matrix) | set(union.counters) | {actor_id}
+        rows = []  # the speaking replicas: (replica, the cursor it holds)
+        silent = set()
         for r in replicas:
-            if r == actor_id:
-                k = local_clock.get(a)
-            else:
-                published = cursor_matrix.get(r)
-                k = published.get(a) if published is not None else 0
-            if r == a:
-                # implied self-knowledge: a replica has certainly seen
-                # its own sealed ops, published cursor or not
-                k = max(k, union.get(a))
-            lo = k if lo is None else min(lo, k)
-        if lo:
-            watermark[a] = lo
-    return watermark
+            row = local_clock if r == actor_id else cursor_matrix.get(r)
+            if row is not None:
+                rows.append((r, row))
+                continue
+            silent.add(r)
+            if len(silent) > 1:
+                # every actor has a silent replica other than itself
+                return {}
+        # one silent replica zeroes every entry but its own
+        candidates = (
+            [s for s in silent if s in union.counters] if silent
+            else union.counters
+        )
+        watermark: dict[Actor, int] = {}
+        for a in candidates:
+            # implied self-knowledge: a replica has certainly seen its
+            # own sealed ops, published cursor or not
+            lo = max(0, union.get(a)) if silent else None
+            for r, row in rows:
+                k = row.get(a)
+                if r == a:
+                    k = max(k, union.get(a))
+                lo = k if lo is None else min(lo, k)
+                if not lo:
+                    break
+            if lo:
+                watermark[a] = lo
+        return watermark
 
 
 def compute_status(

@@ -11,6 +11,7 @@ committed golden rendering (the same golden tools/run_checks.sh diffs).
 import asyncio
 import json
 import pathlib
+import random
 
 import pytest
 
@@ -21,7 +22,9 @@ from crdt_enc_tpu.backends import (
     MemoryStorage,
     PlainKeyCryptor,
 )
-from crdt_enc_tpu.core import Core, OpenOptions, gcounter_adapter
+from crdt_enc_tpu.core import (
+    Core, OpenOptions, gcounter_adapter, orset_adapter,
+)
 from crdt_enc_tpu.obs import fleet, replication, sink
 from crdt_enc_tpu.utils import trace
 from crdt_enc_tpu.utils.versions import DEFAULT_DATA_VERSION_1
@@ -148,6 +151,284 @@ def test_compute_status_checkpoint_staleness_counts_new_versions():
     assert status["checkpoint"] == {
         "enabled": True, "sealed": True, "staleness_versions": 3,
     }
+
+
+# ---- stability_watermark: differential against the loop it replaced --------
+
+
+def _watermark_loop(actor_id, local_clock, cursor_matrix, union, replicas=None):
+    """The replica x actor enumeration ``stability_watermark`` was until
+    PR 33, verbatim: the oracle the structural function is held to."""
+    if replicas is None:
+        replicas = set(cursor_matrix) | set(union.counters) | {actor_id}
+    watermark = {}
+    for a in union.counters:
+        lo = None
+        for r in replicas:
+            if r == actor_id:
+                k = local_clock.get(a)
+            else:
+                published = cursor_matrix.get(r)
+                k = published.get(a) if published is not None else 0
+            if r == a:
+                # implied self-knowledge: a replica has certainly seen
+                # its own sealed ops, published cursor or not
+                k = max(k, union.get(a))
+            lo = k if lo is None else min(lo, k)
+        if lo:
+            watermark[a] = lo
+    return watermark
+
+
+def _actor(i: int) -> bytes:
+    return bytes([i + 1]) * 16
+
+
+def _union_of(local, matrix, extra=()):
+    union = local.copy()
+    for clock in matrix.values():
+        union.merge(clock)
+    union.merge(VClock(dict(extra)))
+    return union
+
+
+def _random_fleet(seed: int, dense: bool):
+    """1-8 actors, 0-8 publishers (some of them pure consumers that never
+    wrote), rows sparse or dense, zeros in rows; the union is what
+    ``compute_status`` would build: local, rows, and a sealed tail."""
+    rng = random.Random(seed)
+    actors = [_actor(i) for i in range(rng.randint(1, 8))]
+    pool = actors + [_actor(8 + i) for i in range(3)]  # + pure consumers
+    me = rng.choice(pool)
+    keep = 0.9 if dense else 0.4
+
+    def row():
+        return VClock({
+            a: rng.choice([0, rng.randint(1, 9)])
+            for a in actors if rng.random() < keep
+        })
+
+    publishers = rng.sample(
+        [r for r in pool if r != me], rng.randint(0, min(8, len(pool) - 1))
+    )
+    local, matrix = row(), {r: row() for r in publishers}
+    tail = [(a, rng.randint(1, 12)) for a in actors if rng.random() < 0.5]
+    return me, local, matrix, _union_of(local, matrix, tail), pool, rng
+
+
+@pytest.mark.parametrize("explicit", [False, True], ids=["default", "replicas"])
+@pytest.mark.parametrize("dense", [False, True], ids=["sparse", "dense"])
+@pytest.mark.parametrize("seed", range(12))
+def test_watermark_equals_the_loop_on_random_fleets(seed, dense, explicit):
+    me, local, matrix, union, pool, rng = _random_fleet(seed, dense)
+    replicas = None
+    if explicit:
+        # a policy's denominator: any subset of the known members, with
+        # or without this replica, plus now and then a stranger
+        replicas = {r for r in pool + [_actor(20)] if rng.random() < 0.6}
+    want = _watermark_loop(me, local, matrix, union, replicas)
+    got = replication.stability_watermark(me, local, matrix, union, replicas)
+    assert got == want
+    assert all(type(c) is int and c > 0 for c in got.values())
+
+
+D = b"\xdd" * 16
+_FULL = VClock({A: 5, B: 4, C: 3})
+WATERMARK_CASES = {
+    # every replica speaks: the pointwise min of what was published
+    "none_silent": (A, VClock({A: 5, B: 3, C: 3}),
+                    {B: VClock({A: 4, B: 4, C: 1}), C: VClock({A: 5, B: 2, C: 3})},
+                    _FULL, None),
+    # C wrote ops and never published: only C's own entry can survive
+    "one_silent_with_ops": (A, VClock({A: 5, B: 3, C: 2}),
+                            {B: VClock({A: 4, B: 4, C: 1})}, _FULL, None),
+    # the one silent replica is a pinned consumer that never wrote
+    "one_silent_without_ops": (A, VClock({A: 5, B: 3}), {B: VClock({A: 4, B: 4})},
+                               VClock({A: 5, B: 4}), {A, B, D}),
+    "one_silent_local_never_saw_it": (A, VClock({A: 5}), {B: VClock({A: 4, B: 4})},
+                                      _FULL, None),
+    # the folder cells: writers that never publish a cursor
+    "two_silent": (A, VClock({A: 5, B: 4, C: 3}), {}, _FULL, None),
+    "two_silent_one_publisher": (A, VClock({A: 5, B: 4, C: 3, D: 1}),
+                                 {B: VClock({A: 5, B: 4, C: 3, D: 1})},
+                                 VClock({A: 5, B: 4, C: 3, D: 1}), None),
+    # a reader outside its own denominator: its clock is not consulted
+    "actor_not_in_replicas": (D, VClock({A: 1}),
+                              {A: VClock({A: 5, B: 2}), B: VClock({A: 3, B: 4})},
+                              VClock({A: 5, B: 4}), {A, B}),
+    "actor_not_in_replicas_one_silent": (D, VClock({A: 9, C: 9}),
+                                         {A: VClock({A: 5, C: 2})},
+                                         VClock({A: 9, C: 9}), {A, C}),
+    # read/policy.py: a pinned member nobody has heard from is silent ...
+    "pinned_unheard_member": (A, VClock({A: 5, B: 4}), {B: VClock({A: 5, B: 4})},
+                              VClock({A: 5, B: 4}), {A, B, D}),
+    # ... and a quarantined one is simply absent from the denominator
+    "quarantined_member_absent": (A, VClock({A: 5, B: 3, C: 3}),
+                                  {B: VClock({A: 4, B: 4, C: 1})}, _FULL, {A, B}),
+    "publisher_with_empty_row": (A, VClock({A: 5, B: 4}), {B: VClock()},
+                                 VClock({A: 5, B: 4}), None),
+    "consumer_publisher_empty_row": (A, VClock({A: 5}), {D: VClock()},
+                                     VClock({A: 5}), None),
+    "empty_union": (A, VClock(), {B: VClock()}, VClock(), None),
+    "empty_union_pinned": (A, VClock(), {}, VClock(), {A, B, C}),
+    "empty_denominator": (A, VClock({A: 5}), {}, VClock({A: 5}), set()),
+    "alone": (A, VClock({A: 5}), {}, VClock({A: 5}), None),
+    # a caller's union need not dominate the rows it is given
+    "union_below_a_row": (A, VClock({A: 5, B: 6}), {B: VClock({A: 7, B: 9})},
+                          VClock({A: 2, B: 3}), None),
+    "union_lacks_a_published_actor": (A, VClock({A: 5, C: 2}),
+                                      {B: VClock({A: 4, B: 4, C: 2})},
+                                      VClock({A: 5, B: 4}), None),
+    "zero_entries_in_union_and_rows": (A, VClock({A: 5, B: 0}),
+                                       {B: VClock({A: 0, B: 4})},
+                                       VClock({A: 5, B: 4, C: 0}), None),
+    # the local replica's own published row is never read for itself
+    "own_row_in_the_matrix": (A, VClock({A: 5, B: 2}),
+                              {A: VClock({A: 1}), B: VClock({A: 3, B: 4})},
+                              VClock({A: 5, B: 4}), None),
+    "denominator_as_a_list": (A, VClock({A: 5, B: 4, C: 3}),
+                              {B: VClock({A: 5, B: 4, C: 3})}, _FULL,
+                              [C, A, B, C]),
+}
+
+
+@pytest.mark.parametrize("case", list(WATERMARK_CASES))
+def test_watermark_equals_the_loop_on_named_cases(case):
+    args = WATERMARK_CASES[case]
+    assert replication.stability_watermark(*args) == _watermark_loop(*args)
+
+
+def test_watermark_values_of_the_named_structures():
+    """The three structures, by hand (not only against the oracle)."""
+    wm = replication.stability_watermark
+    assert wm(*WATERMARK_CASES["none_silent"]) == {A: 4, B: 2, C: 1}
+    assert wm(*WATERMARK_CASES["one_silent_with_ops"]) == {C: 1}
+    assert wm(*WATERMARK_CASES["one_silent_without_ops"]) == {}
+    assert wm(*WATERMARK_CASES["two_silent"]) == {}
+    assert wm(*WATERMARK_CASES["quarantined_member_absent"]) == {A: 4, B: 3, C: 1}
+    assert wm(*WATERMARK_CASES["alone"]) == {A: 5}
+
+
+def test_watermark_wire_and_status_bytes_equal_under_both(monkeypatch):
+    """What leaves the process is byte-identical: the sealed delta
+    link's cleartext (``wire.build_delta_obj`` packed) and the status
+    JSON, computed with the function and with the loop it replaced."""
+    from crdt_enc_tpu.delta import wire
+    from crdt_enc_tpu.utils import codec
+
+    def link(watermark):
+        return codec.pack(wire.build_delta_obj(wire.DeltaRecord(
+            base_name="b" * 8, new_name="n" * 8,
+            base_cursor=VClock({A: 1}), new_cursor=VClock({A: 2}),
+            sealer=A, adapter=b"orset", watermark=watermark,
+            delta_obj={b"k": 1},
+        )))
+
+    def status(me, local, matrix):
+        return json.dumps(replication.compute_status(
+            me, local, matrix, [(C, 4, 9)], RID, {A: 1}, True,
+        ), sort_keys=True)
+
+    fleets = [WATERMARK_CASES[c] for c in (
+        "none_silent", "one_silent_with_ops", "two_silent",
+        "publisher_with_empty_row", "own_row_in_the_matrix",
+    )] + [_random_fleet(seed, dense)[:4] + (None,)
+          for seed in range(6) for dense in (False, True)]
+    for me, local, matrix, union, replicas in fleets:
+        new_link = link(replication.stability_watermark(
+            me, local, matrix, union, replicas))
+        new_status = status(me, local, matrix)
+        with monkeypatch.context() as m:
+            m.setattr(replication, "stability_watermark", _watermark_loop)
+            assert status(me, local, matrix) == new_status
+        assert link(_watermark_loop(me, local, matrix, union, replicas)) == new_link
+
+
+class _CountingClock(VClock):
+    """A clock that counts the lookups made through ``get``."""
+
+    lookups = 0
+
+    def get(self, actor):
+        _CountingClock.lookups += 1
+        return super().get(actor)
+
+
+def _scaling_case(case: str, n: int):
+    """``(args, expected watermark, bound on lookups)`` at ``n`` devices."""
+    devices = [i.to_bytes(16, "big") for i in range(1, n + 1)]
+    me = (n + 1).to_bytes(16, "big")
+    pubs = [(n + 2 + i).to_bytes(16, "big") for i in range(4)]
+    clock = {a: 1 + i % 7 for i, a in enumerate(devices)}
+    local, union = _CountingClock(dict(clock)), _CountingClock(dict(clock))
+    five = set(pubs) | {me}
+    if case == "silent_devices":
+        # the folder: writers that never publish a cursor; two silent
+        # replicas end the pass, no pair is visited
+        return (me, local, {}, union), {}, 0
+    dense = {p: _CountingClock({a: c + j for a, c in clock.items()})
+             for j, p in enumerate(pubs)}
+    if case == "one_silent_device":
+        # a pinned membership of five publishers and one silent writer:
+        # one entry, one pass over the rows
+        return ((me, local, dense, union, five | {devices[0]}),
+                {devices[0]: clock[devices[0]]}, 2 * (len(five) + 1))
+    if case == "dense_publishers":
+        # 5 publishers x n actors, nobody silent: what was published
+        return (me, local, dense, union, five), clock, 2 * (n + 5 + 5 * n)
+    if case == "sparse_publishers":
+        # an actor's min stops at the first row that lacks it, so the
+        # rows nobody filled cost nothing
+        sparse = {p: _CountingClock({a: 1 for a in devices[j::4]})
+                  for j, p in enumerate(pubs)}
+        return (me, local, sparse, union, five), {}, 2 * (n + 5 + 2 * n)
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "silent_devices", "one_silent_device", "dense_publishers",
+    "sparse_publishers",
+])
+def test_watermark_lookups_scale_with_what_was_published(case):
+    """No wall clock is read: the lookups through ``VClock.get`` are bounded
+    by a small constant x (actors + replicas + published entries), where
+    the replica x actor loop makes 9 million at 3,000 devices; and the
+    count is linear, twice the devices at most twice the lookups."""
+    counts = []
+    for n in (1500, 3000):
+        args, want, bound = _scaling_case(case, n)
+        _CountingClock.lookups = 0
+        assert replication.stability_watermark(*args) == want
+        assert _CountingClock.lookups <= bound
+        counts.append(_CountingClock.lookups)
+    assert counts[1] <= 2 * counts[0]
+
+
+def test_watermark_span_nests_under_its_callers():
+    """``repl.watermark`` is a child of ``repl.compute`` (the sample) and
+    of ``delta.seal`` (the link's tag), never of ``core.compact``: the
+    ``unattributed_ms`` metrics subtract the same direct children."""
+    async def go():
+        opts = make_opts(MemoryStorage(MemoryRemote()))
+        opts.adapter = orset_adapter()
+        core = await Core.open(opts)
+        for i in range(60):
+            await core.update(
+                lambda s, m=b"m%d" % i: s.add_ctx(core.actor_id, m))
+        await core.compact()
+        await core.update(lambda s: s.add_ctx(core.actor_id, b"tail"))
+        await core.compact()  # seals a delta link, tagged with the watermark
+
+    trace.reset()
+    run(go())
+    snap, tree = trace.snapshot(), trace.tree()
+    trace.reset()
+    assert snap["spans"]["delta.seal"]["count"] == 1
+    assert snap["spans"]["repl.watermark"]["parents"] == [
+        "delta.seal", "repl.compute"]
+    assert "repl.watermark" not in tree.get("core.compact", [])
+    assert snap["spans"]["repl.watermark"]["count"] == 1 + snap["spans"][
+        "repl.compute"]["count"]
 
 
 # ---- the 3-device differential fixture ------------------------------------
