@@ -23,11 +23,14 @@ from ..astutil import const_str
 from ..engine import SEV_ERROR, SEV_WARNING, Finding, Project, rule
 
 _RECEIVERS = {"trace", "record", "_record"}
-_KINDS = {"span", "add", "gauge", "observe"}
+_KINDS = {"span", "add", "add_many", "gauge", "observe"}
 _TABLE_ROW_RE = re.compile(r"^\|\s*`([^`]+)`\s*\|")
 _REGISTRY_SECTIONS = ("## Span registry", "## Counter & gauge registry")
-#: names maintained inside obs.record itself (no trace.* call site)
-_INTERNAL = {"events_dropped"}
+#: names maintained inside obs itself (no trace.* call site): the event
+#: ring's drops, and the collector's totals that obs.runtime folds in
+#: without taking the registry's lock from its callback
+_INTERNAL = {"events_dropped", "gc_passes", "gc_pause_us", "gc_full_passes",
+             "gc_full_pause_us", "gc_collected"}
 _PROOF_PREFIXES = ("stream.",)
 
 DOC_REL = "docs/observability.md"
@@ -86,35 +89,39 @@ def span_names_registered(project: Project):
                 continue
             if not call.args:
                 continue
-            arg = call.args[0]
             kind = func.attr
-            name = const_str(arg)
-            if name is not None:
-                used.add(name)
-                if name not in registered:
+            args = [call.args[0]]
+            if kind == "add_many" and isinstance(args[0], ast.Dict):
+                # a literal dict of counters: every key is a name
+                args = [k for k in args[0].keys if k is not None]
+            for arg in args:
+                name = const_str(arg)
+                if name is not None:
+                    used.add(name)
+                    if name not in registered:
+                        yield Finding(
+                            rule="SPN001", severity=SEV_ERROR, path=mod.rel,
+                            line=call.lineno, context=mod.context_of(call),
+                            message=(
+                                f'{kind}("{name}") is not in the '
+                                f"{DOC_REL} registry"
+                            ),
+                        )
+                elif isinstance(arg, ast.JoinedStr):
                     yield Finding(
-                        rule="SPN001", severity=SEV_ERROR, path=mod.rel,
+                        rule="SPN001", severity=SEV_WARNING, path=mod.rel,
                         line=call.lineno, context=mod.context_of(call),
                         message=(
-                            f'{kind}("{name}") is not in the '
-                            f"{DOC_REL} registry"
+                            f"f-string {kind} name — dynamic cardinality "
+                            "breaks the aggregate table"
                         ),
                     )
-            elif isinstance(arg, ast.JoinedStr):
-                yield Finding(
-                    rule="SPN001", severity=SEV_WARNING, path=mod.rel,
-                    line=call.lineno, context=mod.context_of(call),
-                    message=(
-                        f"f-string {kind} name — dynamic cardinality "
-                        "breaks the aggregate table"
-                    ),
-                )
-            else:
-                yield Finding(
-                    rule="SPN001", severity=SEV_WARNING, path=mod.rel,
-                    line=call.lineno, context=mod.context_of(call),
-                    message=f"non-literal {kind} name",
-                )
+                else:
+                    yield Finding(
+                        rule="SPN001", severity=SEV_WARNING, path=mod.rel,
+                        line=call.lineno, context=mod.context_of(call),
+                        message=f"non-literal {kind} name",
+                    )
     if project.partial:
         # a path-subset run can't prove a registered name is unemitted
         return

@@ -454,6 +454,37 @@ def _run_to_end(coro):
     raise RuntimeError("a sync port twin awaited the event loop")
 
 
+async def _job_to_end(coro, queue_us: str, return_us: str):
+    """``coro`` driven to its end (:func:`_run_to_end`) as ONE
+    worker-thread job, with the two hand-offs around it counted on the
+    spans' clock, whether it returns or raises: ``queue_us`` takes the
+    microseconds from the submit here on the loop to the job's first
+    instruction (a free worker thread, and the interpreter lock),
+    ``return_us`` those from its last instruction to this task running
+    again (the loop's ready queue).  Counters and no spans: the job's own
+    spans keep the parent they had, and a solo ``compact()`` no further
+    child of its root."""
+    first = last = 0.0
+
+    def job():
+        nonlocal first, last
+        first = time.perf_counter()
+        try:
+            return _run_to_end(coro)
+        finally:
+            last = time.perf_counter()
+
+    submit = time.perf_counter()
+    try:
+        return await asyncio.to_thread(job)
+    finally:
+        if last:  # else cancelled with the job still out: nothing to split
+            trace.add_many({
+                queue_us: int(1e6 * (first - submit)),
+                return_us: int(1e6 * (time.perf_counter() - last)),
+            })
+
+
 class Core:
     """One replica's runtime.  Construct via ``Core.open``."""
 
@@ -2299,12 +2330,12 @@ class Core:
             if twins.offers(self.storage, INGEST_TWINS):
                 how = "ingest_jobs"
                 d = self._data
-                poll = await asyncio.to_thread(
-                    _run_to_end,
+                poll = await _job_to_end(
                     self._poll_steps(
                         frozenset(d.read_metas), frozenset(d.read_states),
                         d.next_op_versions.copy(), _JobPorts(self.storage),
                     ),
+                    "ingest_job_queue_us", "ingest_job_return_us",
                 )
                 if poll.ops is not None:
                     actors, files, groups = poll.ops
@@ -2658,8 +2689,9 @@ class Core:
         ports = self._job_ports(plan)
         if ports is not None:
             trace.add("seal_jobs", 1)
-            out = await asyncio.to_thread(
-                _run_to_end, self._seal_tail(plan, ports)
+            out = await _job_to_end(
+                self._seal_tail(plan, ports),
+                "seal_job_queue_us", "seal_job_return_us",
             )
         else:
             trace.add("seal_stepwise", 1)

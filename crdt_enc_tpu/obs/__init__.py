@@ -12,7 +12,7 @@ is the full layer on top of the span/counter registry PR 1 seeded:
 * :mod:`.timeline` — Chrome-trace/Perfetto JSON export of the event log
   (per-thread lanes, chunk-index args, counter tracks) plus the chunk
   overlap analysis the streaming-pipeline acceptance tests assert on.
-* :mod:`.runtime`  — JAX runtime signals: XLA recompile counting via
+* :mod:`.runtime`  — the runtime's signals: XLA recompile counting via
   ``jax.monitoring``, H2D transfer accounting, device memory gauges
   sampled at fold boundaries.
 * :mod:`.sink`     — run-scoped JSONL metrics sink (``CRDT_OBS_SINK``,

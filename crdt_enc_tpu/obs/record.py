@@ -54,7 +54,17 @@ instrumented long-running service can leave events on without unbounded
 growth.  Off by default, and ``reset()`` restores the default off state
 (seam tests cannot leak event recording into later tests).  Counter and
 gauge updates also append (``kind: "counter"/"gauge"``) while events are
-on, which is what the timeline's counter tracks are built from.
+on, which is what the timeline's counter tracks are built from.  A
+``kind: "pause"`` entry is a stretch in which the interpreter ran none of
+the program (``runtime.gc``, a collector pass: obs.runtime): it has the
+shape of a span's entry, its ``parent`` the span it landed in, and no
+aggregate under ``snapshot()["spans"]``: a pause is no phase.
+
+Whoever keeps totals of its own because it may not wait for this
+registry's lock (the collector's callback can fire inside it) registers
+one function with :func:`on_read`; every read or clearing of the registry
+calls it first, outside the lock, and it folds what it has in
+(:func:`fold`, :func:`pause_entry`).
 
 Span and metric names are REGISTERED in ``docs/observability.md``;
 ``tools/check_span_names.py`` lints the tree against the registry.
@@ -89,6 +99,38 @@ _gauges: dict[str, float] = {}
 _events_enabled = False
 _events_capacity = DEFAULT_EVENT_CAPACITY
 _events: deque = deque(maxlen=DEFAULT_EVENT_CAPACITY)
+
+
+# Called, outside ``_lock``, before the registry is read or cleared
+# (``snapshot``, ``events``, ``drain_events``, ``reset``): see on_read.
+_read_hooks: list = []
+
+
+def on_read(fold) -> None:
+    """Register ``fold()``, which brings totals kept outside the registry
+    into it (module docs).  It runs on the reading thread with ``_lock``
+    free, may take it, and is registered once a process."""
+    if fold not in _read_hooks:
+        _read_hooks.append(fold)
+
+
+def _fold_in() -> None:
+    for hook in _read_hooks:
+        hook()
+
+
+def fold(counts: dict, entries=()) -> None:
+    """What an :func:`on_read` hook brings in, under one acquisition of
+    the lock: ``counts`` added to the counters and ``entries`` (built by
+    :func:`pause_entry`) appended to the event log while it is on.  These
+    are nobody's increments: no counter tap sees them and no counter
+    entry is logged for them."""
+    with _lock:
+        for name, n in counts.items():
+            _counters[name] = _counters.get(name, 0) + n
+        if _events_enabled:
+            for e in entries:
+                _append_event_locked(e)
 
 
 # --------------------------------------------------------------- histogram
@@ -173,6 +215,7 @@ def drain_events() -> list[dict]:
     occurrences are removed, so successive drains never hand out the
     same event twice (the metrics sink drains, keeping one timeline per
     record instead of a cumulative re-copy)."""
+    _fold_in()
     with _lock:
         out = [dict(e) for e in _events]
         _events.clear()
@@ -184,7 +227,9 @@ def events() -> list[dict]:
     Span entries: name, t0, t1 (``time.perf_counter`` seconds — monotonic,
     cross-thread comparable), meta (the span's ``meta`` arg), tid/thread
     (recording thread), kind ("span").  Counter/gauge entries carry
-    ``kind: "counter"/"gauge"`` and the post-update ``value`` at ``t0``."""
+    ``kind: "counter"/"gauge"`` and the post-update ``value`` at ``t0``;
+    a ``kind: "pause"`` entry has a span entry's keys (module docs)."""
+    _fold_in()
     with _lock:
         return [dict(e) for e in _events]
 
@@ -198,6 +243,24 @@ def _append_event_locked(entry: dict) -> None:
 def _event_base(name: str, kind: str) -> dict:
     t = threading.current_thread()
     return {"name": name, "kind": kind, "tid": t.ident, "thread": t.name}
+
+
+def _interval_entry(name: str, kind: str, t0: float, t1: float, meta,
+                    sid: int, parent: tuple | None) -> dict:
+    """The entry of something that lasted (a span, a pause), recorded by
+    the calling thread; ``parent`` is an ``_open`` value."""
+    e = _event_base(name, kind)
+    e["t0"], e["t1"], e["meta"] = t0, t1, meta
+    e["id"] = sid
+    e["parent"] = parent[0] if parent is not None else None
+    return e
+
+
+def pause_entry(name: str, t0: float, t1: float, meta) -> dict:
+    """A ``kind: "pause"`` entry (module docs) for ``[t0, t1]`` on the
+    spans' clock, its parent the span open in the calling context.  Takes
+    no lock: the caller hands it to :func:`fold` when it may."""
+    return _interval_entry(name, "pause", t0, t1, meta, next(_ids), _open.get())
 
 
 # ------------------------------------------------------------------- spans
@@ -270,11 +333,9 @@ def _record_span(name: str, t0: float, t1: float, meta, sid: int,
         slot[3][idx] = slot[3].get(idx, 0) + 1
         slot[4].add(parent[1] if parent is not None else None)
         if _events_enabled:
-            e = _event_base(name, "span")
-            e["t0"], e["t1"], e["meta"] = t0, t1, meta
-            e["id"] = sid
-            e["parent"] = parent[0] if parent is not None else None
-            _append_event_locked(e)
+            _append_event_locked(
+                _interval_entry(name, "span", t0, t1, meta, sid, parent)
+            )
     logger.debug("span %s: %.6fs", name, dt)
 
 
@@ -315,18 +376,31 @@ def counter_tap():
         _taps.reset(token)
 
 
+def _add_locked(name: str, n: int, taps: tuple) -> None:
+    value = _counters.get(name, 0) + n
+    _counters[name] = value
+    for tap in taps:
+        tap[name] = tap.get(name, 0) + n
+    if _events_enabled:
+        e = _event_base(name, "counter")
+        e["t0"] = e["t1"] = time.perf_counter()
+        e["meta"], e["value"] = None, value
+        _append_event_locked(e)
+
+
 def add(name: str, n: int = 1) -> None:
     """Bump a counter (e.g. ops folded, states merged, bytes decrypted)."""
     with _lock:
-        value = _counters.get(name, 0) + n
-        _counters[name] = value
-        for tap in _taps.get():
-            tap[name] = tap.get(name, 0) + n
-        if _events_enabled:
-            e = _event_base(name, "counter")
-            e["t0"] = e["t1"] = time.perf_counter()
-            e["meta"], e["value"] = None, value
-            _append_event_locked(e)
+        _add_locked(name, n, _taps.get())
+
+
+def add_many(counts: dict) -> None:
+    """``add(name, n)`` for every item of ``counts``, under one
+    acquisition of the registry's lock."""
+    with _lock:
+        taps = _taps.get()
+        for name, n in counts.items():
+            _add_locked(name, n, taps)
 
 
 def gauge(name: str, value: float) -> None:
@@ -346,6 +420,7 @@ def snapshot() -> dict:
     "p50_ms", "p95_ms", "p99_ms", "parents"}}, "counters": {...},
     "gauges": {...}}.  ``parents`` lists the names of the spans this one
     was seen under, sorted, ``None`` (first) where it was a root."""
+    _fold_in()
     with _lock:
         return {
             "spans": {
@@ -381,8 +456,10 @@ def reset() -> None:
     """Clear every aggregate (the parents seen included) and the event
     log, and restore the event defaults (recording OFF, default
     capacity) — a test or run that enabled events cannot leak recording
-    state into the next one."""
+    state into the next one.  Totals kept outside the registry
+    (:func:`on_read`) are folded in first and cleared with the rest."""
     global _events_enabled, _events_capacity, _events
+    _fold_in()
     with _lock:
         _spans.clear()
         _counters.clear()

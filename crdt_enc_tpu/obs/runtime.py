@@ -1,4 +1,5 @@
-"""JAX runtime signals: recompiles, H2D transfers, device memory.
+"""The runtime's signals: recompiles, H2D/D2H transfers, device memory,
+and the interpreter's collector passes.
 
 The regressions ADVICE r5 caught by hand — an unbounded-recompile fold
 loop, a silent fallback off the device path — are exactly the ones this
@@ -25,13 +26,32 @@ module makes mechanical:
   observable.  A no-op on backends without allocator stats (CPU), probed
   once and cached.
 
+* **Collector passes** (:func:`track_gc`): a pass of the interpreter's
+  cyclic collector holds the interpreter lock, so it stops every worker
+  job and every pull, inside whichever span happens to be open.  One
+  function on ``gc.callbacks`` counts ``gc_passes`` / ``gc_pause_us``
+  (every generation), ``gc_full_passes`` / ``gc_full_pause_us``
+  (generation 2) and ``gc_collected``; a pass of generation 1 or 2 is
+  also a ``runtime.gc`` ``pause`` entry of the event log (parent: the
+  span open in the collecting thread) and, while
+  ``record.jax_annotations`` is set, a ``jax.profiler`` annotation of
+  that name, so the profiler's trace shows it on the device's clock.
+  Never a span aggregate: a pause is no phase of the program.  A pass
+  can start inside ``with record._lock:``, so the callback never waits
+  for that lock: it keeps plain totals (passes never nest) and the
+  registry folds them in wherever it is read (``record.on_read``).
+
 Nothing here imports jax at module load: the registry stays importable in
 jax-less tooling contexts, and the listeners attach only when asked.
 """
 
 from __future__ import annotations
 
+import gc
+import sys
 import threading
+import time
+from collections import deque
 
 from . import record
 
@@ -108,6 +128,124 @@ def _set_recompiles(on: bool) -> None:
             )
             jax.monitoring.register_event_listener(_on_event)
             _listener_installed = True
+
+
+# ------------------------------------------------------- collector passes
+_gc_installed = False
+_gc_enabled = False
+_gc_explicit = False  # an operator choice must stick
+# The pass in flight: perf_counter_ns at its start (None: no pass that is
+# being accounted) and its annotation.  Passes never nest, and a pass
+# ends on the thread it started on.
+_gc_t0: int | None = None
+_gc_ann = None
+# Totals since the process started, bumped by the callback alone, under
+# the counters they are published as.  Whole microseconds a pass, the same
+# for both pause totals, so the full passes never read ahead of all passes.
+_GC_COUNTERS = ("gc_passes", "gc_pause_us", "gc_full_passes",
+                "gc_full_pause_us", "gc_collected", "events_dropped")
+_gc_totals = [0] * len(_GC_COUNTERS)
+_gc_folded = [0] * len(_GC_COUNTERS)  # what the registry was handed of them
+_gc_fold_lock = threading.Lock()  # between readers; never the callback's
+# pause entries waiting for a reader, bounded like the event log itself
+# (a drop counts into ``events_dropped``)
+_gc_pauses: deque = deque(maxlen=4096)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The one ``gc.callbacks`` entry.  Two clock reads and a few adds a
+    pass; takes no lock (it may run inside ``record._lock``)."""
+    global _gc_t0, _gc_ann
+    if phase == "start":
+        if not _gc_enabled:
+            return
+        if info["generation"] and record.jax_annotations:
+            profiler = sys.modules.get("jax.profiler")
+            if profiler is not None:
+                _gc_ann = profiler.TraceAnnotation("runtime.gc")
+                _gc_ann.__enter__()
+        _gc_t0 = time.perf_counter_ns()
+        return
+    t1 = time.perf_counter_ns()
+    t0 = _gc_t0
+    if t0 is None:  # tracking began inside this pass
+        return
+    _gc_t0 = None
+    if _gc_ann is not None:
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+    generation, totals = info["generation"], _gc_totals
+    us = (t1 - t0 + 500) // 1000
+    # the wider total first: a reader between two adds never sees the
+    # full passes ahead of all passes
+    totals[1] += us
+    totals[0] += 1
+    totals[4] += info["collected"]
+    if not generation:
+        return
+    if generation == 2:
+        totals[3] += us
+        totals[2] += 1
+    if record.events_enabled():
+        if len(_gc_pauses) == _gc_pauses.maxlen:
+            totals[5] += 1
+        # perf_counter_ns is perf_counter's clock, the spans' own
+        _gc_pauses.append(record.pause_entry(
+            "runtime.gc", t0 / 1e9, t1 / 1e9,
+            {"generation": generation, "collected": info["collected"]},
+        ))
+
+
+def _fold_gc() -> None:
+    """Bring what the callback has counted since the last fold into the
+    registry (``record.on_read``): the counters' growth and the pause
+    entries, through ``record.fold`` and not ``record.add``: a pause is
+    nobody's increment, so no counter tap sees it."""
+    with _gc_fold_lock:
+        now = tuple(_gc_totals)  # one copy: no pass can fall inside it
+        grown = {}
+        for i, name in enumerate(_GC_COUNTERS):
+            if now[i] != _gc_folded[i]:
+                grown[name] = now[i] - _gc_folded[i]
+                _gc_folded[i] = now[i]
+        pauses = []
+        while _gc_pauses:  # each pop is whole, whatever the callback appends
+            pauses.append(_gc_pauses.popleft())
+        if grown or pauses:
+            record.fold(grown, pauses)
+
+
+def track_gc(on: bool = True) -> None:
+    """Start (or stop) accounting the collector's passes (module docs).
+    Idempotent; the callback is appended to ``gc.callbacks`` once per
+    process and toggles via a flag.  The counts are cleared by
+    ``trace.reset()`` like every other counter.  Changes nothing about
+    when or what the collector collects.  An explicit call here is an
+    OPERATOR choice — the accelerator's default-on wiring
+    (:func:`ensure_gc_tracking`) never overrides it."""
+    global _gc_explicit
+    with _lock:
+        _gc_explicit = True
+    _set_gc(on)
+
+
+def ensure_gc_tracking() -> None:
+    """Default-on wiring (TpuAccelerator.__init__), as
+    :func:`ensure_recompile_tracking`."""
+    with _lock:
+        if _gc_explicit:
+            return
+    _set_gc(True)
+
+
+def _set_gc(on: bool) -> None:
+    global _gc_installed, _gc_enabled
+    with _lock:
+        _gc_enabled = on
+        if on and not _gc_installed:
+            record.on_read(_fold_gc)
+            gc.callbacks.append(_on_gc)
+            _gc_installed = True
 
 
 def recompile_count() -> int:

@@ -13,6 +13,10 @@ k+1's decrypt/decode/H2D riding under chunk k's fold).  This module turns
   index) in ``args``, so overlap is also *programmatically* checkable —
   :func:`chunk_overlaps` is what the acceptance tests assert on — and
   with the span's ``id`` and its ``parent``'s id, the span that caused it;
+* pauses (``kind: "pause"``: ``runtime.gc``, a collector pass of
+  generation 1 or 2) as ``X`` events of category ``pause`` on the lane of
+  the thread that collected, ``args`` holding the generation, the objects
+  collected and, as ``parent``, the span the pass landed in;
 * counter/gauge updates as counter-track (``ph: "C"``) events, so
   ``h2d_bytes`` or ``device_bytes_in_use`` plot as stepped graphs above
   the lanes.
@@ -75,7 +79,10 @@ def to_chrome_trace(events: list | None = None) -> dict:
             "dur": (e["t1"] - e["t0"]) * 1e6,
             "args": {},
         }
-        if e.get("meta") is not None:
+        if kind == "pause":
+            ev["cat"] = "pause"
+            ev["args"].update(e.get("meta") or {})
+        elif e.get("meta") is not None:
             ev["args"]["chunk"] = e["meta"]
         if e.get("id") is not None:
             # the span that caused this one, by id: the tree is readable

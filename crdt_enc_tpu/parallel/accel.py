@@ -139,6 +139,9 @@ class TpuAccelerator(HostAccelerator):
         # unbounded-recompile bug class, now mechanically visible
         # (default-on; an explicit operator track_recompiles(False) wins)
         obs_runtime.ensure_recompile_tracking()
+        # likewise the collector's passes (gc_passes, gc_pause_us, ...):
+        # a pass stops every thread inside whichever span is open
+        obs_runtime.ensure_gc_tracking()
         # CrdtMap scatter phase: "host" (numpy reference), "device"
         # (ops/map_device.py jit), or None = device for batches past
         # min_device_batch

@@ -144,6 +144,19 @@ class _TenantWork:
         return self.result.error is None
 
 
+async def _take_slot(sem: asyncio.Semaphore, idx: int) -> None:
+    """Take one of a phase's ``io_width`` slots (its semaphore) for tenant
+    ``idx``; the caller releases it.  Where every slot is taken the wait
+    for one is the span ``serve.slot_wait`` (meta: the tenant), a child of
+    the phase's span: the part of a tenant's latency that is queueing, not
+    service.  A tenant that finds a slot free opens no span."""
+    if sem.locked():
+        with trace.span("serve.slot_wait", meta=idx):
+            await sem.acquire()
+    else:
+        await sem.acquire()
+
+
 def _actor_table(state, actors) -> list:
     """Sorted actor table for the native decoders: the storage listing
     plus every actor the state mentions (the serving twin of
@@ -483,7 +496,8 @@ class FoldService:
         sem = asyncio.Semaphore(max(1, self.config.io_width))
 
         async def one(w: _TenantWork):
-            async with sem:
+            await _take_slot(sem, w.idx)
+            try:
                 try:
                     with trace.span("serve.ingest", meta=w.idx):
                         # remote meta, snapshots, then the decrypt-
@@ -498,6 +512,8 @@ class FoldService:
                 except Exception as e:  # tenant isolation, never fleet-fatal
                     w.result.error = repr(e)
                     w.result.path = "error"
+            finally:
+                sem.release()
 
         await asyncio.gather(*(one(w) for w in works))
 
@@ -1188,7 +1204,8 @@ class FoldService:
         sem = asyncio.Semaphore(max(1, self.config.io_width))
 
         async def one(w: _TenantWork):
-            async with sem:
+            await _take_slot(sem, w.idx)
+            try:
                 if not w.ok:
                     trace.add("serve_tenant_errors", 1)
                     w.result.latency_s = time.perf_counter() - t0
@@ -1234,5 +1251,7 @@ class FoldService:
                     # COMPLETIONS — failed seals carry their latency on
                     # the TenantResult but stay out of the percentiles
                     trace.observe("serve.tenant", dt)
+            finally:
+                sem.release()
 
         await asyncio.gather(*(one(w) for w in works))

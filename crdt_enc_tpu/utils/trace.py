@@ -1,6 +1,6 @@
 """Compat shim: the tracing core was promoted into the first-class
 observability subsystem at :mod:`crdt_enc_tpu.obs.record` (ISSUE 2) —
-timelines live in ``obs.timeline``, JAX runtime signals in
+timelines live in ``obs.timeline``, the runtime's signals in
 ``obs.runtime``, the metrics sink in ``obs.sink``.
 
 Every existing import site (``from crdt_enc_tpu.utils import trace``)

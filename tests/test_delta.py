@@ -1166,9 +1166,11 @@ def test_seal_job_and_stepwise_publish_identical_bytes(kind, case, tmp_path):
     assert book == book_s
     drift = {
         k for k in set(counted) | set(counted_s)
-        # the second drive finds the first one's programs compiled
+        # the second drive finds the first one's programs compiled; the
+        # job's hand-offs and the collector's passes are times and chance
         if k not in ("seal_jobs", "seal_stepwise", "ingest_jobs",
                      "ingest_stepwise", "jax_compiles")
+        and not k.startswith(("gc_", "seal_job_", "ingest_job_"))
         and counted.get(k) != counted_s.get(k)
     }
     assert not drift, drift

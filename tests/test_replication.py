@@ -361,7 +361,9 @@ def _scaling_case(case: str, n: int):
     pubs = [(n + 2 + i).to_bytes(16, "big") for i in range(4)]
     clock = {a: 1 + i % 7 for i, a in enumerate(devices)}
     local, union = _CountingClock(dict(clock)), _CountingClock(dict(clock))
-    five = set(pubs) | {me}
+    # the denominator in one fixed order at every size: as a set of bytes
+    # its order, and with it where an actor's min stops, followed the hash seed
+    five = [me, *pubs]
     if case == "silent_devices":
         # the folder: writers that never publish a cursor; two silent
         # replicas end the pass, no pair is visited
@@ -371,7 +373,7 @@ def _scaling_case(case: str, n: int):
     if case == "one_silent_device":
         # a pinned membership of five publishers and one silent writer:
         # one entry, one pass over the rows
-        return ((me, local, dense, union, five | {devices[0]}),
+        return ((me, local, dense, union, five + [devices[0]]),
                 {devices[0]: clock[devices[0]]}, 2 * (len(five) + 1))
     if case == "dense_publishers":
         # 5 publishers x n actors, nobody silent: what was published

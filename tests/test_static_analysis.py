@@ -715,6 +715,30 @@ def test_span_qualified_receiver_spelling_matched(tmp_path):
     assert len(errs) == 1 and "not.in.registry" in errs[0].message
 
 
+def test_span_add_many_literal_keys_are_names(tmp_path):
+    """``trace.add_many({...})``: every literal key is a counter name,
+    held to the registry and counted as a call site like ``add``'s; a key
+    that is no literal is the usual warning."""
+    findings, _ = analyze(
+        tmp_path,
+        """
+        from .utils import trace
+        def work(name):
+            trace.add_many({"h2d_bytes": 1, "not.in.registry": 2, name: 3})
+            with trace.span("phase.x"):
+                pass
+            with trace.span("stream.h2d"):
+                pass
+        """,
+        ["SPN001"],
+    )
+    errs = errors_of(findings)
+    assert len(errs) == 1 and "not.in.registry" in errs[0].message
+    warns = [f.message for f in findings if f.severity == "warning"]
+    assert "non-literal add_many name" in warns
+    assert not any("`h2d_bytes`" in m for m in warns)
+
+
 # ------------------------------------------------------------------ OBS001
 
 
