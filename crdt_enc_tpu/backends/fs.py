@@ -24,6 +24,10 @@ the dense version scan would find it.
 from __future__ import annotations
 
 import asyncio
+import collections
+import contextlib
+import contextvars
+import itertools
 import logging
 import os
 import uuid
@@ -166,7 +170,7 @@ def _native_step(step: str, *args, counted: str = "fs_steps") -> bool:
     would read back as an identical-content success; it is raised as
     ``_fsync_dir`` would have raised it.  ``counted`` names the counter
     pair: the seal tail's writes are ``fs_steps_*``, a poll's reads
-    ``fs_reads_*``."""
+    ``fs_reads_*``, the chunk iterator's windows ``fs_chunk_reads_*``."""
     from .. import native
 
     try:
@@ -544,16 +548,18 @@ class FsStorage(Storage):
     def _probe_actors(
         self, actor_first_versions: list[tuple[Actor, int]]
     ) -> list[tuple[Actor, int]]:
-        """Prefilter for the scan fan-out: keep only actors whose NEXT
-        wanted op file exists.  The dense scan reads nothing for the
-        others (their log is fully consumed or GC'd), but the per-actor
-        task/queue/thread machinery below costs ~1ms each — at 10k
-        replicas a warm-open tail ingest was spending seconds
-        discovering that 99% of actors had nothing new.  One stat per
-        actor replaces all of it; the stats are dirfd-relative (resolve
-        two path components, not the whole remote prefix) because on
-        containerized kernels every path walk costs ~100µs+ — this
-        probe IS the warm-open floor, measured, not guessed."""
+        """Prefilter for the per-actor scans (the ones a native read's
+        non-zero status falls back to, and ``stat_ops``): keep only
+        actors whose NEXT wanted op file exists.  The dense scan reads
+        nothing for the others (their log is fully consumed or GC'd),
+        but a per-actor task, queue and thread hop costs ~1ms each — at
+        10k replicas seconds spent discovering that 99% of actors had
+        nothing new.  One stat per actor replaces all of it; the stats
+        are dirfd-relative (resolve two path components, not the whole
+        remote prefix) because on containerized kernels every path walk
+        costs ~100µs+.  A native read (``load_op_runs``,
+        ``load_op_window``) needs no such pass: an absent first file is
+        its ``counts_out[i] == 0``."""
         n = len(actor_first_versions)
         if n > 64:  # the C loop only pays off past its setup cost
             try:
@@ -595,9 +601,26 @@ class FsStorage(Storage):
             os.close(dfd)
         return out
 
-    # how many actors scan concurrently ahead of the emitter; in-flight
-    # memory is bounded by ~window × 2 × CHUNK_BYTES (one queued + one
-    # in-progress round per actor)
+    # The chunk iterator reads WINDOWS of wanted actors: one worker hop and
+    # one native call (``load_op_window``) a window, CHUNK_WINDOWS of them
+    # on their threads ahead of the emitter.  The windows in flight share
+    # one chunk budget: a call's buffer is a CHUNK_WINDOWS-th of it, with a
+    # size slot a KiB, and a window is as many actors as fill those slots
+    # with 24 files each, four times what ``open()`` finds in a folder
+    # nobody compacted for six rounds; a window whose runs are longer
+    # stops where its buffers are full and is gone on with from there.
+    # Eight by 128 is measured (PERF.md §6, PR 35): on the chip's host a
+    # file is five system calls and 0.14 ms however it is asked for, so
+    # what a read stage can win is threads side by side, 1,000 one-file
+    # actors take 135 ms in one window, 67 in four at a time and 48 in
+    # eight, and windows of 256 leave a thousand actors only four.
+    CHUNK_WINDOWS = 8
+    CHUNK_WINDOW_FILES = (CHUNK_BYTES // CHUNK_WINDOWS) >> 10
+    CHUNK_WINDOW_ACTORS = CHUNK_WINDOW_FILES // 24
+    # in a window that fell back (``_iter_rounds``): how many actors scan
+    # concurrently ahead of the emitter; in-flight memory is bounded by
+    # ~window × 2 × CHUNK_BYTES (one queued + one in-progress round per
+    # actor)
     CHUNK_SCAN_WINDOW = 4
 
     async def iter_op_chunks(
@@ -607,13 +630,91 @@ class FsStorage(Storage):
     ):
         """Bounded-memory op reading for the pipelined ingest: yields
         ``(actor, version, raw)`` lists of ~max_bytes, per-actor version
-        order preserved across chunks (a chunk may end mid-actor).
-
-        Actors scan concurrently (a window of CHUNK_SCAN_WINDOW, FIFO, so
-        the per-file Python fallback on a high-latency remote does not
-        serialize the whole read stage) while emission stays in actor
-        order."""
+        order preserved across chunks (a chunk may end mid-actor), in the
+        order of the request."""
         max_bytes = max_bytes if max_bytes is not None else self.CHUNK_BYTES
+        chunk: list[tuple[Actor, int, bytes]] = []
+        size = 0
+        async with contextlib.aclosing(
+            self._iter_windows(actor_first_versions, max_bytes)
+        ) as reads:
+            async for files in reads:
+                for item in files:
+                    chunk.append(item)
+                    size += len(item[2])
+                    if size >= max_bytes:
+                        yield chunk
+                        chunk, size = [], 0
+        if chunk:
+            yield chunk
+
+    async def _iter_windows(
+        self, wanted: list[tuple[Actor, int]], max_bytes: int
+    ):
+        """The files of ``wanted``, window by window, each window's as it
+        was read: **status 0 or today's path from that window's start**
+        (``_iter_rounds``; a window that fell back is not read natively
+        again).  Every window in flight was handed to its thread before
+        the emitter awaits anything, so the read of the windows behind
+        overlaps the emission of the one in front."""
+        cap = max(1, min(max_bytes, self.CHUNK_BYTES) // self.CHUNK_WINDOWS)
+        ahead = (
+            wanted[i : i + self.CHUNK_WINDOW_ACTORS]
+            for i in range(0, len(wanted), self.CHUNK_WINDOW_ACTORS)
+        )
+        loop = asyncio.get_running_loop()
+        inflight: collections.deque = collections.deque()
+
+        def start(window):
+            # ``asyncio.to_thread`` less the coroutine: the job is its
+            # thread's before this returns, not a tick of the loop later
+            # (the ingest's consumer holds the loop for its session's
+            # vocabulary walk right after the producer's first step)
+            job = loop.run_in_executor(
+                None, contextvars.copy_context().run, self._native_runs,
+                window, self.CHUNK_WINDOW_FILES, cap, True,
+            )
+            return window, job
+
+        def top_up():
+            for window in itertools.islice(
+                ahead, self.CHUNK_WINDOWS - len(inflight)
+            ):
+                inflight.append(start(window))
+
+        try:
+            top_up()
+            while inflight:
+                window, job = inflight.popleft()
+                read = await job
+                if read is None:
+                    top_up()
+                    async with contextlib.aclosing(
+                        self._iter_rounds(window, max_bytes)
+                    ) as rounds:
+                        async for files in rounds:
+                            yield files
+                    continue
+                files, rest = read
+                if rest:  # its buffers were full: go on from there, first
+                    inflight.appendleft(start(rest))
+                top_up()
+                yield files
+        finally:
+            for _, job in inflight:
+                job.cancel()
+
+    async def _iter_rounds(
+        self, actor_first_versions: list[tuple[Actor, int]], max_bytes: int
+    ):
+        """The per-actor path of the chunk iterator, what a window runs
+        whose native call returned a status: the probe, then a bounded
+        two-pass round at a time an actor (``_chunk_round``: a race
+        between the passes or no library is the per-file re-probe), a
+        round's files a yield.  CHUNK_SCAN_WINDOW actors scan
+        concurrently, FIFO, so that the per-file Python path on a
+        high-latency remote does not serialize the whole read stage,
+        while emission stays in actor order."""
         actor_first_versions = await self._run(
             self._probe_actors, actor_first_versions
         )
@@ -644,8 +745,6 @@ class FsStorage(Storage):
             out_q: asyncio.Queue = asyncio.Queue(maxsize=1)
             queues.append(out_q)
             tasks.append(asyncio.create_task(scan_actor(actor, first, out_q)))
-        chunk: list[tuple[Actor, int, bytes]] = []
-        size = 0
         try:
             for out_q in queues:
                 while True:
@@ -654,14 +753,7 @@ class FsStorage(Storage):
                         break
                     if isinstance(files, Exception):
                         raise files
-                    for item in files:
-                        chunk.append(item)
-                        size += len(item[2])
-                        if size >= max_bytes:
-                            yield chunk
-                            chunk, size = [], 0
-            if chunk:
-                yield chunk
+                    yield files
         finally:
             for t in tasks:
                 t.cancel()
@@ -671,41 +763,58 @@ class FsStorage(Storage):
     LOAD_RUNS_FILES = 1024
     LOAD_RUNS_BYTES = 1 << 20
 
-    def _load_op_runs(
-        self, actor_first_versions: list[tuple[Actor, int]]
-    ) -> list[tuple[Actor, int, bytes]] | None:
+    def _native_runs(
+        self,
+        wanted: list[tuple[Actor, int]],
+        max_files: int,
+        max_bytes: int,
+        window: bool,
+    ):
         """The probe, the dense scan and the reads of every wanted actor
-        as ONE native call under one ``ops/`` descriptor; None on any
-        status but 0 (``fs_reads_*``)."""
+        as ONE native call under one ``ops/`` descriptor: ``(files,
+        rest)``, or None on any status but 0.  A poll's load
+        (``load_op_runs``, ``fs_reads_*``) is whole or a status.  A
+        ``window`` of the chunk iterator (``load_op_window``,
+        ``fs_chunk_reads_*``) whose runs its buffers do not hold stops
+        there, and ``rest`` is what it did not read: the pair it stopped
+        in, from its next version, and the pairs behind it."""
         import ctypes
 
         import numpy as np
 
         from .. import native
 
-        n = len(actor_first_versions)
+        n = len(wanted)
         i64 = ctypes.c_int64
         counts = (i64 * n)()
-        sizes = (i64 * self.LOAD_RUNS_FILES)()
-        buf = np.empty(self.LOAD_RUNS_BYTES, np.uint8)
-        n_files, n_bytes = i64(), i64()
+        sizes = (i64 * max_files)()
+        buf = np.empty(max_bytes, np.uint8)
+        n_files, n_bytes, stop = i64(), i64(), i64(n)
+        step, counted = "load_op_runs", "fs_reads"
+        outs = [ctypes.byref(n_files), ctypes.byref(n_bytes)]
+        if window:
+            step, counted = "load_op_window", "fs_chunk_reads"
+            outs.append(ctypes.byref(stop))
         if not _native_step(
-            "load_op_runs", os.fsencode(self._ops_dir()), n,
-            b"".join(a.hex().encode() + b"\0" for a, _ in actor_first_versions),
-            (i64 * n)(*(first for _, first in actor_first_versions)),
-            len(sizes), len(buf), counts, sizes,
-            buf.ctypes.data_as(native.u8p),
-            ctypes.byref(n_files), ctypes.byref(n_bytes), counted="fs_reads",
+            step, os.fsencode(self._ops_dir()), n,
+            b"".join(a.hex().encode() + b"\0" for a, _ in wanted),
+            (i64 * n)(*(first for _, first in wanted)),
+            max_files, max_bytes, counts, sizes,
+            buf.ctypes.data_as(native.u8p), *outs, counted=counted,
         ):
             return None
         raw = buf[: n_bytes.value].tobytes()
         each = iter(sizes[: n_files.value])
         out, end = [], 0
-        for (actor, first), count in zip(actor_first_versions, counts):
+        for (actor, first), count in zip(wanted, counts):
             for v in range(first, first + count):
                 start, end = end, end + next(each)
                 out.append((actor, v, raw[start:end]))
-        return out
+        rest = list(wanted[stop.value :])
+        if rest:
+            actor, first = rest[0]
+            rest[0] = (actor, first + counts[stop.value])
+        return out, rest
 
     def _scan_actor(self, actor: Actor, first: int) -> list[tuple[Actor, int, bytes]]:
         """One actor's dense run from ``first``: the native rounds, then
@@ -730,14 +839,17 @@ class FsStorage(Storage):
     ) -> list[tuple[Actor, int, bytes]]:
         if not actor_first_versions:
             return []
-        files = self._load_op_runs(actor_first_versions)
-        if files is None:
-            files = [
-                item
-                for actor, first in self._probe_actors(actor_first_versions)
-                for item in self._scan_actor(actor, first)
-            ]
-        return files
+        read = self._native_runs(
+            actor_first_versions, self.LOAD_RUNS_FILES, self.LOAD_RUNS_BYTES,
+            False,
+        )
+        if read is not None:
+            return read[0]
+        return [
+            item
+            for actor, first in self._probe_actors(actor_first_versions)
+            for item in self._scan_actor(actor, first)
+        ]
 
     async def load_ops(
         self, actor_first_versions: list[tuple[Actor, int]]

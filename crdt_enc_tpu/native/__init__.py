@@ -274,6 +274,11 @@ def _bind(lib) -> None:
         ctypes.c_int64, ctypes.c_int64, i64p, i64p, u8p, i64p, i64p,
     ]
     lib.load_op_runs.restype = ctypes.c_int32
+    lib.load_op_window.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, i64p,
+        ctypes.c_int64, ctypes.c_int64, i64p, i64p, u8p, i64p, i64p, i64p,
+    ]
+    lib.load_op_window.restype = ctypes.c_int32
     # (the two-pass count+decode batch protocol still exists in C —
     # orset_count_rows_batch / orset_decode_batch[_h] — but the Python
     # span decoder moved to the single-pass grow/take protocol below, so

@@ -35,6 +35,10 @@
 //                  file -> close.  All bytes land in one buffer, in the
 //                  order asked.
 //
+// The folder's chunk iterator reads the same way, a window of wanted
+// devices a call (`load_op_window`: `load_op_runs` that, where its buffers
+// are full, ends clean and says where, so the next call goes on there).
+//
 // As with the writers below, a surprise is never handled here: a file
 // that is there and cannot be opened or read, one that ends before or
 // after its size, a listing or a load the caller's buffers do not hold,
@@ -332,22 +336,30 @@ int32_t list_dir_names(const char* dir, char* buf, int64_t cap,
 // i's run (0: nothing new, the probe's answer); sizes_out holds the file
 // sizes of all runs in order (at most max_files), `buf` their bytes back
 // to back (at most cap); *files_out and *bytes_out the totals.  An
-// absent ops_dir, actor directory or first file is an empty run.  ERANGE
-// when the buffers do not hold the runs; any file that is there and does
-// not read back whole at its size is EIO or its errno.
-int32_t load_op_runs(const char* ops_dir, int64_t n, const char* actors,
-                     const int64_t* firsts, int64_t max_files, int64_t cap,
-                     int64_t* counts_out, int64_t* sizes_out, uint8_t* buf,
-                     int64_t* files_out, int64_t* bytes_out) {
+// absent ops_dir, actor directory or first file is an empty run.  Any
+// file that is there and does not read back whole at its size is EIO or
+// its errno.  Buffers that do not hold the runs: ERANGE without
+// `stop_out` (a poll's load is whole or a status); with it the call ends
+// clean before the file that does not fit, *stop_out the pair it is in
+// (counts_out says how far into its run; n when every run ended), and
+// only a first file that alone overflows `buf` is ERANGE.
+static int32_t read_op_runs(const char* ops_dir, int64_t n,
+                            const char* actors, const int64_t* firsts,
+                            int64_t max_files, int64_t cap,
+                            int64_t* counts_out, int64_t* sizes_out,
+                            uint8_t* buf, int64_t* files_out,
+                            int64_t* bytes_out, int64_t* stop_out) {
   *files_out = *bytes_out = 0;
+  if (stop_out != nullptr) *stop_out = n;
   for (int64_t i = 0; i < n; i++) counts_out[i] = 0;
   int ofd = open_dir(ops_dir);
   if (ofd < 0) return errno == ENOENT ? 0 : errno;
   int status = 0;
-  int64_t files = 0, used = 0;
+  int64_t files = 0, used = 0, stop = n;
   const char* actor = actors;
   char rel[320];
-  for (int64_t i = 0; i < n && status == 0; i++, actor += strlen(actor) + 1) {
+  for (int64_t i = 0; i < n && status == 0 && stop == n;
+       i++, actor += strlen(actor) + 1) {
     for (int64_t v = firsts[i];; v++) {
       int len = snprintf(rel, sizeof rel, "%s/%lld", actor, (long long)v);
       if (len <= 0 || (size_t)len >= sizeof rel) {
@@ -375,7 +387,8 @@ int32_t load_op_runs(const char* ops_dir, int64_t n, const char* actors,
       }
       int64_t want = (int64_t)st.st_size;
       if (files >= max_files || used + want > cap) {
-        status = ERANGE;
+        if (stop_out != nullptr && files > 0) stop = i;
+        else status = ERANGE;
         close(fd);
         break;
       }
@@ -408,7 +421,28 @@ int32_t load_op_runs(const char* ops_dir, int64_t n, const char* actors,
   close(ofd);
   *files_out = files;
   *bytes_out = used;
+  if (stop_out != nullptr) *stop_out = stop;
   return status;
+}
+
+// A poll's load: every wanted run, whole, or a status.
+int32_t load_op_runs(const char* ops_dir, int64_t n, const char* actors,
+                     const int64_t* firsts, int64_t max_files, int64_t cap,
+                     int64_t* counts_out, int64_t* sizes_out, uint8_t* buf,
+                     int64_t* files_out, int64_t* bytes_out) {
+  return read_op_runs(ops_dir, n, actors, firsts, max_files, cap, counts_out,
+                      sizes_out, buf, files_out, bytes_out, nullptr);
+}
+
+// One window of the chunk iterator: the runs of its pairs as far as the
+// buffers hold them, and where it stopped (the next call resumes there).
+int32_t load_op_window(const char* ops_dir, int64_t n, const char* actors,
+                       const int64_t* firsts, int64_t max_files, int64_t cap,
+                       int64_t* counts_out, int64_t* sizes_out, uint8_t* buf,
+                       int64_t* files_out, int64_t* bytes_out,
+                       int64_t* stop_out) {
+  return read_op_runs(ops_dir, n, actors, firsts, max_files, cap, counts_out,
+                      sizes_out, buf, files_out, bytes_out, stop_out);
 }
 
 // ---- the writers: one call a file step --------------------------------

@@ -417,7 +417,10 @@ def test_scan_error_propagates_not_hangs(tmp_path):
 
         import unittest.mock as mock
 
-        with mock.patch.object(lib, "read_op_files", broken_read), \
+        # a file that is there and unreadable: the window's one call says
+        # EACCES, the per-actor round's read fails, the per-file probe raises
+        with mock.patch.object(lib, "load_op_window", lambda *a: 13), \
+                mock.patch.object(lib, "read_op_files", broken_read), \
                 mock.patch.object(fsmod, "_read_file", failing_rf):
             with pytest.raises(PermissionError):
                 chunks = []

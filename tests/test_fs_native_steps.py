@@ -666,3 +666,402 @@ def test_a_polls_spans_fire_once_a_tenant_under_serve_ingest(
     assert POLL_SPANS <= set(trace.tree()["serve.ingest"])
     calls = 4 * tenants
     assert reads() == ((calls, 0) if mode == "native" else (0, calls))
+
+
+# ---- the chunk iterator (ISSUE 35): a window of wanted devices is ONE
+# call of ``load_op_window`` in one worker hop; any status but 0 sends that
+# window through the per-actor rounds.  A third door on the reads above ----
+
+
+def chunk_reads() -> tuple:
+    counted = trace.snapshot()["counters"]
+    return (counted.get("fs_chunk_reads_native", 0),
+            counted.get("fs_chunk_reads_python", 0))
+
+
+def chunked(s, wanted, max_bytes=None) -> list:
+    import asyncio
+
+    async def go():
+        return [chunk async for chunk in s.iter_op_chunks(wanted, max_bytes)]
+
+    return asyncio.run(go())
+
+
+def flat(chunks) -> list:
+    return [item for chunk in chunks for item in chunk]
+
+
+def small_windows(monkeypatch, actors, files=64, buffer=4096) -> None:
+    """Windows of ``actors`` devices whose one call holds ``files`` files
+    and ``buffer`` bytes (a CHUNK_WINDOWS-th of the chunk budget)."""
+    monkeypatch.setattr(FsStorage, "CHUNK_WINDOW_ACTORS", actors)
+    monkeypatch.setattr(FsStorage, "CHUNK_WINDOW_FILES", files)
+    monkeypatch.setattr(
+        FsStorage, "CHUNK_BYTES", buffer * FsStorage.CHUNK_WINDOWS
+    )
+
+
+# each shape: (root, monkeypatch) -> the request, the files every door
+# gives, the native calls of a clean pass and the windows of them that
+# fell back all the same; ``forced`` is what runs when every call of
+# ``load_op_window`` is a status: the windows, one fallback each
+
+
+def shape_no_ops_directory(root, mp):
+    return [(A, 1), (B, 7)], [], (1, 0), 1
+
+
+def shape_absent_device_directory(root, mp):
+    put(root, f"r/ops/{A.hex()}/1", b"one")
+    return [(B, 1), (A, 1), (C, 4)], [(A, 1, b"one")], (1, 0), 1
+
+
+def shape_nothing_new(root, mp):
+    put(root, f"r/ops/{A.hex()}/1", b"one")
+    put(root, f"r/ops/{B.hex()}/3", b"three")
+    os.makedirs(os.path.join(root, f"r/ops/{C.hex()}"))
+    return [(A, 2), (B, 4), (C, 1)], [], (1, 0), 1
+
+
+def shape_a_gap_ends_the_run(root, mp):
+    for v in (1, 2, 4, 5):
+        put(root, f"r/ops/{A.hex()}/{v}", b"v%d" % v)
+    put(root, f"r/ops/{B.hex()}/9", b"nine")
+    small_windows(mp, actors=1)
+    return ([(A, 1), (B, 9)],
+            [(A, 1, b"v1"), (A, 2, b"v2"), (B, 9, b"nine")], (2, 0), 2)
+
+
+def shape_a_run_crosses_a_windows_edge(root, mp):
+    """Four files of 30 bytes into buffers of 100: the call ends clean
+    after A's third, and the rest of the window (A from 4, then B) is one
+    more call, ahead of the window behind it (C)."""
+    for v in (1, 2, 3, 4):
+        put(root, f"r/ops/{A.hex()}/{v}", bytes([v]) * 30)
+    put(root, f"r/ops/{B.hex()}/1", b"b" * 30)
+    put(root, f"r/ops/{C.hex()}/5", b"c" * 30)
+    small_windows(mp, actors=2, buffer=100)
+    expected = [(A, v, bytes([v]) * 30) for v in (1, 2, 3, 4)]
+    expected += [(B, 1, b"b" * 30), (C, 5, b"c" * 30)]
+    return [(A, 1), (B, 1), (C, 5)], expected, (3, 0), 2
+
+
+def shape_a_run_longer_than_a_windows_buffers(root, mp):
+    """Eleven files into size slots for four: three calls for the one
+    device, each going on where the last one stopped."""
+    for v in range(1, 12):
+        put(root, f"r/ops/{A.hex()}/{v}", b"%02d" % v)
+    put(root, f"r/ops/{B.hex()}/1", b"b")
+    small_windows(mp, actors=1, files=4)
+    expected = [(A, v, b"%02d" % v) for v in range(1, 12)] + [(B, 1, b"b")]
+    return [(A, 1), (B, 1)], expected, (4, 0), 2
+
+
+def shape_one_file_larger_than_the_buffer(root, mp):
+    """A's second file alone overflows a call's buffer: the call that
+    would start with it is ERANGE, and that window (A from 2, B) runs the
+    per-actor rounds, whose buffer is the file's size."""
+    put(root, f"r/ops/{A.hex()}/1", b"small")
+    put(root, f"r/ops/{A.hex()}/2", b"L" * 300)
+    put(root, f"r/ops/{A.hex()}/3", b"after")
+    put(root, f"r/ops/{B.hex()}/1", b"b")
+    put(root, f"r/ops/{C.hex()}/1", b"c")
+    small_windows(mp, actors=2, buffer=100)
+    expected = [(A, 1, b"small"), (A, 2, b"L" * 300), (A, 3, b"after"),
+                (B, 1, b"b"), (C, 1, b"c")]
+    return [(A, 1), (B, 1), (C, 1)], expected, (2, 1), 2
+
+
+def shape_a_zero_byte_file(root, mp):
+    put(root, f"r/ops/{A.hex()}/1", b"")
+    put(root, f"r/ops/{A.hex()}/2", b"two")
+    put(root, f"r/ops/{B.hex()}/1", b"")
+    return ([(A, 1), (B, 1)],
+            [(A, 1, b""), (A, 2, b"two"), (B, 1, b"")], (1, 0), 1)
+
+
+def shape_no_regular_file_where_a_version_should_be(root, mp):
+    put(root, f"r/ops/{A.hex()}/1", b"one")
+    os.makedirs(os.path.join(root, f"r/ops/{A.hex()}/2"))
+    put(root, f"r/ops/{A.hex()}/3", b"three")
+    os.makedirs(os.path.join(root, f"r/ops/{B.hex()}/1"))
+    put(root, f"r/ops/{C.hex()}/4", b"four")
+    os.mkfifo(os.path.join(root, f"r/ops/{C.hex()}/5"))
+    return ([(A, 1), (B, 1), (C, 4)],
+            [(A, 1, b"one"), (C, 4, b"four")], (1, 0), 1)
+
+
+def shape_a_file_that_does_not_end_at_its_size(root, mp):
+    """``/proc/version`` says 0 bytes and holds a line: the one call is a
+    status, the round's second pass is a race, and the per-file probe
+    reads what is there, as it does behind ``load_ops_sync``."""
+    put(root, f"r/ops/{A.hex()}/1", b"one")
+    os.symlink("/proc/version", os.path.join(root, f"r/ops/{A.hex()}/2"))
+    put(root, f"r/ops/{B.hex()}/1", b"b")
+    with open("/proc/version", "rb") as fh:
+        line = fh.read()
+    assert line and os.stat("/proc/version").st_size == 0
+    return ([(A, 1), (B, 1)],
+            [(A, 1, b"one"), (A, 2, line), (B, 1, b"b")], (0, 1), 1)
+
+
+CHUNK_SHAPES = [
+    shape_no_ops_directory,
+    shape_absent_device_directory,
+    shape_nothing_new,
+    shape_a_gap_ends_the_run,
+    shape_a_run_crosses_a_windows_edge,
+    shape_a_run_longer_than_a_windows_buffers,
+    shape_one_file_larger_than_the_buffer,
+    shape_a_zero_byte_file,
+    shape_no_regular_file_where_a_version_should_be,
+    shape_a_file_that_does_not_end_at_its_size,
+]
+# without a library every read is per file, and opening a FIFO waits for
+# its writer: that shape's loud half is the test after this one
+DOORS = [
+    (shape, door)
+    for shape in CHUNK_SHAPES
+    for door in ("native", "forced", "python")
+    if (shape, door) != (shape_no_regular_file_where_a_version_should_be, "python")
+]
+
+
+@pytest.mark.parametrize(
+    "shape,door", DOORS, ids=[f"{s.__name__[6:]}-{d}" for s, d in DOORS]
+)
+def test_the_chunk_iterator_gives_the_answer_load_ops_gives(
+    shape, door, tmp_path, monkeypatch
+):
+    """Concatenated, the chunks equal ``load_ops_sync`` of the request:
+    from the windows' native calls, from the per-actor rounds when every
+    call is a status, and per file on a machine without a toolchain."""
+    lib = native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    wanted, expected, clean, windows = shape(root, monkeypatch)
+    if door == "forced":
+        monkeypatch.setattr(lib, "load_op_window", lambda *a: 5)
+    elif door == "python":
+        no_toolchain(monkeypatch)
+    trace.reset()
+    assert s.load_ops_sync(wanted) == expected
+    assert chunk_reads() == (0, 0)  # a poll's read counts as ``fs_reads_*``
+    assert flat(chunked(s, wanted)) == expected
+    assert chunk_reads() == (clean if door == "native" else (0, windows))
+    trace.reset()
+
+
+@pytest.mark.parametrize("read", ["load_ops_sync", "iter_op_chunks"])
+def test_a_directory_where_a_version_should_be_is_loud_per_file(
+    read, tmp_path, monkeypatch
+):
+    """The per-file path cannot tell a directory from a defect and
+    raises; the chunk iterator hands that on as the poll's read does."""
+    root = str(tmp_path)
+    s = storage_at(root)
+    put(root, f"r/ops/{A.hex()}/1", b"one")
+    os.makedirs(os.path.join(root, f"r/ops/{A.hex()}/2"))
+    no_toolchain(monkeypatch)
+    with pytest.raises(IsADirectoryError):
+        if read == "load_ops_sync":
+            s.load_ops_sync([(A, 1)])
+        else:
+            chunked(s, [(A, 1)])
+
+
+@pytest.mark.parametrize("door", ["native", "forced"])
+def test_a_file_removed_mid_window_ends_its_run(door, tmp_path, monkeypatch):
+    """The sync tool takes A's second file away while the window is being
+    read.  The one call opens a file before it sizes it, so it finds the
+    file or does not; the rounds size a run and then read it, and the
+    race between their passes (``_ScanRace``) is the per-file probe.
+    Either way A's run ends where the file was and B's is whole."""
+    lib = native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    for v in (1, 2, 3):
+        put(root, f"r/ops/{A.hex()}/{v}", b"a%d" % v)
+    put(root, f"r/ops/{B.hex()}/1", b"b1")
+    raced = "load_op_window" if door == "native" else "read_op_files"
+    real = getattr(lib, raced)
+
+    def taken_away(*args):
+        if os.path.exists(os.path.join(root, f"r/ops/{A.hex()}/2")):
+            os.remove(os.path.join(root, f"r/ops/{A.hex()}/2"))
+        return real(*args)
+
+    monkeypatch.setattr(lib, raced, taken_away)
+    if door == "forced":
+        monkeypatch.setattr(lib, "load_op_window", lambda *a: 11)
+    trace.reset()
+    expected = [(A, 1, b"a1"), (B, 1, b"b1")]
+    assert flat(chunked(s, [(A, 1), (B, 1)])) == expected
+    assert chunk_reads() == ((1, 0) if door == "native" else (0, 1))
+    assert s.load_ops_sync([(A, 1), (B, 1)]) == expected
+    trace.reset()
+
+
+def thousand_devices(root) -> tuple:
+    devices = [(i + 1).to_bytes(16, "big") for i in range(1000)]
+    for i, actor in enumerate(devices):
+        put(root, f"r/ops/{actor.hex()}/3", b"device %d" % i)
+    return ([(actor, 3) for actor in devices],
+            [(actor, 3, b"device %d" % i) for i, actor in enumerate(devices)])
+
+
+def count_hops(monkeypatch) -> dict:
+    """Worker hops by their job: the windows' (``_native_runs``) and
+    whatever went through ``_run`` (the probe and the rounds)."""
+    hops = {"windows": 0, "rounds": 0}
+    real_window, real_run = FsStorage._native_runs, FsStorage._run
+
+    def window(self, *args):
+        hops["windows"] += 1
+        return real_window(self, *args)
+
+    async def run(self, fn, *args):
+        hops["rounds"] += 1
+        return await real_run(self, fn, *args)
+
+    monkeypatch.setattr(FsStorage, "_native_runs", window)
+    monkeypatch.setattr(FsStorage, "_run", run)
+    return hops
+
+
+@pytest.mark.parametrize("window", [None, 256, 64])
+def test_a_round_of_a_thousand_devices_is_a_hop_a_window(
+    window, tmp_path, monkeypatch
+):
+    """1,000 devices with one new file each: ``ceil(1,000 / window)``
+    worker hops, as many native calls, and nothing else: no probe pass,
+    no task, queue or hop a device."""
+    native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    wanted, expected = thousand_devices(root)
+    if window is not None:
+        monkeypatch.setattr(FsStorage, "CHUNK_WINDOW_ACTORS", window)
+    windows = -(-1000 // FsStorage.CHUNK_WINDOW_ACTORS)
+    hops = count_hops(monkeypatch)
+    trace.reset()
+    chunks = chunked(s, wanted)
+    assert flat(chunks) == expected and len(chunks) == 1
+    assert hops == {"windows": windows, "rounds": 0}
+    assert chunk_reads() == (windows, 0)
+    assert reads() == (0, 0) and steps() == (0, 0)
+    trace.reset()
+
+
+def test_a_forced_status_counts_python_once_a_window(tmp_path, monkeypatch):
+    """Every call a status: each window is counted once and runs the
+    probe and the rounds for its devices, and the files are the same."""
+    lib = native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    wanted, expected = thousand_devices(root)
+    monkeypatch.setattr(FsStorage, "CHUNK_WINDOW_ACTORS", 256)
+    monkeypatch.setattr(lib, "load_op_window", lambda *a: 34)
+    hops = count_hops(monkeypatch)
+    trace.reset()
+    assert flat(chunked(s, wanted)) == expected
+    assert chunk_reads() == (0, 4)
+    # a window: its one hop, then the probe and a round a device
+    assert hops == {"windows": 4, "rounds": 4 + 1000}
+    trace.reset()
+
+
+def test_the_windows_behind_are_being_read_while_the_first_is_emitted(
+    tmp_path, monkeypatch
+):
+    """The emitter never waits on a window that was not started: when the
+    first window's files come out, every window in flight behind it is on
+    its thread already (here they are held there until the test has seen
+    the first chunk: the seven started beside it and the one that took
+    its place), and the windows after those are not."""
+    import asyncio
+
+    native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    devices = [bytes([i + 1]) * 16 for i in range(12)]
+    for actor in devices:
+        put(root, f"r/ops/{actor.hex()}/1", actor * 4)
+    wanted = [(actor, 1) for actor in devices]
+    small_windows(monkeypatch, actors=1)  # twelve windows, eight in flight
+    entered, release = [], threading.Event()
+    real = FsStorage._native_runs
+
+    def held(self, window, *args):
+        entered.append(window[0][0])
+        if window[0][0] != devices[0]:
+            assert release.wait(timeout=30)
+        return real(self, window, *args)
+
+    monkeypatch.setattr(FsStorage, "_native_runs", held)
+
+    async def go():
+        chunks = aiter(s.iter_op_chunks(wanted, max_bytes=1))
+        first = await anext(chunks)
+        for _ in range(3000):  # the jobs were submitted; let them enter
+            if len(entered) > FsStorage.CHUNK_WINDOWS:
+                break
+            await asyncio.sleep(0.01)
+        seen = list(entered)
+        release.set()
+        return first, seen, [first] + [chunk async for chunk in chunks]
+
+    first, seen, chunks = asyncio.run(go())
+    assert first == [(devices[0], 1, devices[0] * 4)]
+    assert sorted(seen) == devices[:9]
+    assert flat(chunks) == [(actor, 1, actor * 4) for actor in devices]
+    assert sorted(entered) == devices
+
+
+def test_max_bytes_cuts_a_chunk_mid_device(tmp_path, monkeypatch):
+    native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    for v in range(1, 11):
+        put(root, f"r/ops/{A.hex()}/{v}", bytes([v]) * 100)
+    put(root, f"r/ops/{B.hex()}/1", b"b" * 100)
+    expected = [(A, v, bytes([v]) * 100) for v in range(1, 11)]
+    expected.append((B, 1, b"b" * 100))
+    for forced in (False, True):
+        if forced:
+            monkeypatch.setattr(native.load(), "load_op_window", lambda *a: 5)
+        chunks = chunked(s, [(A, 1), (B, 1)], max_bytes=250)
+        assert [len(chunk) for chunk in chunks] == [3, 3, 3, 2]
+        assert flat(chunks) == expected
+        assert chunks[0][-1][:2] == (A, 3) and chunks[1][0][:2] == (A, 4)
+
+
+def test_a_folders_compact_reads_its_op_files_by_the_window(tmp_path):
+    """End to end: ``open()`` and ``compact()`` over ``FsStorage`` take
+    the writer's files in through the chunk iterator, every window a
+    native call."""
+    import asyncio
+
+    from test_serve import make_opts, write_orset
+
+    from crdt_enc_tpu.core import Core
+
+    native.load()
+
+    def storage(name):
+        return FsStorage(str(tmp_path / name), str(tmp_path / "remote"))
+
+    async def go():
+        writer = await write_orset(storage("w"), 12, b"m")
+        trace.reset()
+        core = await Core.open(make_opts(storage("c")))
+        await core.compact()
+        assert core.with_state(lambda s: dict(s.entries)) == writer.with_state(
+            lambda s: dict(s.entries)
+        )
+
+    asyncio.run(go())
+    native_reads, python_reads = chunk_reads()
+    assert native_reads >= 1 and python_reads == 0
+    trace.reset()
