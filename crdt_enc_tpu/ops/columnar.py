@@ -319,6 +319,63 @@ def orset_planes_to_state(
     return state
 
 
+def _set_cells(target: dict, m_idx, a_objs: list, vals: list, mobj: list) -> None:
+    """Member-contiguous cells into ``target``'s nested dicts: a nonzero
+    value is set, a zero removes the slot, a member left with no slot
+    goes."""
+    starts = np.flatnonzero(np.r_[True, np.diff(m_idx) != 0])
+    ends = np.r_[starts[1:], len(m_idx)]
+    for s, e in zip(starts.tolist(), ends.tolist()):
+        mo = mobj[int(m_idx[s])]
+        slot = target.get(mo)
+        if slot is None:
+            slot = {a: v for a, v in zip(a_objs[s:e], vals[s:e]) if v}
+            if slot:
+                target[mo] = slot
+            continue
+        for a, v in zip(a_objs[s:e], vals[s:e]):
+            if v:
+                slot[a] = v
+            else:
+                slot.pop(a, None)
+        if not slot:
+            del target[mo]
+
+
+def orset_cells_to_state(
+    state: ORSet,
+    member: np.ndarray,  # (N,) the batch's rows: indices into ``members``
+    actor: np.ndarray,  # (N,) ... and into ``replicas``
+    add_c: np.ndarray,  # (N,) the post-fold add word of each row's cell
+    rm_c: np.ndarray,  # (N,) ... and its remove word
+    members: Vocab,
+    replicas: Vocab,
+) -> ORSet:
+    """The partial form of :func:`orset_planes_to_state`: write into
+    ``state``, whose entries and horizons equal the planes as they stood
+    BEFORE a fold and whose clock is already the post-fold one, what the
+    fold changed, given the post-fold words of the cells its rows named
+    (``ops.orset.orset_gather_cells``).  A fold touches no other add word;
+    the only other remove words it changes are horizons the advanced
+    clock caught up with, retired here from the clock.  The result is the
+    state ``orset_planes_to_state`` builds from the whole post-fold planes
+    (tests/test_resident_fold.py)."""
+    R = len(replicas)
+    key = np.asarray(member, np.int64) * R + np.asarray(actor, np.int64)
+    key, first = np.unique(key, return_index=True)  # member-major order
+    if len(key):
+        m_idx = key // R
+        a_objs = np.asarray(replicas.items, dtype=object)[key % R].tolist()
+        mobj = members.items
+        _set_cells(state.entries, m_idx, a_objs,
+                   np.asarray(add_c)[first].tolist(), mobj)
+        _set_cells(state.deferred, m_idx, a_objs,
+                   np.asarray(rm_c)[first].tolist(), mobj)
+    for mo in list(state.deferred):
+        state._normalize_member(mo)  # the one host home of the retire rule
+    return state
+
+
 def orset_fold_sparse_host(
     state: ORSet,
     kind: np.ndarray,

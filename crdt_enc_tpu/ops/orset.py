@@ -208,6 +208,28 @@ def orset_fold_coo(
 
 
 @jax.jit
+def orset_gather_cells(
+    add: jax.Array,  # (E, R) int32 — post-fold planes, resident
+    rm: jax.Array,
+    member: jax.Array,  # (N,) int32 — the fold's own (padded) row columns
+    actor: jax.Array,  # (N,) int32  (>= R ⇒ padding row)
+):
+    """The add and remove words of the cell each op row names: what a
+    fold over resident planes brings home instead of the planes.  A fold
+    changes the planes only at cells its rows name (and retires horizons
+    the advanced clock caught up with, which the host can do from the
+    clock alone: ``ops.columnar.orset_cells_to_state``), so these two
+    ``(N,)`` arrays and the clock are the whole difference between the
+    host state before and after.  One value per ROW, duplicates and all:
+    the rows are already on the device and already bucket-padded, so the
+    compile classes are the fold's own and nothing more is uploaded.
+    Padding rows clamp to a real cell; the caller reads only the first
+    real-row-count values."""
+    a = jnp.minimum(actor, add.shape[1] - 1)
+    return add[member, a], rm[member, a]
+
+
+@jax.jit
 def orset_apply_batch_planes(
     clock0: jax.Array,  # (R,) int32 — CURRENT state clock
     add0: jax.Array,  # (E, R) int32 — current state planes

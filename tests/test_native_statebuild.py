@@ -208,3 +208,37 @@ def test_decrypt_blobs_packed_survives_blob_list_mutation():
     blobs = [xchacha.encrypt_blob(key, b"v%d" % i) for i in range(24)]
     out = xchacha.decrypt_blobs(key, blobs)
     assert [bytes(v) for v in out] == [b"v%d" % i for i in range(24)]
+
+
+class _Libc:
+    def __init__(self, calls):
+        self.mallopt = lambda param, value: calls.append((param, value)) or 1
+
+
+@pytest.mark.parametrize("libc", ["glibc", "no mallopt", "no libc"])
+def test_warm_sets_the_thread_arenas_growth_once_and_only_where_glibc_is(monkeypatch, libc):
+    """warm() has glibc open a thread's arena heap whole (M_TOP_PAD = the
+    heap's 64 MB) and pins the two thresholds that setting one freezes;
+    a second warm() sets nothing again; without mallopt it does nothing."""
+    calls = []
+
+    def cdll(name):
+        assert name is None
+        if libc == "no libc":
+            raise OSError("no such library")
+        return _Libc(calls) if libc == "glibc" else object()
+
+    monkeypatch.setattr(native, "_arenas_set", False)
+    monkeypatch.setattr(native.ctypes, "CDLL", cdll)
+    native._grow_thread_arenas_whole()
+    native._grow_thread_arenas_whole()
+    want = [(-3, 32 << 20), (-2, 64 << 20), (-1, 128 << 20)]
+    assert calls == (want if libc == "glibc" else [])
+    assert native._arenas_set is True
+
+
+def test_warm_reaches_the_arena_setting(monkeypatch):
+    seen = []
+    monkeypatch.setattr(native, "_grow_thread_arenas_whole", lambda: seen.append(1))
+    native.warm()
+    assert seen == [1]
