@@ -373,7 +373,8 @@ class _SealOutcome:
 
     name: str | None = None  # the snapshot, durable
     delta_version: int | None = None  # own delta published here
-    base: tuple | None = None  # (name, bytes | None, cursor) to retain
+    # (name, bytes | None, cursor, state | None) to retain
+    base: tuple | None = None
     stale_states: list | None = None  # GC done; these snapshots removed
     checkpoint_sig: tuple | None = None  # checkpoint durable
     error: BaseException | None = None  # what stopped the steps
@@ -2440,11 +2441,21 @@ class Core:
             trace.add("delta_cut_fallbacks", 1)
             trace.add("delta_seal_skipped", 1)
             return plan
+        # the base as an object is TAKEN, not borrowed: the verify
+        # mutates it on a worker thread, so from this slice on it is the
+        # plan's alone.  A tail that fails before its commit leaves the
+        # old name with its bytes and no object, and a second plan made
+        # while this one's tail runs finds none: both unpack the bytes
+        base_state, base["state"] = base["state"], None
         try:
-            with trace.span("delta.base_unpack"):
-                base_state = self.adapter.state_from_obj(
-                    codec.unpack(base["bytes"])
-                )
+            if base_state is None:
+                with trace.span("delta.base_unpack"):
+                    base_state = self.adapter.state_from_obj(
+                        codec.unpack(base["bytes"])
+                    )
+                trace.add("delta_base_unpacked", 1)
+            else:
+                trace.add("delta_base_reused", 1)
             with trace.span("delta.diff"):
                 dobj = codec_cls.diff(base_state, d.state)
         except Exception:
@@ -2466,7 +2477,7 @@ class Core:
         return plan
 
     def _set_delta_base(
-        self, name: str, state_bytes: bytes | None, cursor_obj
+        self, name: str, state_bytes: bytes | None, cursor_obj, state=None
     ) -> None:
         """Retain the just-sealed snapshot as the next diff base.
         ``state_bytes`` is a resident O(state) canonical copy per Core —
@@ -2479,9 +2490,21 @@ class Core:
         tier's device planes ARE the base, so no host copy is retained —
         ``delta_base_bytes`` drops to ~0 and the next cycle either cuts
         on device again or seals one snapshot-only link
-        (``delta_cut_fallbacks``) that re-retains the bytes."""
+        (``delta_cut_fallbacks``) that re-retains the bytes.
+
+        ``state`` is that snapshot as an object, and only one source has
+        it: a seal-time verify that returned true, whose base copy, the
+        delta applied, packed to ``state_bytes`` (:meth:`_seal_delta`).
+        The next host-route plan diffs against it and skips the unpack
+        of the bytes; it takes the object out of here when it does
+        (:meth:`_plan_delta_seal`), so what this dict names is never
+        mutated.  Every other caller leaves it None.  It is the copy the
+        verify used to free at its end, kept to the next plan instead: a
+        host-route Core's floor between rounds is one state higher, its
+        peak what it was."""
         self._delta_base = {
             "name": name, "bytes": state_bytes, "cursor": cursor_obj,
+            "state": state,
         }
         trace.gauge(
             "delta_base_bytes",
@@ -2539,6 +2562,7 @@ class Core:
         dp = plan.delta
         if name == dp["base_name"]:
             return  # idempotent re-seal of the identical snapshot
+        verified = False
         if dp["dobj"] is not None:
             with trace.span("delta.size"):
                 delta_len = len(codec.pack(dp["dobj"]))
@@ -2546,17 +2570,17 @@ class Core:
                 # a delta no smaller than the state saves nothing
                 trace.add("delta_seal_skipped", 1)
                 dp["dobj"] = None
-            elif self._delta_verify and not await ports.offload(
-                self._verify_delta_plan, dp
-            ):
-                logger.warning(
-                    "delta diff does not refold to the sealed state; "
-                    "refusing to publish it (snapshot only)"
-                )
-                trace.add("delta_seal_divergence", 1)
-                dp["dobj"] = None
+            elif self._delta_verify:
+                verified = await ports.offload(self._verify_delta_plan, dp)
+                if not verified:
+                    logger.warning(
+                        "delta diff does not refold to the sealed state; "
+                        "refusing to publish it (snapshot only)"
+                    )
+                    trace.add("delta_seal_divergence", 1)
+                    dp["dobj"] = None
         if dp["dobj"] is None:
-            out.base = (name, dp["new_bytes"], dp["cursor"])
+            out.base = (name, dp["new_bytes"], dp["cursor"], None)
             last = plan.last_delta_version
             if last:
                 trace.add("delta_pruned", 1)
@@ -2612,11 +2636,18 @@ class Core:
         # a published device-cut proves the warm planes ARE this
         # snapshot: drop the host base copy (the planes take over as
         # the base; _plan_delta_seal's bytes-None branch covers any
-        # future cycle where they no longer line up)
+        # future cycle where they no longer line up).  A verify that
+        # held left the plan's base copy equal to this snapshot: it moves
+        # on to the next plan (None in a device-cut plan, whose copy the
+        # verify built from the planes and dropped)
+        state = None
+        if verified:
+            state, dp["base_state"] = dp["base_state"], None
         out.base = (
             name,
             None if dp.get("device_cut") else dp["new_bytes"],
             dp["cursor"],
+            state,
         )
 
     # --------------------------------------------------------------- compact
