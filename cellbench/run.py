@@ -184,11 +184,16 @@ async def run_window(driver, seconds: float, first_round: int, n_rounds: int,
 
 async def warm_worker_threads(obj) -> None:
     """Every worker thread of the loop's default executor packs ``obj`` once
-    with the program's canonical packer, as the seal tail does off the loop
-    (``delta.verify``).  A thread's first large pack is about ten times slower
-    than its later ones (fresh memory under its allocator), and which of the
-    ``cpu + 4`` threads a call lands on is chance: without this, one call in
-    four of the first windows measured was a second or two longer."""
+    with the program's canonical packer, all ``cpu + 4`` of them held at a
+    barrier so that each thread takes one.  What that warms is the packer's
+    one output buffer on that thread (PR 23: a thread's first large pack was
+    some ten times slower than its later ones).  What it does not warm is
+    what the seal tail's ``delta.verify`` does off the loop, which builds a
+    state's worth of small objects before it packs them: a thread's first
+    such build was 1.4-1.8 s against 0.25 s later, under glibc's page-by-page
+    growth of a new arena, and since PR 41 the program itself opens a
+    thread's arena whole (``native.warm()``, ``M_TOP_PAD``).  The call stays
+    because it is part of every cell's ``setup_s`` as measured."""
     from crdt_enc_tpu.utils import codec
 
     n = min(32, (os.cpu_count() or 1) + 4)  # ThreadPoolExecutor's own default

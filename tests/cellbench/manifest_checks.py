@@ -10,6 +10,7 @@ Code (readers, drivers) is always the checkout's; only data is per root.
 """
 
 import glob
+import json
 import os
 import re
 
@@ -28,7 +29,9 @@ TINY = {
 }
 # the writers of a toy round, per kind of deployment: (the mix's key, the toy's size)
 WRITERS = {"folder": ("active_devices", "devices"), "fleet": ("active_tenants", "tenants")}
-TINY_WRITERS = {"backlog": 8, "trickle": 2, "busy": 6, "quiet": 2}
+# trickle: 6 files of 48 ops are 288 rows, past the accelerator's smallest
+# device batch (256), so a toy round folds on the device as the cell's does
+TINY_WRITERS = {"backlog": 8, "trickle": 6, "busy": 6, "quiet": 2}
 
 
 def cells(manifest: dict) -> list:
@@ -59,6 +62,15 @@ def tiny(manifest: dict, root: str, cell: str) -> dict:
         mix = run.load_json(root, "cellbench", "traffic", entry["traffic"] + ".json")
         writers = min(mix[key], TINY[kind][size])
     return {"config": TINY[kind], "traffic": {key: writers, "max_ops_per_s": 2500}}
+
+
+def toy(manifest: dict, root: str, cell: str) -> dict:
+    """The overlay under which a traced CPU line of ``cell`` carries every
+    metric the cell lists: ``tests/cellbench/toys/<cell>.json`` where the
+    cell's tests brought one (a toy of the family's size has no peer with a
+    share of its own, no tenant large enough to spill), else ``tiny()``."""
+    path = os.path.join(root, "tests", "cellbench", "toys", cell + ".json")
+    return run.load_json(path) if os.path.exists(path) else tiny(manifest, root, cell)
 
 
 def check_contract_keys(manifest: dict, root: str) -> None:
@@ -110,32 +122,78 @@ def check_cell(manifest: dict, root: str, cell: str) -> None:
     assert loaded["per_layer"]
 
 
+def families(spec: dict) -> tuple:
+    """The driver families of a metric file: its ``driver`` is a prefix, or a
+    list of prefixes, of the driver modules whose cells may list it
+    (``"folder"`` admits ``folder``, ``folder_peers``, ``folder_10k``)."""
+    return tuple(spec["driver"]) if isinstance(spec["driver"], list) else (spec["driver"],)
+
+
+def check_pair(manifest: dict, root: str, metric: str, cell: str) -> None:
+    """One (metric, cell) pair of the manifest: the cell exists, reports the
+    end-to-end metric that the metric moves, and is driven by a module of the
+    metric's family.  Which cells a metric has is its entry's ``workloads``
+    and nothing else: a later cell of the family takes the metric by
+    appending its name there, with no metric file touched."""
+    entry = entry_of(manifest, "per_layer", metric)
+    spec = run.load_json(root, "cellbench", "layer_metrics", metric + ".json")
+    known = cells(manifest)
+    assert cell in known, (metric, cell)
+    moved = entry_of(manifest, "end_to_end", entry["moves"])
+    assert cell in moved.get("workloads", known), (metric, cell)
+    driver = config_of(manifest, root, cell)["driver"]
+    assert driver.startswith(families(spec)), (metric, cell, driver)
+
+
 def check_layer_metric(manifest: dict, root: str, metric: str) -> None:
     entry = entry_of(manifest, "per_layer", metric)
     assert set(entry) <= {"name", "unit", "better", "source", "layer", "moves",
                           "workloads"}
     assert entry["source"] in SOURCES
     spec = run.load_json(root, "cellbench", "layer_metrics", metric + ".json")
+    assert spec["name"] == metric
     for key in ("layer", "unit", "better", "source", "moves"):
         assert spec[key] == entry[key], key
     assert os.path.exists(
         os.path.join(CODE, "cellbench", "readers", spec["reader"] + ".py"))
-    # one driver's name, or the names of all whose cells may list the metric
-    drivers = spec["driver"] if isinstance(spec["driver"], list) else [spec["driver"]]
-    moved = entry_of(manifest, "end_to_end", entry["moves"])
-    known = cells(manifest)
+    assert entry["workloads"] and len(set(entry["workloads"])) == len(entry["workloads"])
     for cell in entry["workloads"]:
-        assert cell in known
-        assert cell in moved.get("workloads", known), (metric, cell)
-        assert config_of(manifest, root, cell)["driver"] in drivers, (metric, cell)
+        check_pair(manifest, root, metric, cell)
     if "_roofline" in metric:
         assert entry["unit"] == "%"
+
+
+def definition(spec: dict) -> dict:
+    """What a metric file defines, less what two files of one definition may
+    differ in: the name, the family, the words."""
+    return {k: v for k, v in spec.items()
+            if k not in ("name", "driver", "what", "may_be_absent")}
+
+
+def check_no_two_files_define_the_same(manifest: dict, root: str) -> None:
+    """Every file under ``layer_metrics/`` is an entry's and every entry has
+    its file; no two files are equal but for name, driver, words and
+    ``may_be_absent``: a cell that wants a metric that is there is appended
+    to its entry's ``workloads``, never served by a copy of the file."""
+    paths = sorted(glob.glob(os.path.join(root, "cellbench", "layer_metrics", "*.json")))
+    names = [os.path.basename(p)[:-len(".json")] for p in paths]
+    assert sorted(m["name"] for m in manifest["per_layer"]) == names
+    seen = {}
+    for name, path in zip(names, paths):
+        key = json.dumps(definition(run.load_json(path)), sort_keys=True)
+        assert key not in seen, f"{name} is {seen[key]} again: list the cell there"
+        seen[key] = name
 
 
 def listed(root: str, cell: str) -> dict:
     """name -> metric file (with the manifest's name and unit) of every
     per-layer metric that lists ``cell``."""
     return {m["name"]: m for m in run.load_cell(root, cell)["per_layer"]}
+
+
+def demanded(spec: dict) -> bool:
+    """Whether a CPU toy line of a cell that lists the metric must carry it."""
+    return spec["source"] != "device_trace" and not spec.get("may_be_absent")
 
 
 def check_toy_line(root: str, cell: str, carried) -> None:
@@ -148,8 +206,7 @@ def check_toy_line(root: str, cell: str, carried) -> None:
     specs = listed(root, cell)
     carried = set(carried)
     assert carried <= set(specs), carried - set(specs)
-    must = {name for name, spec in specs.items()
-            if spec["source"] != "device_trace" and not spec.get("may_be_absent")}
+    must = {name for name, spec in specs.items() if demanded(spec)}
     assert must <= carried, must - carried
     assert not any(specs[name]["source"] == "device_trace" for name in carried)
 

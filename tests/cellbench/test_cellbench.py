@@ -125,18 +125,26 @@ def test_control_a_withheld_op_file_is_not_correct(cell, capsys):
 
 @pytest.mark.parametrize("cell", [c for c in CELLS if c.endswith((".backlog", ".busy"))])
 def test_narrowed_counters_in_the_timed_path_are_not_correct(cell, capsys, monkeypatch):
-    """The timed path broken underneath: the planes the fold hands back lose
-    every counter bit above the low 7-bit limb (what skipping the kernel's
-    high-limb pass on large counters would do).  The rest of the run is
-    driven as always and must say ``correct: false``."""
+    """The timed path broken underneath: what the fold hands back loses every
+    counter bit above the low 7-bit limb (what skipping the kernel's
+    high-limb pass on large counters would do), at both doors a writeback
+    goes through: whole planes (a round that built them, a merge, and the
+    clock of every round) and, since PR 41, the touched cells of a fold over
+    resident planes.  The rest of the run is driven as always and must say
+    ``correct: false``."""
     import crdt_enc_tpu.ops as K
 
-    whole = K.orset_planes_to_state
+    whole, partial = K.orset_planes_to_state, K.orset_cells_to_state
 
-    def low_limb_only(clock, add, rm, members, replicas):
+    def low_limb_planes(clock, add, rm, members, replicas):
         return whole(clock & 0x7F, add & 0x7F, rm & 0x7F, members, replicas)
 
-    monkeypatch.setattr(K, "orset_planes_to_state", low_limb_only)
+    def low_limb_cells(state, member, actor, add_c, rm_c, members, replicas):
+        return partial(state, member, actor, add_c & 0x7F, rm_c & 0x7F,
+                       members, replicas)
+
+    monkeypatch.setattr(K, "orset_planes_to_state", low_limb_planes)
+    monkeypatch.setattr(K, "orset_cells_to_state", low_limb_cells)
     assert run.run_cell(cell, 14, 0.5, False, require_tpu=False,
                         shrink=tiny(cell)) == 0
     assert last_line(capsys)["correct"] is False

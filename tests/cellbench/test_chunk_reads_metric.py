@@ -1,9 +1,12 @@
-"""The 21 per-layer metrics that four PRs (27/29, 31, 34, 35) had to leave out,
-brought in as data: ``native_chunk_reads_pct.folder`` whole, and twenty copies
-of metric files the benchmark has, each for a cell of another driver.  File
-and manifest entry agree; a copy is its original but for its name, its driver
-and its cells; a CPU toy run of the folder driver reads the share of native
-read windows as 100, and a window without the counters leaves it out.
+"""The two per-layer metrics of a solo folder's native reads and file steps
+(PR 39): ``native_chunk_reads_pct.folder`` whole, and
+``native_file_steps_pct.folder``, which is the fleet's file with another
+``moves`` and layer (a folder has no serve tail), so a definition of its own.
+The nineteen other files PR 39 brought were copies for cells of other drivers;
+since ISSUE 43 those cells are listed by the originals' entries
+(``test_metric_cell_pairs.py`` holds every pair).  A CPU toy run of the folder
+driver reads the share of native read windows as 100, and a window without
+the counters leaves it out.
 
 Nothing here is a measurement: the toy run is on the CPU at toy sizes.
 """
@@ -20,9 +23,6 @@ from test_cellbench import tiny
 ROOT = run.ROOT
 MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
 FOLDER = ["orset_folder_1k.backlog", "orset_folder_1k.trickle"]
-PEERS = ["orset_folder_peers.backlog"]
-DELTA = ["orset_folder_peers_delta.backlog"]
-ZIPF = ["orset_fleet_zipf.busy"]
 
 # PERF.md section 7 "After PR 35" gave the file whole
 CHUNK_READS = {
@@ -34,31 +34,14 @@ CHUNK_READS = {
              "scale": 100},
 }
 
-
-def copies(bases, source, driver, cells, **differs):
-    return {f"{b}.{driver}": (f"{b}.{source}", cells, differs) for b in bases}
-
-
-# new file -> (its original, its cells, the keys that differ besides name and driver)
+# file -> (the file it was made from, the keys in which it differs from it)
 NEW = {
-    "native_chunk_reads_pct.folder": (None, FOLDER, {}),
-    **copies(["native_chunk_reads_pct"], "folder", "folder_peers", PEERS),
-    **copies(["native_chunk_reads_pct"], "folder", "folder_peers_delta", DELTA),
+    "native_chunk_reads_pct.folder": (None, {}),
     # the folder has no serve seal tail: its file steps are the snapshot's
     # publish, the local meta's replace and the GC lists of one compact()
-    **copies(["native_file_steps_pct"], "fleet", "folder", FOLDER,
-             moves="compact_ms", layer="storage list/load/GC"),
-    **copies(["gc_pause_ms", "gc_full_pause_ms", "watermark_ms"],
-             "folder", "folder_peers", PEERS),
-    **copies(["gc_pause_ms", "gc_full_pause_ms", "watermark_ms"],
-             "folder", "folder_peers_delta", DELTA),
-    **copies(["seal_job_pct", "native_file_steps_pct", "ingest_job_pct",
-              "native_reads_pct", "gc_pause_ms", "gc_full_pause_ms",
-              "ingest_job_queue_ms", "ingest_job_return_ms", "seal_job_queue_ms",
-              "seal_job_return_ms"], "fleet", "fleet_zipf", ZIPF),
-    # opened only by a tenant that found all sixteen slots taken: a toy
-    # fleet's window has no such span, and the file says so
-    **copies(["slot_wait_ms"], "fleet", "fleet_zipf", ZIPF, may_be_absent=True),
+    "native_file_steps_pct.folder": (
+        "native_file_steps_pct.fleet",
+        {"moves": "compact_ms", "layer": "storage list/load/GC"}),
 }
 
 
@@ -66,25 +49,16 @@ def spec_of(metric: str) -> dict:
     return run.load_json(ROOT, "cellbench", "layer_metrics", metric + ".json")
 
 
-def test_there_are_twenty_one_and_they_are_appended_after_what_was_there():
-    assert len(NEW) == 21
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names.index("seal_job_return_ms.fleet") < min(names.index(n) for n in NEW), (
-        "what the benchmark had stands before them, in its place")
-
-
 @pytest.mark.parametrize("metric", list(NEW))
-def test_new_file_is_its_original_but_for_name_driver_and_cells(metric):
-    original, cells, differs = NEW[metric]
+def test_file_is_what_it_was_made_from_but_for_what_differs(metric):
+    original, differs = NEW[metric]
     spec = spec_of(metric)
-    driver = metric.rsplit(".", 1)[1]
-    assert (spec["name"], spec["driver"]) == (metric, driver)
+    assert (spec["name"], spec["driver"]) == (metric, "folder")
     want = {**CHUNK_READS, "what": spec["what"]} if original is None else spec_of(original)
-    want = {**want, **differs, "name": metric, "driver": driver}
-    assert spec == want
+    assert spec == {**want, **differs, "name": metric, "driver": "folder"}
     assert spec["what"]
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
-    assert entry["workloads"] == cells
+    assert entry["workloads"][:len(FOLDER)] == FOLDER, "the solo folder's cells first"
     for key in ("layer", "unit", "better", "source", "moves"):
         assert entry[key] == spec[key], key
 

@@ -18,18 +18,20 @@ import manifest_checks as checks
 
 ROOT = run.ROOT
 CELL = "orset_fleet_zipf.busy"
-# the metrics the cell came with (PR 26); later entries may list the cell too
+# the metrics the cell came with (PR 26): five of its own, and eight of the
+# uniform fleet's that it reads from their entries (ISSUE 43; it had copies);
+# later entries may list the cell too
 THIRTEEN = {m + ".fleet_zipf" for m in (
     "buckets_per_cycle", "solo_spills_per_cycle", "stack_fill_pct", "warm_hit_pct",
-    "solo_fold_ms", "ingest_wall_ms", "fold_wall_ms", "seal_wall_ms",
+    "solo_fold_ms")} | {m + ".fleet" for m in (
+    "ingest_wall_ms", "fold_wall_ms", "seal_wall_ms",
     "unattributed_ms", "device_launches", "h2d_bytes_per_op", "d2h_bytes_per_op",
     "d2h_pulls_per_tenant")}
 # the overlay tests/cellbench/test_cellbench.py lays over every fleet cell
 OVERLAY = {"tenants": 6, "members": 16, "initial_files_per_device": 8}
-# 12 tenants, vocabularies 64 down to 5: rank 1 (16 writers) is past a
-# cells_cap of 256, rank 2 sits at it, the rest fall into two more classes
-SPILL = {"tenants": 12, "members": 64, "members_floor": 4, "team_ranks": 1,
-         "initial_files_per_device": 1, "serve": {"cells_cap": 256}}
+# 12 tenants, several bucket classes and a solo spill
+# (tests/cellbench/toys/<cell>.json says why)
+SPILL = checks.toy(run.load_json(ROOT, "BENCHMARK.json"), ROOT, CELL)
 # 24 tenants whose vocabularies are still filling: tenants change class, and
 # buckets their slot count, from one round to the next; rank 2's head (1,024
 # ops) is past rows_cap, so it folds alone there and first cuts in round 1
@@ -125,9 +127,8 @@ def check_the_thirteen(root: str) -> None:
 
 
 def test_cell_runs_several_bucket_classes_and_a_solo_spill(capsys):
-    shrink = {"config": SPILL, "traffic": {"active_tenants": 12, "max_ops_per_s": 4000}}
     assert run.run_cell(CELL, 2**31 + 26, 0.5, True, require_tpu=False,
-                        shrink=shrink) == 0
+                        shrink=SPILL) == 0
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     metrics = {k: v["value"] for k, v in line["metrics"].items()}
@@ -139,7 +140,7 @@ def test_cell_runs_several_bucket_classes_and_a_solo_spill(capsys):
     assert metrics["warm_hit_pct.fleet_zipf"] == pytest.approx(100 * 11 / 12)
     check_the_thirteen(ROOT)
     checks.check_toy_line(ROOT, CELL, metrics)
-    assert THIRTEEN - set(metrics) == {"device_launches.fleet_zipf"}, "the CPU has no device trace"
+    assert THIRTEEN - set(metrics) == {"device_launches.fleet"}, "the CPU has no device trace"
 
 
 # ------------------------------------- the driver's warm-up, the span tree
@@ -236,7 +237,7 @@ def test_span_tree_stays_closed_with_the_solo_span(drifted):
     assert snap["spans"]["serve.solo"]["count"] == 1
     assert "serve.solo" in tree["serve.phase.fallback"]
     # the metric's children are still the spans that partition the root
-    spec = run.load_json(ROOT, "cellbench", "layer_metrics", "unattributed_ms.fleet_zipf.json")
+    spec = run.load_json(ROOT, "cellbench", "layer_metrics", "unattributed_ms.fleet.json")
     parts = sorted(part for child in tree["serve.run_cycle"]
                    for part in (tree[child] if child == "serve.cycle" else [child]))
     assert spec["args"]["children"] == parts

@@ -1,12 +1,15 @@
 """``orset_folder_10k.backlog`` (PR 41): BASELINE.json's config 3 whole, with
 its planes resident on the chip.  The configuration is the 1,000-device
-folder's but for its scale; every ``.folder_10k`` copy is its ``.folder``
-original but for name, driver and cell; the driver refuses a program whose
-routing does not keep the planes on the chip; the module strings of the two
-kernels' metrics are pinned against the jitted functions the product path
-calls; and **a traced toy line carries every metric the cell lists** but the
-readings of the device trace: the rule PR 40 was refused for (a listed metric
-of a span its program could not reach was missing from the line).
+folder's but for its scale; the cell reads the solo folder's metrics by being
+listed in their entries (ISSUE 43: its driver ``folder_10k`` is of the family
+``folder``; the ``.folder_10k`` copies it came with are gone) and three that
+came with it, which the 1,000-device cells read too since they fold over
+resident planes as well; the driver refuses a program whose routing does not
+keep the planes on the chip; the module strings of the gather's two metrics
+are pinned against the jitted function the product path calls; and **a traced
+toy line carries every metric the cell lists** but the readings of the device
+trace: the rule PR 40 was refused for (a listed metric of a span its program
+could not reach was missing from the line).
 
 Nothing here is a measurement: the toy run is on the CPU at toy sizes.
 """
@@ -18,27 +21,34 @@ import pytest
 
 from cellbench import gather_bytes, run
 from cellbench.drivers import folder, folder_10k
-from cellbench.readers import gather_roofline_pct
+from cellbench.readers import counter_ratio, gather_roofline_pct, trace_kernel_ms
 
 import manifest_checks as checks
 
 ROOT = run.ROOT
 MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
 CELL = "orset_folder_10k.backlog"
-SUFFIX = ".folder_10k"
+SOLO = ["orset_folder_1k.backlog", "orset_folder_1k.trickle"]
 
-# eleven, which fill the manifest's per_layer to its limit of 128: the cell
-# lists no kernel's time beside its roofline, nor a count d2h_bytes_per_op implies
-COPIED = ["device_row_pct", "orset_fold_roofline", "h2d_bytes_per_op",
-          "d2h_bytes_per_op", "delta_plan_ms", "delta_seal_ms", "storage_ms",
-          "writeback_ms"]
-OWN = ["plane_cache_hit_pct", "gather_roofline", "fold_pull_ms"]
+# the solo folder's entries that list the cell: the eight it had copies of,
+# the four PR 41 had to cut for want of room, the five ISSUE 43 adds
+SHARED = [m + ".folder" for m in (
+    "device_row_pct", "orset_fold_roofline", "h2d_bytes_per_op", "d2h_bytes_per_op",
+    "delta_plan_ms", "delta_seal_ms", "storage_ms", "writeback_ms",
+    "fold_kernel_ms", "device_launches", "aead_ms", "ingest_load_ms",
+    "gather_kernel_ms", "cells_pulled_per_op", "delta_base_reuse_pct",
+    "delta_verify_pack_ms", "delta_seal_only_ms")]
+# what came with the cell keeps its name
+OWN = [m + ".folder_10k" for m in ("plane_cache_hit_pct", "gather_roofline", "fold_pull_ms")]
 # what reads the profiler's trace of the device: absent from a CPU line
-DEVICE_ONLY = {m + SUFFIX for m in ("orset_fold_roofline", "gather_roofline")}
-# the device programs the two kernels' metrics match
+DEVICE_ONLY = {"orset_fold_roofline.folder", "fold_kernel_ms.folder",
+               "device_launches.folder", "gather_kernel_ms.folder",
+               "gather_roofline.folder_10k"}
+# the device programs the kernels' metrics match
 FOLD = ["_fold_ablk", "_fold_wide", "orset_fold"]
 GATHER = ["orset_gather_cells"]
-PINS = {"orset_fold_roofline.folder_10k": FOLD, "gather_roofline.folder_10k": GATHER}
+PINS = {"gather_roofline.folder_10k": GATHER, "gather_kernel_ms.folder": GATHER,
+        "orset_fold_roofline.folder": FOLD, "fold_kernel_ms.folder": FOLD}
 
 
 def spec_of(metric: str) -> dict:
@@ -48,12 +58,11 @@ def spec_of(metric: str) -> dict:
 # ------------------------------------------------ the manifest and the files
 
 
-def test_the_cell_lists_exactly_these_and_no_metric_of_the_host_sparse_fold():
+def test_the_cell_lists_these_and_no_metric_its_program_cannot_reach():
     listed = checks.listed(ROOT, CELL)
-    assert set(listed) == {m + SUFFIX for m in COPIED + OWN}
-    assert len(MANIFEST["per_layer"]) <= 128, "the manifest's own limit"
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names.index("seal_job_return_ms.fleet_zipf") < min(names.index(n) for n in listed)
+    assert set(SHARED + OWN) <= set(listed)
+    # a toy round takes the per-file path, where no decode span opens (PR 41)
+    assert "decode_ms.folder" not in listed
     for name, spec in listed.items():
         text = json.dumps(spec)
         # spans and counters the delivered program cannot reach in this cell
@@ -63,25 +72,16 @@ def test_the_cell_lists_exactly_these_and_no_metric_of_the_host_sparse_fold():
     entry = checks.entry_of(MANIFEST, "workloads", CELL)
     assert (entry["chips"], entry["traffic"], entry["config"]) == (1, "backlog", "orset_folder_10k")
     for metric in ("compact_ops_per_s", "compact_ms"):
-        assert checks.entry_of(MANIFEST, "end_to_end", metric)["workloads"][-1] == CELL
+        assert CELL in checks.entry_of(MANIFEST, "end_to_end", metric)["workloads"]
 
 
-@pytest.mark.parametrize("base", COPIED)
-def test_copy_is_its_original_but_for_name_driver_and_cell(base):
-    spec, original = spec_of(base + SUFFIX), spec_of(base + ".folder")
-    assert spec == {**original, "name": base + SUFFIX, "driver": "folder_10k"}
-    entry = checks.entry_of(MANIFEST, "per_layer", base + SUFFIX)
-    assert entry["workloads"] == [CELL]
-    was = checks.entry_of(MANIFEST, "per_layer", base + ".folder")
-    assert {**was, "name": entry["name"], "workloads": [CELL]} == entry
-
-
-@pytest.mark.parametrize("metric", OWN)
-def test_own_metric_file_agrees_with_its_entry(metric):
-    checks.check_layer_metric(MANIFEST, ROOT, metric + SUFFIX)
-    spec = spec_of(metric + SUFFIX)
-    assert spec["driver"] == "folder_10k" and spec["what"]
-    assert checks.entry_of(MANIFEST, "per_layer", metric + SUFFIX)["workloads"] == [CELL]
+@pytest.mark.parametrize("metric", OWN + ["gather_kernel_ms.folder",
+                                          "cells_pulled_per_op.folder"])
+def test_metric_of_the_resident_fold_is_read_by_every_cell_that_folds_so(metric):
+    checks.check_layer_metric(MANIFEST, ROOT, metric)
+    spec = spec_of(metric)
+    assert spec["driver"] == "folder" and spec["what"]
+    assert checks.entry_of(MANIFEST, "per_layer", metric)["workloads"] == SOLO + [CELL]
 
 
 def test_configuration_is_config_3_whole_and_the_solo_folder_otherwise():
@@ -200,7 +200,7 @@ def plane(name, **lines):
 
 
 def test_gather_roofline_reads_the_gathers_events_over_the_rounds_shapes():
-    args = spec_of("gather_roofline" + SUFFIX)["args"]
+    args = spec_of("gather_roofline.folder_10k")["args"]
     host = plane("/host:CPU", python=[["cellbench.call", 0.0, 1e9]])
     dev = plane("/device:TPU:0", XLA_Modules=[
         ["jit_orset_gather_cells(1)", 10.0, 2e6],    # 2 ms, in ns
@@ -219,6 +219,22 @@ def test_gather_roofline_reads_the_gathers_events_over_the_rounds_shapes():
     only_fold = {"planes": [host, plane("/device:TPU:0", XLA_Modules=[
         ["jit__fold_ablk(2)", 20.0, 8e6]])]}
     assert gather_roofline_pct.read({**window, "trace": only_fold}, args) is None
+    # the roofline's denominator by itself: the gather's 4 ms over two calls
+    kernel = spec_of("gather_kernel_ms.folder")["args"]
+    assert kernel["match"] == args["match"] and kernel["line"] == args["line"]
+    assert trace_kernel_ms.read(window, kernel) == pytest.approx(2.0)
+    assert trace_kernel_ms.read({**window, "trace": only_fold}, kernel) is None
+
+
+def test_base_reuse_share_reads_the_two_counters_of_a_host_route_plan():
+    args = spec_of("delta_base_reuse_pct.folder")["args"]
+    window = {"calls": 4, "ops": 100, "spans": {}, "trace": None, "shapes": [],
+              "peaks": {}, "counters": {"delta_base_reused": 3, "delta_base_unpacked": 1}}
+    assert counter_ratio.read(window, args) == 75.0
+    assert counter_ratio.read({**window, "counters": {"delta_base_reused": 4}}, args) == 100.0
+    # a program that unpacks every round (the parent of PR 42 has neither counter)
+    assert counter_ratio.read({**window, "counters": {"delta_base_unpacked": 4}}, args) == 0
+    assert counter_ratio.read({**window, "counters": {"ops_folded": 5}}, args) is None
 
 
 # ------------------------------------------------ the cell, end to end (toy)
@@ -233,19 +249,24 @@ def test_traced_toy_line_carries_every_listed_metric_but_the_device_trace(capsys
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert all(c == {"value": 0, "limit": 0} for c in line["compared"].values())
     listed = checks.listed(ROOT, CELL)
-    assert DEVICE_ONLY == {n for n, spec in listed.items()
+    assert DEVICE_ONLY <= {n for n, spec in listed.items()
                            if spec["source"] == "device_trace"}
-    assert set(line["metrics"]) == set(listed) - DEVICE_ONLY
+    assert set(SHARED + OWN) - DEVICE_ONLY <= set(line["metrics"])
     checks.check_toy_line(ROOT, CELL, line["metrics"])
     for name, reading in line["metrics"].items():
         assert reading["unit"] == listed[name]["unit"], name
     value = {k: v["value"] for k, v in line["metrics"].items()}
-    assert value["device_row_pct" + SUFFIX] == 100
-    assert value["plane_cache_hit_pct" + SUFFIX] == 100
+    assert value["device_row_pct.folder"] == 100
+    assert value["plane_cache_hit_pct.folder_10k"] == 100
     # 8 files of 48 ops a round: 384 rows in a class of 512, two words a row
-    assert value["h2d_bytes_per_op" + SUFFIX] == pytest.approx(13 * 512 / 384)
-    assert value["d2h_bytes_per_op" + SUFFIX] == pytest.approx((8 * 512 + 4 * 8) / 384)
-    assert value["fold_pull_ms" + SUFFIX] > 0 and value["writeback_ms" + SUFFIX] > 0
+    assert value["h2d_bytes_per_op.folder"] == pytest.approx(13 * 512 / 384)
+    assert value["d2h_bytes_per_op.folder"] == pytest.approx((8 * 512 + 4 * 8) / 384)
+    assert value["cells_pulled_per_op.folder"] == pytest.approx(2 * 512 / 384)
+    assert value["fold_pull_ms.folder_10k"] > 0 and value["writeback_ms.folder"] > 0
+    # every plan of the window diffed against the object the last verify left
+    assert value["delta_base_reuse_pct.folder"] == 100
+    assert 0 < value["delta_verify_pack_ms.folder"] < value["delta_seal_ms.folder"]
+    assert 0 < value["delta_seal_only_ms.folder"] < value["delta_seal_ms.folder"]
 
 
 def test_untraced_toy_line_reports_the_three_end_to_end_metrics(capsys):

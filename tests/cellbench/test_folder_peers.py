@@ -18,13 +18,10 @@ import manifest_checks as checks
 
 ROOT = run.ROOT
 CELL = "orset_folder_peers.backlog"
-# 40 devices: every share has 8, so the measured share's 384 ops a round are
-# past the accelerator's smallest device batch and the op fold keeps planes
-# on the device for the next merge to drop
-TOY = {"config": {"devices": 40, "members": 32, "initial_files_per_device": 3},
-       "traffic": {"active_devices": 40, "max_ops_per_s": 9000}}
+# 40 devices, every share 8 (tests/cellbench/toys/<cell>.json says why)
+TOY = checks.toy(run.load_json(ROOT, "BENCHMARK.json"), ROOT, CELL)
 DEVICE_ONLY = {"merge_kernel_ms.folder_peers", "orset_merge_roofline.folder_peers",
-               "device_launches.folder_peers"}
+               "device_launches.folder"}
 
 
 def toy_driver(workdir: str, seed: int):
@@ -141,8 +138,8 @@ def test_traced_toy_run_reports_the_merge(capsys):
     assert value["snapshot_bytes_per_op.folder_peers"] > 0
     # the stack alone: 5 states x 2 planes of the toy's cells, every call
     ops = 40 * 48
-    assert value["h2d_bytes_per_op.folder_peers"] * ops >= 5 * 2 * 4 * 32 * 40
-    assert value["d2h_bytes_per_op.folder_peers"] * ops >= 2 * 2 * 4 * 32 * 40, (
+    assert value["h2d_bytes_per_op.folder"] * ops >= 5 * 2 * 4 * 32 * 40
+    assert value["d2h_bytes_per_op.folder"] * ops >= 2 * 2 * 4 * 32 * 40, (
         "the merged planes and the folded planes come back through pull")
 
 
@@ -175,21 +172,24 @@ def test_configuration_keeps_the_solo_folders_widths():
     assert len(peers["source"]) <= 200
 
 
-@pytest.mark.parametrize("metric", [
-    "unattributed_ms", "storage_ms", "delta_plan_ms", "delta_seal_ms",
-    "repl_status_ms", "ingest_wait_ms", "h2d_bytes_per_op", "d2h_bytes_per_op",
-    "device_launches",
-])
-def test_copied_metric_reads_what_the_solo_folders_reads(metric):
-    """The timed call is the same ``Core.compact()``: a ``.folder_peers`` copy
-    reads the spans and counters its ``.folder`` original reads (a metric
-    file's driver has to be its cells' configuration's, so the cell cannot
-    list the originals)."""
-    solo = run.load_json(ROOT, "cellbench", "layer_metrics", metric + ".folder.json")
-    copy = run.load_json(ROOT, "cellbench", "layer_metrics", metric + ".folder_peers.json")
-    for key in ("reader", "args", "unit", "better", "source", "layer", "moves"):
-        assert copy[key] == solo[key], key
-    assert copy["driver"] == "folder_peers"
+def test_the_cell_reads_the_solo_folders_metrics_from_the_solo_folders_entries():
+    """The timed call is the same ``Core.compact()``: the cell is listed by
+    the ``.folder`` entries of the spans and counters it shares (ISSUE 43;
+    it had ``.folder_peers`` copies of them before), beside the ten of its
+    own path, which keep their names."""
+    listed = checks.listed(ROOT, CELL)
+    shared = {m + ".folder" for m in (
+        "unattributed_ms", "storage_ms", "delta_plan_ms", "delta_seal_ms",
+        "repl_status_ms", "ingest_wait_ms", "h2d_bytes_per_op", "d2h_bytes_per_op",
+        "device_launches", "native_chunk_reads_pct", "gc_pause_ms",
+        "gc_full_pause_ms", "watermark_ms")}
+    own = {m + ".folder_peers" for m in (
+        "snapshot_ingest_ms", "snapshot_merge_ms", "merge_host_ms",
+        "snapshots_per_merge", "plane_cache_drops_per_merge", "snapshot_bytes_per_op",
+        "merge_kernel_ms", "orset_merge_roofline", "op_fold_ms", "delta_read_ms")}
+    assert shared | own <= set(listed)
+    assert all(listed[name]["driver"] == "folder" for name in shared)
+    assert all(listed[name]["driver"] == "folder_peers" for name in own)
 
 
 # ------------------------------------------------------------ the readers

@@ -16,21 +16,26 @@ from cellbench.drivers import folder_peers_delta
 import manifest_checks as checks
 
 ROOT = run.ROOT
+MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
 CELL = "orset_folder_peers_delta.backlog"
 SUFFIX = ".folder_peers_delta"
-# test_folder_peers.py's toy with a wider vocabulary and a longer head: every
-# share has 8 devices, so the measured share's 384 ops a round keep planes on
-# the device for the links to stale, and a peer's state (some 1,300 slots) is
-# well over a round's delta, so that the size guard never keeps a link back
-TOY = {"config": {"devices": 40, "members": 256, "initial_files_per_device": 6},
-       "traffic": {"active_devices": 40, "max_ops_per_s": 9000}}
-NEW = ["delta_ingest_ms", "delta_apply_ms", "delta_route_pct", "delta_links_per_pass",
-       "delta_fallbacks_pct", "delta_bytes_per_op", "delta_slots_per_op",
-       "plane_cache_drops_per_pass"]
-COPIED = ["delta_read_ms", "op_fold_ms", "ingest_wait_ms", "storage_ms",
-          "delta_plan_ms", "delta_seal_ms", "repl_status_ms", "unattributed_ms",
-          "h2d_bytes_per_op", "d2h_bytes_per_op", "device_launches"]
-DEVICE_ONLY = {"device_launches" + SUFFIX}
+# test_folder_peers.py's toy with a wider vocabulary and a longer head
+# (tests/cellbench/toys/<cell>.json says why)
+TOY = checks.toy(MANIFEST, ROOT, CELL)
+NEW = [m + SUFFIX for m in (
+    "delta_ingest_ms", "delta_apply_ms", "delta_route_pct", "delta_links_per_pass",
+    "delta_fallbacks_pct", "delta_bytes_per_op", "delta_slots_per_op",
+    "plane_cache_drops_per_pass")]
+# the pass that was the snapshot merge's preamble is this cell's whole route:
+# the peers folder's file with another layer, so a definition of its own
+OWN_LAYER = "delta_read_ms" + SUFFIX
+# what the cell shares with the peers folder and with the solo folder, read
+# from their entries (ISSUE 43; it had copies of all ten before)
+SHARED = ["op_fold_ms.folder_peers"] + [m + ".folder" for m in (
+    "ingest_wait_ms", "storage_ms", "delta_plan_ms", "delta_seal_ms",
+    "repl_status_ms", "unattributed_ms", "h2d_bytes_per_op", "d2h_bytes_per_op",
+    "device_launches")]
+DEVICE_ONLY = {"device_launches.folder"}
 
 
 def toy_driver(workdir: str, seed: int, rounds: int = 3):
@@ -83,7 +88,7 @@ def test_configuration_is_the_peers_folder_but_for_what_syncs():
         assert delta["assumed"][key] == peers["assumed"][key]
     assert len(delta["source"]) <= 200
     assert not {"withhold_peer", "withhold_link"} & set(delta)
-    entry = next(c for c in run.load_json(ROOT, "BENCHMARK.json")["configs"]
+    entry = next(c for c in MANIFEST["configs"]
                  if c["name"] == "orset_folder_peers_delta")
     assert entry["reduced"] == ["devices", "initial_ops"] and entry["source"] == delta["source"]
 
@@ -188,7 +193,7 @@ def test_link_a_toy_peer_published_is_the_plain_diff_of_its_two_states(seed, tmp
 
 def check_new_and_copied_are_listed(root: str) -> None:
     """The nineteen the cell came with (PR 32) are among what it lists."""
-    assert {m + SUFFIX for m in NEW + COPIED} <= set(checks.listed(root, CELL))
+    assert set(NEW + [OWN_LAYER] + SHARED) <= set(checks.listed(root, CELL))
 
 
 def traced(capsys, fault: dict | None = None, seconds: float = 3.0):
@@ -212,7 +217,7 @@ def test_traced_toy_run_takes_every_foreign_state_in_through_a_link(capsys):
     # what reads the device trace finds nothing on the CPU and is left out;
     # every other listed metric is there unless its own file says it may not be
     checks.check_toy_line(ROOT, CELL, line["metrics"])
-    assert {m + SUFFIX for m in NEW + COPIED} - set(line["metrics"]) == DEVICE_ONLY
+    assert set(NEW + [OWN_LAYER] + SHARED) - set(line["metrics"]) == DEVICE_ONLY
     assert value["delta_route_pct" + SUFFIX] == 100, "states_merged is 0 in the window"
     assert value["delta_links_per_pass" + SUFFIX] == 4
     assert value["delta_fallbacks_pct" + SUFFIX] == 0
@@ -282,23 +287,21 @@ def test_a_compactor_keeps_its_peers_bases_after_it_has_gcd_them(tmp_path):
 # ------------------------------------------------------- the metric files
 
 
-@pytest.mark.parametrize("metric", COPIED)
-def test_copied_metric_reads_what_the_peers_folders_reads(metric):
-    """The timed call is the same ``Core.compact()``: a ``.folder_peers_delta``
-    copy reads the spans and counters its ``.folder_peers`` original reads (a
-    metric file's driver has to be its cells' configuration's)."""
-    peers = run.load_json(ROOT, "cellbench", "layer_metrics", metric + ".folder_peers.json")
-    copy = run.load_json(ROOT, "cellbench", "layer_metrics", metric + SUFFIX + ".json")
+def test_delta_read_is_the_peers_folders_file_in_this_cells_layer():
+    peers = run.load_json(ROOT, "cellbench", "layer_metrics", "delta_read_ms.folder_peers.json")
+    own = run.load_json(ROOT, "cellbench", "layer_metrics", OWN_LAYER + ".json")
     for key in ("reader", "args", "unit", "better", "source", "moves"):
-        assert copy[key] == peers[key], key
-    assert copy["driver"] == "folder_peers_delta"
-    # the pass that was the snapshot merge's preamble is this cell's whole route
-    assert copy["layer"] == ("delta consumer" if metric == "delta_read_ms" else peers["layer"])
+        assert own[key] == peers[key], key
+    assert (own["driver"], own["layer"]) == ("folder_peers_delta", "delta consumer")
+    assert peers["layer"] == "snapshot merge"
+    # and the fold that follows is the peers folder's entry, both cells in it
+    entry = checks.entry_of(MANIFEST, "per_layer", "op_fold_ms.folder_peers")
+    assert entry["workloads"] == ["orset_folder_peers.backlog", CELL]
 
 
 @pytest.mark.parametrize("metric", NEW)
 def test_new_metric_uses_a_reader_that_is_there_and_names_no_kernel(metric):
-    spec = run.load_json(ROOT, "cellbench", "layer_metrics", metric + SUFFIX + ".json")
+    spec = run.load_json(ROOT, "cellbench", "layer_metrics", metric + ".json")
     assert spec["reader"] in ("span_ms", "counter_ratio", "counter_per_op")
     assert spec["layer"] == "delta consumer" and "match" not in spec["args"]
     assert "roofline" not in metric, "no new kernel, so no new roofline share"

@@ -4,13 +4,19 @@ edit no file the benchmark has, its tests among them.
 
 A temporary root gets ``BENCHMARK.json``, the data directories and the test
 files, byte for byte, and then what such a PR would bring: a configuration
-with ``driver: folder`` and no "folder" in its name, a fifth mix, a one-chip
-cell on them, a four-chip cell, a span metric of the layer "host runtime:
-waits and pauses" whose file names two drivers, a kernel metric with
-``args.modules`` and the test file that pins its module strings.  Every
-manifest-level check of the benchmark's tests is then made on that root
-through the functions the tests themselves call, the one-chip cell runs at toy
-size on the CPU, and what was there is still there, in its place.
+whose ``driver`` is a new module ``folder_scratch`` (a subclass of
+``folder.Driver``) and whose name has no "folder" in it, a fifth mix, a
+one-chip cell on them, a four-chip cell, and its per-layer metrics **both
+ways in** (ISSUE 43): the cell takes ``storage_ms.folder`` and
+``gc_pause_ms.folder`` by appending its name to those entries' ``workloads``
+and nothing else (its driver is of the family ``folder``; no metric file is
+touched, no copy made), and brings what is new as new files with new entries:
+a span metric of the layer "host runtime: waits and pauses" whose file names
+two families, a kernel metric with ``args.modules`` and the test file that
+pins its module strings.  Every manifest-level check of the benchmark's tests
+is then made on that root through the functions the tests themselves call,
+the one-chip cell runs traced at toy size on the CPU with the shared and the
+new metrics in its line, and what was there is still there, in its place.
 
 Which case fails when a pin comes back (ISSUE 39, items 1-7):
 ``test_the_sets_the_tests_pin_are_subsets`` for "the nine are the manifest's
@@ -22,7 +28,7 @@ overlay chosen by the configuration's name or a mix looked up in a table (4);
 ``test_every_cell_agrees...`` for ``chips == 1`` (5);
 ``test_kernel_metric_is_pinned...`` for a table in ``test_new_readers.py``
 that must hold every kernel metric (6); ``test_every_layer_metric_agrees...``
-for a metric file's ``driver`` that must be one name (7).
+for a metric file's ``driver`` that must be one name, or the cell's own (7).
 
 Nothing here is a measurement, and nothing scratch is in the real manifest.
 """
@@ -32,10 +38,13 @@ import glob
 import json
 import os
 import shutil
+import sys
+import types
 
 import pytest
 
 from cellbench import run
+from cellbench.drivers import folder
 
 import manifest_checks as checks
 import test_fleet_zipf as zipf
@@ -46,6 +55,10 @@ ROOT = run.ROOT
 REAL = run.load_json(ROOT, "BENCHMARK.json")
 CELL, CELL4 = "orset_scratch.drip", "orset_scratch.backlog"
 SPAN = "scratch_gc_ms.folders"
+# what the cell shares with the solo folder: taken by its name appended to
+# these entries' ``workloads``, and by nothing else
+SHARED = ["storage_ms.folder", "gc_pause_ms.folder"]
+DRIVER = "folder_scratch"
 # in two parts, so that this file does not itself name the kernel metric whole
 # and pass for the test file that pins it
 KERNEL = "scratch_fold_kernel_ms" + ".folder"
@@ -74,7 +87,7 @@ def added(tmp_path_factory):
         shutil.copy(path, tests)
 
     config = run.load_json(ROOT, "cellbench", "configs", "orset_folder_1k.json")
-    config["name"] = "orset_scratch"
+    config.update(name="orset_scratch", driver=DRIVER)
     write(bench / "configs" / "orset_scratch.json", config)
     write(bench / "traffic" / "drip.json", {
         "name": "drip", "what": "every round three devices wrote one op file",
@@ -91,7 +104,7 @@ def added(tmp_path_factory):
     span = {"name": SPAN, "unit": "ms", "better": "lower", "source": "program_span",
             "layer": waits.LAYER, "moves": "compact_ms"}
     write(bench / "layer_metrics" / (SPAN + ".json"), {
-        **span, "driver": ["folder", "folder_peers_delta"], "reader": "span_ms",
+        **span, "driver": [DRIVER, "folder_peers_delta"], "reader": "span_ms",
         "args": {"spans": ["compact.gc"]}})
     kernel = {"name": KERNEL, "unit": "ms", "better": "lower", "source": "device_trace",
               "layer": "fold kernels", "moves": "compact_ops_per_s"}
@@ -109,11 +122,22 @@ def added(tmp_path_factory):
     manifest["per_layer"].append({**span, "workloads": [
         CELL, CELL4, "orset_folder_peers_delta.backlog"]})
     manifest["per_layer"].append({**kernel, "workloads": [CELL]})
+    for name in SHARED:
+        checks.entry_of(manifest, "per_layer", name)["workloads"].append(CELL)
     for m in manifest["end_to_end"]:
         if m["name"] in ("compact_ms", "compact_ops_per_s"):
             m["workloads"] += [CELL, CELL4]
     write(root / "BENCHMARK.json", manifest)
     return str(root), manifest
+
+
+@pytest.fixture
+def scratch_driver(monkeypatch):
+    """The driver module such a PR brings as ``cellbench/drivers/folder_scratch.py``
+    (code is always the checkout's, so here it is put where the import finds it)."""
+    module = types.ModuleType(f"cellbench.drivers.{DRIVER}")
+    module.Driver = type("Driver", (folder.Driver,), {})
+    monkeypatch.setitem(sys.modules, module.__name__, module)
 
 
 # ----------------------------------------- (c) what was there is still there
@@ -122,16 +146,23 @@ def added(tmp_path_factory):
 def test_additions_are_a_suffix_of_each_list_and_nothing_else(added):
     root, manifest = added
     same = lambda a, b: json.dumps(a) == json.dumps(b)  # noqa: E731
-    for kind in ("configs", "workloads", "per_layer"):
+    for kind in ("configs", "workloads"):
         assert same(manifest[kind][:len(REAL[kind])], REAL[kind]), kind
         assert len(manifest[kind]) > len(REAL[kind])
-    # an end-to-end entry is what it was; a cell that reports the metric is
+    # a metric entry is what it was; a cell that reports the metric is
     # appended to its ``workloads``, as every PR that added a cell has done
+    # with the end-to-end entries and, since ISSUE 43, does with the per-layer
+    # entries whose spans and counters it shares
     assert len(manifest["end_to_end"]) == len(REAL["end_to_end"])
-    for got, was in zip(manifest["end_to_end"], REAL["end_to_end"]):
-        cells = was.get("workloads", [])
-        assert same({**got, "workloads": got.get("workloads", [])[:len(cells)]},
-                    {**was, "workloads": cells})
+    assert len(manifest["per_layer"]) == len(REAL["per_layer"]) + 2
+    for kind in ("end_to_end", "per_layer"):
+        for got, was in zip(manifest[kind], REAL[kind]):
+            cells = was.get("workloads", [])
+            assert same({**got, "workloads": got.get("workloads", [])[:len(cells)]},
+                        {**was, "workloads": cells})
+            added_cells = got.get("workloads", [])[len(cells):]
+            if kind == "per_layer":
+                assert added_cells == ([CELL] if got["name"] in SHARED else []), got["name"]
     for key in ("command", "paths", "run_seconds"):
         assert manifest[key] == REAL[key]
     # and every file that was there, the tests among them, byte for byte
@@ -165,7 +196,19 @@ def test_every_layer_metric_agrees_and_a_file_may_name_several_drivers(added):
     root, manifest = added
     for m in manifest["per_layer"]:
         checks.check_layer_metric(manifest, root, m["name"])
-    # the span metric lists cells of two drivers; a driver it does not name fails
+    # the shared entries admit the cell by its driver's prefix, with their files
+    # byte for byte what they were (``test_additions_are_a_suffix...``)
+    for name in SHARED:
+        assert CELL in checks.entry_of(manifest, "per_layer", name)["workloads"]
+        assert run.load_json(root, "cellbench", "layer_metrics", name + ".json")["driver"] == "folder"
+    assert checks.config_of(manifest, root, CELL)["driver"] == DRIVER
+    # a fleet's entry does not: the family is a prefix of the driver module's name
+    wrong = json.loads(json.dumps(manifest))
+    checks.entry_of(wrong, "per_layer", "seal_ms.fleet")["workloads"].append(CELL)
+    with pytest.raises(AssertionError):
+        checks.check_layer_metric(wrong, root, "seal_ms.fleet")
+    checks.check_no_two_files_define_the_same(manifest, root)
+    # the span metric lists cells of two families; a driver of neither fails
     wrong = json.loads(json.dumps(manifest))
     checks.entry_of(wrong, "per_layer", SPAN)["workloads"].append("orset_folder_peers.backlog")
     with pytest.raises(AssertionError):
@@ -215,7 +258,8 @@ def test_overlay_is_chosen_by_the_driver_and_a_fifth_mix_by_its_file(added):
 # ------------------------- (b) the one-chip cell, end to end at toy size
 
 
-def test_one_chip_cell_runs_and_its_traced_line_carries_the_appended_metric(added, capsys):
+@pytest.mark.usefixtures("scratch_driver")
+def test_one_chip_cell_runs_and_its_traced_line_carries_shared_and_new_metrics(added, capsys):
     root, manifest = added
     shrink = checks.tiny(manifest, root, CELL)
     assert run.run_cell(CELL, 2**31 + 39, 0.5, True, root=root,
@@ -224,8 +268,11 @@ def test_one_chip_cell_runs_and_its_traced_line_carries_the_appended_metric(adde
     assert set(line) == {"correct", "attempted", "failed", "metrics", "device",
                          "breakdown", "compared"}
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
-    assert set(line["metrics"]) == {SPAN}, "the kernel metric has no device trace to read"
+    assert set(line["metrics"]) == {SPAN, *SHARED}, (
+        "the kernel metric has no device trace to read")
     assert line["metrics"][SPAN]["value"] > 0 and line["metrics"][SPAN]["unit"] == "ms"
+    assert line["metrics"]["storage_ms.folder"]["value"] > line["metrics"][SPAN]["value"], (
+        "compact.gc is one of the four spans storage_ms sums")
     checks.check_toy_line(root, CELL, line["metrics"])
     assert run.run_cell(CELL, 2**31 + 39, 0.5, False, root=root,
                         require_tpu=False, shrink=shrink) == 0
@@ -236,17 +283,17 @@ def test_one_chip_cell_runs_and_its_traced_line_carries_the_appended_metric(adde
 
 def test_toy_line_may_lack_only_what_a_metric_file_says_it_may(added):
     root, _ = added
-    checks.check_toy_line(root, CELL, {SPAN})
+    checks.check_toy_line(root, CELL, {SPAN, *SHARED})
     with pytest.raises(AssertionError):
-        checks.check_toy_line(root, CELL, set())            # a listed host metric
+        checks.check_toy_line(root, CELL, {SPAN})           # a listed, shared host metric
     with pytest.raises(AssertionError):
-        checks.check_toy_line(root, CELL, {SPAN, "unlisted_ms.folder"})
+        checks.check_toy_line(root, CELL, {SPAN, *SHARED, "unlisted_ms.folder"})
     with pytest.raises(AssertionError):
-        checks.check_toy_line(root, CELL, {SPAN, KERNEL})   # no trace on the CPU
-    # the zipf cell's slot wait says ``may_be_absent``; its original does not
+        checks.check_toy_line(root, CELL, {SPAN, *SHARED, KERNEL})  # no trace on the CPU
+    # the slot wait says ``may_be_absent``: a toy fleet opens no such span
     listed = checks.listed(root, zipf.CELL)
-    assert listed["slot_wait_ms.fleet_zipf"]["may_be_absent"] is True
+    assert listed["slot_wait_ms.fleet"]["may_be_absent"] is True
     host = {name for name, spec in listed.items() if spec["source"] != "device_trace"}
-    checks.check_toy_line(root, zipf.CELL, host - {"slot_wait_ms.fleet_zipf"})
+    checks.check_toy_line(root, zipf.CELL, host - {"slot_wait_ms.fleet"})
     with pytest.raises(AssertionError):
-        checks.check_toy_line(root, zipf.CELL, host - {"seal_job_pct.fleet_zipf"})
+        checks.check_toy_line(root, zipf.CELL, host - {"seal_job_pct.fleet"})
