@@ -67,7 +67,9 @@ import random
 import time
 from dataclasses import dataclass, field
 
+from ..models import ORSet
 from ..utils import trace
+from .bucketing import _bucket
 from .service import FoldService, ServeConfig
 from .warm import DEFAULT_BYTE_BUDGET
 
@@ -159,6 +161,8 @@ class TenantEntry:
     last_sealed: int = -1
     quarantined_at: int | None = None
     last_error: str | None = None
+    # what admission projected for this tenant's warm planes, bytes
+    cost_bytes: int = 0
 
     def status(self) -> dict | None:
         return getattr(self.core, "last_replication_status", None)
@@ -181,6 +185,7 @@ class FleetDaemon:
             [], self.config.serve, live_port=live_port, mesh=mesh
         )
         self._entries: dict[str, TenantEntry] = {}
+        self._cost_bytes = 0  # summed TenantEntry.cost_bytes
         self._rng = random.Random(f"crdt-daemon-{seed}")
         self._cycle = 0
         # the deterministic-clock seam: every wall-time read (uptime,
@@ -218,14 +223,28 @@ class FleetDaemon:
     def entry(self, tid: str) -> TenantEntry | None:
         return self._entries.get(tid)
 
-    def _admission_cost(self) -> int:
-        """Per-tenant resident-bytes estimate for the admission gate:
-        the warm tier's OBSERVED mean entry size once it has data, the
-        configured estimate before that."""
+    def _admission_cost(self, core) -> tuple[int, bool]:
+        """``core``'s resident-bytes estimate for the admission gate, and
+        whether it is the tenant's own (else one estimate stands for
+        every tenant of the fleet): the warm tier's OBSERVED mean entry
+        size once it has data.  Before that, under the warm budget
+        itself (``admission_bytes`` 0), what the tenant's planes would
+        hold in the tier: its state's members × replicas at the
+        planner's size classes, 4 B a cell, the planes a
+        :class:`~.warm.WarmEntry` counts (clock, add, rm) — a thousand
+        small folders are a few MB, whatever ``tenant_cost_bytes`` says.
+        The configured estimate answers where a state cannot say (no
+        OR-Set planes to size), and under an operator's own
+        ``admission_bytes``, whose unit it is."""
         warm = self.service.warm
         if warm is not None and len(warm):
-            return max(1, warm.bytes_held // len(warm))
-        return self.config.tenant_cost_bytes
+            return max(1, warm.bytes_held // len(warm)), False
+        state = getattr(getattr(core, "_data", None), "state", None)
+        if self.config.admission_bytes or not isinstance(state, ORSet):
+            return self.config.tenant_cost_bytes, False
+        e_b = _bucket(len(state.entries.keys() | state.deferred.keys()))
+        r_b = _bucket(len(state.clock.counters))
+        return 4 * (r_b + 2 * e_b * r_b), True
 
     def _admit_locked(self, core, tid: str) -> TenantEntry:
         if self.state != "running":
@@ -237,14 +256,19 @@ class FleetDaemon:
                 f"fleet full ({len(self._entries)} tenants)"
             )
         budget = self.config.admission_bytes or self.config.serve.warm_bytes
-        projected = (len(self._entries) + 1) * self._admission_cost()
+        cost, own = self._admission_cost(core)
+        projected = cost + (
+            self._cost_bytes if own else len(self._entries) * cost
+        )
         if projected > budget:
             raise AdmissionError(
-                f"byte budget: {len(self._entries) + 1} tenants × "
-                f"{self._admission_cost()}B/tenant > {budget}B warm budget"
+                f"byte budget: {len(self._entries) + 1} tenants, "
+                f"{projected}B projected ({cost}B this tenant) > "
+                f"{budget}B warm budget"
             )
-        entry = TenantEntry(tid, core)
+        entry = TenantEntry(tid, core, cost_bytes=cost)
         self._entries[tid] = entry
+        self._cost_bytes += cost
         trace.add("daemon_admitted", 1)
         return entry
 
@@ -290,6 +314,7 @@ class FleetDaemon:
             entry = self._entries.pop(tid, None)
             if entry is None:
                 raise KeyError(f"unknown tenant {tid!r}")
+            self._cost_bytes -= entry.cost_bytes
             self._fail_waiters(tid, "evicted")
             if checkpoint:
                 try:
@@ -310,7 +335,9 @@ class FleetDaemon:
         path and must be safe to repeat.  Pending freshness waiters
         fail loudly, exactly as on evict."""
         async with self._lock:
-            if self._entries.pop(tid, None) is not None:
+            entry = self._entries.pop(tid, None)
+            if entry is not None:
+                self._cost_bytes -= entry.cost_bytes
                 self._fail_waiters(tid, "discarded")
                 trace.add("daemon_evicted", 1)
 
@@ -390,20 +417,37 @@ class FleetDaemon:
             "results": {},
         }
 
-        # ---- state-machine transitions into this cycle
-        probes: list[TenantEntry] = []
-        for entry in self._entries.values():
-            if entry.state == BACKOFF and cycle >= entry.eligible_at:
-                entry.state = ACTIVE  # re-probe path
-            elif entry.state == QUARANTINED:
-                parked = cycle - (entry.quarantined_at or cycle)
-                if parked and parked % cfg.quarantine_probe_every == 0:
-                    probes.append(entry)
+        # ---- state-machine transitions into this cycle, the due filter
+        # and the score sort: the scheduler's own time (daemon.select)
+        sel = trace.span("daemon.select")  # meta: tenants due
+        with sel:
+            probes: list[TenantEntry] = []
+            for entry in self._entries.values():
+                if entry.state == BACKOFF and cycle >= entry.eligible_at:
+                    entry.state = ACTIVE  # re-probe path
+                elif entry.state == QUARANTINED:
+                    parked = cycle - (entry.quarantined_at or cycle)
+                    if parked and parked % cfg.quarantine_probe_every == 0:
+                        probes.append(entry)
 
-        candidates = [
-            e for e in self._entries.values() if e.state == ACTIVE
-        ]
-        target = self._slo_target()
+            candidates = [
+                e for e in self._entries.values() if e.state == ACTIVE
+            ]
+            target = self._slo_target()
+            due: list[TenantEntry] = []
+            selected: list[TenantEntry] = []
+            if not self.degraded:
+                due = sorted(
+                    (e for e in candidates if self._due(e, target)),
+                    key=lambda e: self._score(e, target), reverse=True,
+                )
+                selected = due[: max(1, cfg.batch)]
+            sel.meta = len(due)
+        # all four every cycle, 0 where that is the count: a reader
+        # tells "none" from "not counted"
+        trace.add("daemon_due", len(due))
+        trace.add("daemon_selected", len(selected))
+        trace.add("daemon_deferred", len(due) - len(selected))
 
         if self.degraded:
             # breaker open: shed decrypt/decode — poll only, except the
@@ -419,11 +463,6 @@ class FleetDaemon:
                 candidates = [c for c in candidates if c is not probe]
             await self._poll(candidates, report)
         else:
-            due = sorted(
-                (e for e in candidates if self._due(e, target)),
-                key=lambda e: self._score(e, target), reverse=True,
-            )
-            selected = due[: max(1, cfg.batch)]
             if probes:
                 # one quarantined re-probe per cycle, APPENDED past the
                 # batch cap and outside the due filter — the ring's
@@ -612,6 +651,7 @@ class FleetDaemon:
         failures ride the same backoff machine — an unreachable remote
         backs its tenant off whether it surfaced in a seal or a poll."""
         entries = [e for e in entries if e.state == ACTIVE]
+        trace.add("daemon_polled", len(entries))
         if not entries:
             return
         sem = asyncio.Semaphore(max(1, self.config.serve.io_width))
@@ -731,31 +771,48 @@ class FleetDaemon:
         self.service.close()
         return errors
 
-    async def run_forever(self, *, max_cycles: int = 0) -> None:
-        """The supervised loop: cycle, pace by ``interval_s``, drain on
-        request (or after ``max_cycles`` > 0 — the bounded CI smoke).
-        A cycle that raises unexpectedly is logged and the loop keeps
-        going — the daemon only stops on drain."""
+    async def step(self, *, pace: bool = True) -> dict | None:
+        """One pass of the supervised loop, as :meth:`run_forever` makes
+        it: a cycle, then the pacing wait of :meth:`next_interval` that a
+        drain request cuts short (span ``daemon.pace``; ``pace=False`` is
+        the last pass of a bounded run, which does not wait).  A cycle
+        that raises unexpectedly is logged and survived (the pass then
+        returns ``None``); the ``RuntimeError`` of a drained daemon is
+        raised.  Returns the cycle's report."""
+        report = None
         try:
-            while not self._drain_requested.is_set():
-                try:
-                    await self.run_cycle()
-                except RuntimeError:
-                    raise  # drained under us: stop, don't spin
-                except Exception:
-                    logger.exception(
-                        "supervised cycle %d failed; continuing",
-                        self._cycle,
-                    )
-                if max_cycles and self._cycle >= max_cycles:
-                    break
+            report = await self.run_cycle()
+        except RuntimeError:
+            raise  # drained under us: stop, don't spin
+        except Exception:
+            logger.exception(
+                "supervised cycle %d failed; continuing", self._cycle
+            )
+        if pace:
+            interval = self.next_interval()
+            with trace.span("daemon.pace", meta=interval):
                 try:
                     await asyncio.wait_for(
-                        self._drain_requested.wait(),
-                        timeout=self.next_interval(),
+                        self._drain_requested.wait(), timeout=interval
                     )
                 except asyncio.TimeoutError:
                     pass
+        return report
+
+    async def run_forever(self, *, max_cycles: int = 0) -> None:
+        """The supervised loop: :meth:`step` after :meth:`step`, paced by
+        ``interval_s``, drain on request (or after ``max_cycles`` > 0 —
+        the bounded CI smoke).  A cycle that raises unexpectedly is
+        logged and the loop keeps going — the daemon only stops on
+        drain."""
+        try:
+            while not self._drain_requested.is_set():
+                # every run_cycle that does not raise RuntimeError
+                # counts one, so the last pass is known before it runs
+                last = bool(max_cycles) and self._cycle + 1 >= max_cycles
+                await self.step(pace=not last)
+                if last:
+                    break
         finally:
             await self.drain()
 

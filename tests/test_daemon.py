@@ -565,3 +565,209 @@ def test_sim_daemon_schedule_runs_clean():
     result = run_schedule(sched)
     assert result.ok, result.violation
     assert result.daemon_cycles == 5
+
+
+# ------------------------------------- step(), the pass run_forever makes
+# (ISSUE 45: the benchmark's timed call is the code the process runs)
+
+
+async def two_tenants(**cfg):
+    """A daemon over one tenant with a backlog and one in sync."""
+    busy_r = MemoryRemote()
+    await seed_tenant(MemoryStorage(busy_r), 12, b"st")
+    busy = await Core.open(make_opts(MemoryStorage(busy_r)))
+    quiet = await Core.open(make_opts(MemoryStorage(MemoryRemote())))
+    await quiet.compact()
+    return FleetDaemon([busy, quiet], quick_cfg(**cfg))
+
+
+@pytest.mark.parametrize("max_cycles", [1, 3])
+def test_run_forever_is_the_loop_over_step(max_cycles):
+    """Same reports, ``max_cycles`` kept, the last pass of a bounded run
+    not paced, drain in ``finally``."""
+
+    async def scenario():
+        daemon = await two_tenants(interval_s=0.01, max_idle_cycles=100)
+        passes, reports = [], []
+        step = daemon.step
+
+        async def watched(*, pace=True):
+            passes.append(pace)
+            reports.append(await step(pace=pace))
+            assert daemon.last_cycle_report is reports[-1]
+            return reports[-1]
+
+        daemon.step = watched
+        await daemon.run_forever(max_cycles=max_cycles)
+        assert passes == [True] * (max_cycles - 1) + [False]
+        assert [r["cycle"] for r in reports] == list(range(1, max_cycles + 1))
+        assert reports[0]["results"]["t0"]["outcome"] == "sealed"
+        assert daemon.cycle == max_cycles and daemon.state == "drained"
+
+    run(scenario())
+
+
+def test_step_survives_a_raising_cycle_and_run_forever_keeps_going():
+    async def scenario():
+        daemon = await two_tenants(interval_s=0.01, max_idle_cycles=100)
+        cycle_locked, calls = daemon._cycle_locked, []
+
+        async def flaky():
+            calls.append(daemon.cycle)
+            if len(calls) == 2:
+                raise ValueError("injected")
+            return await cycle_locked()
+
+        daemon._cycle_locked = flaky
+        assert (await daemon.step())["cycle"] == 1
+        assert await daemon.step() is None  # logged and survived, paced
+        await daemon.run_forever(max_cycles=4)  # cycles 3 and 4
+        assert calls == [1, 2, 3, 4] and daemon.state == "drained"
+        # a drained daemon's RuntimeError is raised, not survived
+        with pytest.raises(RuntimeError):
+            await daemon.step()
+
+    run(scenario())
+
+
+def test_step_paces_by_next_interval_and_a_drain_request_cuts_it_short():
+    async def scenario():
+        trace.reset()
+        daemon = await two_tenants(interval_s=0.2, max_idle_cycles=100)
+        loop = asyncio.get_running_loop()
+        t0 = loop.time()
+        await daemon.step()
+        assert loop.time() - t0 >= 0.2
+        daemon.config.interval_s = 30.0
+        loop.call_later(0.05, daemon.request_drain)
+        t0 = loop.time()
+        await daemon.step()
+        assert loop.time() - t0 < 5.0
+        pace = trace.snapshot()["spans"]["daemon.pace"]
+        assert pace["count"] == 2 and pace["parents"] == [None]
+        assert 0.2 <= pace["seconds"] < 5.2
+        await daemon.drain()
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("batch, due, selected, deferred, polled", [
+    (256, 2, 2, 0, 0),  # first cycle: never-sealed tenants are all due
+    (1, 2, 1, 1, 1),  # the cap leaves one due tenant out, and polls it
+])
+def test_select_span_and_the_four_counters(batch, due, selected, deferred,
+                                           polled):
+    """All four counters every cycle, 0 where that is the count; the
+    scheduler's span first under ``daemon.cycle``."""
+
+    async def scenario():
+        trace.reset()
+        daemon = await two_tenants(batch=batch, max_idle_cycles=100)
+        await daemon.run_cycle()
+        snap = trace.snapshot()
+        got = {k: snap["counters"][k] for k in (
+            "daemon_due", "daemon_selected", "daemon_deferred",
+            "daemon_polled")}  # a KeyError here is "not counted"
+        assert got == {"daemon_due": due, "daemon_selected": selected,
+                       "daemon_deferred": deferred, "daemon_polled": polled}
+        assert snap["spans"]["daemon.select"]["parents"] == ["daemon.cycle"]
+        children = set(trace.tree()["daemon.cycle"])
+        assert {"daemon.select", "serve.run_cycle"} <= children
+        assert children <= {"daemon.select", "serve.run_cycle", "daemon.poll"}
+        # a second cycle at the default cap: nothing due, both polled
+        if batch == 256:
+            await daemon.run_cycle()
+            after = trace.snapshot()["counters"]
+            assert after["daemon_due"] == 2 and after["daemon_deferred"] == 0
+            assert after["daemon_polled"] == 2
+            assert "daemon.poll" in trace.tree()["daemon.cycle"]
+        await daemon.drain()
+
+    run(scenario())
+
+
+# ----------------------------------------------------- admission by shape
+
+
+def stand_in(members: int, replicas: int):
+    """A core as far as admission looks at it: a state of that shape."""
+    import types
+
+    from crdt_enc_tpu.models import ORSet
+
+    state = ORSet()
+    actors = [b"%016d" % r for r in range(replicas)]
+    state.entries = {m: {a: 1 for a in actors[:1]} for m in range(members)}
+    state.clock.counters.update({a: 1 for a in actors})
+    return types.SimpleNamespace(_data=types.SimpleNamespace(state=state))
+
+
+def admitted(cores, **cfg) -> int:
+    """How many of ``cores`` a daemon with the CLI's configuration (and
+    ``cfg`` over it) admits before it refuses one."""
+    daemon = FleetDaemon([], DaemonConfig(interval_s=1.0, **cfg))
+    try:
+        for n, core in enumerate(cores):
+            try:
+                daemon._admit_locked(core, f"t{n}")
+            except AdmissionError as e:
+                assert "byte budget" in str(e)
+                return n
+        return len(cores)
+    finally:
+        daemon.service.close()
+
+
+@pytest.mark.parametrize("cores, cfg, want", [
+    # the benchmark's fleet: 64 x 4 cells in classes 64 x 8, 4,128 B each
+    ([stand_in(64, 4)] * 1024, {}, 1024),
+    # tenants that really are 1 MiB each (512 x 256 cells, 4 B, two planes
+    # and the clock) are refused where the estimate refused them
+    ([stand_in(512, 256)] * 300, {}, 255),
+    # a mixed fleet is the sum of its tenants, not the newcomer's times n
+    ([stand_in(512, 256)] * 200 + [stand_in(64, 4)] * 800, {}, 1000),
+    # an empty state sits at the classes' floor
+    ([stand_in(0, 0)] * 2000, {}, 2000),
+    # a state that cannot say: the configured estimate, as before
+    ([object()] * 300, {}, 256),
+    ([object()] * 300, {"tenant_cost_bytes": 1 << 19}, 300),
+    # an operator's own budget is in the estimate's unit, whatever the shape
+    ([stand_in(64, 4)] * 300, {"admission_bytes": 100 << 20}, 100),
+    ([stand_in(64, 4)] * 300,
+     {"admission_bytes": 1 << 20, "tenant_cost_bytes": 4096}, 256),
+])
+def test_admission_by_shape(cores, cfg, want):
+    assert admitted(cores, **cfg) == want
+
+
+def test_admission_by_the_observed_mean_once_the_warm_tier_has_data():
+    async def scenario():
+        daemon = await two_tenants(max_idle_cycles=100)
+        await daemon.run_cycle()
+        warm = daemon.service.warm
+        assert len(warm) == 1 and warm.bytes_held > 0
+        mean = warm.bytes_held
+        daemon.config.serve.warm_bytes = 3 * mean  # room for one more
+        await daemon.admit(stand_in(512, 256))  # whatever its own shape
+        with pytest.raises(AdmissionError, match=f"{mean}B this tenant"):
+            await daemon.admit(stand_in(0, 0))
+        await daemon.drain()
+
+    run(scenario())
+
+
+def test_eviction_gives_its_share_of_the_budget_back():
+    async def scenario():
+        big = stand_in(512, 256)  # 1 MiB and 1 KiB of planes
+        daemon = FleetDaemon([big] * 255, DaemonConfig(interval_s=1.0))
+        with pytest.raises(AdmissionError):
+            await daemon.admit(big)
+        await daemon.evict("t0", checkpoint=False)
+        await daemon.discard("t1")
+        await daemon.admit(big, tid="a")
+        await daemon.admit(big, tid="b")
+        with pytest.raises(AdmissionError):
+            await daemon.admit(big, tid="c")
+        daemon.service.close()
+
+    run(scenario())
