@@ -2376,13 +2376,20 @@ class Core:
             getattr(d.state, "_mut", None) if _mut is None else _mut,
         )
 
-    def _plan_delta_seal(self, state_obj, cursor_obj, _cut=None):
+    def _plan_delta_seal(self, state_obj, cursor_obj, _cut=None, owned=False):
         """Sync section of the delta seal (docs/delta.md): diff the
         about-to-be-sealed state against the retained base (this
         replica's previous snapshot), self-verify, and hand the await
         half (:meth:`_seal_delta`) an immutable plan.  Runs BEFORE the
         first await of the seal tail so a concurrent apply cannot tear
         the (base, new, delta) triple.
+
+        ``owned`` says that ``state_obj`` is a copy nobody else holds
+        (:meth:`_plan_seal` built it in this slice): a host-route plan
+        that will be verified then keeps it as ``state_obj`` for the
+        verify to compare its applied base against
+        (:meth:`_verify_delta_plan`), which drops it.  The service's
+        object aliases the live entry dicts and is never kept.
 
         The plan always carries ``new_bytes`` — the canonical packed
         state — which becomes the NEXT base even when no delta can be
@@ -2409,6 +2416,7 @@ class Core:
             "base_state": None,
             "base_name": "",
             "base_cursor": None,
+            "state_obj": None,
         }
         base = self._delta_base
         if base is None:
@@ -2474,6 +2482,8 @@ class Core:
         plan["base_state"] = base_state
         plan["base_name"] = base["name"]
         plan["base_cursor"] = base["cursor"]
+        if owned and self._delta_verify:
+            plan["state_obj"] = state_obj
         return plan
 
     def _set_delta_base(
@@ -2514,8 +2524,13 @@ class Core:
     def _verify_delta_plan(self, plan) -> bool:
         """The refusal-to-publish guard (worker thread — the plan owns
         every input, so nothing races the live state): apply the delta
-        to the base copy and require byte-identity with the sealed
-        state.  A codec bug must surface HERE, on the sealer, not as
+        to the base copy and require that it IS the sealed state.  Where
+        the plan kept the object its ``new_bytes`` were packed from, the
+        applied base as an object is compared with that object, both
+        walked together and neither packed (``codec.canon_same``: true
+        only where the two pack to the same bytes); a plan without the
+        object, and every answer but true, is decided by the bytes, as
+        ever.  A codec bug must surface HERE, on the sealer, not as
         divergence scattered across the fleet (``CRDT_DELTA_VERIFY=0``
         opts out)."""
         with trace.span("delta.verify"):
@@ -2539,13 +2554,25 @@ class Core:
                 with trace.span("delta.verify.apply"):
                     plan["codec"].apply(base_state, plan["dobj"])
                 with trace.span("delta.verify.pack"):
-                    return (
-                        codec.pack(self.adapter.state_to_obj(base_state))
-                        == plan["new_bytes"]
-                    )
+                    return self._applied_is_sealed(base_state, plan)
             except Exception:
                 logger.warning("delta verify crashed", exc_info=True)
                 return False
+            finally:
+                # held from the plan to the comparison and no further:
+                # the rest of the tail runs with what it always ran with
+                plan["state_obj"] = None
+
+    def _applied_is_sealed(self, base_state, plan) -> bool:
+        """The comparison of :meth:`_verify_delta_plan`, a frame of its
+        own so that both objects are freed inside its span."""
+        applied = self.adapter.state_to_obj(base_state)
+        sealed, plan["state_obj"] = plan["state_obj"], None
+        if sealed is not None and codec.canon_same(applied, sealed):
+            trace.add("delta_verify_structural", 1)
+            return True
+        trace.add("delta_verify_bytes", 1)
+        return codec.pack(applied) == plan["new_bytes"]
 
     async def _seal_delta(self, plan, name: str, ports, out) -> None:
         """The delta steps of the seal tail (:meth:`_seal_steps`):
@@ -2561,15 +2588,17 @@ class Core:
 
         dp = plan.delta
         if name == dp["base_name"]:
+            dp["state_obj"] = None
             return  # idempotent re-seal of the identical snapshot
         verified = False
         if dp["dobj"] is not None:
             with trace.span("delta.size"):
                 delta_len = len(codec.pack(dp["dobj"]))
             if delta_len >= len(dp["new_bytes"]):
-                # a delta no smaller than the state saves nothing
+                # a delta no smaller than the state saves nothing (and
+                # no verify will read the plan's object)
                 trace.add("delta_seal_skipped", 1)
-                dp["dobj"] = None
+                dp["dobj"] = dp["state_obj"] = None
             elif self._delta_verify:
                 verified = await ports.offload(self._verify_delta_plan, dp)
                 if not verified:
@@ -2755,13 +2784,17 @@ class Core:
         interleave and seal a torn (state, cursor, delta) triple."""
         d = self._data
         key = self._latest_key()
-        if _state_obj is not None and _state_obj[1] == getattr(
+        # who built the object decides whether the delta plan may keep
+        # it: the caller's aliases the live entry dicts and is good for
+        # this slice alone, the one built here is a copy of its own
+        owned = _state_obj is None or _state_obj[1] != getattr(
             d.state, "_mut", None
-        ):
-            state_obj = _state_obj[0]
-        else:
+        )
+        if owned:
             with trace.span("seal.state_obj"):
                 state_obj = self.adapter.state_to_obj(d.state)
+        else:
+            state_obj = _state_obj[0]
         cursor_obj = d.next_op_versions.to_obj()
         snap_mut = getattr(d.state, "_mut", None)
         # delta plan (diff + self-verify) in the SAME slice: the
@@ -2771,7 +2804,7 @@ class Core:
         # trusted blindly
         with trace.span("delta.plan"):
             delta_plan = self._plan_delta_seal(
-                state_obj, cursor_obj, _cut=_delta_cut
+                state_obj, cursor_obj, _cut=_delta_cut, owned=owned
             )
         if delta_plan is not None:
             state_bytes = delta_plan["new_bytes"]

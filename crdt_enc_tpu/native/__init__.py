@@ -208,6 +208,8 @@ def _bind_state(lib) -> None:
     lib.bytes_lens_join.restype = ctypes.c_int64
     lib.canon_pack.argtypes = [ctypes.py_object]
     lib.canon_pack.restype = ctypes.py_object
+    lib.canon_same.argtypes = [ctypes.py_object, ctypes.py_object]
+    lib.canon_same.restype = ctypes.py_object
 
 
 def _bind(lib) -> None:

@@ -540,9 +540,159 @@ int canon_emit(PyObject* obj, Out& out, int depth) {
   return 0;  // sets, numpy scalars, custom types → Python fallback
 }
 
+// --- canon_same: do two graphs pack to the same canonical bytes? -------
+// Walks both under canon_emit's type table, without packing either.
+// 1 = the same bytes, 0 = a difference found, 2 = cannot say (a type
+// canon_emit declines, a map key whose Python equality is wider than
+// its bytes, the depth limit, or a Python error, cleared).
+
+const int SAME = 1, DIFFERS = 0, UNSURE = 2;
+
+enum Kind { K_NONE, K_BOOL, K_INT, K_BYTES, K_STR, K_FLOAT, K_SEQ, K_MAP,
+            K_OTHER };
+
+Kind kind_of(PyObject* o) {
+  // exact types by pointer, the common ones of a state first (bool has a
+  // type of its own, so PyLong_Type is never a bool)
+  PyTypeObject* t = Py_TYPE(o);
+  if (t == &PyLong_Type) return K_INT;
+  if (t == &PyBytes_Type) return K_BYTES;
+  if (t == &PyDict_Type) return K_MAP;
+  if (t == &PyUnicode_Type) return K_STR;
+  if (t == &PyList_Type || t == &PyTuple_Type) return K_SEQ;
+  if (t == &PyFloat_Type) return K_FLOAT;
+  if (o == Py_None) return K_NONE;
+  if (t == &PyBool_Type) return K_BOOL;
+  return K_OTHER;
+}
+
+// A map is compared by looking each key of one side up in the other, so
+// by Python's hash and ==, which make 1, True and 1.0 one key (and 0.0
+// and -0.0) where their bytes differ.  Among None, exact int, bytes, str
+// and tuples of these, equal keys are equal bytes; any other key is not
+// looked up at all.
+bool key_is_exact(PyObject* k, int depth) {
+  const Kind kd = kind_of(k);
+  if (kd == K_BYTES || kd == K_INT || kd == K_STR || kd == K_NONE)
+    return true;
+  if (!PyTuple_CheckExact(k) || depth > 200) return false;
+  const Py_ssize_t n = PyTuple_GET_SIZE(k);
+  for (Py_ssize_t i = 0; i < n; ++i)
+    if (!key_is_exact(PyTuple_GET_ITEM(k, i), depth + 1)) return false;
+  return true;
+}
+
+int canon_same_walk(PyObject* a, PyObject* b, int depth) {
+  if (depth > 200) return UNSURE;
+  const Kind ka = kind_of(a), kb = kind_of(b);
+  if (ka == K_OTHER || kb == K_OTHER) return UNSURE;
+  // every kind opens with header bytes no other kind uses
+  if (ka != kb) return DIFFERS;
+  switch (ka) {
+    case K_NONE:
+      return SAME;
+    case K_BOOL:
+      return a == b ? SAME : DIFFERS;
+    case K_INT: {
+      // one machine word, the counters of a state: no call at all
+      if (PyUnstable_Long_IsCompact((PyLongObject*)a) &&
+          PyUnstable_Long_IsCompact((PyLongObject*)b))
+        return PyUnstable_Long_CompactValue((PyLongObject*)a) ==
+                       PyUnstable_Long_CompactValue((PyLongObject*)b)
+                   ? SAME : DIFFERS;
+      // canon_emit's range: [-2^63, 2^64); outside it nothing packs
+      int oa = 0, ob = 0;
+      const long long va = PyLong_AsLongLongAndOverflow(a, &oa);
+      const long long vb = PyLong_AsLongLongAndOverflow(b, &ob);
+      if (oa < 0 || ob < 0) return UNSURE;
+      if (!oa && !ob) return va == vb ? SAME : DIFFERS;
+      if (oa != ob) return DIFFERS;  // one under 2^63, one at or over it
+      const unsigned long long ua = PyLong_AsUnsignedLongLong(a);
+      const unsigned long long ub = PyLong_AsUnsignedLongLong(b);
+      if (PyErr_Occurred()) {
+        PyErr_Clear();
+        return UNSURE;
+      }
+      return ua == ub ? SAME : DIFFERS;
+    }
+    case K_BYTES: {
+      const Py_ssize_t n = PyBytes_GET_SIZE(a);
+      if (n != PyBytes_GET_SIZE(b)) return DIFFERS;
+      return memcmp(PyBytes_AS_STRING(a), PyBytes_AS_STRING(b), (size_t)n)
+                 ? DIFFERS : SAME;
+    }
+    case K_STR: {
+      Py_ssize_t na, nb;
+      const char* sa = PyUnicode_AsUTF8AndSize(a, &na);
+      const char* sb = sa ? PyUnicode_AsUTF8AndSize(b, &nb) : nullptr;
+      if (sb == nullptr) {
+        PyErr_Clear();
+        return UNSURE;
+      }
+      return (na == nb && !memcmp(sa, sb, (size_t)na)) ? SAME : DIFFERS;
+    }
+    case K_FLOAT: {
+      const double da = PyFloat_AS_DOUBLE(a), db = PyFloat_AS_DOUBLE(b);
+      return memcmp(&da, &db, 8) ? DIFFERS : SAME;
+    }
+    case K_SEQ: {
+      const int la = PyList_CheckExact(a), lb = PyList_CheckExact(b);
+      const Py_ssize_t n = la ? PyList_GET_SIZE(a) : PyTuple_GET_SIZE(a);
+      if (n != (lb ? PyList_GET_SIZE(b) : PyTuple_GET_SIZE(b)))
+        return DIFFERS;
+      for (Py_ssize_t i = 0; i < n; ++i) {
+        const int rc = canon_same_walk(
+            la ? PyList_GET_ITEM(a, i) : PyTuple_GET_ITEM(a, i),
+            lb ? PyList_GET_ITEM(b, i) : PyTuple_GET_ITEM(b, i), depth + 1);
+        if (rc != SAME) return rc;
+      }
+      return SAME;
+    }
+    case K_MAP: {
+      if (PyDict_GET_SIZE(a) != PyDict_GET_SIZE(b)) return DIFFERS;
+      Py_ssize_t pos = 0;
+      PyObject *key, *val;
+      // the side looked up IN first: a lookup never shows which of its
+      // keys it matched
+      while (PyDict_Next(b, &pos, &key, &val))
+        if (!key_is_exact(key, depth + 1)) return UNSURE;
+      pos = 0;
+      while (PyDict_Next(a, &pos, &key, &val)) {
+        if (!key_is_exact(key, depth + 1)) return UNSURE;
+        // exact keys alone: the lookup runs no code that could reach
+        // either map, and the borrowed references stay good
+        PyObject* other = PyDict_GetItemWithError(b, key);
+        if (other == nullptr) {
+          if (!PyErr_Occurred()) return DIFFERS;
+          PyErr_Clear();
+          return UNSURE;
+        }
+        const int rc = canon_same_walk(val, other, depth + 1);
+        if (rc != SAME) return rc;
+      }
+      // equal sizes, every key of a in b under an equality that is
+      // equality of bytes: the key sets are one
+      return SAME;
+    }
+    case K_OTHER:
+      break;
+  }
+  return UNSURE;
+}
+
 }  // namespace
 
 extern "C" {
+
+// ``True`` only if ``canon_pack(a) == canon_pack(b)``; ``False`` where a
+// difference was found; ``None`` where it cannot say cheaply.  No sort,
+// no buffer, no allocation: never an exception.
+PyObject* canon_same(PyObject* a, PyObject* b) {
+  const int rc = canon_same_walk(a, b, 0);
+  if (rc == SAME) Py_RETURN_TRUE;
+  if (rc == DIFFERS) Py_RETURN_FALSE;
+  Py_RETURN_NONE;
+}
 
 // Canonical-pack ``obj``; returns a bytes object, Py_None when the
 // object graph contains a type this packer does not handle (caller

@@ -15,17 +15,22 @@ import msgpack
 logger = logging.getLogger("crdt_enc_tpu.codec")
 
 _native_pack = None  # resolved lazily; False = unavailable for good
+_native_same = None  # likewise
 
 
-def _warn_no_native_pack(exc: Exception) -> None:
-    """The canonical-pack fast path disabling must be VISIBLE (EXC001):
-    a binding regression would otherwise silently put ~400ms back on
-    every canonical_bytes call.  Logged once — the resolution is cached
-    for the process, so the fallback decision happens exactly once too."""
-    logger.warning(
-        "native canon_pack unavailable (%r); using the Python "
-        "canonicalization path for all packs", exc
-    )
+def _native_fn(name: str, instead: str):
+    """A function of the native state library, or False.  Disabling a
+    fast path must be VISIBLE (EXC001): a binding regression would
+    otherwise silently put ~400ms back on every canonical_bytes call.
+    Logged once a function — the resolution is cached for the process,
+    so the fallback decision happens exactly once too."""
+    try:
+        from .. import native
+
+        return getattr(native.load_state(), name)
+    except Exception as e:
+        logger.warning("native %s unavailable (%r); %s", name, e, instead)
+        return False
 
 
 def pack(obj) -> bytes:
@@ -40,18 +45,32 @@ def pack(obj) -> bytes:
     does an environment without the native build."""
     global _native_pack
     if _native_pack is None:
-        try:
-            from .. import native
-
-            _native_pack = native.load_state().canon_pack
-        except Exception as e:
-            _warn_no_native_pack(e)
-            _native_pack = False
+        _native_pack = _native_fn(
+            "canon_pack",
+            "using the Python canonicalization path for all packs",
+        )
     if _native_pack:
         out = _native_pack(obj)
         if out is not None:
             return out
     return msgpack.packb(_canon(obj), use_bin_type=True)
+
+
+def canon_same(a, b) -> bool | None:
+    """``True`` only if ``pack(a) == pack(b)``, found without packing
+    either (statebuild.cpp ``canon_same``): the two graphs walked
+    together under the packer's own type table, a map by one lookup a
+    key.  ``False`` where a difference was found.  ``None`` where it
+    cannot say cheaply — a type the native packer declines, a map with a
+    ``bool`` or ``float`` key (Python's hashing makes ``1``, ``True`` and
+    ``1.0`` one key; their bytes differ), the packer's depth limit, no
+    native build — and the caller compares the bytes."""
+    global _native_same
+    if _native_same is None:
+        _native_same = _native_fn(
+            "canon_same", "every comparison packs both sides"
+        )
+    return _native_same(a, b) if _native_same else None
 
 
 def pack_array(packed_items) -> bytes:
