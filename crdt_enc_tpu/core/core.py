@@ -1051,7 +1051,11 @@ class Core:
         ``_orset_fresh_fold_native``); when the state provably has not
         mutated since, the checkpoint packs straight from those rows —
         the zero-copy decode→planes tail, no dict walk (the solo twin
-        of the fold service's planes-packed ``_packed`` path)."""
+        of the fold service's planes-packed ``_packed`` path).  Every
+        other ORSet is packed from its dicts, by one native pass or the
+        Python walk (``orset_pack_checkpoint``); counters
+        ``checkpoint_pack_rows`` / ``_native`` / ``_walk`` say which of
+        the three producers ran."""
         state = self._data.state
         if type(state) is ORSet:
             from ..ops.columnar import (
@@ -1067,6 +1071,7 @@ class Core:
                 # its whole lifetime
                 state._ckpt_rows = None
                 if stash[0] == getattr(state, "_mut", None):
+                    trace.add("checkpoint_pack_rows", 1)
                     return (
                         CHECKPOINT_FMT_ORSET,
                         orset_pack_checkpoint_rows(*stash[1]),

@@ -202,6 +202,8 @@ def _bind_state(lib) -> None:
         ctypes.py_object, ctypes.py_object, ctypes.py_object,
     ]
     lib.grouped_rows_dicts.restype = ctypes.c_int
+    lib.dicts_grouped_rows.argtypes = [ctypes.py_object] * 5
+    lib.dicts_grouped_rows.restype = ctypes.py_object
     lib.bytes_lens_join.argtypes = [
         ctypes.py_object, u64p, u8p, ctypes.c_int64, ctypes.c_int64
     ]

@@ -802,6 +802,100 @@ int grouped_rows_dicts(const int32_t* m_idx, const int32_t* a_idx,
     return 0;
 }
 
+// ``index[key]``, or ``key`` appended to ``items`` and entered at its
+// position: -1 on any failure, the Python error left set.
+static Py_ssize_t intern_at(PyObject* items, PyObject* index, PyObject* key) {
+    PyObject* at = PyDict_GetItemWithError(index, key);  // borrowed
+    if (at != nullptr) return PyLong_AsSsize_t(at);       // -1 + error: not an int
+    if (PyErr_Occurred()) return -1;
+    const Py_ssize_t idx = PyList_GET_SIZE(items);
+    if (idx >= INT32_MAX) return -1;
+    PyObject* pos = PyLong_FromSsize_t(idx);
+    if (!pos) return -1;
+    const bool ok = PyDict_SetItem(index, key, pos) == 0 &&
+                    PyList_Append(items, key) == 0;
+    Py_DECREF(pos);
+    return ok ? idx : -1;
+}
+
+// The way back (ops/columnar.py orset_pack_checkpoint): one
+// ``{member: {actor: counter}}`` table → its three checkpoint row
+// buffers, ``(int32 member index, int32 actor index, int64 counter)``
+// as three ``bytes``, in the order the Python loop there emits them:
+// both levels walked by PyDict_Next, a member interned at its first
+// sight (before its slots, so a member without slots is listed too), an
+// actor looked up and appended on a miss.  ``members`` / ``actors`` are
+// the interning tables' lists and ``member_index`` / ``actor_index``
+// their ``{object: position}`` dicts; all four GROW here.  The buffers
+// are sized from the slot dicts' lengths before they are filled, and
+// the fill is bounded by that size (a key's ``__eq__`` is Python).
+//
+// Returns the tuple, or ``Py_None`` where it declines (a slot map that
+// is not exactly a dict, a counter that is not an int or is outside
+// int64, an index that does not fit, any Python error, cleared): the
+// caller then runs its loop from scratch, on fresh tables.  Never an
+// exception.
+PyObject* dicts_grouped_rows(PyObject* table, PyObject* members,
+                             PyObject* member_index, PyObject* actors,
+                             PyObject* actor_index) {
+    if (!PyDict_CheckExact(table) || !PyList_CheckExact(members) ||
+        !PyDict_CheckExact(member_index) || !PyList_CheckExact(actors) ||
+        !PyDict_CheckExact(actor_index))
+        Py_RETURN_NONE;
+    PyObject *m, *slots, *r, *c;
+    Py_ssize_t pos = 0, n = 0;
+    while (PyDict_Next(table, &pos, &m, &slots)) {
+        if (!PyDict_CheckExact(slots)) Py_RETURN_NONE;
+        n += PyDict_GET_SIZE(slots);
+    }
+    PyObject* mb = PyBytes_FromStringAndSize(nullptr, n * 4);
+    PyObject* ab = PyBytes_FromStringAndSize(nullptr, n * 4);
+    PyObject* cb = PyBytes_FromStringAndSize(nullptr, n * 8);
+    PyObject* out = nullptr;
+    if (mb && ab && cb) {
+        // a bytes object's buffer is malloc-aligned past a 32-byte header
+        int32_t* m_out = (int32_t*)PyBytes_AS_STRING(mb);
+        int32_t* a_out = (int32_t*)PyBytes_AS_STRING(ab);
+        int64_t* c_out = (int64_t*)PyBytes_AS_STRING(cb);
+        Py_ssize_t k = 0;
+        bool ok = true;
+        pos = 0;
+        while (ok && PyDict_Next(table, &pos, &m, &slots)) {
+            const Py_ssize_t e = intern_at(members, member_index, m);
+            if (e < 0 || !PyDict_CheckExact(slots)) { ok = false; break; }
+            Py_ssize_t spos = 0;
+            while (PyDict_Next(slots, &spos, &r, &c)) {
+                if (k >= n || !PyLong_Check(c)) { ok = false; break; }
+                const Py_ssize_t a = intern_at(actors, actor_index, r);
+                if (a < 0 || a > INT32_MAX) { ok = false; break; }
+                long long v;
+                if (PyUnstable_Long_IsCompact((PyLongObject*)c)) {
+                    v = (long long)PyUnstable_Long_CompactValue(
+                        (PyLongObject*)c);
+                } else {
+                    int of = 0;
+                    v = PyLong_AsLongLongAndOverflow(c, &of);
+                    if (of != 0 || (v == -1 && PyErr_Occurred())) {
+                        ok = false;
+                        break;
+                    }
+                }
+                m_out[k] = (int32_t)e;
+                a_out[k] = (int32_t)a;
+                c_out[k] = (int64_t)v;
+                ++k;
+            }
+        }
+        if (ok && k == n) out = PyTuple_Pack(3, mb, ab, cb);
+    }
+    Py_XDECREF(mb);
+    Py_XDECREF(ab);
+    Py_XDECREF(cb);
+    if (out) return out;
+    PyErr_Clear();
+    Py_RETURN_NONE;
+}
+
 // Build {actor_obj: counter} for the nonzero entries of a dense clock —
 // the native twin of ops/columnar.py dense_to_vclock's dict body.
 // Returns a NEW dict, or NULL on error.

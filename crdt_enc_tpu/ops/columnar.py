@@ -278,6 +278,28 @@ def _grouped_rows_dicts_native(
     return False
 
 
+def _dicts_grouped_rows_native(table: dict, members: Vocab, actors: Vocab):
+    """ONE home for the native ``dicts_grouped_rows`` invocation
+    (statebuild.cpp), the way back from ``grouped_rows_dicts``: one
+    ``{member: {actor: counter}}`` table → its ``(member index, actor
+    index, counter)`` row buffers as three ``bytes`` (int32, int32,
+    int64), in the order of a walk of the dicts, ``members`` and
+    ``actors`` interned as that walk meets them.  ``None`` where the
+    native library is unavailable or declines (a slot map that is not a
+    dict, a counter that is not an int or is outside int64), with the
+    two tables then in an UNKNOWN state: the caller starts over on fresh
+    ones."""
+    try:
+        from .. import native
+
+        return native.load_state().dicts_grouped_rows(
+            table, members.items, members.index, actors.items, actors.index
+        )
+    except Exception as e:
+        _warn_no_native_state(e)
+    return None
+
+
 def _fill_dicts_from_plane(plane: np.ndarray, members: Vocab,
                            replicas: Vocab, target: dict) -> None:
     """Nonzero plane cells → nested ``{member: {actor: counter}}`` dicts.
@@ -764,45 +786,75 @@ def orset_pack_checkpoint(state: ORSet) -> dict | None:
     per-key msgpack map walk.  Lossless by value; byte-identity of the
     canonical serialization follows because ``codec.pack`` re-sorts maps.
 
+    The row buffers come from one native pass a table
+    (:func:`_dicts_grouped_rows_native`) wherever the library loads and
+    the state is dicts of ints, else from the Python walk
+    (:func:`_dicts_grouped_rows_walk`) started over on fresh tables: the
+    two give equal payloads, key for key and byte for byte.  Counters
+    ``checkpoint_pack_native`` / ``checkpoint_pack_walk`` say which made
+    the payload.
+
     Returns None when any counter falls outside int64 (precision must
     never be lost — the caller then uses the generic ``state_to_obj``
     encoding instead).
     """
-    actors = Vocab()
-    members = Vocab()
-    for r in state.clock.counters:
-        actors.intern(r)
-
-    def rows(table: dict):
-        m_idx, a_idx, ctr = [], [], []
-        for m, slots in table.items():
-            e = members.intern(m)
-            for r, c in slots.items():
-                m_idx.append(e)
-                a_idx.append(actors.intern(r))
-                ctr.append(c)
-        return (
-            np.asarray(m_idx, np.int32),
-            np.asarray(a_idx, np.int32),
-            np.asarray(ctr, np.int64),
-        )
-
     try:
         clock_ctr = np.asarray(
             list(state.clock.counters.values()), np.int64
         )
-        em, ea, ec = rows(state.entries)
-        dm, da, dc = rows(state.deferred)
+        how = "checkpoint_pack_native"
+        got = _checkpoint_rows(state, _dicts_grouped_rows_native)
+        if got is None:
+            how = "checkpoint_pack_walk"
+            got = _checkpoint_rows(state, _dicts_grouped_rows_walk)
     except OverflowError:
         return None
+    trace.add(how, 1)
+    actors, members, (em, ea, ec), (dm, da, dc) = got
     return {
         b"actors": list(actors.items),
         b"members": list(members.items),
         b"nc": len(state.clock.counters),
         b"cc": clock_ctr.tobytes(),
-        b"em": em.tobytes(), b"ea": ea.tobytes(), b"ec": ec.tobytes(),
-        b"dm": dm.tobytes(), b"da": da.tobytes(), b"dc": dc.tobytes(),
+        b"em": em, b"ea": ea, b"ec": ec,
+        b"dm": dm, b"da": da, b"dc": dc,
     }
+
+
+def _checkpoint_rows(state: ORSet, table_rows):
+    """Fresh interning tables, the clock's actors first (so they stay
+    aligned with ``cc``), and the row bytes of ``entries`` then
+    ``deferred`` by ``table_rows(table, members, actors)``:
+    ``(actors, members, entry rows, deferred rows)``, or ``None`` where
+    ``table_rows`` declined a table."""
+    actors = Vocab(state.clock.counters)
+    members = Vocab()
+    rows = []
+    for table in (state.entries, state.deferred):
+        got = table_rows(table, members, actors)
+        if got is None:
+            return None
+        rows.append(got)
+    return actors, members, rows[0], rows[1]
+
+
+def _dicts_grouped_rows_walk(table: dict, members: Vocab, actors: Vocab):
+    """:func:`_dicts_grouped_rows_native` as a Python loop over every
+    slot: the fallback where the native pass is unavailable or declines,
+    and the tests' oracle for it.  ``OverflowError`` for a counter
+    outside int64."""
+    m_idx, a_idx, ctr = [], [], []
+    for m, slots in table.items():
+        e = members.intern(m)
+        for r, c in slots.items():
+            m_idx.append(e)
+            a_idx.append(actors.intern(r))
+            ctr.append(c)
+    return (
+        np.asarray(m_idx, np.int32).tobytes(),
+        np.asarray(a_idx, np.int32).tobytes(),
+        np.asarray(ctr, np.int64).tobytes(),
+    )
 
 
 def orset_unpack_checkpoint(obj) -> ORSet:

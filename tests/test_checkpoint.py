@@ -8,6 +8,7 @@ wrong adapter) falls back to the cold path with the reason traced.
 """
 
 import asyncio
+import copy
 import random
 
 import pytest
@@ -114,6 +115,306 @@ def test_columnar_checkpoint_empty_and_overflow():
     big = ORSet()
     big.clock.counters[b"a" * 16] = 2**70  # outside int64
     assert orset_pack_checkpoint(big) is None  # generic fmt takes over
+
+
+# ---- the dict pass: one native walk a table, the Python loop its oracle -----
+
+
+def _pack_counts() -> dict:
+    """How often each producer of the format-1 payload has run."""
+    c = trace.snapshot()["counters"]
+    return {
+        how: c.get(f"checkpoint_pack_{how}", 0)
+        for how in ("native", "walk", "rows")
+    }
+
+
+def _walk_only(monkeypatch):
+    """``orset_pack_checkpoint`` by the Python loop alone (the native
+    pass made to decline): the oracle."""
+    from crdt_enc_tpu.ops import columnar as C
+
+    monkeypatch.setattr(
+        C, "_dicts_grouped_rows_native", lambda table, members, actors: None
+    )
+
+
+def _state_empty():
+    from crdt_enc_tpu.models import ORSet
+
+    return ORSet()
+
+
+def _fresh_fold(seed: int, R: int, E: int, N: int):
+    """``(state, actors, counters)`` after one combined fold of ``N``
+    random rows into an empty state (``orset_fold_sparse_host``): adds,
+    removes the clock covers, and future-horizon removes that survive
+    it, so the DEFERRED table (dm/da/dc) gets real coverage too."""
+    import numpy as np
+
+    from crdt_enc_tpu.models import ORSet
+    from crdt_enc_tpu.ops import columnar as C
+
+    rng = np.random.default_rng(seed)
+    actors = sorted(rng.bytes(16) for _ in range(R))
+    counters = np.zeros(R, np.int64)
+    kind = np.zeros(N, np.int8)
+    member = rng.integers(0, E, N).astype(np.int32)
+    actor = rng.integers(0, R, N).astype(np.int32)
+    ctr = np.zeros(N, np.int32)
+    for i in range(N):
+        a = int(actor[i])
+        roll = rng.random()
+        if roll < 0.05:
+            kind[i], ctr[i] = 1, counters[a] + 3
+        elif roll < 0.18 and counters[a]:
+            kind[i], ctr[i] = 1, counters[a]
+        else:
+            counters[a] += 1
+            ctr[i] = counters[a]
+    state = ORSet()
+    C.orset_fold_sparse_host(
+        state, kind, member, actor, ctr,
+        C.Vocab(list(range(E))), C.Vocab(actors),
+    )
+    assert state.entries and state.deferred
+    return state, actors, counters
+
+
+def _state_fresh_fold():
+    return _fresh_fold(11, 32, 120, 4000)[0]
+
+
+def _state_removes_live_horizons():
+    from crdt_enc_tpu.models import ORSet
+    from crdt_enc_tpu.models.orset import AddOp, RmOp
+    from crdt_enc_tpu.models.vclock import VClock
+
+    rng = random.Random(5)
+    actors = [bytes([i]) * 16 for i in range(9)]
+    s = ORSet()
+    for i in range(600):
+        a = rng.choice(actors)
+        s.apply(AddOp(rng.randrange(50), s.clock.inc(a)))
+        if i % 5 == 0:
+            m = rng.choice(list(s.entries))
+            # a remove that saw dots this replica has not: a live horizon
+            ahead = {r: c + 2 for r, c in s.entries[m].items()}
+            s.apply(RmOp(m, VClock(ahead)))
+    assert s.deferred
+    return s
+
+
+def _state_deferred_actor_off_clock():
+    from crdt_enc_tpu.models import ORSet
+    from crdt_enc_tpu.models.orset import AddOp, RmOp
+    from crdt_enc_tpu.models.vclock import VClock
+
+    s = ORSet()
+    a = b"a" * 16
+    for m in (b"x", b"y", b"z"):
+        s.apply(AddOp(m, s.clock.inc(a)))
+    # the clock never saw b"q"*16: it is interned after the clock's
+    # actors, by the deferred table's walk
+    s.apply(RmOp(b"y", VClock({b"q" * 16: 7})))
+    s.apply(RmOp(b"never-added", VClock({b"r" * 16: 2, a: 99})))
+    assert b"q" * 16 not in s.clock.counters and s.deferred
+    return s
+
+
+def _state_mixed_member_types():
+    from crdt_enc_tpu.models import ORSet
+    from crdt_enc_tpu.models.orset import AddOp, RmOp
+    from crdt_enc_tpu.models.vclock import VClock
+
+    s = ORSet()
+    actors = [bytes([i]) * 16 for i in range(4)]
+    for i, m in enumerate([7, b"7", "7", (1, "t"), -3, "", b"", 2**40]):
+        s.apply(AddOp(m, s.clock.inc(actors[i % 4])))
+        s.apply(AddOp(m, s.clock.inc(actors[(i + 1) % 4])))
+    s.apply(RmOp("7", VClock({actors[0]: 50})))
+    return s
+
+
+def _state_10k_actors_sparse():
+    from crdt_enc_tpu.models import ORSet
+    from crdt_enc_tpu.models.vclock import VClock
+
+    rng = random.Random(3)
+    actors = [i.to_bytes(16, "big") for i in range(10_000)]
+    s = ORSet()
+    s.clock = VClock({a: rng.randrange(1, 2**40) for a in actors})
+    for _ in range(3000):
+        a = rng.choice(actors)
+        s.entries.setdefault(rng.randrange(500), {})[a] = rng.randrange(
+            1, s.clock.counters[a] + 1
+        )
+    for _ in range(40):
+        a = rng.choice(actors)
+        s.deferred.setdefault(rng.randrange(600), {})[a] = (
+            s.clock.counters[a] + rng.randrange(1, 9)
+        )
+    return s
+
+
+def _state_counter_2_63(table):
+    def build():
+        s = _state_deferred_actor_off_clock()
+        next(iter(getattr(s, table).values()))[b"a" * 16] = 2**63
+        return s
+
+    return build
+
+
+class _Slots(dict):
+    """A slot map that is a mapping but not exactly a ``dict``."""
+
+
+def _state_slot_map_not_a_dict():
+    s = _state_removes_live_horizons()
+    m = list(s.entries)[3]
+    s.entries[m] = _Slots(s.entries[m])
+    return s
+
+
+def _state_counter_not_an_int():
+    import numpy as np
+
+    s = _state_removes_live_horizons()
+    slots = s.entries[list(s.entries)[2]]
+    r = next(iter(slots))
+    slots[r] = np.int64(slots[r])
+    return s
+
+
+@pytest.mark.parametrize(
+    "build,library,ran",
+    [
+        (_state_empty, True, "native"),
+        (_state_fresh_fold, True, "native"),
+        (_state_removes_live_horizons, True, "native"),
+        (_state_deferred_actor_off_clock, True, "native"),
+        (_state_mixed_member_types, True, "native"),
+        (_state_10k_actors_sparse, True, "native"),
+        (_state_counter_2_63("entries"), True, None),
+        (_state_counter_2_63("deferred"), True, None),
+        (_state_slot_map_not_a_dict, True, "walk"),
+        (_state_counter_not_an_int, True, "walk"),
+        (_state_removes_live_horizons, False, "walk"),
+        (_state_empty, False, "walk"),
+    ],
+    ids=[
+        "empty", "fresh_fold", "removes_live_horizons",
+        "deferred_actor_off_clock", "mixed_member_types",
+        "10k_actors_sparse", "entry_counter_2_63", "deferred_counter_2_63",
+        "slot_map_not_a_dict", "counter_not_an_int", "library_fails_to_load",
+        "library_fails_to_load_empty",
+    ],
+)
+def test_dict_pass_payload_equals_the_loops(build, library, ran, monkeypatch):
+    """The native pass over the state's dicts and the Python loop give
+    one payload, key for key and byte for byte; where the pass declines
+    (or the library is not there) the loop's payload is what comes out;
+    a counter outside int64 is ``None`` from both (the pass declines,
+    the loop overflows) and counts for neither; the counter says which
+    producer made the payload; and the payload unpacks to the state."""
+    from crdt_enc_tpu import native
+    from crdt_enc_tpu.ops import columnar as C
+
+    def tables(s):
+        return s.clock.counters, s.entries, s.deferred
+
+    state = build()
+    before = copy.deepcopy(state)
+    if not library:
+        def broken():
+            raise RuntimeError("no C-API library on this box")
+
+        monkeypatch.setattr(native, "load_state", broken)
+    counts = _pack_counts()
+    got = C.orset_pack_checkpoint(state)
+    if ran:
+        counts[ran] += 1
+    assert _pack_counts() == counts
+    _walk_only(monkeypatch)
+    want = C.orset_pack_checkpoint(state)
+    assert tables(state) == tables(before)  # neither touched it
+    if any(c == 2**63 for t in (state.entries, state.deferred)
+           for slots in t.values() for c in slots.values()):
+        assert got is None and want is None
+        return
+    assert got is not None
+    assert list(got) == list(want)  # key for key, in order
+    for k in want:
+        assert type(got[k]) is type(want[k]), k
+        assert got[k] == want[k], k
+    assert codec.pack(got) == codec.pack(want)
+    back = C.orset_unpack_checkpoint(codec.unpack(codec.pack(got)))
+    assert tables(back) == tables(before)
+    if build is not _state_counter_not_an_int:  # msgpack packs no numpy int
+        assert codec.pack(back.to_obj()) == codec.pack(before.to_obj())
+
+
+def test_compact_after_a_mutating_round_packs_natively(tmp_path):
+    """Over ``FsStorage``: the round after the first is a steady round
+    (the state mutated since any fresh fold), its checkpoint comes from
+    the native dict pass, once, and a fresh ``open()`` restores that
+    checkpoint to the same canonical bytes."""
+    def storage():
+        return FsStorage(str(tmp_path / "local"), str(tmp_path / "remote"))
+
+    async def go():
+        c1 = await Core.open(make_opts(storage(), orset_adapter()))
+        for i in range(20):
+            await c1.apply_ops([_ops_orset_rm(c1, i)])
+        await c1.compact()
+        for i in range(20, 35):
+            await c1.apply_ops([_ops_orset_rm(c1, i)])
+        counts = _pack_counts()
+        await c1.compact()
+        counts["native"] += 1
+        assert _pack_counts() == counts
+        want = c1.with_state(canonical_bytes)
+        warm = await Core.open(
+            make_opts(storage(), orset_adapter(), create=False)
+        )
+        assert warm.opened_from_checkpoint, warm.checkpoint_fallback_reason
+        assert warm.with_state(canonical_bytes) == want
+
+    run(go())
+
+
+def test_checkpoint_of_either_producer_opens_under_the_other(
+    tmp_path, monkeypatch
+):
+    """A checkpoint the Python loop wrote (the program before the native
+    pass) opens in a replica that packs natively, and the reverse: the
+    stored payloads are one."""
+    def storage():
+        return FsStorage(str(tmp_path / "local"), str(tmp_path / "remote"))
+
+    async def go():
+        c1 = await Core.open(make_opts(storage(), orset_adapter()))
+        for i in range(30):
+            await c1.apply_ops([_ops_orset_rm(c1, i)])
+        await c1.compact()
+        native_blob = await storage().load_local_checkpoint()
+        want = c1.with_state(canonical_bytes)
+        _walk_only(monkeypatch)
+        assert await c1.save_checkpoint()
+        loop_blob = await storage().load_local_checkpoint()
+        monkeypatch.undo()
+        loop_ckpt = await c1._open_sealed(loop_blob)
+        native_ckpt = await c1._open_sealed(native_blob)
+        assert loop_ckpt[b"state"] == native_ckpt[b"state"]
+        assert int(loop_ckpt[b"fmt"]) == int(native_ckpt[b"fmt"]) == 1
+        warm = await Core.open(
+            make_opts(storage(), orset_adapter(), create=False)
+        )  # the loop's file, read back by the native program
+        assert warm.opened_from_checkpoint, warm.checkpoint_fallback_reason
+        assert warm.with_state(canonical_bytes) == want
+
+    run(go())
 
 
 # ---- warm open == cold open, across adapters (differential) ----------------
@@ -500,44 +801,12 @@ def test_pack_checkpoint_rows_semantically_equal_to_dict_walk():
     """A fresh streaming fold stashes its surviving rows; packing the
     checkpoint from them must unpack to a state canonically identical
     to the dict-walk pack, and the stash must be mut-epoch-guarded."""
-    import secrets
-
-    import numpy as np
-
-    from crdt_enc_tpu.models import ORSet
     from crdt_enc_tpu.models.orset import AddOp
     from crdt_enc_tpu.models.vclock import Dot
     from crdt_enc_tpu.ops import columnar as C
-    from crdt_enc_tpu.ops.columnar import Vocab
 
-    rng = np.random.default_rng(4)
-    R, E, N = 64, 200, 9000  # ≥ CKPT_STASH_MIN_ROWS surviving rows
-    actors = sorted(secrets.token_bytes(16) for _ in range(R))
-    members = Vocab(list(range(E)))
-    replicas = Vocab(actors)
-    counters = np.zeros(R, np.int64)
-    kind = np.zeros(N, np.int8)
-    member = rng.integers(0, E, N).astype(np.int32)
-    actor = rng.integers(0, R, N).astype(np.int32)
-    ctr = np.zeros(N, np.int32)
-    for i in range(N):
-        a = int(actor[i])
-        roll = rng.random()
-        if roll < 0.05:
-            # future-horizon remove: survives the merged clock, so the
-            # DEFERRED table (dm/da/dc) gets real coverage too
-            kind[i] = 1
-            ctr[i] = counters[a] + 3
-        elif roll < 0.18 and counters[a]:
-            kind[i] = 1
-            ctr[i] = counters[a]
-        else:
-            counters[a] += 1
-            ctr[i] = counters[a]
-    state = ORSet()
-    C.orset_fold_sparse_host(
-        state, kind, member, actor, ctr, members, replicas
-    )
+    # ≥ CKPT_STASH_MIN_ROWS surviving rows
+    state, actors, counters = _fresh_fold(4, 64, 200, 9000)
     stash = getattr(state, "_ckpt_rows", None)
     assert stash is not None and stash[0] == state._mut
     from_rows = C.orset_unpack_checkpoint(
@@ -588,8 +857,11 @@ def test_streaming_compact_checkpoints_from_rows(storage_factory, monkeypatch):
             )
 
         monkeypatch.setattr(C, "orset_pack_checkpoint", forbidden)
+        counts = _pack_counts()
         await reader.compact()
         monkeypatch.undo()
+        counts["rows"] += 1
+        assert _pack_counts() == counts
 
         warm = await Core.open(make_opts(
             storage_factory("r"), orset_adapter(), create=False,

@@ -88,6 +88,11 @@ def test_cycle_over_four_size_classes_and_a_spill_matches_the_plain_reference():
             want = expected_counters(plan, r, reached[r + 1])
             assert {k: counters.get(k, 0) for k in want} == want
             assert want["serve_buckets_folded"] >= 3 and want["serve_solo_spills"] == 1
+            # the spilled tenant has no planes pack: its checkpoint comes from
+            # its own dicts; the batched tenants' from the planes, which count
+            # for none of the three producers
+            packs = {k: v for k, v in counters.items() if k.startswith("checkpoint_pack_")}
+            assert packs == {"checkpoint_pack_native": 1}
             folds = [e["meta"] for e in trace.events() if e["name"] == "serve.fold"]
             assert len(folds) == want["serve_buckets_folded"]
             classes |= {tuple(int(x) for x in m.split(":")[1].split("x")[2:]) for m in folds}
