@@ -27,6 +27,7 @@ import asyncio
 import collections
 import contextlib
 import contextvars
+import functools
 import itertools
 import logging
 import os
@@ -157,7 +158,7 @@ def _remove_quiet(path: str) -> None:
         pass
 
 
-def _native_step(step: str, *args, counted: str = "fs_steps") -> bool:
+def _native_step(step: str, *args, counted: str | None = "fs_steps") -> bool:
     """One file step as ONE call into ``native/io.cpp`` (interpreter lock
     released, paths relative to a directory opened once; the protocol is
     in that file's header).  True iff the step ran clean (status 0): the
@@ -170,7 +171,9 @@ def _native_step(step: str, *args, counted: str = "fs_steps") -> bool:
     would read back as an identical-content success; it is raised as
     ``_fsync_dir`` would have raised it.  ``counted`` names the counter
     pair: the seal tail's writes are ``fs_steps_*``, a poll's reads
-    ``fs_reads_*``, the chunk iterator's windows ``fs_chunk_reads_*``."""
+    ``fs_reads_*``, the chunk iterator's windows ``fs_chunk_reads_*``;
+    None where the caller's one read may be several steps and it counts
+    the read itself (``load_ops_sync``)."""
     from .. import native
 
     try:
@@ -180,11 +183,13 @@ def _native_step(step: str, *args, counted: str = "fs_steps") -> bool:
     else:
         status = getattr(lib, step)(*args)
         if status == 0:
-            trace.add(counted + "_native", 1)
+            if counted:
+                trace.add(counted + "_native", 1)
             return True
         if status < 0:
             raise OSError(-status, os.strerror(-status), os.fsdecode(args[0]))
-    trace.add(counted + "_python", 1)
+    if counted:
+        trace.add(counted + "_python", 1)
     return False
 
 
@@ -382,34 +387,22 @@ class FsStorage(Storage):
     async def list_op_actors(self) -> list[Actor]:
         return await self._run(self.list_op_actors_sync)
 
-    # One C++ call scans/reads a whole dense per-actor run (SURVEY.md §2.2:
-    # the bulk load path gets a native reader) — per-file Python open/read
-    # costs ~10-20µs of interpreter overhead, which dominates at
-    # compaction scale.  Each round is capped in files AND bytes so one
-    # gigantic log never demands an unbounded flat buffer; the loop
-    # continues where the previous round stopped.
+    # one round of the size-only scan (``stat_ops``): capped in files so
+    # that one gigantic log never demands an unbounded array; the loop
+    # continues where the previous round stopped
     NATIVE_SCAN_BATCH = 65_536
-    NATIVE_SCAN_BYTES = 256 << 20
     # Chunk budget for the pipelined ingest (iter_op_chunks): small enough
     # that a few in-flight chunks bound host memory AND the read/decrypt/
     # decode/reduce stages get real overlap, large enough that the batched
     # decrypt/decode amortize.
     CHUNK_BYTES = 24 << 20
 
-    class _ScanRace(Exception):
-        """A file in the round shrank/vanished/errored between the two
-        native passes; the round starting at ``.version`` needs a per-file
-        re-probe."""
-
-        def __init__(self, version: int):
-            self.version = version
-
     def _scan_sizes_native(self, lib, d: bytes, v: int):
         """One bounded native size-only pass (``scan_op_sizes``): the
         dense per-file sizes from version ``v``, as ``(sizes[:n],
         exhausted)`` — ``exhausted`` means the directory ran out inside
         this round.  The single encoding of the native scan calling
-        convention; the bulk reader and ``stat_ops`` both build on it."""
+        convention, under ``stat_ops``."""
         import ctypes
 
         import numpy as np
@@ -421,54 +414,6 @@ class FsStorage(Storage):
         ))
         n = max(n, 0)
         return sizes[:n], n < self.NATIVE_SCAN_BATCH
-
-    def _scan_round_native(self, lib, d: bytes, actor: Actor, v: int, max_bytes: int):
-        """One bounded native round.  Returns ``(files, next_v, done)``;
-        raises :class:`_ScanRace` on a mid-round race (nothing consumed)
-        and lets native-load/ctypes errors propagate to the caller."""
-        import ctypes
-
-        import numpy as np
-
-        from .. import native
-
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        sizes, exhausted = self._scan_sizes_native(lib, d, v)
-        n = len(sizes)
-        if n == 0:
-            return [], v, True
-        scanned = n
-        # byte cap: shrink this round to the prefix that fits (but always
-        # take at least one file so progress is guaranteed)
-        cum = np.cumsum(sizes)
-        if cum[-1] > max_bytes:
-            n = max(1, int(np.searchsorted(cum, max_bytes, "right")))
-            sizes = sizes[:n]
-        offsets = np.zeros(n, np.int64)
-        np.cumsum(sizes[:-1], out=offsets[1:])
-        buf = np.empty(int(sizes.sum()), np.uint8)
-        got = lib.read_op_files(
-            d, v, n,
-            offsets.ctypes.data_as(i64p),
-            sizes.ctypes.data_as(i64p),
-            buf.ctypes.data_as(native.u8p),
-        )
-        if got != n:
-            logger.debug(
-                "native bulk read raced at actor %s v%d; "
-                "re-probing round per-file", actor.hex(), v,
-            )
-            raise self._ScanRace(v)
-        files = [
-            (
-                actor,
-                v + i,
-                buf[int(offsets[i]) : int(offsets[i]) + int(sizes[i])].tobytes(),
-            )
-            for i in range(n)
-        ]
-        done = exhausted and n == scanned
-        return files, v + n, done
 
     @staticmethod
     def _warn_native_unavailable() -> None:
@@ -486,70 +431,10 @@ class FsStorage(Storage):
         else:
             logger.debug("native call failed", exc_info=True)
 
-    def _scan_native(self, actor: Actor, first: int):
-        """Dense scan via the native reader.
-
-        Returns ``None`` (native path unavailable → Python scans from
-        ``first``) or ``(files, resume_v)`` where ``resume_v`` is None for a
-        completed run, or the version the Python scan should continue from —
-        the start of a round whose bulk read failed (``read_op_files``
-        reports only -1, so the whole round is re-read; it is bounded by the
-        batch/byte caps).  The per-file re-scan then distinguishes a benign
-        race (file gone → clean dense end) from a real defect (file present
-        but unreadable → loud error), so neither case is masked."""
-        from .. import native
-
-        out: list[tuple[Actor, int, bytes]] = []
-        v = first
-        try:
-            lib = native.load()
-            d = self._ops_dir(actor).encode()
-            while True:
-                try:
-                    files, v, done = self._scan_round_native(
-                        lib, d, actor, v, self.NATIVE_SCAN_BYTES
-                    )
-                except self._ScanRace as race:
-                    return out, race.version
-                out.extend(files)
-                if done:
-                    return out, None
-        except Exception:
-            self._warn_native_unavailable()
-            return (out, v) if out else None
-
-    def _chunk_round(self, actor: Actor, v: int, max_bytes: int):
-        """One bounded round for the chunk iterator: native fast path with
-        a per-file Python continuation on race or native unavailability.
-        Returns ``(files, next_v, done)``."""
-        from .. import native
-
-        files: list[tuple[Actor, int, bytes]] = []
-        size = 0
-        try:
-            lib = native.load()
-            d = self._ops_dir(actor).encode()
-            try:
-                return self._scan_round_native(lib, d, actor, v, max_bytes)
-            except self._ScanRace:
-                pass  # re-probe this round per file below
-        except Exception:
-            self._warn_native_unavailable()
-        dd = self._ops_dir(actor)
-        while size < max_bytes:
-            raw = _read_file(os.path.join(dd, str(v)))
-            if raw is None:
-                return files, v, True
-            files.append((actor, v, raw))
-            size += len(raw)
-            v += 1
-        return files, v, False
-
     def _probe_actors(
         self, actor_first_versions: list[tuple[Actor, int]]
     ) -> list[tuple[Actor, int]]:
-        """Prefilter for the per-actor scans (the ones a native read's
-        non-zero status falls back to, and ``stat_ops``): keep only
+        """Prefilter for the per-actor scans of ``stat_ops``: keep only
         actors whose NEXT wanted op file exists.  The dense scan reads
         nothing for the others (their log is fully consumed or GC'd),
         but a per-actor task, queue and thread hop costs ~1ms each — at
@@ -557,9 +442,7 @@ class FsStorage(Storage):
         nothing new.  One stat per actor replaces all of it; the stats
         are dirfd-relative (resolve two path components, not the whole
         remote prefix) because on containerized kernels every path walk
-        costs ~100µs+.  A native read (``load_op_runs``,
-        ``load_op_window``) needs no such pass: an absent first file is
-        its ``counts_out[i] == 0``."""
+        costs ~100µs+."""
         n = len(actor_first_versions)
         if n > 64:  # the C loop only pays off past its setup cost
             try:
@@ -617,11 +500,6 @@ class FsStorage(Storage):
     CHUNK_WINDOWS = 8
     CHUNK_WINDOW_FILES = (CHUNK_BYTES // CHUNK_WINDOWS) >> 10
     CHUNK_WINDOW_ACTORS = CHUNK_WINDOW_FILES // 24
-    # in a window that fell back (``_iter_rounds``): how many actors scan
-    # concurrently ahead of the emitter; in-flight memory is bounded by
-    # ~window × 2 × CHUNK_BYTES (one queued + one in-progress round per
-    # actor)
-    CHUNK_SCAN_WINDOW = 4
 
     async def iter_op_chunks(
         self,
@@ -652,11 +530,12 @@ class FsStorage(Storage):
         self, wanted: list[tuple[Actor, int]], max_bytes: int
     ):
         """The files of ``wanted``, window by window, each window's as it
-        was read: **status 0 or today's path from that window's start**
-        (``_iter_rounds``; a window that fell back is not read natively
-        again).  Every window in flight was handed to its thread before
-        the emitter awaits anything, so the read of the windows behind
-        overlaps the emission of the one in front."""
+        was read: by ``_native_runs`` or, from the call that returned a
+        status, by ``_file_runs`` under the same budget (a window that
+        fell back is not read natively again).  Every window in flight
+        was handed to its thread before the emitter awaits anything, so
+        the read of the windows behind overlaps the emission of the one
+        in front."""
         cap = max(1, min(max_bytes, self.CHUNK_BYTES) // self.CHUNK_WINDOWS)
         ahead = (
             wanted[i : i + self.CHUNK_WINDOW_ACTORS]
@@ -664,102 +543,52 @@ class FsStorage(Storage):
         )
         loop = asyncio.get_running_loop()
         inflight: collections.deque = collections.deque()
+        native_runs = functools.partial(
+            self._native_runs, counted="fs_chunk_reads"
+        )
 
-        def start(window):
+        def start(window, read_runs):
             # ``asyncio.to_thread`` less the coroutine: the job is its
             # thread's before this returns, not a tick of the loop later
             # (the ingest's consumer holds the loop for its session's
             # vocabulary walk right after the producer's first step)
             job = loop.run_in_executor(
-                None, contextvars.copy_context().run, self._native_runs,
-                window, self.CHUNK_WINDOW_FILES, cap, True,
+                None, contextvars.copy_context().run, read_runs,
+                window, self.CHUNK_WINDOW_FILES, cap,
             )
-            return window, job
+            return read_runs, window, job
 
         def top_up():
             for window in itertools.islice(
                 ahead, self.CHUNK_WINDOWS - len(inflight)
             ):
-                inflight.append(start(window))
+                inflight.append(start(window, native_runs))
 
         try:
             top_up()
             while inflight:
-                window, job = inflight.popleft()
+                read_runs, window, job = inflight.popleft()
                 read = await job
-                if read is None:
-                    top_up()
-                    async with contextlib.aclosing(
-                        self._iter_rounds(window, max_bytes)
-                    ) as rounds:
-                        async for files in rounds:
-                            yield files
+                if read is None:  # a status: per file from here on
+                    inflight.appendleft(start(window, self._file_runs))
                     continue
                 files, rest = read
-                if rest:  # its buffers were full: go on from there, first
-                    inflight.appendleft(start(rest))
+                if rest:  # its budget was full: go on from there, first
+                    inflight.appendleft(start(rest, read_runs))
                 top_up()
                 yield files
         finally:
-            for _, job in inflight:
+            for _, _, job in inflight:
                 job.cancel()
 
-    async def _iter_rounds(
-        self, actor_first_versions: list[tuple[Actor, int]], max_bytes: int
-    ):
-        """The per-actor path of the chunk iterator, what a window runs
-        whose native call returned a status: the probe, then a bounded
-        two-pass round at a time an actor (``_chunk_round``: a race
-        between the passes or no library is the per-file re-probe), a
-        round's files a yield.  CHUNK_SCAN_WINDOW actors scan
-        concurrently, FIFO, so that the per-file Python path on a
-        high-latency remote does not serialize the whole read stage,
-        while emission stays in actor order."""
-        actor_first_versions = await self._run(
-            self._probe_actors, actor_first_versions
-        )
-        window = asyncio.Semaphore(self.CHUNK_SCAN_WINDOW)
-
-        async def scan_actor(actor: Actor, first: int, out_q: asyncio.Queue):
-            # the semaphore is held for the actor's whole scan; waiters are
-            # FIFO, so the window always covers the actor being emitted —
-            # no deadlock against the bounded queues
-            try:
-                async with window:
-                    v, done = first, False
-                    while not done:
-                        files, v, done = await self._run(
-                            self._chunk_round, actor, v, max_bytes
-                        )
-                        if files:
-                            await out_q.put(files)
-                    await out_q.put(None)
-            except Exception as e:
-                # the emitter must never block forever on a dead scanner —
-                # deliver the failure in-position and let it re-raise
-                await out_q.put(e)
-
-        queues: list[asyncio.Queue] = []
-        tasks: list[asyncio.Task] = []
-        for actor, first in actor_first_versions:
-            out_q: asyncio.Queue = asyncio.Queue(maxsize=1)
-            queues.append(out_q)
-            tasks.append(asyncio.create_task(scan_actor(actor, first, out_q)))
-        try:
-            for out_q in queues:
-                while True:
-                    files = await out_q.get()
-                    if files is None:
-                        break
-                    if isinstance(files, Exception):
-                        raise files
-                    yield files
-        finally:
-            for t in tasks:
-                t.cancel()
-
-    # what ONE ``load_op_runs`` call may bring back; a larger load is the
-    # bounded rounds' of ``_scan_native``
+    # The two readers of op files, under one contract: ``reader(wanted,
+    # max_files, max_bytes) -> (files, rest)``.  ``files`` are in the order
+    # asked, every run dense from its first version (an absent first file
+    # is an empty run: no probe); ``rest`` is empty when every run ended,
+    # otherwise the pair the reader stopped in, from its next version, and
+    # the pairs behind it.  A reader stops before the file its budget does
+    # not hold, and never before its first.  The buffers of ONE native
+    # call of a poll's load (a larger load is drained in several):
     LOAD_RUNS_FILES = 1024
     LOAD_RUNS_BYTES = 1 << 20
 
@@ -768,16 +597,12 @@ class FsStorage(Storage):
         wanted: list[tuple[Actor, int]],
         max_files: int,
         max_bytes: int,
-        window: bool,
+        counted: str | None = None,
     ):
-        """The probe, the dense scan and the reads of every wanted actor
-        as ONE native call under one ``ops/`` descriptor: ``(files,
-        rest)``, or None on any status but 0.  A poll's load
-        (``load_op_runs``, ``fs_reads_*``) is whole or a status.  A
-        ``window`` of the chunk iterator (``load_op_window``,
-        ``fs_chunk_reads_*``) whose runs its buffers do not hold stops
-        there, and ``rest`` is what it did not read: the pair it stopped
-        in, from its next version, and the pairs behind it."""
+        """The native reader: the probe, the dense scan and the reads of
+        every wanted actor as ONE call (``load_op_window``) under one
+        ``ops/`` descriptor, or None on any status but 0 (a first file
+        that alone overflows the buffer among them)."""
         import ctypes
 
         import numpy as np
@@ -790,17 +615,13 @@ class FsStorage(Storage):
         sizes = (i64 * max_files)()
         buf = np.empty(max_bytes, np.uint8)
         n_files, n_bytes, stop = i64(), i64(), i64(n)
-        step, counted = "load_op_runs", "fs_reads"
-        outs = [ctypes.byref(n_files), ctypes.byref(n_bytes)]
-        if window:
-            step, counted = "load_op_window", "fs_chunk_reads"
-            outs.append(ctypes.byref(stop))
         if not _native_step(
-            step, os.fsencode(self._ops_dir()), n,
+            "load_op_window", os.fsencode(self._ops_dir()), n,
             b"".join(a.hex().encode() + b"\0" for a, _ in wanted),
             (i64 * n)(*(first for _, first in wanted)),
             max_files, max_bytes, counts, sizes,
-            buf.ctypes.data_as(native.u8p), *outs, counted=counted,
+            buf.ctypes.data_as(native.u8p), ctypes.byref(n_files),
+            ctypes.byref(n_bytes), ctypes.byref(stop), counted=counted,
         ):
             return None
         raw = buf[: n_bytes.value].tobytes()
@@ -816,40 +637,51 @@ class FsStorage(Storage):
             rest[0] = (actor, first + counts[stop.value])
         return out, rest
 
-    def _scan_actor(self, actor: Actor, first: int) -> list[tuple[Actor, int, bytes]]:
-        """One actor's dense run from ``first``: the native rounds, then
-        per file from wherever they stopped."""
-        res = self._scan_native(actor, first)
-        if res is None:
-            out, v = [], first
-        else:
-            out, v = res
-            if v is None:
-                return out
-        d = self._ops_dir(actor)
-        while True:
-            raw = _read_file(os.path.join(d, str(v)))
-            if raw is None:
-                return out
-            out.append((actor, v, raw))
-            v += 1
+    def _file_runs(
+        self, wanted: list[tuple[Actor, int]], max_files: int, max_bytes: int
+    ):
+        """The per-file reader, what a status of the native one falls to:
+        one ``_read_file`` a file, which tells a benign race (file gone:
+        the run ends) from a defect (file present and unreadable: loud).
+        A first file is taken whatever its size."""
+        files: list[tuple[Actor, int, bytes]] = []
+        size = 0
+        for i, (actor, first) in enumerate(wanted):
+            d = self._ops_dir(actor)
+            for v in itertools.count(first):
+                try:
+                    raw = _read_file(os.path.join(d, str(v)))
+                except NotADirectoryError:
+                    raw = None  # a junk file of the actor's name: no run
+                if raw is None:
+                    break
+                if files and (
+                    len(files) >= max_files or size + len(raw) > max_bytes
+                ):
+                    return files, [(actor, v), *wanted[i + 1 :]]
+                files.append((actor, v, raw))
+                size += len(raw)
+        return files, []
 
     def load_ops_sync(
         self, actor_first_versions: list[tuple[Actor, int]]
     ) -> list[tuple[Actor, int, bytes]]:
-        if not actor_first_versions:
-            return []
-        read = self._native_runs(
-            actor_first_versions, self.LOAD_RUNS_FILES, self.LOAD_RUNS_BYTES,
-            False,
-        )
-        if read is not None:
-            return read[0]
-        return [
-            item
-            for actor, first in self._probe_actors(actor_first_versions)
-            for item in self._scan_actor(actor, first)
-        ]
+        """A poll's load, drained: the native reader until a call returns
+        a status, the per-file reader from where the drain stands then.
+        One read of ``fs_reads_*``, however many calls it took."""
+        out: list[tuple[Actor, int, bytes]] = []
+        rest, read_runs = actor_first_versions, self._native_runs
+        while rest:
+            read = read_runs(rest, self.LOAD_RUNS_FILES, self.LOAD_RUNS_BYTES)
+            if read is None:  # a status: per file from here on
+                trace.add("fs_reads_python", 1)
+                read_runs = self._file_runs
+                continue
+            files, rest = read
+            out += files
+        if actor_first_versions and read_runs == self._native_runs:
+            trace.add("fs_reads_native", 1)
+        return out
 
     async def load_ops(
         self, actor_first_versions: list[tuple[Actor, int]]
@@ -860,11 +692,10 @@ class FsStorage(Storage):
         self, actor_first_versions: list[tuple[Actor, int]]
     ) -> list[tuple[Actor, int, int]]:
         """Dense tail sizing for the replication-status backlog probe:
-        the native ``scan_op_sizes`` pass (one C call per round — the
-        same first pass the bulk reader uses, without the read), with a
+        the native ``scan_op_sizes`` pass (one C call per round), with a
         per-file ``os.stat`` continuation when the native path is
-        unavailable.  Probe-prefiltered like ``load_ops``, so a fully
-        consumed log costs one stat per actor, not a scan."""
+        unavailable.  Probe-prefiltered, so a fully consumed log costs
+        one stat per actor, not a scan."""
         actor_first_versions = await self._run(
             self._probe_actors, actor_first_versions
         )

@@ -283,10 +283,6 @@ def _bind(lib) -> None:
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, i64p
     ]
     lib.scan_op_sizes.restype = ctypes.c_int64
-    lib.read_op_files.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, i64p, i64p, u8p
-    ]
-    lib.read_op_files.restype = ctypes.c_int64
     lib.probe_op_files.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, u8p
     ]
@@ -308,16 +304,11 @@ def _bind(lib) -> None:
     lib.remove_names.restype = ctypes.c_int32
     lib.file_step_flushes.argtypes = []
     lib.file_step_flushes.restype = ctypes.c_int64
-    # a poll's reads (io.cpp "a poll's reads"): status 0, or today's path
+    # the reads (io.cpp "the reads"): status 0, or fs.py reads on itself
     lib.list_dir_names.argtypes = [
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64, i64p, i64p
     ]
     lib.list_dir_names.restype = ctypes.c_int32
-    lib.load_op_runs.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, i64p,
-        ctypes.c_int64, ctypes.c_int64, i64p, i64p, u8p, i64p, i64p,
-    ]
-    lib.load_op_runs.restype = ctypes.c_int32
     lib.load_op_window.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, i64p,
         ctypes.c_int64, ctypes.c_int64, i64p, i64p, u8p, i64p, i64p, i64p,

@@ -1,27 +1,18 @@
-// Bulk op-file reader: the C++ load path for dense per-actor op logs.
+// File I/O of FsStorage (backends/fs.py is the only caller): the one
+// reader of op-file runs, the listing, the writers, and the size scan and
+// probe of the replication status.
 //
-// An op-log scan reads remote/ops/<actor>/<N> for N = first, first+1, …
-// until the first missing file (the dense-version contract,
-// crdt-enc-tokio/src/lib.rs:254-269).  Per-file Python open/read costs
-// ~10-20µs of interpreter overhead; at compaction scale (SURVEY.md §2.2:
-// "the bulk load path (1M op files) gets a C++ reader") that dwarfs the
-// I/O itself.  Two-pass protocol so ctypes needs no growable buffers:
+// The reads.
 //
-//   pass 1  scan_op_sizes(dir, first, max)  → per-file sizes (stat loop)
-//   pass 2  read_op_files(dir, first, n, buf, offsets)  → one flat buffer
-//
-// A file that shrinks/vanishes between passes returns -1 and the caller
-// falls back to the per-file Python path (the sync tool may race us; op
-// files themselves are immutable once published).
-//
-// A poll's reads (backends/fs.py is the only caller).
-//
-// A replica polling its remote makes four reads: the names in meta/, in
-// states/ and in ops/, and the dense runs of the actors with a new file.
-// Each is ONE call below (`list_dir_names`, `load_op_runs`), so that a
-// worker job making all four (Core's ingest job) hands the interpreter
-// lock round four times and not once a system call, and walks the long
-// <tenant>/remote/ prefix four times and not once a file:
+// An op log is remote/ops/<actor>/<N> for N = first, first+1, ... until the
+// first missing file (the dense-version contract,
+// crdt-enc-tokio/src/lib.rs:254-269).  A replica polling its remote makes
+// four reads: the names in meta/, in states/ and in ops/, and the dense
+// runs of the actors with a new file.  Each is ONE call below
+// (`list_dir_names`, `load_op_window`), so that a worker job making all
+// four (Core's ingest job) hands the interpreter lock round four times and
+// not once a system call, and walks the long <tenant>/remote/ prefix four
+// times and not once a file:
 //
 //   list names     open(dir) -> readdir to the end -> close; names back
 //                  NUL-separated in the caller's buffer, `.` and `..`
@@ -33,18 +24,19 @@
 //                  fstat [not a regular file: the run ends] -> read
 //                  st_size bytes -> read again, which must say end of
 //                  file -> close.  All bytes land in one buffer, in the
-//                  order asked.
+//                  order asked.  Where the buffers are full the call ends
+//                  clean and says where, so the next call goes on there.
 //
 // The folder's chunk iterator reads the same way, a window of wanted
-// devices a call (`load_op_window`: `load_op_runs` that, where its buffers
-// are full, ends clean and says where, so the next call goes on there).
+// devices a call.
 //
 // As with the writers below, a surprise is never handled here: a file
 // that is there and cannot be opened or read, one that ends before or
-// after its size, a listing or a load the caller's buffers do not hold,
-// any other errno, is a non-zero status, and fs.py runs today's Python
-// path from its start, which tells a benign race (file gone: the dense
-// run ends) from a defect (file present but unreadable: loud).
+// after its size, a listing the caller's buffer does not hold, a file
+// larger than the whole buffer, any other errno, is a non-zero status, and
+// fs.py reads on file by file (a listing: from its start), which tells a
+// benign race (file gone: the dense run ends) from a defect (file present
+// but unreadable: loud).
 //
 // File steps: the writers (backends/fs.py is the only caller).
 //
@@ -220,8 +212,9 @@ bool maybe_python_int(const char* name) {
 
 extern "C" {
 
-// Pass 1: sizes of the dense run starting at `first`.  Writes up to
-// max_files sizes; returns the count of consecutive existing files.
+// Sizes of the dense run starting at `first` (the replication status'
+// backlog probe).  Writes up to max_files sizes; returns the count of
+// consecutive existing files.
 int64_t scan_op_sizes(const char* dir, int64_t first, int64_t max_files,
                       int64_t* sizes_out) {
   char path[4096];
@@ -233,38 +226,6 @@ int64_t scan_op_sizes(const char* dir, int64_t first, int64_t max_files,
     sizes_out[n] = (int64_t)st.st_size;
   }
   return n;
-}
-
-// Pass 2: read n_files consecutive files into one flat buffer at the
-// given offsets (offsets[i] .. offsets[i] + sizes[i]).  Returns n_files,
-// or -1 if any file is missing or its size changed (caller falls back).
-int64_t read_op_files(const char* dir, int64_t first, int64_t n_files,
-                      const int64_t* offsets, const int64_t* sizes,
-                      uint8_t* buf) {
-  char path[4096];
-  for (int64_t i = 0; i < n_files; i++) {
-    if (path_join(path, sizeof(path), dir, first + i) != 0) return -1;
-    int fd = open(path, O_RDONLY);
-    if (fd < 0) return -1;
-    int64_t want = sizes[i];
-    uint8_t* dst = buf + offsets[i];
-    int64_t got = 0;
-    while (got < want) {
-      ssize_t r = read(fd, dst + got, (size_t)(want - got));
-      if (r < 0 && errno == EINTR) continue;  // signal mid-read: retry
-      if (r <= 0) { close(fd); return -1; }
-      got += r;
-    }
-    // file must end exactly where pass 1 said (immutable once published)
-    uint8_t extra;
-    ssize_t tail;
-    do {
-      tail = read(fd, &extra, 1);
-    } while (tail < 0 && errno == EINTR);
-    if (tail != 0) { close(fd); return -1; }
-    close(fd);
-  }
-  return n_files;
 }
 
 // Warm-open tail probe: does remote/ops/<actor>/<first> exist, for many
@@ -288,7 +249,7 @@ int64_t probe_op_files(const char* base_dir, int64_t n,
   return n;
 }
 
-// ---- a poll's reads: one call a read (protocol in the header) ----------
+// ---- the reads: one call a read (protocol in the header) ------------
 
 // Names of the entries of `dir`, NUL-separated into `buf` (`cap` bytes);
 // their count in *n_out, the bytes used in *used_out.  An absent
@@ -332,25 +293,24 @@ int32_t list_dir_names(const char* dir, char* buf, int64_t cap,
 
 // The dense runs of n wanted (actor, first version) pairs under
 // `ops_dir` (`actors`: flat NUL-separated directory names), every path
-// relative to the one descriptor.  counts_out[i] is the length of pair
-// i's run (0: nothing new, the probe's answer); sizes_out holds the file
-// sizes of all runs in order (at most max_files), `buf` their bytes back
-// to back (at most cap); *files_out and *bytes_out the totals.  An
-// absent ops_dir, actor directory or first file is an empty run.  Any
-// file that is there and does not read back whole at its size is EIO or
-// its errno.  Buffers that do not hold the runs: ERANGE without
-// `stop_out` (a poll's load is whole or a status); with it the call ends
-// clean before the file that does not fit, *stop_out the pair it is in
-// (counts_out says how far into its run; n when every run ended), and
-// only a first file that alone overflows `buf` is ERANGE.
-static int32_t read_op_runs(const char* ops_dir, int64_t n,
-                            const char* actors, const int64_t* firsts,
-                            int64_t max_files, int64_t cap,
-                            int64_t* counts_out, int64_t* sizes_out,
-                            uint8_t* buf, int64_t* files_out,
-                            int64_t* bytes_out, int64_t* stop_out) {
+// relative to the one descriptor, as far as the buffers hold them.
+// counts_out[i] is the length of pair i's run (0: nothing new, the
+// probe's answer); sizes_out holds the file sizes of all runs in order
+// (at most max_files), `buf` their bytes back to back (at most cap);
+// *files_out and *bytes_out the totals.  An absent ops_dir, actor
+// directory or first file is an empty run.  Any file that is there and
+// does not read back whole at its size is EIO or its errno.  Buffers that
+// do not hold the runs: the call ends clean before the file that does not
+// fit, *stop_out the pair it is in (counts_out says how far into its run;
+// n when every run ended), and the next call resumes there; only a first
+// file that alone overflows `buf` is ERANGE.
+int32_t load_op_window(const char* ops_dir, int64_t n, const char* actors,
+                       const int64_t* firsts, int64_t max_files, int64_t cap,
+                       int64_t* counts_out, int64_t* sizes_out, uint8_t* buf,
+                       int64_t* files_out, int64_t* bytes_out,
+                       int64_t* stop_out) {
   *files_out = *bytes_out = 0;
-  if (stop_out != nullptr) *stop_out = n;
+  *stop_out = n;
   for (int64_t i = 0; i < n; i++) counts_out[i] = 0;
   int ofd = open_dir(ops_dir);
   if (ofd < 0) return errno == ENOENT ? 0 : errno;
@@ -387,7 +347,7 @@ static int32_t read_op_runs(const char* ops_dir, int64_t n,
       }
       int64_t want = (int64_t)st.st_size;
       if (files >= max_files || used + want > cap) {
-        if (stop_out != nullptr && files > 0) stop = i;
+        if (files > 0) stop = i;
         else status = ERANGE;
         close(fd);
         break;
@@ -421,28 +381,8 @@ static int32_t read_op_runs(const char* ops_dir, int64_t n,
   close(ofd);
   *files_out = files;
   *bytes_out = used;
-  if (stop_out != nullptr) *stop_out = stop;
+  *stop_out = stop;
   return status;
-}
-
-// A poll's load: every wanted run, whole, or a status.
-int32_t load_op_runs(const char* ops_dir, int64_t n, const char* actors,
-                     const int64_t* firsts, int64_t max_files, int64_t cap,
-                     int64_t* counts_out, int64_t* sizes_out, uint8_t* buf,
-                     int64_t* files_out, int64_t* bytes_out) {
-  return read_op_runs(ops_dir, n, actors, firsts, max_files, cap, counts_out,
-                      sizes_out, buf, files_out, bytes_out, nullptr);
-}
-
-// One window of the chunk iterator: the runs of its pairs as far as the
-// buffers hold them, and where it stopped (the next call resumes there).
-int32_t load_op_window(const char* ops_dir, int64_t n, const char* actors,
-                       const int64_t* firsts, int64_t max_files, int64_t cap,
-                       int64_t* counts_out, int64_t* sizes_out, uint8_t* buf,
-                       int64_t* files_out, int64_t* bytes_out,
-                       int64_t* stop_out) {
-  return read_op_runs(ops_dir, n, actors, firsts, max_files, cap, counts_out,
-                      sizes_out, buf, files_out, bytes_out, stop_out);
 }
 
 // ---- the writers: one call a file step --------------------------------

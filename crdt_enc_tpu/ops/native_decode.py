@@ -35,7 +35,7 @@ def decode_orset_payload_batch(payloads: list, actors_sorted: list):
     part = decode_orset_payload_spans(payloads, actors_sorted)
     if part is None:
         return None
-    return combine_orset_spans([part])
+    return intern_orset_spans(part)
 
 
 def _shared_buffer_of(payloads):
@@ -55,12 +55,10 @@ def _shared_buffer_of(payloads):
 
 
 def decode_orset_payload_spans(payloads, actors_sorted: list, cache=None):
-    """Native two-pass decode of one payload chunk to raw span columns.
+    """Native decode of one payload chunk to raw span columns.
 
-    ``payloads`` is a list of blob bytes, or a packed ``(buffer,
-    offsets)`` pair straight from ``decrypt_blobs_packed`` — the packed
-    form skips materializing and re-joining per-blob Python objects (at
-    100k-tiny-file scale that overhead dwarfed the decrypt itself).
+    ``payloads`` is a list of blob bytes or of views into the batch
+    decrypt's one cleartext buffer (``_shared_buffer_of``).
 
     ``cache`` (optional dict the caller owns for the life of one actor
     table, e.g. a payload stream): reuses the flattened actor table and
@@ -68,17 +66,11 @@ def decode_orset_payload_spans(payloads, actors_sorted: list, cache=None):
     100k actors costs more than the decode.
 
     Returns ``(buf, kind, moff, mlen, actor, counter)`` — member values
-    stay as (offset, length) spans into ``buf`` so chunks decoded at
-    different times can be combined and interned once
-    (``combine_orset_spans``) — or None to request Python fallback.
+    stay as (offset, length) spans into ``buf``, interned by
+    ``intern_orset_spans`` — or None to request Python fallback.
     """
     lib = native.load()
-    packed = isinstance(payloads, tuple)
-    if packed:
-        big, offs = payloads
-        n_payloads = len(offs) - 1
-    else:
-        n_payloads = len(payloads)
+    n_payloads = len(payloads)
     if n_payloads == 0:
         return (
             np.zeros(0, np.uint8),
@@ -88,27 +80,22 @@ def decode_orset_payload_spans(payloads, actors_sorted: list, cache=None):
             np.zeros(0, np.int32),
             np.zeros(0, np.int32),
         )
-    if packed:
-        bases = offs[:-1].astype(np.uint64, copy=True)
-        lens = np.diff(offs).astype(np.uint64)
+    lens = np.array([len(p) for p in payloads], np.uint64)
+    big = _shared_buffer_of(payloads)
+    if big is not None:
+        # every payload is a view into ONE buffer (the batch decrypt's
+        # packed cleartext): address arithmetic recovers the offsets —
+        # no join of the whole chunk
+        base0 = np.frombuffer(big, np.uint8).ctypes.data
+        bases = np.fromiter(
+            (np.frombuffer(p, np.uint8).ctypes.data - base0
+             for p in payloads),
+            np.uint64, count=n_payloads,
+        )
     else:
-        big = _shared_buffer_of(payloads)
-        if big is not None:
-            # every payload is a view into ONE buffer (the batch
-            # decrypt's packed cleartext): address arithmetic recovers
-            # the offsets — no join of the whole chunk
-            lens = np.array([len(p) for p in payloads], np.uint64)
-            base0 = np.frombuffer(big, np.uint8).ctypes.data
-            bases = np.fromiter(
-                (np.frombuffer(p, np.uint8).ctypes.data - base0
-                 for p in payloads),
-                np.uint64, count=n_payloads,
-            )
-        else:
-            big = b"".join(payloads)
-            lens = np.array([len(p) for p in payloads], np.uint64)
-            bases = np.zeros(n_payloads, np.uint64)
-            np.cumsum(lens[:-1], out=bases[1:])
+        big = b"".join(payloads)
+        bases = np.zeros(n_payloads, np.uint64)
+        np.cumsum(lens[:-1], out=bases[1:])
     buf = np.frombuffer(big, np.uint8)
     bp = buf.ctypes.data_as(native.u8p)
     if cache is not None and "actors" in cache:
@@ -165,32 +152,15 @@ def decode_orset_payload_spans(payloads, actors_sorted: list, cache=None):
     return buf, kind, moff, mlen, actor, counter
 
 
-def combine_orset_spans(parts: list, *, with_bytes: bool = False):
-    """Concatenate span chunks from ``decode_orset_payload_spans`` and
-    intern the member spans once.  Returns the same tuple as
+def intern_orset_spans(part, *, with_bytes: bool = False):
+    """Intern the member spans of one chunk from
+    ``decode_orset_payload_spans``.  Returns the same tuple as
     ``decode_orset_payload_batch``; with ``with_bytes`` a sixth element
     carries each unique member's WIRE bytes (the interning key), so a
     session-level remap can recognize an already-seen member with one
     bytes-dict hit instead of an object intern + canonical re-pack per
     chunk."""
-    if not parts:
-        kind = np.zeros(0, np.int8)
-        actor = counter = np.zeros(0, np.int32)
-        if with_bytes:
-            return kind, np.zeros(0, np.int32), actor, counter, [], []
-        return kind, np.zeros(0, np.int32), actor, counter, []
-    if len(parts) == 1:
-        buf, kind, moff, mlen, actor, counter = parts[0]
-    else:
-        bufs = [p[0] for p in parts]
-        base = np.zeros(len(bufs), np.uint64)
-        np.cumsum([len(b) for b in bufs[:-1]], out=base[1:])
-        buf = np.concatenate(bufs) if bufs else np.zeros(0, np.uint8)
-        kind = np.concatenate([p[1] for p in parts])
-        moff = np.concatenate([p[2] + b for p, b in zip(parts, base)])
-        mlen = np.concatenate([p[3] for p in parts])
-        actor = np.concatenate([p[4] for p in parts])
-        counter = np.concatenate([p[5] for p in parts])
+    buf, kind, moff, mlen, actor, counter = part
     if len(kind) == 0:
         if with_bytes:
             return kind, np.zeros(0, np.int32), actor, counter, [], []

@@ -192,7 +192,7 @@ class OrsetFoldSession:
         decode of one chunk's payloads.  The ctypes call releases the GIL,
         so the core decodes chunk i+1 while chunk i reduces."""
         from ..ops.native_decode import (
-            combine_orset_spans, decode_orset_payload_spans,
+            decode_orset_payload_spans, intern_orset_spans,
         )
 
         with trace.span("session.decode"):
@@ -201,7 +201,7 @@ class OrsetFoldSession:
             )
             if part is None:
                 raise SessionDeclined("native decoder declined the chunk")
-            return combine_orset_spans([part], with_bytes=True)
+            return intern_orset_spans(part, with_bytes=True)
 
     def reduce_chunk(self, decoded) -> None:
         """Stage 2, serialized by the caller (mutates vocab + planes)."""

@@ -305,36 +305,15 @@ def test_rotation_vs_meta_ingestion_race_keeps_all_keys(storage_factory):
     run(go())
 
 
-def test_native_op_scan_matches_python(tmp_path):
-    """The C++ bulk op reader must return exactly what the per-file
-    Python scan returns, including partial (first > 1) scans."""
+def test_a_load_over_one_calls_buffers_is_drained_natively(tmp_path, monkeypatch):
+    """A load larger than ONE native call brings back is several calls,
+    each going on where the last one stopped: the same files as one
+    call's, and one native read however many calls it took.  A file that
+    alone overflows the buffer is the per-file reader's, from there on."""
+    from test_fs_native_steps import reads
+
     from crdt_enc_tpu.backends.fs import FsStorage
-
-    async def go():
-        s = FsStorage(str(tmp_path / "l"), str(tmp_path / "remote"))
-        actor = b"\x01" * 16
-        blobs = [bytes([i]) * (i * 37 + 1) for i in range(12)]
-        for v, b in enumerate(blobs, start=1):
-            await s.store_ops(actor, v, b)
-        for first in (1, 5, 13):
-            files, resume = s._scan_native(actor, first)
-            assert resume is None  # run completed natively
-            expect = [
-                (actor, v, blobs[v - 1])
-                for v in range(first, len(blobs) + 1)
-            ]
-            assert files == expect
-            loaded = await s.load_ops([(actor, first)])
-            assert loaded == expect
-
-    run(go())
-
-
-def test_native_op_scan_byte_cap_rounds(tmp_path):
-    """A tiny byte cap forces many native read rounds; the result must be
-    identical to one unbounded round (progress guaranteed even when a
-    single file exceeds the cap)."""
-    from crdt_enc_tpu.backends.fs import FsStorage
+    from crdt_enc_tpu.utils import trace
 
     async def go():
         s = FsStorage(str(tmp_path / "l"), str(tmp_path / "remote"))
@@ -342,49 +321,64 @@ def test_native_op_scan_byte_cap_rounds(tmp_path):
         blobs = [bytes([i]) * (200 + i) for i in range(9)]
         for v, b in enumerate(blobs, start=1):
             await s.store_ops(actor, v, b)
-        s.NATIVE_SCAN_BYTES = 64  # smaller than every single file
-        files, resume = s._scan_native(actor, 1)
-        assert resume is None
-        assert files == [(actor, v, blobs[v - 1]) for v in range(1, 10)]
+        expect = [(actor, v, blobs[v - 1]) for v in range(1, 10)]
+        calls = []
+        real = FsStorage._native_runs
+
+        def counting(self, wanted, *args, **kw):
+            calls.append(list(wanted))
+            return real(self, wanted, *args, **kw)
+
+        monkeypatch.setattr(FsStorage, "_native_runs", counting)
+        for files, nbytes, n_calls in ((1024, 512, 5), (2, 1 << 20, 5), (9, 1 << 20, 1)):
+            monkeypatch.setattr(FsStorage, "LOAD_RUNS_FILES", files)
+            monkeypatch.setattr(FsStorage, "LOAD_RUNS_BYTES", nbytes)
+            calls.clear()
+            trace.reset()
+            assert await s.load_ops([(actor, 1)]) == expect
+            assert len(calls) == n_calls and reads() == (1, 0)
+            assert calls[-1] == [(actor, 9 if n_calls > 1 else 1)]
+        monkeypatch.setattr(FsStorage, "LOAD_RUNS_BYTES", 64)  # under every file
+        calls.clear()
+        trace.reset()
+        assert await s.load_ops([(actor, 1)]) == expect
+        assert len(calls) == 1 and reads() == (0, 1)
+        trace.reset()
 
     run(go())
 
 
-def test_native_scan_race_keeps_prefix_and_reprobes(tmp_path, monkeypatch):
-    """A failed native bulk read must not discard already-read rounds; the
-    per-file scan re-probes the failed round, so a vanished file ends the
-    dense run cleanly while other files still load (advisor finding)."""
+def test_a_file_removed_under_the_per_file_reader_ends_its_run(tmp_path, monkeypatch):
+    """A file the sync tool takes away while the per-file reader is in
+    its run ends the dense run cleanly there: the files before it are
+    kept, nothing is raised, and a load that fell back reads the same."""
+    import os as _os
+
+    import crdt_enc_tpu.backends.fs as fsmod
+    from crdt_enc_tpu import native
     from crdt_enc_tpu.backends.fs import FsStorage
 
     async def go():
         s = FsStorage(str(tmp_path / "l"), str(tmp_path / "remote"))
-        actor = b"\x03" * 16
+        actor, other = b"\x03" * 16, b"\x04" * 16
         blobs = [bytes([i]) * 50 for i in range(8)]
         for v, b in enumerate(blobs, start=1):
             await s.store_ops(actor, v, b)
-        s.NATIVE_SCAN_BATCH = 3  # several native rounds
+        await s.store_ops(other, 1, b"whole")
+        real_rf = fsmod._read_file
 
-        from crdt_enc_tpu import native
+        def racy_rf(path):
+            if path.endswith(_os.sep + "4") and _os.path.exists(path):
+                _os.remove(path)  # gone between the third read and this one
+            return real_rf(path)
 
-        lib = native.load()
-        real_read = lib.read_op_files
-        fail_from = 4  # fail every round starting at version >= 4
-
-        def racy_read(d, first, n, offsets, sizes, buf):
-            if first >= fail_from:
-                return -1
-            return real_read(d, first, n, offsets, sizes, buf)
-
-        monkeypatch.setattr(lib, "read_op_files", racy_read)
-        files, resume = s._scan_native(actor, 1)
-        # round 1 (v1-3) succeeded natively; the failed round is handed off
-        assert files == [(actor, v, blobs[v - 1]) for v in (1, 2, 3)]
-        assert resume == 4
-        # load_ops transparently finishes per-file: full result, no loss
-        loaded = await s.load_ops([(actor, 1)])
-        assert loaded == [
-            (actor, v, blobs[v - 1]) for v in range(1, len(blobs) + 1)
-        ]
+        monkeypatch.setattr(fsmod, "_read_file", racy_rf)
+        wanted = [(actor, 1), (other, 1)]
+        expect = [(actor, v, blobs[v - 1]) for v in (1, 2, 3)]
+        expect.append((other, 1, b"whole"))
+        assert s._file_runs(wanted, 1024, 1 << 20) == (expect, [])
+        monkeypatch.setattr(native.load(), "load_op_window", lambda *a: 11)
+        assert await s.load_ops(wanted) == expect
 
     run(go())
 
@@ -394,8 +388,8 @@ def test_unreadable_op_file_raises_loudly(tmp_path, monkeypatch):
     scan must raise, not silently truncate the log (reviewer finding).
     Unreadability is simulated by monkeypatching (chmod 0 would not bind
     when tests run as root): the one native call reports the errno and
-    reads nothing, the native bulk round of the Python path fails, and the
-    per-file re-probe hits the open error — the exact production sequence."""
+    reads nothing, and the per-file reader hits the open error — the
+    exact production sequence."""
     import os as _os
 
     import pytest
@@ -410,14 +404,6 @@ def test_unreadable_op_file_raises_loudly(tmp_path, monkeypatch):
         for v in range(1, 6):
             await s.store_ops(actor, v, bytes([v]) * 40)
 
-        lib = native.load()
-        real_read = lib.read_op_files
-
-        def failing_read(d, first, n, offsets, sizes, buf):
-            if first <= 3 < first + n:
-                return -1  # the unreadable file fails the whole bulk round
-            return real_read(d, first, n, offsets, sizes, buf)
-
         real_rf = fsmod._read_file
 
         def failing_rf(path):
@@ -425,8 +411,7 @@ def test_unreadable_op_file_raises_loudly(tmp_path, monkeypatch):
                 raise PermissionError(path)
             return real_rf(path)
 
-        monkeypatch.setattr(lib, "load_op_runs", lambda *a: 13)  # EACCES
-        monkeypatch.setattr(lib, "read_op_files", failing_read)
+        monkeypatch.setattr(native.load(), "load_op_window", lambda *a: 13)  # EACCES
         monkeypatch.setattr(fsmod, "_read_file", failing_rf)
         with pytest.raises(PermissionError):
             await s.load_ops([(actor, 1)])

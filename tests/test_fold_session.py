@@ -389,8 +389,8 @@ def test_mid_stream_decline_keeps_version_order():
 
 
 def test_scan_error_propagates_not_hangs(tmp_path):
-    """A dead actor scanner must deliver its failure to the chunk emitter,
-    not leave it awaiting a sentinel that never comes."""
+    """A window's read that dies on its thread must deliver its failure
+    to the chunk emitter, not leave it awaiting a result that never comes."""
     import os as _os
 
     import crdt_enc_tpu.backends.fs as fsmod
@@ -404,10 +404,6 @@ def test_scan_error_propagates_not_hangs(tmp_path):
             await s.store_ops(actor, v, bytes([v]) * 30)
 
         lib = native.load()
-
-        def broken_read(*a):
-            return -1  # force the per-file fallback
-
         real_rf = fsmod._read_file
 
         def failing_rf(path):
@@ -418,9 +414,8 @@ def test_scan_error_propagates_not_hangs(tmp_path):
         import unittest.mock as mock
 
         # a file that is there and unreadable: the window's one call says
-        # EACCES, the per-actor round's read fails, the per-file probe raises
+        # EACCES and the per-file reader raises
         with mock.patch.object(lib, "load_op_window", lambda *a: 13), \
-                mock.patch.object(lib, "read_op_files", broken_read), \
                 mock.patch.object(fsmod, "_read_file", failing_rf):
             with pytest.raises(PermissionError):
                 chunks = []

@@ -357,7 +357,7 @@ def test_threads_racing_for_one_version_one_wins(mode, tmp_path):
     }
 
 
-# ---- a poll's reads (ISSUE 31): ``list_dir_names`` and ``load_op_runs``
+# ---- a poll's reads (ISSUE 31): ``list_dir_names`` and ``load_op_window``
 # against the Python path, each case run both ways on the same tree ----
 
 
@@ -443,22 +443,25 @@ def test_a_read_gives_one_answer_either_way(mode, case, calls, tmp_path):
 
 @pytest.mark.parametrize("forced", [5, 34])  # EIO; ERANGE, as a full buffer
 def test_a_nonzero_read_status_runs_the_python_path(forced, tmp_path, monkeypatch):
-    """Status 0 or today's path from its start: the probe, the native
-    rounds of ``_scan_native`` and ``_list_dir``, untouched."""
+    """Status 0 or the Python path: ``_list_dir`` from its start, and the
+    per-file reader over the whole request, which needs no probe."""
     lib = native.load()
     root = str(tmp_path)
     s = storage_at(root)
     _, expected = junk_names_are_not_ours_to_judge(s, root)
     seen = []
-    real_probe = FsStorage._probe_actors
+    real_file_runs = FsStorage._file_runs
 
-    def probe(self, wanted):
+    def file_runs(self, wanted, *budget):
         seen.append(list(wanted))
-        return real_probe(self, wanted)
+        return real_file_runs(self, wanted, *budget)
 
     monkeypatch.setattr(lib, "list_dir_names", lambda *a: forced)
-    monkeypatch.setattr(lib, "load_op_runs", lambda *a: forced)
-    monkeypatch.setattr(FsStorage, "_probe_actors", probe)
+    monkeypatch.setattr(lib, "load_op_window", lambda *a: forced)
+    monkeypatch.setattr(FsStorage, "_file_runs", file_runs)
+    monkeypatch.setattr(
+        FsStorage, "_probe_actors", lambda *a: pytest.fail("a read probed")
+    )
     trace.reset()
     got = (s.list_remote_meta_names_sync(), s.list_state_names_sync(),
            s.list_op_actors_sync(), s.load_ops_sync([(A, 1), (B, 1), (C, 1)]))
@@ -468,13 +471,13 @@ def test_a_nonzero_read_status_runs_the_python_path(forced, tmp_path, monkeypatc
     trace.reset()
 
 
-def test_what_is_no_regular_file_ends_the_run_as_the_native_rounds_end_it(
+def test_what_is_no_regular_file_ends_the_run_natively_and_is_loud_per_file(
     tmp_path, monkeypatch
 ):
-    """A directory or a FIFO where a version should be: ``scan_op_sizes``
-    has always ended the dense run there (the per-file Python path, which
-    a machine without a toolchain runs, raises), and the one call ends it
-    there too, without opening the FIFO for good."""
+    """A directory or a FIFO where a version should be: the one call ends
+    the dense run there, without opening the FIFO for good.  The per-file
+    reader, which a status falls to and a machine without a toolchain
+    runs, cannot tell the directory from a defect and raises."""
     lib = native.load()
     root = str(tmp_path)
     s = storage_at(root)
@@ -489,15 +492,18 @@ def test_what_is_no_regular_file_ends_the_run_as_the_native_rounds_end_it(
     trace.reset()
     assert s.load_ops_sync(wanted) == expected
     assert reads() == (1, 0)
-    monkeypatch.setattr(lib, "load_op_runs", lambda *a: 5)
-    assert s.load_ops_sync(wanted) == expected
+    monkeypatch.setattr(lib, "load_op_window", lambda *a: 5)
+    with pytest.raises(IsADirectoryError):
+        s.load_ops_sync(wanted)
     assert reads() == (1, 1)
     trace.reset()
 
 
-def test_reads_the_buffers_do_not_hold_are_pythons(tmp_path, monkeypatch):
-    """A listing or a load larger than ONE call brings back is ERANGE
-    from the library itself, and reads the same through the rounds."""
+def test_reads_the_buffers_do_not_hold_are_drained_natively(tmp_path, monkeypatch):
+    """A listing larger than ONE call brings back is ERANGE from the
+    library itself and Python's; a load larger than one call's buffers
+    is several native calls, each going on where the last one stopped,
+    and one native read."""
     native.load()
     root = str(tmp_path)
     s = storage_at(root)
@@ -507,38 +513,38 @@ def test_reads_the_buffers_do_not_hold_are_pythons(tmp_path, monkeypatch):
     assert s.list_op_actors_sync() == [A, B, C]
     assert reads() == (0, 1)
     wanted = [(B, 1), (C, 1), (A, 7)]
+    hops = count_hops(monkeypatch)
     monkeypatch.setattr(FsStorage, "LOAD_RUNS_BYTES", 2000)  # B's third: 2,000
     assert s.load_ops_sync(wanted) == expected[0]
-    assert reads() == (0, 2)
+    assert reads() == (1, 1) and hops == {"native": 3, "per_file": 0}
     monkeypatch.setattr(FsStorage, "LOAD_RUNS_BYTES", 1 << 20)
     monkeypatch.setattr(FsStorage, "LOAD_RUNS_FILES", 4)
     assert s.load_ops_sync(wanted) == expected[0]
-    assert reads() == (0, 3)
+    assert reads() == (2, 1) and hops == {"native": 5, "per_file": 0}
     monkeypatch.setattr(FsStorage, "LOAD_RUNS_FILES", 5)
     assert s.load_ops_sync(wanted) == expected[0]
-    assert reads() == (1, 3)
+    assert reads() == (3, 1) and hops == {"native": 6, "per_file": 0}
     trace.reset()
 
 
-def test_a_file_removed_between_the_two_passes_ends_the_run(tmp_path, monkeypatch):
-    """The Python path sizes a run and then reads it; a file the sync
-    tool takes away in between is today's ``_ScanRace``: the round is
-    read again file by file, and the run ends where the file was.  The
-    one native call opens a file before it sizes it, and a surprise it
-    does meet (here: a status) lands on this same path."""
+def test_a_file_removed_between_a_status_and_the_per_file_read_ends_the_run(
+    tmp_path, monkeypatch
+):
+    """The one native call opens a file before it sizes it, so it finds
+    a file or does not; a surprise it does meet is a status, and a file
+    the sync tool takes away before the per-file reader comes to it ends
+    the run where the file was."""
     lib = native.load()
     root = str(tmp_path)
     s = storage_at(root)
     for v in (1, 2, 3):
         put(root, f"r/ops/{A.hex()}/{v}", b"v%d" % v)
-    real_read = lib.read_op_files
 
     def raced(*args):
         os.remove(os.path.join(root, f"r/ops/{A.hex()}/2"))
-        return real_read(*args)
+        return 11  # EAGAIN, say
 
-    monkeypatch.setattr(lib, "load_op_runs", lambda *a: 11)  # EAGAIN, say
-    monkeypatch.setattr(lib, "read_op_files", raced)
+    monkeypatch.setattr(lib, "load_op_window", raced)
     trace.reset()
     assert s.load_ops_sync([(A, 1)]) == [(A, 1, b"v1")]
     assert reads() == (0, 1)
@@ -549,7 +555,7 @@ def test_a_file_removed_between_the_two_passes_ends_the_run(tmp_path, monkeypatc
 
 
 def test_a_file_that_does_not_end_at_its_size_is_a_status(tmp_path):
-    """``load_op_runs`` holds an op file to the size it had when opened:
+    """``load_op_window`` holds an op file to the size it had when opened:
     a ``/proc`` file says 0 and holds more, as a file still growing
     would, and is a status with nothing brought back."""
     import ctypes
@@ -559,13 +565,14 @@ def test_a_file_that_does_not_end_at_its_size_is_a_status(tmp_path):
     os.makedirs(tmp_path / "ops" / "aa")
     os.symlink("/proc/self/status", tmp_path / "ops" / "aa" / "1")
     counts, sizes, buf = (i64 * 1)(), (i64 * 8)(), (ctypes.c_uint8 * 64)()
-    files, nbytes = i64(7), i64(7)
-    status = lib.load_op_runs(
+    files, nbytes, stop = i64(7), i64(7), i64(7)
+    status = lib.load_op_window(
         os.fsencode(tmp_path / "ops"), 1, b"aa\0", (i64 * 1)(1), 8, 64,
         counts, sizes, buf, ctypes.byref(files), ctypes.byref(nbytes),
+        ctypes.byref(stop),
     )
     assert status != 0
-    assert (files.value, nbytes.value, counts[0]) == (0, 0, 0)
+    assert (files.value, nbytes.value, counts[0], stop.value) == (0, 0, 0, 1)
 
 
 def test_sixteen_threads_poll_sixteen_remotes(mode, tmp_path):
@@ -669,8 +676,8 @@ def test_a_polls_spans_fire_once_a_tenant_under_serve_ingest(
 
 
 # ---- the chunk iterator (ISSUE 35): a window of wanted devices is ONE
-# call of ``load_op_window`` in one worker hop; any status but 0 sends that
-# window through the per-actor rounds.  A third door on the reads above ----
+# call of ``load_op_window`` in one worker hop; any status but 0 hands that
+# window to the per-file reader.  A third door on the reads above ----
 
 
 def chunk_reads() -> tuple:
@@ -760,8 +767,8 @@ def shape_a_run_longer_than_a_windows_buffers(root, mp):
 
 def shape_one_file_larger_than_the_buffer(root, mp):
     """A's second file alone overflows a call's buffer: the call that
-    would start with it is ERANGE, and that window (A from 2, B) runs the
-    per-actor rounds, whose buffer is the file's size."""
+    would start with it is ERANGE, and that window (A from 2, B) is the
+    per-file reader's, which takes a first file whatever its size."""
     put(root, f"r/ops/{A.hex()}/1", b"small")
     put(root, f"r/ops/{A.hex()}/2", b"L" * 300)
     put(root, f"r/ops/{A.hex()}/3", b"after")
@@ -794,8 +801,8 @@ def shape_no_regular_file_where_a_version_should_be(root, mp):
 
 def shape_a_file_that_does_not_end_at_its_size(root, mp):
     """``/proc/version`` says 0 bytes and holds a line: the one call is a
-    status, the round's second pass is a race, and the per-file probe
-    reads what is there, as it does behind ``load_ops_sync``."""
+    status, and the per-file reader reads what is there, as it does
+    behind ``load_ops_sync``."""
     put(root, f"r/ops/{A.hex()}/1", b"one")
     os.symlink("/proc/version", os.path.join(root, f"r/ops/{A.hex()}/2"))
     put(root, f"r/ops/{B.hex()}/1", b"b")
@@ -818,13 +825,15 @@ CHUNK_SHAPES = [
     shape_no_regular_file_where_a_version_should_be,
     shape_a_file_that_does_not_end_at_its_size,
 ]
-# without a library every read is per file, and opening a FIFO waits for
-# its writer: that shape's loud half is the test after this one
+# behind a status and without a library every read is per file, which is
+# loud at a directory and would wait for a FIFO's writer: that shape's
+# loud half is the test after this one
 DOORS = [
     (shape, door)
     for shape in CHUNK_SHAPES
     for door in ("native", "forced", "python")
-    if (shape, door) != (shape_no_regular_file_where_a_version_should_be, "python")
+    if door == "native"
+    or shape is not shape_no_regular_file_where_a_version_should_be
 ]
 
 
@@ -835,8 +844,8 @@ def test_the_chunk_iterator_gives_the_answer_load_ops_gives(
     shape, door, tmp_path, monkeypatch
 ):
     """Concatenated, the chunks equal ``load_ops_sync`` of the request:
-    from the windows' native calls, from the per-actor rounds when every
-    call is a status, and per file on a machine without a toolchain."""
+    from the windows' native calls, and from the per-file reader when
+    every call is a status and on a machine without a toolchain."""
     lib = native.load()
     root = str(tmp_path)
     s = storage_at(root)
@@ -853,17 +862,22 @@ def test_the_chunk_iterator_gives_the_answer_load_ops_gives(
     trace.reset()
 
 
+@pytest.mark.parametrize("door", ["forced", "python"])
 @pytest.mark.parametrize("read", ["load_ops_sync", "iter_op_chunks"])
 def test_a_directory_where_a_version_should_be_is_loud_per_file(
-    read, tmp_path, monkeypatch
+    read, door, tmp_path, monkeypatch
 ):
-    """The per-file path cannot tell a directory from a defect and
-    raises; the chunk iterator hands that on as the poll's read does."""
+    """The per-file reader cannot tell a directory from a defect and
+    raises, behind a status as without a library; the chunk iterator
+    hands that on as the poll's read does."""
     root = str(tmp_path)
     s = storage_at(root)
     put(root, f"r/ops/{A.hex()}/1", b"one")
     os.makedirs(os.path.join(root, f"r/ops/{A.hex()}/2"))
-    no_toolchain(monkeypatch)
+    if door == "forced":
+        monkeypatch.setattr(native.load(), "load_op_window", lambda *a: 5)
+    else:
+        no_toolchain(monkeypatch)
     with pytest.raises(IsADirectoryError):
         if read == "load_ops_sync":
             s.load_ops_sync([(A, 1)])
@@ -875,8 +889,7 @@ def test_a_directory_where_a_version_should_be_is_loud_per_file(
 def test_a_file_removed_mid_window_ends_its_run(door, tmp_path, monkeypatch):
     """The sync tool takes A's second file away while the window is being
     read.  The one call opens a file before it sizes it, so it finds the
-    file or does not; the rounds size a run and then read it, and the
-    race between their passes (``_ScanRace``) is the per-file probe.
+    file or does not; behind a status the per-file reader finds it gone.
     Either way A's run ends where the file was and B's is whole."""
     lib = native.load()
     root = str(tmp_path)
@@ -884,22 +897,140 @@ def test_a_file_removed_mid_window_ends_its_run(door, tmp_path, monkeypatch):
     for v in (1, 2, 3):
         put(root, f"r/ops/{A.hex()}/{v}", b"a%d" % v)
     put(root, f"r/ops/{B.hex()}/1", b"b1")
-    raced = "load_op_window" if door == "native" else "read_op_files"
-    real = getattr(lib, raced)
+    real = lib.load_op_window
 
     def taken_away(*args):
         if os.path.exists(os.path.join(root, f"r/ops/{A.hex()}/2")):
             os.remove(os.path.join(root, f"r/ops/{A.hex()}/2"))
-        return real(*args)
+        return real(*args) if door == "native" else 11
 
-    monkeypatch.setattr(lib, raced, taken_away)
-    if door == "forced":
-        monkeypatch.setattr(lib, "load_op_window", lambda *a: 11)
+    monkeypatch.setattr(lib, "load_op_window", taken_away)
     trace.reset()
     expected = [(A, 1, b"a1"), (B, 1, b"b1")]
     assert flat(chunked(s, [(A, 1), (B, 1)])) == expected
     assert chunk_reads() == ((1, 0) if door == "native" else (0, 1))
     assert s.load_ops_sync([(A, 1), (B, 1)]) == expected
+    trace.reset()
+
+
+# ---- the two readers' one contract (ISSUE 48): ``reader(wanted,
+# max_files, max_bytes) -> (files, rest)``, each call of a drain held to
+# what the contract says of the request and the files that are there ----
+
+
+def shape_runs_asked_from_their_middle_and_past_their_end(root, mp):
+    blobs = [bytes([i]) * (i * 37 + 1) for i in range(12)]
+    for actor in (A, B, C):
+        for v, blob in enumerate(blobs, start=1):
+            put(root, f"r/ops/{actor.hex()}/{v}", blob)
+    expected = [(A, v, blobs[v - 1]) for v in range(5, 13)]
+    expected += [(C, 12, blobs[11])]
+    return [(A, 5), (B, 13), (C, 12)], expected, (1, 0), 1
+
+
+# shape -> its budgets ``(max_files, max_bytes)``: one that holds
+# everything, one that stops mid-run and one that stops between two pairs,
+# where the shape has such a place; no budget is under a file's size (a
+# first file that alone overflows is a status natively, by design)
+EVERYTHING = {"everything": (64, 1 << 16)}
+CONTRACT_SHAPES = {
+    shape_no_ops_directory: EVERYTHING,
+    shape_absent_device_directory: EVERYTHING,
+    shape_nothing_new: EVERYTHING,
+    shape_a_gap_ends_the_run: {
+        **EVERYTHING, "mid_run": (1, 1 << 16), "between_pairs": (2, 1 << 16)},
+    shape_a_run_crosses_a_windows_edge: {
+        **EVERYTHING, "mid_run": (64, 100), "between_pairs": (64, 120)},
+    shape_a_run_longer_than_a_windows_buffers: {
+        **EVERYTHING, "mid_run": (4, 1 << 16), "between_pairs": (11, 1 << 16)},
+    shape_one_file_larger_than_the_buffer: {
+        **EVERYTHING, "mid_run": (64, 305), "between_pairs": (64, 310)},
+    shape_a_zero_byte_file: {
+        **EVERYTHING, "mid_run": (1, 1 << 16), "between_pairs": (2, 1 << 16)},
+    shape_runs_asked_from_their_middle_and_past_their_end: {
+        **EVERYTHING, "mid_run": (64, 700), "between_pairs": (8, 1 << 16)},
+}
+CONTRACT = [
+    (shape, budget, reader)
+    for shape, budgets in CONTRACT_SHAPES.items()
+    for budget in budgets
+    for reader in ("_native_runs", "_file_runs")
+]
+
+
+def the_contract(wanted, there, max_files, max_bytes) -> tuple:
+    """What one call answers, ``there`` being the files of the request
+    that are there, in its order: it stops before the file its budget
+    does not hold, and never before its first."""
+    files, size = [], 0
+    for actor, version, raw in there:
+        if files and (len(files) >= max_files or size + len(raw) > max_bytes):
+            behind = [pair[0] for pair in wanted].index(actor)
+            return files, [(actor, version), *wanted[behind + 1:]]
+        files.append((actor, version, raw))
+        size += len(raw)
+    return files, []
+
+
+@pytest.mark.parametrize(
+    "shape,budget,reader", CONTRACT,
+    ids=[f"{s.__name__[6:]}-{b}-{r}" for s, b, r in CONTRACT],
+)
+def test_the_two_readers_keep_one_contract(
+    shape, budget, reader, tmp_path, monkeypatch
+):
+    native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    wanted, expected, _, _ = shape(root, monkeypatch)
+    max_files, max_bytes = CONTRACT_SHAPES[shape][budget]
+    got, rest, stops = [], wanted, []
+    while rest:
+        files, behind = getattr(s, reader)(rest, max_files, max_bytes)
+        assert (files, behind) == the_contract(
+            rest, expected[len(got):], max_files, max_bytes
+        )
+        assert files or not behind, "a drain makes progress"
+        got += files
+        if behind:
+            stops.append(got[-1][0] == behind[0][0])
+        rest = behind
+    assert got == expected
+    if budget == "everything":
+        assert not stops
+    else:  # each stop: was it inside a run?
+        assert (budget == "mid_run") in stops
+
+
+def test_a_window_that_fell_back_is_read_within_its_budget(tmp_path, monkeypatch):
+    """The memory a window may hold is its call's budget, whichever
+    reader runs: behind a status the per-file reader stops where the
+    native call's buffers would have been full (a first file is taken
+    whatever its size), and is gone on with from there."""
+    lib = native.load()
+    root = str(tmp_path)
+    s = storage_at(root)
+    for v in range(1, 10):
+        put(root, f"r/ops/{A.hex()}/{v}", bytes([v]) * 40)
+    put(root, f"r/ops/{B.hex()}/1", b"L" * 300)
+    put(root, f"r/ops/{B.hex()}/2", b"b" * 40)
+    expected = [(A, v, bytes([v]) * 40) for v in range(1, 10)]
+    expected += [(B, 1, b"L" * 300), (B, 2, b"b" * 40)]
+    small_windows(monkeypatch, actors=2, files=3, buffer=100)
+    monkeypatch.setattr(lib, "load_op_window", lambda *a: 5)
+    taken = []
+    real = FsStorage._file_runs
+
+    def file_runs(self, *args):
+        files, rest = real(self, *args)
+        taken.append([len(raw) for _, _, raw in files])
+        return files, rest
+
+    monkeypatch.setattr(FsStorage, "_file_runs", file_runs)
+    trace.reset()
+    assert flat(chunked(s, [(A, 1), (B, 1)])) == expected
+    assert taken == [[40, 40]] * 4 + [[40], [300], [40]]
+    assert chunk_reads() == (0, 1)
     trace.reset()
 
 
@@ -912,21 +1043,20 @@ def thousand_devices(root) -> tuple:
 
 
 def count_hops(monkeypatch) -> dict:
-    """Worker hops by their job: the windows' (``_native_runs``) and
-    whatever went through ``_run`` (the probe and the rounds)."""
-    hops = {"windows": 0, "rounds": 0}
-    real_window, real_run = FsStorage._native_runs, FsStorage._run
+    """Calls of the two readers (in the chunk iterator: worker hops)."""
+    hops = {"native": 0, "per_file": 0}
+    real_native, real_per_file = FsStorage._native_runs, FsStorage._file_runs
 
-    def window(self, *args):
-        hops["windows"] += 1
-        return real_window(self, *args)
+    def native_runs(self, *args, **kw):
+        hops["native"] += 1
+        return real_native(self, *args, **kw)
 
-    async def run(self, fn, *args):
-        hops["rounds"] += 1
-        return await real_run(self, fn, *args)
+    def file_runs(self, *args):
+        hops["per_file"] += 1
+        return real_per_file(self, *args)
 
-    monkeypatch.setattr(FsStorage, "_native_runs", window)
-    monkeypatch.setattr(FsStorage, "_run", run)
+    monkeypatch.setattr(FsStorage, "_native_runs", native_runs)
+    monkeypatch.setattr(FsStorage, "_file_runs", file_runs)
     return hops
 
 
@@ -935,8 +1065,8 @@ def test_a_round_of_a_thousand_devices_is_a_hop_a_window(
     window, tmp_path, monkeypatch
 ):
     """1,000 devices with one new file each: ``ceil(1,000 / window)``
-    worker hops, as many native calls, and nothing else: no probe pass,
-    no task, queue or hop a device."""
+    worker hops, as many native calls, and nothing else: no probe pass
+    and no hop a device."""
     native.load()
     root = str(tmp_path)
     s = storage_at(root)
@@ -948,15 +1078,15 @@ def test_a_round_of_a_thousand_devices_is_a_hop_a_window(
     trace.reset()
     chunks = chunked(s, wanted)
     assert flat(chunks) == expected and len(chunks) == 1
-    assert hops == {"windows": windows, "rounds": 0}
+    assert hops == {"native": windows, "per_file": 0}
     assert chunk_reads() == (windows, 0)
     assert reads() == (0, 0) and steps() == (0, 0)
     trace.reset()
 
 
 def test_a_forced_status_counts_python_once_a_window(tmp_path, monkeypatch):
-    """Every call a status: each window is counted once and runs the
-    probe and the rounds for its devices, and the files are the same."""
+    """Every call a status: each window is counted once and handed to
+    the per-file reader, one more hop, and the files are the same."""
     lib = native.load()
     root = str(tmp_path)
     s = storage_at(root)
@@ -967,8 +1097,7 @@ def test_a_forced_status_counts_python_once_a_window(tmp_path, monkeypatch):
     trace.reset()
     assert flat(chunked(s, wanted)) == expected
     assert chunk_reads() == (0, 4)
-    # a window: its one hop, then the probe and a round a device
-    assert hops == {"windows": 4, "rounds": 4 + 1000}
+    assert hops == {"native": 4, "per_file": 4}
     trace.reset()
 
 
@@ -993,11 +1122,11 @@ def test_the_windows_behind_are_being_read_while_the_first_is_emitted(
     entered, release = [], threading.Event()
     real = FsStorage._native_runs
 
-    def held(self, window, *args):
+    def held(self, window, *args, **kw):
         entered.append(window[0][0])
         if window[0][0] != devices[0]:
             assert release.wait(timeout=30)
-        return real(self, window, *args)
+        return real(self, window, *args, **kw)
 
     monkeypatch.setattr(FsStorage, "_native_runs", held)
 

@@ -989,7 +989,7 @@ def _read_body(body: str, monkeypatch) -> None:
         no_toolchain(monkeypatch)
     elif body == "status":
         monkeypatch.setattr(lib, "list_dir_names", lambda *a: 5)  # EIO, say
-        monkeypatch.setattr(lib, "load_op_runs", lambda *a: 5)
+        monkeypatch.setattr(lib, "load_op_window", lambda *a: 5)
 
 
 @pytest.mark.parametrize("storage", ["twins", "awaited"])
