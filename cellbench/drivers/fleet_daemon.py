@@ -22,8 +22,12 @@ status 2 and nothing on standard output.
 each of its ``active_tenants`` tenants, stored through ``FsStorage`` objects of
 the writers' own by a thread with an event loop of its own, so neither an
 ``await`` nor a synchronous stretch of the program's loop holds a tick back.
-The clock starts with the first ``publish()`` (the first warm-up step) and
-stops in ``check()``; tick 0 is stored before that ``publish()`` returns.  The
+The mix is the cell's own, as the harness loaded it (``plan.traffic``): one
+deployment has a cell below its knee and a cell above it, and the driver is
+told which by nothing else.  The clock starts with the first ``publish()``
+(the first warm-up step) and stops in ``check()``; tick 0 is stored before
+that ``publish()`` returns.  A clock that runs out of prepared ticks before
+then fails the run: its tail was offered another load than the cell's.  The
 harness's ``publish(r)`` / ``call(r)`` protocol stays as it is: ``publish``
 only makes sure the clock runs (and hands ``withhold`` to the next tick to
 land), ``call`` is the step.  The plan is the harness's; where it has fewer
@@ -46,7 +50,10 @@ rate, and it falls where the loop falls behind (both by ``fleet.py``'s
 ``batch`` tenants each.  Then every bucket shape a cycle of the window can meet
 is folded once over throw-away tenants by a second ``FoldService`` of the same
 ``ServeConfig`` (the compiled programs are the process's): slots 1 to the
-power of two that holds ``batch``, rows of one to ``SHAPE_FILES`` files.  The
+power of two that holds ``batch``, rows of one to ``shape_files`` files (the
+mix's key, 3 where it has none: the most files a tenant may have waiting at a
+visit and find its fold compiled; one cycle for each rows class, which the
+service pads to a power of two, so 1, 2, 3, 6 and 11 files of 24 ops).  The
 mix's warm-up steps then run under the ticking clock, so that the window opens
 on a loop in its steady state: after the head the tenants come due by idleness
 in the blocks the head was taken in, ``max_idle_cycles`` later, and the
@@ -63,10 +70,10 @@ import time
 import types
 from collections import deque
 
-from cellbench import gen, run
+from cellbench import gen
 from cellbench.drivers import fleet
 
-SHAPE_FILES = 3  # files a tenant may have waiting at a visit and find compiled
+SHAPE_FILES = 3  # the mix's ``shape_files`` where it states none
 WATCHED = ("daemon.select", "serve.run_cycle", "daemon.poll", "daemon.pace")
 COUNTED = ("daemon_due", "daemon_selected", "daemon_deferred", "daemon_polled",
            "serve_rows_folded")
@@ -76,16 +83,17 @@ def say(*a) -> None:
     print("cellbench:", *a, file=sys.stderr, flush=True)
 
 
-def open_mix(config: dict) -> dict:
-    """The open-loop mix of this configuration's cell, found as the harness
-    finds it: by the manifest's entry, in ``traffic/<mix>.json``."""
-    manifest = run.load_json(run.ROOT, "BENCHMARK.json")
-    for w in manifest["workloads"]:
-        if w["config"] == config["name"]:
-            mix = run.load_json(run.ROOT, run.BENCH, "traffic", w["traffic"] + ".json")
-            if str(mix.get("loop", "")).startswith("open"):
-                return mix
-    raise SystemExit(f"cellbench: no open-loop mix for {config['name']!r}")
+def shape_file_counts(most: int, opf: int) -> list:
+    """The file counts from 1 to ``most`` that each open a rows class of their
+    own: a tenant's rows are padded to a power of two, so of the counts that
+    share a class the first folds it for all."""
+    counts, top = [], 0
+    for files in range(1, most + 1):
+        rows = 1 << (files * opf - 1).bit_length()
+        if rows > top:
+            counts.append(files)
+            top = rows
+    return counts
 
 
 def daemon_config(config: dict):
@@ -208,15 +216,19 @@ class Driver(fleet.Driver):
     def __init__(self, config: dict, plan: gen.Plan, workdir: str):
         refuse_unless_daemon_serves(config)
         self.config = config
-        mix = open_mix(config)
+        mix = plan.traffic
+        if not str(mix.get("loop", "")).startswith("open"):
+            raise SystemExit(f"cellbench: the driver fleet_daemon needs an open-loop "
+                             f"mix, and {mix.get('name')!r} is not one")
         self.tick_s = mix["tick_s"]
+        self.shape_files = mix.get("shape_files", SHAPE_FILES)
         need = -(-mix["min_clock_s"] // self.tick_s)
         if plan.n_rounds < need:
             # a toy window: the harness prepared by --seconds, the warm-up's
             # steps run under the clock besides
             files = len(plan.files_of_round(0))
             plan = gen.plan_run(
-                config, {"active_tenants": files, "active_devices": 1,
+                config, {**mix, "active_tenants": files, "active_devices": 1,
                          "files_per_device": 1}, plan.seed, int(need))
         super().__init__(config, plan, workdir)
         self.pending: dict = {}  # tenant -> [[actor, version, ops, t_stored]]
@@ -281,7 +293,7 @@ class Driver(fleet.Driver):
 
     async def _fold_every_shape(self, most: int) -> int:
         """Every ``(slots, rows)`` class a cycle over at most ``most`` tenants
-        with one to ``SHAPE_FILES`` new files each can make, folded (and cut)
+        with one to ``shape_files`` new files each can make, folded (and cut)
         once: the same throw-away tenants serve every class, more than half
         the slots of each."""
         from crdt_enc_tpu.serve import FoldService
@@ -321,7 +333,7 @@ class Driver(fleet.Driver):
             await cycle(len(cores), max(len(ids), -(-members // opf)))
             count, slots = 0, top
             while slots >= 1:
-                for files in range(1, SHAPE_FILES + 1):
+                for files in shape_file_counts(self.shape_files, opf):
                     await cycle(slots // 2 + 1, files)
                     count += 1
                 slots //= 2
@@ -358,8 +370,8 @@ class Driver(fleet.Driver):
         if report is None:  # the cycle raised: every tenant with files failed
             waiting = sum(1 for files in self.pending.values() if files)
             return {"ops": 0, "attempted": max(1, waiting),
-                    "failed": max(1, waiting), "latencies": []}
-        ops, latencies = 0, []
+                    "failed": max(1, waiting), "latencies": [], "most": 0}
+        ops, latencies, most = 0, [], 0
         for tid, res in report["results"].items():
             if res["outcome"] != "sealed":
                 continue
@@ -377,6 +389,7 @@ class Driver(fleet.Driver):
                 else:
                     left.append(f)
             self.pending[tenant] = left
+            most = max(most, len(files) - len(left))
         self.unsealed -= len(latencies)
         return {
             "ops": ops,
@@ -384,6 +397,7 @@ class Driver(fleet.Driver):
             "failed": sum(1 for res in report["results"].values()
                           if res["outcome"] == "error"),
             "latencies": latencies,
+            "most": most,  # files the fullest visit took in: the rows class
         }
 
     async def call(self, r: int) -> dict:
@@ -396,7 +410,8 @@ class Driver(fleet.Driver):
         inside = self.arrivals.ticks[ticks:]
         self.steps.append({
             "r": r, "cycle": self.daemon.cycle, "wall": wall,
-            "files": len(outcome["latencies"]), "ticks": len(inside),
+            "files": len(outcome["latencies"]), "most": outcome["most"],
+            "ticks": len(inside),
             "late": max((late for _, late, _ in inside), default=0.0),
             "unsealed": self.unsealed,
         })
@@ -411,8 +426,9 @@ class Driver(fleet.Driver):
         self._print_steps()
         self._take_landed()
         if arrivals.ran_out:
-            say("the prepared ticks ran out before the window closed: the clock "
-                "stopped early (raise the mix's max_ops_per_s)")
+            # the window's tail was offered nothing: another load, not this cell's
+            raise RuntimeError("the prepared ticks ran out before the window closed: "
+                               "raise the mix's max_ops_per_s")
         for tenant, ab, version, ops in arrivals.withheld:
             self.pending.setdefault(tenant, []).append([ab, version, ops, None])
             self.unsealed += 1
@@ -441,7 +457,8 @@ class Driver(fleet.Driver):
             say(f"step {s['r']} (cycle {s['cycle']}) {s['wall']:.3f} s: due "
                 f"{d['daemon_due']} selected {d['daemon_selected']} deferred "
                 f"{d['daemon_deferred']} polled {d['daemon_polled']}; sealed "
-                f"{s['files']} files, {d['serve_rows_folded']} rows; select "
+                f"{s['files']} files, {s['most']} in the fullest visit, "
+                f"{d['serve_rows_folded']} rows; select "
                 f"{1e3 * d['daemon.select']:.1f} ms, service "
                 f"{1e3 * d['serve.run_cycle']:.0f}, poll {1e3 * d['daemon.poll']:.0f}, "
                 f"pace {1e3 * d['daemon.pace']:.0f}; {s['ticks']} ticks landed "

@@ -35,9 +35,12 @@ class Plan:
 
     Rows are in file order, ``opf`` rows to a file; files are in round order,
     the head (round -1) first.  ``actor`` is the global writer index
-    ``tenant * D + device``."""
+    ``tenant * D + device``.  ``traffic`` is the mix the rounds were drawn
+    from (the cell's, under any overlay): a driver that needs more of its
+    cell's mix than the rounds show reads it here."""
 
     seed: int
+    traffic: dict
     tenants: int
     devices: int
     members: int
@@ -167,7 +170,8 @@ def plan_run(config: dict, traffic: dict, seed: int, n_rounds: int) -> Plan:
     counter = _dense_rank(actor, kind == 0).astype(np.int32)
     live = ~((kind == 1) & (counter == 0))
     return Plan(
-        seed=seed, tenants=T, devices=D, members=E, opf=opf, n_rounds=n_rounds,
+        seed=seed, traffic=traffic, tenants=T, devices=D, members=E, opf=opf,
+        n_rounds=n_rounds,
         kind=kind, member=member, actor=actor, counter=counter, live=live,
         f_actor=f_actor, f_version=f_version,
         round_files=bounds, actor_bytes=actor_table(D),

@@ -139,7 +139,7 @@ def plan_zipf(config: dict, uniform: gen.Plan) -> ZipfPlan:
     counter = gen._dense_rank(actor, kind == 0).astype(np.int32)
     live = ~((kind == 1) & (counter == 0))
     return ZipfPlan(
-        seed=uniform.seed, tenants=T, devices=wide, members=config["members"],
+        seed=uniform.seed, traffic=uniform.traffic, tenants=T, devices=wide, members=config["members"],
         opf=opf, n_rounds=uniform.n_rounds,
         kind=kind, member=member, actor=actor, counter=counter, live=live,
         f_actor=f_actor, f_version=f_version, round_files=bounds,

@@ -2,14 +2,28 @@
 functions of a manifest and the root it was loaded from: the tests of the
 committed benchmark call them with ``BENCHMARK.json`` and the checkout, and
 ``test_harness_takes_additions.py`` calls them again on a temporary root to
-which a configuration, a mix, two cells and two per-layer metrics were
-appended.  A check says what must be there, never how many entries there are
-or where in a list one stands, so a later PR that appends needs no edit here.
+which configurations, a mix, cells and per-layer metrics were appended.  A
+check says what must be there, never how many entries there are or where in a
+list one stands, so a later PR that appends needs no edit here.
+
+**This module is the only way into the manifest** (ISSUE 49).  A test file
+hands ``BENCHMARK.json`` whole to a function of this module and never
+subscripts it, iterates over it or takes an entry out of it.  What a cell's
+test file holds about its own entries it states in a function of its own named
+``check_*`` with exactly the parameters ``(manifest, root)``, built from the
+``hold_*`` functions here, which can say "is in", "in this order" and "lists",
+and cannot say "is last" or "lists nothing else".
+``manifest_level_checks()`` finds every such function, here and in every
+``test_*.py`` beside this file (a later PR's among them), and the harness test
+runs them all on its augmented root: a pin of a position or a count fails
+there, in the PR that writes it, and not in the next PR that appends.
 
 Code (readers, drivers) is always the checkout's; only data is per root.
 """
 
 import glob
+import importlib
+import inspect
 import json
 import os
 import re
@@ -21,6 +35,9 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 KINDS = ("configs", "workloads", "end_to_end", "per_layer")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# how many entries the driver of the round admits in each list (its contract):
+# the only counts a test holds, and no number a later PR has to move
+LIMITS = {"configs": 24, "workloads": 24, "end_to_end": 16, "per_layer": 128}
 
 # toy sizes laid over the real files: an argument the command never passes
 TINY = {
@@ -36,6 +53,15 @@ TINY_WRITERS = {"backlog": 8, "trickle": 6, "busy": 6, "quiet": 2}
 
 def cells(manifest: dict) -> list:
     return [w["name"] for w in manifest["workloads"]]
+
+
+def metrics(manifest: dict) -> list:
+    return [m["name"] for m in manifest["per_layer"]]
+
+
+def pairs(manifest: dict) -> list:
+    """Every (per-layer metric, cell) pair the manifest lists."""
+    return [(m["name"], cell) for m in manifest["per_layer"] for cell in m["workloads"]]
 
 
 def entry_of(manifest: dict, kind: str, name: str) -> dict:
@@ -81,7 +107,13 @@ def check_contract_keys(manifest: dict, root: str) -> None:
     assert all(not w.startswith("/") and ".." not in w for w in manifest["command"])
 
 
-def check_names_and_units(manifest: dict) -> None:
+def check_list_lengths(manifest: dict, root: str) -> None:
+    """Each list holds at least one entry and no more than the driver admits."""
+    for kind, most in LIMITS.items():
+        assert 1 <= len(manifest[kind]) <= most, (kind, len(manifest[kind]), most)
+
+
+def check_names_and_units(manifest: dict, root: str) -> None:
     names = [x["name"] for kind in KINDS for x in manifest[kind]]
     names += [w["traffic"] for w in manifest["workloads"]]
     names += [k for c in manifest["configs"] for k in c["reduced"]]
@@ -98,7 +130,7 @@ def check_names_and_units(manifest: dict) -> None:
         assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
 
 
-def check_four_chip_share(manifest: dict) -> None:
+def check_four_chip_share(manifest: dict, root: str) -> None:
     """At most half of the cells, rounded down, but always one, may ask for 4."""
     four = [w["name"] for w in manifest["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(manifest["workloads"]) // 2), four
@@ -120,6 +152,11 @@ def check_cell(manifest: dict, root: str, cell: str) -> None:
     reported = [m["name"] for m in loaded["end_to_end"]]
     assert "setup_s" in reported and len(reported) >= 2
     assert loaded["per_layer"]
+
+
+def check_every_cell(manifest: dict, root: str) -> None:
+    for cell in cells(manifest):
+        check_cell(manifest, root, cell)
 
 
 def families(spec: dict) -> tuple:
@@ -161,6 +198,12 @@ def check_layer_metric(manifest: dict, root: str, metric: str) -> None:
         check_pair(manifest, root, metric, cell)
     if "_roofline" in metric:
         assert entry["unit"] == "%"
+
+
+def check_every_layer_metric(manifest: dict, root: str) -> None:
+    """Every entry against its file, and every (metric, cell) pair in it."""
+    for metric in metrics(manifest):
+        check_layer_metric(manifest, root, metric)
 
 
 def definition(spec: dict) -> dict:
@@ -236,3 +279,88 @@ def check_kernel_metric_is_pinned(root: str, metric: str) -> None:
     raise AssertionError(
         f"no file under tests/cellbench names {metric} and its module strings "
         f"{strings}: pin them against the jitted functions in a new test file")
+
+
+def check_every_kernel_metric_is_pinned(manifest: dict, root: str) -> None:
+    for metric in kernel_strings(root):
+        check_kernel_metric_is_pinned(root, metric)
+
+
+# --------------- what a cell's own ``check_*(manifest, root)`` is built from
+
+
+def in_order(listed, wanted) -> bool:
+    """``wanted`` appears in ``listed`` in that order; others may stand
+    before, between and after."""
+    rest = iter(listed)
+    return all(name in rest for name in wanted)
+
+
+def hold_cell(manifest: dict, root: str, cell: str, *, config: str, traffic: str,
+              chips: int, end_to_end) -> None:
+    """The cell is among the workloads as its file has it (``check_cell``),
+    on that configuration, mix and chips, and reports exactly ``end_to_end``
+    and ``setup_s`` (what a cell is judged on is its own, and no later PR's)."""
+    check_cell(manifest, root, cell)
+    entry = entry_of(manifest, "workloads", cell)
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (config, traffic, chips)
+    reported = {m["name"] for m in manifest["end_to_end"]
+                if cell in m.get("workloads", [cell])}
+    assert reported == {*end_to_end, "setup_s"}, reported
+
+
+def hold_config(manifest: dict, root: str, config: str, *, reduced) -> dict:
+    """The configuration's entry gives its file's source (at most 200
+    characters) and its cuts; returns the file."""
+    entry = entry_of(manifest, "configs", config)
+    file = run.load_json(root, entry["file"])
+    assert entry["source"] == file["source"] and len(file["source"]) <= 200
+    assert entry["reduced"] == sorted(file["reduced"]) == sorted(reduced)
+    return file
+
+
+def hold_metric(manifest: dict, metric: str, *, cells=(), moves=None, layer=None,
+                source=None) -> None:
+    """The entry lists ``cells`` in that order (a later cell may be listed
+    anywhere) and, where given, moves that metric from that layer."""
+    entry = entry_of(manifest, "per_layer", metric)
+    assert in_order(entry["workloads"], cells), (metric, entry["workloads"])
+    for key, want in (("moves", moves), ("layer", layer), ("source", source)):
+        assert want is None or entry[key] == want, (metric, key, entry[key])
+
+
+def hold_metrics_in_order(manifest: dict, names, layer=None) -> None:
+    """``names`` are entries, in that order among themselves (of ``layer``,
+    where one is given: each is then of it)."""
+    listed = [m["name"] for m in manifest["per_layer"]
+              if layer is None or m["layer"] == layer]
+    assert in_order(listed, names), (names, listed)
+
+
+def hold_cell_lists(root: str, cell: str, names) -> dict:
+    """The cell lists ``names`` at the least; returns all it lists."""
+    got = listed(root, cell)
+    assert set(names) <= set(got), set(names) - set(got)
+    return got
+
+
+# ------------------------------------------------ every check there is
+
+
+def manifest_level_checks() -> dict:
+    """``"<module>.<function>" -> function`` for every function named
+    ``check_*`` whose parameters are exactly ``(manifest, root)``, in this
+    module and in every ``test_*.py`` beside it."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    names = [__name__] + sorted(
+        os.path.basename(p)[:-len(".py")]
+        for p in glob.glob(os.path.join(here, "test_*.py")))
+    found = {}
+    for name in names:
+        module = importlib.import_module(name)
+        for attr, fn in sorted(vars(module).items()):
+            if (attr.startswith("check_") and inspect.isfunction(fn)
+                    and fn.__module__ == module.__name__
+                    and tuple(inspect.signature(fn).parameters) == ("manifest", "root")):
+                found[f"{name}.{attr}"] = fn
+    return found

@@ -22,8 +22,8 @@ import manifest_checks as checks
 
 ROOT = run.ROOT
 MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
-CELLS = [w["name"] for w in MANIFEST["workloads"]]
-LAYER = [m["name"] for m in MANIFEST["per_layer"]]
+CELLS = checks.cells(MANIFEST)
+LAYER = checks.metrics(MANIFEST)
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
@@ -44,8 +44,13 @@ def test_manifest_has_exactly_the_contract_keys():
     checks.check_contract_keys(MANIFEST, ROOT)
 
 
+def test_each_list_is_within_what_the_driver_admits():
+    checks.check_list_lengths(MANIFEST, ROOT)
+    assert checks.LIMITS["per_layer"] == 128, "the driver's, not a count of what is there"
+
+
 def test_names_and_units_use_the_allowed_characters():
-    checks.check_names_and_units(MANIFEST)
+    checks.check_names_and_units(MANIFEST, ROOT)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -54,16 +59,16 @@ def test_cell_file_agrees_with_the_manifest(cell):
 
 
 def test_at_most_half_of_the_cells_ask_for_four_chips():
-    checks.check_four_chip_share(MANIFEST)
+    checks.check_four_chip_share(MANIFEST, ROOT)
     cell = {"name": "c", "config": "x", "traffic": "t", "why": "w"}
     for chips, ok in [([4], True), ([4, 1], True), ([4, 4, 1], False),
                       ([4, 4, 1, 1], True), ([4, 4, 4, 1, 1], False)]:
         manifest = {"workloads": [{**cell, "chips": c} for c in chips]}
         if ok:
-            checks.check_four_chip_share(manifest)
+            checks.check_four_chip_share(manifest, ROOT)
         else:
             with pytest.raises(AssertionError):
-                checks.check_four_chip_share(manifest)
+                checks.check_four_chip_share(manifest, ROOT)
 
 
 @pytest.mark.parametrize("metric", LAYER)

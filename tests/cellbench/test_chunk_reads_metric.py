@@ -18,6 +18,7 @@ import pytest
 from cellbench import run
 from cellbench.readers import counter_ratio
 
+import manifest_checks as checks
 from test_cellbench import tiny
 
 ROOT = run.ROOT
@@ -57,10 +58,16 @@ def test_file_is_what_it_was_made_from_but_for_what_differs(metric):
     want = {**CHUNK_READS, "what": spec["what"]} if original is None else spec_of(original)
     assert spec == {**want, **differs, "name": metric, "driver": "folder"}
     assert spec["what"]
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
-    assert entry["workloads"][:len(FOLDER)] == FOLDER, "the solo folder's cells first"
-    for key in ("layer", "unit", "better", "source", "moves"):
-        assert entry[key] == spec[key], key
+    checks.check_layer_metric(MANIFEST, ROOT, metric)  # the entry is the file's
+
+
+def check_the_solo_folders_cells_read_both(manifest: dict, root: str) -> None:
+    for metric in NEW:
+        checks.hold_metric(manifest, metric, cells=FOLDER)
+
+
+def test_the_solo_folders_cells_are_listed_in_their_order():
+    check_the_solo_folders_cells_read_both(MANIFEST, ROOT)
 
 
 @pytest.mark.parametrize("w", [

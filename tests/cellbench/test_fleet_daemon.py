@@ -25,8 +25,11 @@ import manifest_checks as checks
 ROOT = run.ROOT
 MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
 CELL = "orset_fleet_daemon.steady"
-NEW = {m + ".fleet_daemon" for m in (
-    "poll_ms", "pace_ms", "select_ms", "selected_per_cycle", "deferred_per_cycle")}
+LAYER = "daemon control plane"
+# the five that ISSUE 45 brought, in the order it appended them
+FIVE = [m + ".fleet_daemon" for m in (
+    "poll_ms", "pace_ms", "select_ms", "selected_per_cycle", "deferred_per_cycle")]
+NEW = set(FIVE)
 # what the cell takes from the uniform fleet's entries at the least (ISSUE 45)
 SHARED = {m + ".fleet" for m in (
     "ingest_wall_ms", "listing_ms", "fold_wall_ms", "seal_wall_ms", "unattributed_ms",
@@ -50,9 +53,6 @@ def test_configuration_is_the_fleets_but_for_what_the_daemon_adds():
     assert daemon["driver"] == "fleet_daemon" and daemon["tenants"] == 1024
     assert daemon["guarantees"][:4] == fleet["guarantees"]
     assert len(daemon["guarantees"]) == 6
-    entry = checks.entry_of(MANIFEST, "configs", "orset_fleet_daemon")
-    assert entry["source"] == daemon["source"] and len(daemon["source"]) <= 200
-    assert entry["reduced"] == sorted(daemon["reduced"]) == ["storage"]
 
 
 @pytest.mark.parametrize("block", [config_file("orset_fleet_daemon")["daemon"],
@@ -74,32 +74,36 @@ def test_daemon_block_is_what_the_cli_builds_field_for_field(block):
         assert block["interval_s"] == 1.0
 
 
-def test_the_entries_are_appended_and_the_cell_lists_what_the_issue_names():
-    assert MANIFEST["configs"][-1]["name"] == "orset_fleet_daemon"
-    assert MANIFEST["workloads"][-1] == {
-        k: run.load_cell(ROOT, CELL)["cell"][k]
-        for k in ("name", "config", "traffic", "chips", "why")}
-    assert MANIFEST["workloads"][-1]["chips"] == 1
-    for metric in ("serve_ops_per_s", "seal_p95_ms"):
-        assert checks.entry_of(MANIFEST, "end_to_end", metric)["workloads"][-1] == CELL
-    assert [m["name"] for m in MANIFEST["per_layer"][-5:]] == [
-        m + ".fleet_daemon" for m in ("poll_ms", "pace_ms", "select_ms",
-                                      "selected_per_cycle", "deferred_per_cycle")]
-    listed = checks.listed(ROOT, CELL)
-    assert NEW | SHARED <= set(listed)
-    for name in NEW:
-        entry = checks.entry_of(MANIFEST, "per_layer", name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "seal_p95_ms"
-        assert entry["layer"] == "daemon control plane"
-    assert all(name.endswith((".fleet", ".fleet_daemon")) for name in listed)
-    assert not checks.kernel_strings(ROOT).keys() & NEW, "no new kernel, no new pin"
+def check_the_daemons_entries(manifest: dict, root: str) -> None:
+    """What ISSUE 45 appended, held as what must be there (ISSUE 49: the
+    configuration and the cell are in their lists, the five entries are there
+    in their order and each lists the cell), never as where it stands: later
+    configurations, cells and entries follow, and a later cell of the family
+    reads the five by its name appended to their ``workloads``."""
+    daemon = checks.hold_config(manifest, root, "orset_fleet_daemon", reduced=["storage"])
+    assert daemon["driver"] == "fleet_daemon"
+    checks.hold_cell(manifest, root, CELL, config="orset_fleet_daemon", traffic="steady",
+                     chips=1, end_to_end=["serve_ops_per_s", "seal_p95_ms"])
+    checks.hold_metrics_in_order(manifest, FIVE, layer=LAYER)
+    for name in FIVE:
+        checks.hold_metric(manifest, name, cells=[CELL], moves="seal_p95_ms", layer=LAYER)
+    listed = checks.hold_cell_lists(root, CELL, NEW | SHARED)
+    # every metric the cell lists is of a family its driver belongs to
+    assert all(daemon["driver"].startswith(checks.families(spec))
+               for spec in listed.values())
+    assert not checks.kernel_strings(root).keys() & NEW, "no new kernel, no new pin"
+
+
+def test_the_entries_are_there_and_the_cell_lists_what_the_issue_names():
+    check_the_daemons_entries(MANIFEST, ROOT)
 
 
 def test_the_mix_is_an_open_loop_below_the_knee():
     mix = run.load_json(ROOT, "cellbench", "traffic", "steady.json")
     assert mix["loop"].startswith("open") and mix["tick_s"] == 0.25
     assert mix["active_devices"] == mix["files_per_device"] == 1
-    assert fleet_daemon.open_mix({"name": "orset_fleet_daemon"}) == mix
+    assert run.load_cell(ROOT, CELL)["traffic"] == mix, "the cell's mix, as the harness loads it"
+    assert "shape_files" not in mix, "the steady cell's set-up folds the parent's 27 shapes"
     offered = mix["offered"]
     files_per_s = mix["active_tenants"] / mix["tick_s"]
     assert offered["share_of_knee"] == 0.8
@@ -215,6 +219,7 @@ def test_a_file_stored_mid_cycle_goes_to_the_seal_that_folded_it(ingested_first)
     first = driver._account(report(1.0), 12.0)  # cycle 1 started at 12.0
     if ingested_first:
         assert first["ops"] == 44 and first["latencies"] == [3.0, 0.5]
+        assert first["most"] == 2, "both files in one visit: the rows class of two"
         assert driver.unsealed == 0 and driver.pending[0] == []
         return
     assert first["ops"] == 24 and first["latencies"] == [3.0] and driver.unsealed == 1
@@ -229,7 +234,7 @@ def test_a_file_stored_mid_cycle_goes_to_the_seal_that_folded_it(ingested_first)
     # a cycle that raised fails every tenant with files waiting
     driver.pending[0] = [[b"a", 3, 24, 17.0]]
     raised = driver._account(None, 18.0)
-    assert raised == {"ops": 0, "attempted": 1, "failed": 1, "latencies": []}
+    assert raised == {"ops": 0, "attempted": 1, "failed": 1, "latencies": [], "most": 0}
 
 
 # ------------------------------------------------------ the two questions

@@ -17,6 +17,7 @@ from crdt_enc_tpu.utils import trace
 import manifest_checks as checks
 
 ROOT = run.ROOT
+MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
 CELL = "orset_fleet_zipf.busy"
 # the metrics the cell came with (PR 26): five of its own, and eight of the
 # uniform fleet's that it reads from their entries (ISSUE 43; it had copies);
@@ -31,7 +32,7 @@ THIRTEEN = {m + ".fleet_zipf" for m in (
 OVERLAY = {"tenants": 6, "members": 16, "initial_files_per_device": 8}
 # 12 tenants, several bucket classes and a solo spill
 # (tests/cellbench/toys/<cell>.json says why)
-SPILL = checks.toy(run.load_json(ROOT, "BENCHMARK.json"), ROOT, CELL)
+SPILL = checks.toy(MANIFEST, ROOT, CELL)
 # 24 tenants whose vocabularies are still filling: tenants change class, and
 # buckets their slot count, from one round to the next; rank 2's head (1,024
 # ops) is past rows_cap, so it folds alone there and first cuts in round 1
@@ -121,9 +122,10 @@ def test_the_tests_overlay_gives_a_runnable_plan():
 # ------------------------------------------------- the cell, through run_cell
 
 
-def check_the_thirteen(root: str) -> None:
+def check_the_thirteen(manifest: dict, root: str) -> None:
     """The thirteen are among what the cell lists, whatever else lists it."""
-    assert len(THIRTEEN) == 13 and THIRTEEN <= set(checks.listed(root, CELL))
+    assert len(THIRTEEN) == 13
+    checks.hold_cell_lists(root, CELL, THIRTEEN)
 
 
 def test_cell_runs_several_bucket_classes_and_a_solo_spill(capsys):
@@ -138,7 +140,7 @@ def test_cell_runs_several_bucket_classes_and_a_solo_spill(capsys):
     assert 0 < metrics["stack_fill_pct.fleet_zipf"] < 100
     # the spilled tenant is looked up, and missed, every cycle
     assert metrics["warm_hit_pct.fleet_zipf"] == pytest.approx(100 * 11 / 12)
-    check_the_thirteen(ROOT)
+    check_the_thirteen(MANIFEST, ROOT)
     checks.check_toy_line(ROOT, CELL, metrics)
     assert THIRTEEN - set(metrics) == {"device_launches.fleet"}, "the CPU has no device trace"
 

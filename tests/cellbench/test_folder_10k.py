@@ -58,9 +58,18 @@ def spec_of(metric: str) -> dict:
 # ------------------------------------------------ the manifest and the files
 
 
-def test_the_cell_lists_these_and_no_metric_its_program_cannot_reach():
-    listed = checks.listed(ROOT, CELL)
-    assert set(SHARED + OWN) <= set(listed)
+def check_the_10k_cells_entries(manifest: dict, root: str) -> None:
+    """What the manifest says of the cell, its configuration and the metrics
+    of the resident fold: what must be there, in its order; later cells and
+    entries may follow anywhere (ISSUE 49)."""
+    checks.hold_config(manifest, root, "orset_folder_10k", reduced=["initial_ops"])
+    checks.hold_cell(manifest, root, CELL, config="orset_folder_10k", traffic="backlog",
+                     chips=1, end_to_end=["compact_ops_per_s", "compact_ms"])
+    # every cell that folds over resident planes reads the fold's metrics:
+    # the two solo cells, then this one
+    for metric in OWN + ["gather_kernel_ms.folder", "cells_pulled_per_op.folder"]:
+        checks.hold_metric(manifest, metric, cells=SOLO + [CELL])
+    listed = checks.hold_cell_lists(root, CELL, SHARED + OWN)
     # a toy round takes the per-file path, where no decode span opens (PR 41)
     assert "decode_ms.folder" not in listed
     for name, spec in listed.items():
@@ -69,10 +78,10 @@ def test_the_cell_lists_these_and_no_metric_its_program_cannot_reach():
         assert "host_sparse" not in text and "fold.planes" not in text, name
         assert spec.get("args", {}).get("counter") != "fold_rows_host", name
         assert not spec.get("may_be_absent"), name
-    entry = checks.entry_of(MANIFEST, "workloads", CELL)
-    assert (entry["chips"], entry["traffic"], entry["config"]) == (1, "backlog", "orset_folder_10k")
-    for metric in ("compact_ops_per_s", "compact_ms"):
-        assert CELL in checks.entry_of(MANIFEST, "end_to_end", metric)["workloads"]
+
+
+def test_the_cell_lists_these_and_no_metric_its_program_cannot_reach():
+    check_the_10k_cells_entries(MANIFEST, ROOT)
 
 
 @pytest.mark.parametrize("metric", OWN + ["gather_kernel_ms.folder",
@@ -81,7 +90,6 @@ def test_metric_of_the_resident_fold_is_read_by_every_cell_that_folds_so(metric)
     checks.check_layer_metric(MANIFEST, ROOT, metric)
     spec = spec_of(metric)
     assert spec["driver"] == "folder" and spec["what"]
-    assert checks.entry_of(MANIFEST, "per_layer", metric)["workloads"] == SOLO + [CELL]
 
 
 def test_configuration_is_config_3_whole_and_the_solo_folder_otherwise():
@@ -99,9 +107,7 @@ def test_configuration_is_config_3_whole_and_the_solo_folder_otherwise():
     assert list(whole["reduced"]) == ["initial_ops"]
     assert "4096 x 10000" in whole["layout"] and "resident" in whole["layout"]
     assert whole["driver"] == "folder_10k" and whole["driver"].startswith("folder")
-    entry = checks.entry_of(MANIFEST, "configs", "orset_folder_10k")
-    assert entry["source"] == whole["source"] != solo["source"]
-    assert len(entry["source"]) <= 200
+    assert whole["source"] != solo["source"] and len(whole["source"]) <= 200
 
 
 # ------------------------------------------------------------- the driver

@@ -17,9 +17,10 @@ from cellbench.readers import roofline_counters_pct, trace_modules_ms
 import manifest_checks as checks
 
 ROOT = run.ROOT
+MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
 CELL = "orset_folder_peers.backlog"
 # 40 devices, every share 8 (tests/cellbench/toys/<cell>.json says why)
-TOY = checks.toy(run.load_json(ROOT, "BENCHMARK.json"), ROOT, CELL)
+TOY = checks.toy(MANIFEST, ROOT, CELL)
 DEVICE_ONLY = {"merge_kernel_ms.folder_peers", "orset_merge_roofline.folder_peers",
                "device_launches.folder"}
 
@@ -172,12 +173,11 @@ def test_configuration_keeps_the_solo_folders_widths():
     assert len(peers["source"]) <= 200
 
 
-def test_the_cell_reads_the_solo_folders_metrics_from_the_solo_folders_entries():
+def check_the_cell_reads_the_solo_folders_entries(manifest: dict, root: str) -> None:
     """The timed call is the same ``Core.compact()``: the cell is listed by
     the ``.folder`` entries of the spans and counters it shares (ISSUE 43;
     it had ``.folder_peers`` copies of them before), beside the ten of its
     own path, which keep their names."""
-    listed = checks.listed(ROOT, CELL)
     shared = {m + ".folder" for m in (
         "unattributed_ms", "storage_ms", "delta_plan_ms", "delta_seal_ms",
         "repl_status_ms", "ingest_wait_ms", "h2d_bytes_per_op", "d2h_bytes_per_op",
@@ -187,9 +187,13 @@ def test_the_cell_reads_the_solo_folders_metrics_from_the_solo_folders_entries()
         "snapshot_ingest_ms", "snapshot_merge_ms", "merge_host_ms",
         "snapshots_per_merge", "plane_cache_drops_per_merge", "snapshot_bytes_per_op",
         "merge_kernel_ms", "orset_merge_roofline", "op_fold_ms", "delta_read_ms")}
-    assert shared | own <= set(listed)
+    listed = checks.hold_cell_lists(root, CELL, shared | own)
     assert all(listed[name]["driver"] == "folder" for name in shared)
     assert all(listed[name]["driver"] == "folder_peers" for name in own)
+
+
+def test_the_cell_reads_the_solo_folders_metrics_from_the_solo_folders_entries():
+    check_the_cell_reads_the_solo_folders_entries(MANIFEST, ROOT)
 
 
 # ------------------------------------------------------------ the readers

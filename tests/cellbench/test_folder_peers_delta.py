@@ -88,9 +88,7 @@ def test_configuration_is_the_peers_folder_but_for_what_syncs():
         assert delta["assumed"][key] == peers["assumed"][key]
     assert len(delta["source"]) <= 200
     assert not {"withhold_peer", "withhold_link"} & set(delta)
-    entry = next(c for c in MANIFEST["configs"]
-                 if c["name"] == "orset_folder_peers_delta")
-    assert entry["reduced"] == ["devices", "initial_ops"] and entry["source"] == delta["source"]
+    assert sorted(delta["reduced"]) == ["devices", "initial_ops"]
 
 
 # --------------------------------------------------------- the plain rule
@@ -191,9 +189,15 @@ def test_link_a_toy_peer_published_is_the_plain_diff_of_its_two_states(seed, tmp
 # --------------------------------------------------- the cell, end to end
 
 
-def check_new_and_copied_are_listed(root: str) -> None:
-    """The nineteen the cell came with (PR 32) are among what it lists."""
-    assert set(NEW + [OWN_LAYER] + SHARED) <= set(checks.listed(root, CELL))
+def check_new_and_copied_are_listed(manifest: dict, root: str) -> None:
+    """The nineteen the cell came with (PR 32) are among what it lists; its
+    configuration's entry is its file's; and the fold that follows the pass
+    is the peers folder's entry, both cells in it in their order."""
+    checks.hold_config(manifest, root, "orset_folder_peers_delta",
+                       reduced=["devices", "initial_ops"])
+    checks.hold_cell_lists(root, CELL, NEW + [OWN_LAYER] + SHARED)
+    checks.hold_metric(manifest, "op_fold_ms.folder_peers",
+                       cells=["orset_folder_peers.backlog", CELL])
 
 
 def traced(capsys, fault: dict | None = None, seconds: float = 3.0):
@@ -213,7 +217,7 @@ def test_traced_toy_run_takes_every_foreign_state_in_through_a_link(capsys):
     assert line["correct"] is True and line["failed"] == 0 and calls == 3
     assert line["compared"]["stale_peer_snapshots_left"] == {"value": 0, "limit": 0}
     assert line["compared"]["stale_peer_links_left"] == {"value": 0, "limit": 0}
-    check_new_and_copied_are_listed(ROOT)
+    check_new_and_copied_are_listed(MANIFEST, ROOT)
     # what reads the device trace finds nothing on the CPU and is left out;
     # every other listed metric is there unless its own file says it may not be
     checks.check_toy_line(ROOT, CELL, line["metrics"])
@@ -294,9 +298,6 @@ def test_delta_read_is_the_peers_folders_file_in_this_cells_layer():
         assert own[key] == peers[key], key
     assert (own["driver"], own["layer"]) == ("folder_peers_delta", "delta consumer")
     assert peers["layer"] == "snapshot merge"
-    # and the fold that follows is the peers folder's entry, both cells in it
-    entry = checks.entry_of(MANIFEST, "per_layer", "op_fold_ms.folder_peers")
-    assert entry["workloads"] == ["orset_folder_peers.backlog", CELL]
 
 
 @pytest.mark.parametrize("metric", NEW)

@@ -18,6 +18,22 @@ is then made on that root through the functions the tests themselves call,
 the one-chip cell runs traced at toy size on the CPU with the shared and the
 new metrics in its line, and what was there is still there, in its place.
 
+**Every manifest-level assertion of every test file runs here** (ISSUE 49).
+PR 45's test file pinned positions inline (``MANIFEST["configs"][-1]``,
+``per_layer[-5:]``, ``workloads == [CELL]``) and this file, which ran only the
+functions it knew, did not see them; ``test_metric_cell_pairs.py`` held the
+count of entries at 84.  Now a test file reaches the manifest only through
+``manifest_checks.py`` (``test_no_test_file_reaches_into_the_manifest...``
+reads the sources), states what it holds in functions ``check_*(manifest,
+root)``, and ``test_every_manifest_level_check_holds_on_the_augmented_root``
+runs every such function of every file on the root with the additions, among
+them a second configuration of a driver ``fleet_daemon_scratch`` whose cell
+reads ``poll_ms.fleet_daemon`` by its name appended to that entry's
+``workloads``, and the one-chip cell's name appended to
+``gather_kernel_ms.folder``: ``== [CELL]`` and ``== SOLO + [CELL]`` fail there.
+``test_the_pins_of_the_parent_fail...`` keeps the parent's assertions, word
+for word, as the proof that they do.
+
 Which case fails when a pin comes back (ISSUE 39, items 1-7):
 ``test_the_sets_the_tests_pin_are_subsets`` for "the nine are the manifest's
 last / the layer's only" (1), "the zipf cell lists thirteen" (2) and "the
@@ -37,6 +53,7 @@ import filecmp
 import glob
 import json
 import os
+import re
 import shutil
 import sys
 import types
@@ -47,17 +64,26 @@ from cellbench import run
 from cellbench.drivers import folder
 
 import manifest_checks as checks
+import test_fleet_daemon as daemon
 import test_fleet_zipf as zipf
+import test_folder_10k as ten_k
 import test_folder_peers_delta as delta
 import test_wait_metrics as waits
 
 ROOT = run.ROOT
 REAL = run.load_json(ROOT, "BENCHMARK.json")
 CELL, CELL4 = "orset_scratch.drip", "orset_scratch.backlog"
+# a second deployment a later PR adds: of the daemon's family, never run here
+DAEMON_CELL, DAEMON_DRIVER = "orset_scratch_fleet.steady", "fleet_daemon_scratch"
 SPAN = "scratch_gc_ms.folders"
 # what the cell shares with the solo folder: taken by its name appended to
 # these entries' ``workloads``, and by nothing else
 SHARED = ["storage_ms.folder", "gc_pause_ms.folder"]
+# every entry that gets a scratch cell appended, and the cells it gets: the
+# last two are entries whose ``workloads`` a cell's test file once held whole
+APPENDED = {**{name: [CELL] for name in SHARED},
+            "gather_kernel_ms.folder": [CELL],
+            "poll_ms.fleet_daemon": [DAEMON_CELL]}
 DRIVER = "folder_scratch"
 # in two parts, so that this file does not itself name the kernel metric whole
 # and pass for the test file that pins it
@@ -93,11 +119,17 @@ def added(tmp_path_factory):
         "name": "drip", "what": "every round three devices wrote one op file",
         "loop": "closed, one caller", "active_tenants": 1, "active_devices": 3,
         "files_per_device": 1, "warmup_rounds": 1, "max_ops_per_s": 2500})
+    daemon = run.load_json(ROOT, "cellbench", "configs", "orset_fleet_daemon.json")
+    daemon.update(name="orset_scratch_fleet", driver=DAEMON_DRIVER)
+    write(bench / "configs" / "orset_scratch_fleet.json", daemon)
     cells = [
         {"name": CELL, "config": "orset_scratch", "traffic": "drip", "chips": 1,
          "why": "a cell a later PR adds: a deployment and a mix no test has heard of"},
         {"name": CELL4, "config": "orset_scratch", "traffic": "backlog", "chips": 4,
          "why": "stands for a cell whose state is sharded over four chips; never run here"},
+        {"name": DAEMON_CELL, "config": "orset_scratch_fleet", "traffic": "steady",
+         "chips": 1,
+         "why": "a later cell of the daemon's family that reads poll_ms.fleet_daemon; never run here"},
     ]
     for cell in cells:
         write(bench / "cells" / (cell["name"] + ".json"), cell)
@@ -118,15 +150,21 @@ def added(tmp_path_factory):
         "name": "orset_scratch", "source": config["source"],
         "file": "cellbench/configs/orset_scratch.json",
         "reduced": sorted(config["reduced"]), "why": "a later PR's deployment"})
+    manifest["configs"].append({
+        "name": "orset_scratch_fleet", "source": daemon["source"],
+        "file": "cellbench/configs/orset_scratch_fleet.json",
+        "reduced": sorted(daemon["reduced"]), "why": "a later PR's daemon deployment"})
     manifest["workloads"] += cells
     manifest["per_layer"].append({**span, "workloads": [
         CELL, CELL4, "orset_folder_peers_delta.backlog"]})
     manifest["per_layer"].append({**kernel, "workloads": [CELL]})
-    for name in SHARED:
-        checks.entry_of(manifest, "per_layer", name)["workloads"].append(CELL)
+    for name, new in APPENDED.items():
+        checks.entry_of(manifest, "per_layer", name)["workloads"] += new
     for m in manifest["end_to_end"]:
         if m["name"] in ("compact_ms", "compact_ops_per_s"):
             m["workloads"] += [CELL, CELL4]
+        if m["name"] == "seal_p95_ms":
+            m["workloads"].append(DAEMON_CELL)
     write(root / "BENCHMARK.json", manifest)
     return str(root), manifest
 
@@ -162,7 +200,7 @@ def test_additions_are_a_suffix_of_each_list_and_nothing_else(added):
                         {**was, "workloads": cells})
             added_cells = got.get("workloads", [])[len(cells):]
             if kind == "per_layer":
-                assert added_cells == ([CELL] if got["name"] in SHARED else []), got["name"]
+                assert added_cells == APPENDED.get(got["name"], []), got["name"]
     for key in ("command", "paths", "run_seconds"):
         assert manifest[key] == REAL[key]
     # and every file that was there, the tests among them, byte for byte
@@ -180,16 +218,16 @@ def test_additions_are_a_suffix_of_each_list_and_nothing_else(added):
 def test_contract_keys_names_and_units_hold(added):
     root, manifest = added
     checks.check_contract_keys(manifest, root)
-    checks.check_names_and_units(manifest)
+    checks.check_names_and_units(manifest, root)
 
 
 def test_every_cell_agrees_and_one_of_nine_may_ask_for_four_chips(added):
     root, manifest = added
-    assert len(manifest["workloads"]) == len(REAL["workloads"]) + 2
+    assert len(manifest["workloads"]) == len(REAL["workloads"]) + 3
     for cell in checks.cells(manifest):
         checks.check_cell(manifest, root, cell)
     assert checks.entry_of(manifest, "workloads", CELL4)["chips"] == 4
-    checks.check_four_chip_share(manifest)
+    checks.check_four_chip_share(manifest, root)
 
 
 def test_every_layer_metric_agrees_and_a_file_may_name_several_drivers(added):
@@ -221,9 +259,9 @@ def test_the_sets_the_tests_pin_are_subsets(added):
     assert names[-2:] == [SPAN, KERNEL], "appended: nothing of the nine is last"
     assert checks.entry_of(manifest, "per_layer", SPAN)["layer"] == waits.LAYER
     waits.check_the_nine(manifest, root)
-    zipf.check_the_thirteen(root)
+    zipf.check_the_thirteen(manifest, root)
     assert len(checks.listed(root, zipf.CELL)) > 13
-    delta.check_new_and_copied_are_listed(root)
+    delta.check_new_and_copied_are_listed(manifest, root)
     assert SPAN in checks.listed(root, delta.CELL)
 
 
@@ -253,6 +291,93 @@ def test_overlay_is_chosen_by_the_driver_and_a_fifth_mix_by_its_file(added):
     assert checks.tiny(manifest, root, CELL4)["traffic"]["active_devices"] == 8
     for cell in checks.cells(REAL):
         assert checks.tiny(manifest, root, cell) == checks.tiny(REAL, ROOT, cell)
+
+
+# ------------- every manifest-level check of every test file (ISSUE 49)
+
+# found when this file is collected, so that each is a case of its own; the
+# test files are imported from the checkout, as a later PR's would be
+EVERY_CHECK = checks.manifest_level_checks()
+# what a test file may not do with the real manifest: subscript it, iterate
+# over it, or take an entry out of it (``entry_of`` is for ``manifest_checks``)
+REACHES_IN = re.compile(r"\b(MANIFEST|REAL)\s*\[|\bin\s+(MANIFEST|REAL)\b"
+                        r"|entry_of\(\s*(MANIFEST|REAL)\b")
+
+
+def test_the_checks_are_found_in_this_module_and_in_the_cells_test_files():
+    modules = {name.split(".")[0] for name in EVERY_CHECK}
+    assert {"manifest_checks", "test_fleet_daemon", "test_folder_10k", "test_wait_metrics",
+            "test_fleet_zipf", "test_folder_peers", "test_folder_peers_delta",
+            "test_chunk_reads_metric"} <= modules
+    assert {"manifest_checks.check_list_lengths", "manifest_checks.check_every_cell",
+            "manifest_checks.check_every_layer_metric",
+            "test_fleet_daemon.check_the_daemons_entries"} <= set(EVERY_CHECK)
+    # a check of ``manifest_checks`` with other parameters is reached through
+    # one that takes a manifest and a root
+    per_item = {name for name, fn in vars(checks).items()
+                if name.startswith("check_") and callable(fn)
+                and "manifest_checks." + name not in EVERY_CHECK}
+    assert per_item == {"check_cell", "check_pair", "check_layer_metric",
+                        "check_kernel_metric_is_pinned", "check_toy_line"}
+
+
+@pytest.mark.parametrize("name", sorted(EVERY_CHECK))
+def test_every_manifest_level_check_holds_on_the_augmented_root(added, name):
+    root, manifest = added
+    EVERY_CHECK[name](manifest, root)
+
+
+def test_no_test_file_reaches_into_the_manifest_but_through_manifest_checks():
+    """The other half of the proof: what is not in a ``check_*(manifest,
+    root)`` cannot look at the manifest's lists at all."""
+    this = os.path.basename(__file__)
+    paths = sorted(glob.glob(os.path.join(ROOT, "tests", "cellbench", "test_*.py")))
+    assert len(paths) > 10
+    for path in paths:
+        if os.path.basename(path) == this:
+            continue  # compares the augmented manifest with the real one
+        with open(path) as fh:
+            for n, text in enumerate(fh, 1):
+                assert not REACHES_IN.search(text), (
+                    f"{path}:{n} reaches into the manifest: state it in a "
+                    f"check_*(manifest, root) built from manifest_checks.hold_*")
+    for text in ('assert MANIFEST["configs"][-1]["name"] == x', "for m in MANIFEST:",
+                 'checks.entry_of(MANIFEST, "per_layer", name)["workloads"] == [CELL]',
+                 'len(REAL ["per_layer"]) <= 84'):
+        assert REACHES_IN.search(text), text
+    for text in ("checks.check_cell(MANIFEST, ROOT, cell)", "TOY = checks.toy(MANIFEST, ROOT, CELL)",
+                 "manifest = json.loads(json.dumps(MANIFEST))"):
+        assert not REACHES_IN.search(text), text
+
+
+def parents_daemon_pins(manifest: dict, root: str) -> None:
+    """``test_fleet_daemon.py:78-92`` of the parent (PR 45), word for word
+    but for ``MANIFEST`` and ``ROOT``."""
+    cell = daemon.CELL
+    assert manifest["configs"][-1]["name"] == "orset_fleet_daemon"
+    assert manifest["workloads"][-1] == {
+        k: run.load_cell(root, cell)["cell"][k]
+        for k in ("name", "config", "traffic", "chips", "why")}
+    for metric in ("serve_ops_per_s", "seal_p95_ms"):
+        assert checks.entry_of(manifest, "end_to_end", metric)["workloads"][-1] == cell
+    assert [m["name"] for m in manifest["per_layer"][-5:]] == daemon.FIVE
+    for name in daemon.NEW:
+        assert checks.entry_of(manifest, "per_layer", name)["workloads"] == [cell]
+
+
+def test_the_pins_of_the_parent_fail_on_the_augmented_root(added):
+    """What this file did not see until ISSUE 49, it sees."""
+    root, manifest = added
+    with pytest.raises(AssertionError):
+        parents_daemon_pins(manifest, root)
+    with pytest.raises(AssertionError):  # test_metric_cell_pairs.py:75 of the parent
+        assert len(manifest["per_layer"]) <= 84
+    # the one line of the five that the appended configurations do not reach
+    entry = checks.entry_of(manifest, "per_layer", "poll_ms.fleet_daemon")
+    assert entry["workloads"] != [daemon.CELL] and daemon.CELL in entry["workloads"]
+    # and the 10,000-device cell's: ``== SOLO + [CELL]``
+    entry = checks.entry_of(manifest, "per_layer", "gather_kernel_ms.folder")
+    assert entry["workloads"][:3] == ten_k.SOLO + [ten_k.CELL] != entry["workloads"]
 
 
 # ------------------------- (b) the one-chip cell, end to end at toy size

@@ -15,6 +15,7 @@ from cellbench import run
 from cellbench.readers import counter_per_call, span_ms
 from crdt_enc_tpu.obs import runtime as obs_runtime
 
+import manifest_checks as checks
 from test_cellbench import tiny
 
 ROOT = run.ROOT
@@ -126,23 +127,24 @@ def test_slot_wait_is_summed_over_the_tenants_as_seal_ms_is():
 def test_metric_file_reads_what_the_issue_names(metric):
     cells, moves, source, how = NINE[metric]
     spec = run.load_json(ROOT, "cellbench", "layer_metrics", metric + ".json")
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
     assert spec["reader"] == how["reader"] and spec["args"] == how["args"]
     assert spec["driver"] == metric.rsplit(".", 1)[1], "the family is the name's suffix"
-    assert entry["workloads"][:len(cells)] == cells, "later cells of the family follow"
-    for holder in (spec, entry):
-        assert holder["layer"] == LAYER
-        assert (holder["unit"], holder["better"]) == ("ms", "lower")
-        assert holder["moves"] == moves and holder["source"] == source
+    assert spec["layer"] == LAYER
+    assert (spec["unit"], spec["better"]) == ("ms", "lower")
+    assert spec["moves"] == moves and spec["source"] == source
     assert spec["what"]
+    checks.check_layer_metric(MANIFEST, ROOT, metric)  # the entry is the file's
 
 
 def check_the_nine(manifest: dict, root: str) -> None:
     """The nine are among the layer's metrics and the manifest's entries, in
     their order, each in its own cells.  No position and no count of the
     layer: later entries of it may follow."""
-    of_layer = [m["name"] for m in manifest["per_layer"] if m["layer"] == LAYER]
-    assert [name for name in of_layer if name in NINE] == list(NINE)
+    checks.hold_metrics_in_order(manifest, list(NINE), layer=LAYER)
+    for metric, (cells, moves, source, _) in NINE.items():
+        # its own cells first among themselves; later cells of the family follow
+        checks.hold_metric(manifest, metric, cells=cells, moves=moves, layer=LAYER,
+                           source=source)
     for cell, n in [(c, 2) for c in FOLDERS] + [(c, 7) for c in FLEETS]:
         listed = [m["name"] for m in run.load_cell(root, cell)["per_layer"]]
         assert sum(name in NINE for name in listed) == n, cell
