@@ -2184,10 +2184,11 @@ class Core:
             # accelerator declined (non-columnar CRDT, vocab collision):
             # decode per-op in Python but still fold as one batch
             batch = []
-            for p in payloads:
-                batch.extend(
-                    self.adapter.op_from_obj(o) for o in codec.unpack(p)
-                )
+            with trace.span("ops.bulk_decode"):
+                for p in payloads:
+                    batch.extend(
+                        self.adapter.op_from_obj(o) for o in codec.unpack(p)
+                    )
             self.accel.fold_ops(self._data.state, batch)
             self._advance_cursors(metas)
             trace.add("ops_folded", len(batch))

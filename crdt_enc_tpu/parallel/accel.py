@@ -167,6 +167,8 @@ class TpuAccelerator(HostAccelerator):
     # ------------------------------------------------------------- fold_ops
     def fold_ops(self, state, ops: list):
         if len(ops) < self.min_device_batch:
+            if ops and isinstance(state, LWWMap):
+                trace.add("fold_rows_host", len(ops))
             return super().fold_ops(state, ops)
         if isinstance(state, ORSet):
             return self._fold_orset(state, ops)
@@ -945,8 +947,10 @@ class TpuAccelerator(HostAccelerator):
             LWWOp(None, int(o[0]), bytes(o[1]), o[2], False) for o in rows
         ]
         cols = K.lww_ops_to_columns(ops)
-        V = len(cols.values_sorted)
-        num_values = V if len(cols.actors_sorted) * V < 2**31 else None
+        # the packed-rank multiplier from a bucket, as in _fold_lww (the
+        # rows here are not padded: a batch length is a shape of its own)
+        Vp = _bucket(len(cols.values_sorted))
+        num_values = Vp if len(cols.actors_sorted) * Vp < 2**31 else None
         m_hi, m_lo, m_actor, m_value, present = K.lww_fold(
             cols.key, cols.ts_hi, cols.ts_lo, cols.actor, cols.value,
             num_keys=1, num_values=num_values,
@@ -1102,113 +1106,137 @@ class TpuAccelerator(HostAccelerator):
         return self._fold_counter_dense(state, K.counter_ops_to_columns(ops))
 
     def _fold_lww(self, state: LWWMap, ops: list) -> LWWMap:
-        cols = K.lww_ops_to_columns(ops)
-        Kn = len(cols.keys)
-        if Kn == 0:
-            return state
-        n = len(cols.key)
-        padn = self._round_to(_bucket(n), self._dp()) - n
-        key_col, hi, lo, actor_col, value_col = (
-            cols.key,
-            cols.ts_hi,
-            cols.ts_lo,
-            cols.actor,
-            cols.value,
-        )
-        if padn:
-            key_col = np.concatenate([key_col, np.full(padn, Kn, np.int32)])
-            hi = np.concatenate([hi, np.zeros(padn, np.int32)])
-            lo = np.concatenate([lo, np.zeros(padn, np.int32)])
-            actor_col = np.concatenate([actor_col, np.zeros(padn, np.int32)])
-            value_col = np.concatenate([value_col, np.zeros(padn, np.int32)])
-        if self._mesh_active():
-            from . import mesh as pmesh
-
-            m_hi, m_lo, m_actor, m_value, present = pmesh.lww_fold_sharded(
-                self.mesh, key_col, hi, lo, actor_col, value_col, num_keys=Kn
+        with trace.span("fold.lww.columns"):
+            cols = K.lww_ops_to_columns(ops)
+            Kn = len(cols.keys)
+            if Kn == 0:
+                return state
+            n = len(cols.key)
+            # Every static argument of the programs below comes from a
+            # bucket, so a steady folder compiles nothing after its first
+            # rounds: the rows and the key vocabulary are padded to
+            # ``_bucket`` (a batch names another number of distinct keys
+            # every round), ``num_values`` likewise (below),
+            # ``tile_cap`` is a power of two and the limb counts are
+            # quantized to their 1-4 range (≤ 64 tuples).  Pad rows carry
+            # the sentinel key ``Kp`` (== num_keys ⇒ padding row) and the
+            # tables are cut back to ``Kn`` after the pull.
+            Kp = _bucket(Kn)
+            padn = self._round_to(_bucket(n), self._dp()) - n
+            key_col, hi, lo, actor_col, value_col = (
+                cols.key,
+                cols.ts_hi,
+                cols.ts_lo,
+                cols.actor,
+                cols.value,
             )
-        else:
-            # pack (actor, value) into one cascade when the rank product fits
-            V = len(cols.values_sorted)
-            num_values = V if len(cols.actors_sorted) * V < 2**31 else None
-            if self._lww_pallas_eligible(num_values, hi, len(key_col)):
-                trace.add("pallas_routed", 1)
+            if padn:
+                key_col = np.concatenate([key_col, np.full(padn, Kp, np.int32)])
+                hi = np.concatenate([hi, np.zeros(padn, np.int32)])
+                lo = np.concatenate([lo, np.zeros(padn, np.int32)])
+                actor_col = np.concatenate([actor_col, np.zeros(padn, np.int32)])
+                value_col = np.concatenate([value_col, np.zeros(padn, np.int32)])
+            # pack (actor, value) into one cascade when the rank product
+            # fits; the multiplier is the bucket of the batch's count of
+            # distinct values (any number above every value rank keeps the
+            # lexicographic order, and the count itself differs by round)
+            Vp = _bucket(len(cols.values_sorted))
+            num_values = Vp if len(cols.actors_sorted) * Vp < 2**31 else None
+            pallas = not self._mesh_active() and self._lww_pallas_eligible(
+                num_values, hi, len(key_col)
+            )
+            if pallas:
                 from ..ops.pallas_lww import (
                     lww_column_maxima, lww_fold_pallas, lww_limbs,
                     lww_tile_cap,
                 )
 
                 # maxima on the UNPADDED columns, computed once (the pad
-                # rows are zeros and cannot raise them); the limb counts
-                # are quantized to their 1-4 range, so varying batches
-                # draw from ≤ 64 static tuples — recompiles stay bounded
+                # rows are zeros and cannot raise them)
                 maxima = lww_column_maxima(
                     cols.ts_hi, cols.ts_lo, cols.actor, num_values
                 )
-                m_hi, m_lo, m_actor, m_value, present = lww_fold_pallas(
-                    key_col, hi, lo, actor_col, value_col,
-                    num_keys=Kn, num_values=num_values,
-                    tile_cap=lww_tile_cap(key_col, Kn),
-                    # static limb counts from the batch's host-side maxima:
-                    # the in-kernel per-chunk limb conds measured 4x slower
-                    limbs=lww_limbs(hi, lo, actor_col, num_values,
-                                    maxima=maxima),
+                tile_cap = lww_tile_cap(key_col, Kp)
+                # static limb counts from the batch's host-side maxima:
+                # the in-kernel per-chunk limb conds measured 4x slower
+                limbs = lww_limbs(hi, lo, actor_col, num_values, maxima=maxima)
+        trace.add_many({
+            "lww_folds": 1, "lww_fold_rows": n, "lww_fold_keys": Kn,
+            "fold_rows_device": n,
+        })
+        with trace.span("fold.lww.device"):
+            # the five padded columns upload with the dispatch
+            columns = (key_col, hi, lo, actor_col, value_col)
+            trace.add("h2d_bytes", 5 * key_col.nbytes)  # five int32 columns
+            if self._mesh_active():
+                from . import mesh as pmesh
+
+                tables = pmesh.lww_fold_sharded(
+                    self.mesh, *columns, num_keys=Kp
+                )
+            elif pallas:
+                trace.add_many({"pallas_routed": 1, "lww_folds_pallas": 1})
+                tables = lww_fold_pallas(
+                    *columns, num_keys=Kp, num_values=num_values,
+                    tile_cap=tile_cap, limbs=limbs,
                 )
             else:
-                m_hi, m_lo, m_actor, m_value, present = K.lww_fold(
-                    key_col, hi, lo, actor_col, value_col,
-                    num_keys=Kn, num_values=num_values,
+                tables = K.lww_fold(
+                    *columns, num_keys=Kp, num_values=num_values
                 )
-        m_hi = np.asarray(m_hi)
-        m_lo = np.asarray(m_lo)
-        m_actor = np.asarray(m_actor)
-        m_value = np.asarray(m_value)
-        present = np.asarray(present)
-        # winner rows → tombstone lookup (vectorized over the batch)
-        ki = cols.key
-        win = (
-            (cols.ts_hi == m_hi[ki])
-            & (cols.ts_lo == m_lo[ki])
-            & (cols.actor == m_actor[ki])
-            & (cols.value == m_value[ki])
-        )
-        tomb_by_key = np.zeros(Kn, bool)
-        np.maximum.at(tomb_by_key, ki[win], cols.tombstone[win])
-
-        # vectorized writeback: materialize all winner entries in bulk
-        # (batched .tolist() conversions, no per-key state.apply / LWWOp),
-        # then resolve against existing entries — the host tie-break runs
-        # only on actual key collisions
-        from ..models.lwwmap import _wins
-
-        idx = np.flatnonzero(present)
-        ts64 = (m_hi[idx].astype(np.int64) << 31) | m_lo[idx]
-        items = cols.keys.items
-        actors, values = cols.actors_sorted, cols.values_sorted
-        tombs = tomb_by_key[idx].tolist()
-        new_entries = {
-            items[k]: [
-                t,
-                actors[a],
-                None if tomb else values[v],
-                tomb,
-            ]
-            for k, t, a, v, tomb in zip(
-                idx.tolist(),
-                ts64.tolist(),
-                m_actor[idx].tolist(),
-                m_value[idx].tolist(),
-                tombs,
+            m_hi, m_lo, m_actor, m_value, present = (
+                t[:Kn] for t in obs_runtime.pull(*tables)
             )
-        }
-        entries = state.entries
-        if not entries:
-            state.entries = new_entries
-        else:
-            for key_obj, new in new_entries.items():
-                cur = entries.get(key_obj)
-                if cur is None or _wins(*new, *cur):
-                    entries[key_obj] = new
+        with trace.span("fold.lww.writeback"):
+            # winner rows → tombstone lookup (vectorized over the batch)
+            ki = cols.key
+            win = (
+                (cols.ts_hi == m_hi[ki])
+                & (cols.ts_lo == m_lo[ki])
+                & (cols.actor == m_actor[ki])
+                & (cols.value == m_value[ki])
+            )
+            tomb_by_key = np.zeros(Kn, bool)
+            np.maximum.at(tomb_by_key, ki[win], cols.tombstone[win])
+
+            # vectorized writeback: materialize all winner entries in bulk
+            # (batched .tolist() conversions, no per-key state.apply /
+            # LWWOp), then resolve against existing entries — the host
+            # tie-break runs only on actual key collisions
+            from ..models.lwwmap import _wins
+
+            idx = np.flatnonzero(present)
+            ts64 = (m_hi[idx].astype(np.int64) << 31) | m_lo[idx]
+            items = cols.keys.items
+            actors, values = cols.actors_sorted, cols.values_sorted
+            tombs = tomb_by_key[idx].tolist()
+            new_entries = {
+                items[k]: [
+                    t,
+                    actors[a],
+                    None if tomb else values[v],
+                    tomb,
+                ]
+                for k, t, a, v, tomb in zip(
+                    idx.tolist(),
+                    ts64.tolist(),
+                    m_actor[idx].tolist(),
+                    m_value[idx].tolist(),
+                    tombs,
+                )
+            }
+            entries = state.entries
+            if not entries:
+                state.entries = new_entries
+                written = len(new_entries)
+            else:
+                written = 0
+                for key_obj, new in new_entries.items():
+                    cur = entries.get(key_obj)
+                    if cur is None or _wins(*new, *cur):
+                        entries[key_obj] = new
+                        written += 1
+            trace.add("lww_keys_written", written)
         return state
 
     # --------------------------------------------------------- merge_states
