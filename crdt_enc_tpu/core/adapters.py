@@ -6,6 +6,16 @@ knowledge for dynamically chosen state types, plus the *accelerator* —
 the pluggable execution backend for the two hot paths (per-op fold and
 state merge).  ``HostAccelerator`` is the plain loop; the TPU accelerator
 (crdt_enc_tpu/parallel/accel.py) batches onto the device kernels.
+
+``CrdtAdapter.state_pack`` is the one optional member: the canonical bytes
+of a state, ``codec.pack(state_to_obj(state))`` byte for byte, made without
+building the object where the adapter knows how.  A seal that plans no
+delta link and a checkpoint of a state with no columnar format take the
+state's bytes from it (``core/core.py`` ``_plan_seal``,
+``_pack_checkpoint_state``: checkpoint format 2).  An adapter that says
+nothing gets exactly that expression; ``lwwmap_adapter()`` packs the live
+``entries`` dict, which holds what ``to_obj`` would copy
+(``models/lwwmap.py`` "The entries invariant").
 """
 
 from __future__ import annotations
@@ -31,6 +41,7 @@ from ..models import (
     SeqList,
     VClock,
 )
+from ..utils import codec
 from ..models.orset import op_from_obj as orset_op_from_obj
 from ..models.seqlist import op_from_obj as seqlist_op_from_obj
 from ..models.vclock import Dot
@@ -66,6 +77,13 @@ class CrdtAdapter:
     state_from_obj: Callable = None  # type: ignore[assignment]
     op_to_obj: Callable = field(default=lambda op: op.to_obj())
     op_from_obj: Callable = field(default=lambda obj: obj)
+    # state -> codec.pack(state_to_obj(state)), without the object where
+    # the adapter can (module docstring); None: that expression itself
+    state_pack: Callable = None  # type: ignore[assignment]
+
+    def __post_init__(self):
+        if self.state_pack is None:
+            self.state_pack = lambda s: codec.pack(self.state_to_obj(s))
 
 
 def gcounter_adapter() -> CrdtAdapter:
@@ -102,6 +120,7 @@ def lwwmap_adapter() -> CrdtAdapter:
         new=LWWMap,
         state_from_obj=LWWMap.from_obj,
         op_from_obj=LWWOp.from_obj,
+        state_pack=lambda s: codec.pack(s.entries),
     )
 
 

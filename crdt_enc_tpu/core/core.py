@@ -53,8 +53,9 @@ IO_CONCURRENCY = 16  # bounded pipeline width (reference lib.rs:452,512)
 BULK_MIN_FILES = 16  # below this the per-file asyncio path is cheaper
 
 # local fold-checkpoint payload formats (docs/checkpointing.md)
-CHECKPOINT_FMT_OBJ = 0  # adapter.state_to_obj (any CRDT type)
+CHECKPOINT_FMT_OBJ = 0  # adapter.state_to_obj, nested: read, no longer written
 CHECKPOINT_FMT_ORSET = 1  # ops/columnar.py orset_pack_checkpoint
+CHECKPOINT_FMT_BYTES = 2  # one bin: adapter.state_pack, the snapshot's bytes
 
 logger = logging.getLogger("crdt_enc_tpu.core")
 
@@ -307,6 +308,8 @@ def unpack_checkpoint_state(adapter, fmt: int, st):
         from ..ops.columnar import orset_unpack_checkpoint
 
         return orset_unpack_checkpoint(st)
+    if fmt == CHECKPOINT_FMT_BYTES:
+        return adapter.state_from_obj(codec.unpack(st))
     if fmt == CHECKPOINT_FMT_OBJ:
         return adapter.state_from_obj(st)
     raise CoreError(f"unknown checkpoint format {fmt!r}")
@@ -1041,10 +1044,14 @@ class Core:
             ).digest()
         return self._remote_id_cache
 
-    def _pack_checkpoint_state(self):
+    def _pack_checkpoint_state(self, state_bytes: bytes | None = None):
         """(fmt, obj) for the current state: the packed-columnar ORSet
-        encoding when it applies losslessly, else the adapter's generic
-        object form (identical to the compacted-snapshot payload).
+        encoding when it applies losslessly, else the state's canonical
+        bytes as one ``bin`` (format 2: the compacted snapshot's state
+        part, byte for byte).  ``state_bytes`` is those bytes where the
+        caller already holds them for this slice and epoch (a seal's
+        plan, ``checkpoint_pack_shared``); without them they are made
+        here by the adapter's pack (``checkpoint_pack_bytes``).
 
         A fresh streaming fold stashes its surviving rows on the state
         (``_ckpt_rows``, mut-epoch-guarded — ops/columnar.py
@@ -1079,17 +1086,27 @@ class Core:
             obj = orset_pack_checkpoint(state)
             if obj is not None:
                 return CHECKPOINT_FMT_ORSET, obj
-        return CHECKPOINT_FMT_OBJ, self.adapter.state_to_obj(state)
+        if state_bytes is None:
+            state_bytes = self.adapter.state_pack(state)
+            trace.add("checkpoint_pack_bytes", 1)
+        else:
+            trace.add("checkpoint_pack_shared", 1)
+        return CHECKPOINT_FMT_BYTES, state_bytes
 
     def _unpack_checkpoint_state(self, fmt: int, st):
         return unpack_checkpoint_state(self.adapter, fmt, st)
 
-    def _plan_checkpoint(self, _packed: tuple | None = None) -> dict:
+    def _plan_checkpoint(
+        self, _packed: tuple | None = None, state_bytes: bytes | None = None
+    ) -> dict:
         """The checkpoint payload, every mutable input materialized in
         the calling loop slice so a concurrent apply cannot tear the
         (state, cursor) pair.  Its state part owns what it holds
-        (packed row buffers, or a ``state_to_obj`` copy), so the
+        (packed row buffers, or the state's packed bytes), so the
         payload may be packed later, off the loop.
+
+        ``state_bytes`` is the canonical packed state a seal's plan made
+        in this same slice (:meth:`_pack_checkpoint_state`).
 
         ``_packed`` is the fold service's pre-packed state payload,
         ``(fmt, obj, mut_epoch)``: the service packs from the dense
@@ -1103,7 +1120,7 @@ class Core:
         ):
             fmt, st = _packed[0], _packed[1]
         else:
-            fmt, st = self._pack_checkpoint_state()
+            fmt, st = self._pack_checkpoint_state(state_bytes)
         payload = {
             b"fmt": fmt,
             b"state": st,
@@ -1278,14 +1295,19 @@ class Core:
                     self._stable = None
             snap = obj.get(b"snap")
             if (
-                self._delta_enabled
+                # a base is retained only where a seal will diff against
+                # one: without a codec it would pin O(state) bytes unread
+                self._delta_codec() is not None
                 and isinstance(snap, (bytes, bytearray, memoryview))
             ):
                 snap_name = bytes(snap).decode()
                 if snap_name in read_states:
                     self._set_delta_base(
                         snap_name,
-                        codec.pack(self.adapter.state_to_obj(state)),
+                        # format 2 IS the snapshot's packed state
+                        bytes(obj[b"state"])
+                        if fmt == CHECKPOINT_FMT_BYTES
+                        else self.adapter.state_pack(state),
                         cursor.to_obj(),
                     )
         self.opened_from_checkpoint = True
@@ -2382,13 +2404,30 @@ class Core:
             getattr(d.state, "_mut", None) if _mut is None else _mut,
         )
 
-    def _plan_delta_seal(self, state_obj, cursor_obj, _cut=None, owned=False):
+    def _delta_codec(self):
+        """The delta codec a seal of this replica plans a link with, or
+        None where it plans none: deltas off, a storage without a delta
+        log, or a state type no codec is registered for.  Asked once a
+        plan (:meth:`_plan_seal`), which builds the state as an object
+        only where a plan will read it."""
+        if not self._delta_enabled or not getattr(
+            self.storage, "has_deltas", False
+        ):
+            return None
+        from ..delta import codec_for
+
+        return codec_for(self.adapter.name)
+
+    def _plan_delta_seal(
+        self, state_obj, cursor_obj, codec_cls, _cut=None, owned=False
+    ):
         """Sync section of the delta seal (docs/delta.md): diff the
         about-to-be-sealed state against the retained base (this
         replica's previous snapshot), self-verify, and hand the await
         half (:meth:`_seal_delta`) an immutable plan.  Runs BEFORE the
         first await of the seal tail so a concurrent apply cannot tear
-        the (base, new, delta) triple.
+        the (base, new, delta) triple.  ``codec_cls`` is
+        :meth:`_delta_codec`'s answer, never None here.
 
         ``owned`` says that ``state_obj`` is a copy nobody else holds
         (:meth:`_plan_seal` built it in this slice): a host-route plan
@@ -2399,18 +2438,9 @@ class Core:
 
         The plan always carries ``new_bytes`` — the canonical packed
         state — which becomes the NEXT base even when no delta can be
-        cut this round (first seal, no codec, divergent or oversize
+        cut this round (first seal, divergent or oversize
         diff); ``dobj`` is None in those cases and consumers fall back
         to the full snapshot for this link only."""
-        if not self._delta_enabled or not getattr(
-            self.storage, "has_deltas", False
-        ):
-            return None
-        from ..delta import codec_for
-
-        codec_cls = codec_for(self.adapter.name)
-        if codec_cls is None:
-            return None
         d = self._data
         with trace.span("delta.pack"):
             new_bytes = codec.pack(state_obj)
@@ -2790,39 +2820,49 @@ class Core:
         interleave and seal a torn (state, cursor, delta) triple."""
         d = self._data
         key = self._latest_key()
+        cursor_obj = d.next_op_versions.to_obj()
+        snap_mut = getattr(d.state, "_mut", None)
         # who built the object decides whether the delta plan may keep
         # it: the caller's aliases the live entry dicts and is good for
         # this slice alone, the one built here is a copy of its own
-        owned = _state_obj is None or _state_obj[1] != getattr(
-            d.state, "_mut", None
-        )
-        if owned:
+        owned = _state_obj is None or _state_obj[1] != snap_mut
+        codec_cls = self._delta_codec()
+        delta_plan = None
+        if codec_cls is None and owned:
+            # no plan will read the state as an object, so none is
+            # built: the adapter packs the live state (one serialisation
+            # a seal; the checkpoint below takes the same bytes)
             with trace.span("seal.state_obj"):
-                state_obj = self.adapter.state_to_obj(d.state)
+                state_bytes = self.adapter.state_pack(d.state)
+            trace.add("seal_pack_inplace", 1)
         else:
-            state_obj = _state_obj[0]
-        cursor_obj = d.next_op_versions.to_obj()
-        snap_mut = getattr(d.state, "_mut", None)
-        # delta plan (diff + self-verify) in the SAME slice: the
-        # (base, new, delta) triple must be cut from one stable state.
-        # ``_delta_cut`` is the serving layer's device-cut candidate —
-        # validated (base name + mut epoch) inside the plan, never
-        # trusted blindly
-        with trace.span("delta.plan"):
-            delta_plan = self._plan_delta_seal(
-                state_obj, cursor_obj, _cut=_delta_cut, owned=owned
-            )
-        if delta_plan is not None:
-            state_bytes = delta_plan["new_bytes"]
-        else:
-            with trace.span("seal.state_obj"):
-                state_bytes = codec.pack(state_obj)
+            if owned:
+                with trace.span("seal.state_obj"):
+                    state_obj = self.adapter.state_to_obj(d.state)
+            else:
+                state_obj = _state_obj[0]
+            trace.add("seal_pack_obj", 1)
+            if codec_cls is not None:
+                # delta plan (diff + self-verify) in the SAME slice: the
+                # (base, new, delta) triple must be cut from one stable
+                # state.  ``_delta_cut`` is the serving layer's
+                # device-cut candidate — validated (base name + mut
+                # epoch) inside the plan, never trusted blindly
+                with trace.span("delta.plan"):
+                    delta_plan = self._plan_delta_seal(
+                        state_obj, cursor_obj, codec_cls,
+                        _cut=_delta_cut, owned=owned,
+                    )
+                state_bytes = delta_plan["new_bytes"]
+            else:
+                with trace.span("seal.state_obj"):
+                    state_bytes = codec.pack(state_obj)
         checkpoint = None
         if self._checkpoint_enabled:
             # the freshly compacted state is the ideal warm-open resume
             # point: everything folded, op logs GC'd to the cursor
             with trace.span("checkpoint.save"):
-                checkpoint = self._plan_checkpoint(_packed_state)
+                checkpoint = self._plan_checkpoint(_packed_state, state_bytes)
         assert self._local_meta is not None
         return _SealPlan(
             key=key,

@@ -4,6 +4,18 @@ The ``(timestamp, actor)`` pair totally orders writes (actor bytes break
 timestamp ties deterministically); deletes are tombstoned writes so they win
 over concurrent older puts and survive merges.  The TPU analogue is a
 segment-argmax over packed (ts, actor-rank) keys (``crdt_enc_tpu.ops.lww``).
+
+**The entries invariant.**  ``LWWMap.entries`` holds, for every key, a
+4-sequence ``[int ts, bytes actor, value, bool tombstone]`` — exactly what
+:meth:`LWWMap.to_obj` emits for it — so ``codec.pack(m.entries)`` IS
+``codec.pack(m.to_obj())`` and a seal packs the live map without a copy
+(``core/adapters.py`` ``lwwmap_adapter().state_pack``).  Every writer keeps
+it: ``from_obj`` normalises, ``merge`` copies another map's entries,
+``apply`` stores the tombstone flag as a ``bool`` whatever the op carried
+(an ``LWWOp(..., tombstone=1)`` would otherwise pack as an int), and the
+accelerator's writeback (``parallel/accel.py`` ``_fold_lww``) takes its
+flags from a numpy ``bool`` column's ``.tolist()``.
+``tests/test_seal_single_pack.py`` holds the equality over all four.
 """
 
 from __future__ import annotations
@@ -47,7 +59,8 @@ def _wins(a_ts, a_actor, a_val, a_tomb, b_ts, b_actor, b_val, b_tomb) -> bool:
 
 @dataclass
 class LWWMap:
-    # key -> [ts, actor, value, tombstone]
+    # key -> [ts, actor, value, tombstone]: what to_obj emits, key for key
+    # (the entries invariant, module docstring)
     entries: dict = field(default_factory=dict)
     # mutation epoch: bumped by every mutating method (and by the
     # accelerator's writebacks) — same cache-validity law as ORSet._mut
@@ -66,7 +79,8 @@ class LWWMap:
         if isinstance(op, (list, tuple)):
             op = LWWOp.from_obj(op)
         cur = self.entries.get(op.key)
-        new = [op.ts, op.actor, None if op.tombstone else op.value, op.tombstone]
+        tomb = bool(op.tombstone)  # the entries invariant (module docstring)
+        new = [op.ts, op.actor, None if tomb else op.value, tomb]
         if cur is None or _wins(*new, *cur):
             self.entries[op.key] = new
 

@@ -980,3 +980,204 @@ def test_mutation_while_the_seal_job_is_parked(tmp_path):
     release.set()
     run(go())
     trace.reset()
+
+
+# ---- format 2: the snapshot's bytes (ISSUE 51) ------------------------------
+
+GENERIC_CASES = [c for c in ADAPTER_CASES if c[0] != "orset"]
+
+
+async def _compacted(storage_factory, mk_adapter, build, n=24):
+    core = await Core.open(make_opts(storage_factory("a"), mk_adapter()))
+    for i in range(n):
+        op = build(core, i)
+        await core.apply_ops(op if isinstance(op, list) else [op])
+    await core.compact()
+    return core
+
+
+async def _rewrite_checkpoint(core, storage, change) -> dict:
+    """Seal the stored checkpoint again with ``change`` applied to its
+    payload, under the same key and through the same port."""
+    ckpt = dict(await core._open_sealed(await storage.load_local_checkpoint()))
+    change(ckpt)
+    await storage.store_local_checkpoint(
+        await core._seal_packed(
+            core._latest_key(), codec.pack(ckpt), core.cryptor.encrypt
+        )
+    )
+    return ckpt
+
+
+@pytest.mark.parametrize(
+    "name,mk_adapter,build", GENERIC_CASES, ids=[c[0] for c in GENERIC_CASES]
+)
+def test_format_2_checkpoint_opens_warm_equal_to_cold(
+    storage_factory, name, mk_adapter, build
+):
+    """A state with no columnar format is checkpointed as its canonical
+    bytes, the snapshot's own, and a warm open from them ends where a cold
+    open does."""
+
+    async def go():
+        trace.reset()
+        c1 = await _compacted(storage_factory, mk_adapter, build)
+        counters = trace.snapshot()["counters"]
+        assert counters.get("checkpoint_pack_shared") == 1
+        assert not any(_pack_counts().values())
+        ckpt = await c1._open_sealed(
+            await storage_factory("a").load_local_checkpoint()
+        )
+        assert int(ckpt[b"fmt"]) == 2
+        assert ckpt[b"state"] == c1.with_state(canonical_bytes)
+        w = await Core.open(make_opts(storage_factory("w"), mk_adapter()))
+        for i in range(24, 30):
+            op = build(w, i)
+            await w.apply_ops(op if isinstance(op, list) else [op])
+        warm = await Core.open(
+            make_opts(storage_factory("a"), mk_adapter(), create=False)
+        )
+        assert warm.opened_from_checkpoint, warm.checkpoint_fallback_reason
+        assert warm.with_state(canonical_bytes) == ckpt[b"state"]
+        await warm.read_remote()
+        cold = await Core.open(make_opts(storage_factory("c"), mk_adapter()))
+        await cold.read_remote()
+        assert not cold.opened_from_checkpoint
+        assert warm.with_state(canonical_bytes) == cold.with_state(
+            canonical_bytes
+        )
+
+    run(go())
+
+
+@pytest.mark.parametrize(
+    "name,mk_adapter,build", GENERIC_CASES, ids=[c[0] for c in GENERIC_CASES]
+)
+def test_format_0_checkpoint_of_an_older_program_still_opens_warm(
+    storage_factory, name, mk_adapter, build
+):
+    """Format 0 (the state as an object nested in the payload) is what the
+    program wrote until ISSUE 51: read, never written."""
+
+    async def go():
+        c1 = await _compacted(storage_factory, mk_adapter, build)
+        expected = c1.with_state(canonical_bytes)
+
+        def to_format_0(ckpt):
+            assert int(ckpt[b"fmt"]) == 2
+            ckpt[b"fmt"] = 0
+            ckpt[b"state"] = codec.unpack(ckpt[b"state"])
+
+        await _rewrite_checkpoint(c1, storage_factory("a"), to_format_0)
+        trace.reset()
+        warm = await Core.open(
+            make_opts(storage_factory("a"), mk_adapter(), create=False)
+        )
+        assert warm.opened_from_checkpoint, warm.checkpoint_fallback_reason
+        assert warm.with_state(canonical_bytes) == expected
+        assert "checkpoint_fallbacks" not in trace.snapshot()["counters"]
+        # and what it writes next is format 2 again
+        await warm.save_checkpoint()
+        ckpt = await warm._open_sealed(
+            await storage_factory("a").load_local_checkpoint()
+        )
+        assert int(ckpt[b"fmt"]) == 2 and ckpt[b"state"] == expected
+
+    run(go())
+
+
+@pytest.mark.parametrize("fmt", [3, 9, -1])
+def test_unknown_checkpoint_format_falls_back_malformed(storage_factory, fmt):
+    """What an older reader does with a format 2 file, shown on this one
+    with a format it does not know: drop the file, open cold."""
+
+    async def go():
+        c1 = await _compacted(storage_factory, lwwmap_adapter, _ops_lwwmap)
+        expected = c1.with_state(canonical_bytes)
+        await _rewrite_checkpoint(
+            c1, storage_factory("a"), lambda ckpt: ckpt.update({b"fmt": fmt})
+        )
+        trace.reset()
+        cold = await Core.open(
+            make_opts(storage_factory("a"), lwwmap_adapter(), create=False)
+        )
+        assert not cold.opened_from_checkpoint
+        assert cold.checkpoint_fallback_reason == "malformed"
+        assert trace.snapshot()["counters"].get("checkpoint_fallbacks") == 1
+        assert await storage_factory("a").load_local_checkpoint() is None
+        await cold.read_remote()
+        assert cold.with_state(canonical_bytes) == expected
+
+    run(go())
+
+
+def test_fsck_verify_checkpoint_accepts_format_2(storage_factory):
+    from crdt_enc_tpu.tools.fsck import verify_checkpoint
+
+    async def go():
+        c1 = await _compacted(storage_factory, lwwmap_adapter, _ops_lwwmap)
+        s_a = storage_factory("a")
+        ckpt = await c1._open_sealed(await s_a.load_local_checkpoint())
+        assert int(ckpt[b"fmt"]) == 2
+
+        async def verify():
+            return await verify_checkpoint(
+                s_a, storage_factory("x"), IdentityCryptor(),
+                PlainKeyCryptor(), adapter=lwwmap_adapter(),
+            )
+
+        r = await verify()
+        assert r.ok and r.state_files == 1, [str(i) for i in r.issues]
+        # sealed correctly, wrong state: the divergence is still found
+        real = c1._data.state
+        c1._data.state = type(real)()
+        c1._data.state.apply(_ops_lwwmap(c1, 99))
+        await c1.save_checkpoint()
+        c1._data.state = real
+        r = await verify()
+        assert any(
+            i.family == "checkpoint" and "diverges" in i.problem
+            for i in r.issues
+        )
+
+    run(go())
+
+
+def test_fsck_cli_verify_checkpoint_accepts_format_2(tmp_path):
+    pytest.importorskip("crdt_enc_tpu.native")
+    from crdt_enc_tpu.backends import XChaChaCryptor
+    from crdt_enc_tpu.tools import fsck as fsck_cli
+
+    try:
+        from crdt_enc_tpu import native
+
+        native.load()
+    except Exception:
+        pytest.skip("native crypto unavailable")
+
+    remote = str(tmp_path / "remote")
+    local = str(tmp_path / "localA")
+
+    async def build():
+        c1 = await Core.open(
+            OpenOptions(
+                storage=FsStorage(local, remote),
+                cryptor=XChaChaCryptor(),
+                key_cryptor=PlainKeyCryptor(),
+                adapter=lwwmap_adapter(),
+                supported_data_versions=(DEFAULT_DATA_VERSION_1,),
+                current_data_version=DEFAULT_DATA_VERSION_1,
+                create=True,
+            )
+        )
+        for i in range(20):
+            await c1.apply_ops([_ops_lwwmap(c1, i)])
+        await c1.compact()
+        ckpt = await c1._open_sealed(
+            await c1.storage.load_local_checkpoint()
+        )
+        assert int(ckpt[b"fmt"]) == 2
+
+    run(build())
+    args = [remote, "--verify-checkpoint", local, "--adapter", "lwwmap"]
+    assert fsck_cli.main(args) == 0
