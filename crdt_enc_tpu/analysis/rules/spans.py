@@ -26,11 +26,13 @@ _RECEIVERS = {"trace", "record", "_record"}
 _KINDS = {"span", "add", "add_many", "gauge", "observe"}
 _TABLE_ROW_RE = re.compile(r"^\|\s*`([^`]+)`\s*\|")
 _REGISTRY_SECTIONS = ("## Span registry", "## Counter & gauge registry")
-#: names maintained inside obs itself (no trace.* call site): the event
-#: ring's drops, and the collector's totals that obs.runtime folds in
-#: without taking the registry's lock from its callback
+#: names with no trace.* call site: the event ring's drops, the
+#: collector's totals that obs.runtime folds in without taking the
+#: registry's lock from its callback, and the native packer's totals that
+#: utils.codec folds in the same way (record.on_read)
 _INTERNAL = {"events_dropped", "gc_passes", "gc_pause_us", "gc_full_passes",
-             "gc_full_pause_us", "gc_collected"}
+             "gc_full_pause_us", "gc_collected", "canon_packs", "canon_maps",
+             "canon_maps_sorted", "canon_declined"}
 _PROOF_PREFIXES = ("stream.",)
 
 DOC_REL = "docs/observability.md"

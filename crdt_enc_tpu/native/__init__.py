@@ -212,6 +212,8 @@ def _bind_state(lib) -> None:
     lib.canon_pack.restype = ctypes.py_object
     lib.canon_same.argtypes = [ctypes.py_object, ctypes.py_object]
     lib.canon_same.restype = ctypes.py_object
+    lib.canon_counters.argtypes = []
+    lib.canon_counters.restype = ctypes.py_object
 
 
 def _bind(lib) -> None:

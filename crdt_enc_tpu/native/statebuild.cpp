@@ -31,6 +31,7 @@
 #include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <memory>
 #include <vector>
 
 // Presized dict creation skips the grow/rehash cascade while filling
@@ -393,109 +394,300 @@ int orset_fresh_fold(const int8_t* kind, const int32_t* member,
 // ---------------------------------------------------------------------
 // Canonical msgpack packer — the native twin of utils/codec.py pack():
 // smallest-encoding msgpack with use_bin_type=True semantics and every
-// map emitted with keys sorted by their packed bytes.  Sealing a
-// compacted state at the 100k-replica scale spent ~400ms in the Python
-// _canon + packb walk; this emits the identical bytes in one C pass.
-// Unsupported types return 0 and the Python caller falls back.
+// map emitted with keys sorted by their packed bytes.  The one
+// serialiser of states, ops, links, payloads, cursors and sort keys;
+// a seal packs the whole state through it once a round.
+//
+// ONE growing buffer, handed down the whole recursion, and a map put in
+// order by sorting an INDEX over that buffer:
+//
+//  * `Out` starts on the stack (a small pack allocates nothing but its
+//    result) and moves into a Python ``bytes`` that doubles, or, once it
+//    is large, steps to where the last large pack ended; the result is
+//    that object cut to length, never a copy of it.
+//  * A map writes its header, then each key and its value straight into
+//    the buffer, one after the other, keeping one 24-byte record an
+//    entry: where the key starts, its length, the entry's length and
+//    the key's first 8 bytes as a big-endian word, so nearly every
+//    comparison is one integer compare (ties: memcmp of the rest, then
+//    the shorter key, then the earlier entry — `bytes <` and a stable
+//    sort, which is the Python path's ``sort(key=packb)``).  Records of
+//    a map of up to STACK_RECS entries live on the stack.
+//  * The order is checked while emitting.  A map whose keys arrived in
+//    packed-key order (every map of a state opened from a snapshot, a
+//    one-entry map) is neither sorted nor copied.  A map out of order has its records sorted and its region
+//    of the buffer permuted once through one scratch copy of that
+//    region; an inner map is final before its parent's entry ends, so
+//    the parent moves it as bytes.
+//
+// Unsupported types return 0 and the Python caller falls back; the four
+// totals below say how it engaged (canon_counters).
 // ---------------------------------------------------------------------
 
 namespace {
 
+// Process totals, bumped under the interpreter lock every entry point
+// of this library holds: calls of canon_pack, maps emitted, maps whose
+// keys arrived out of packed order, calls that declined (None).
+uint64_t g_canon_packs = 0, g_canon_maps = 0, g_canon_maps_sorted = 0,
+         g_canon_declined = 0;
+
+// Where the last large pack ended (under the same lock).  A buffer that
+// outgrows LARGE_PACK steps straight to that length and a sixteenth: a
+// state sealed round after round is packed into one allocation of about
+// its size, which the allocator serves from the block the last round
+// freed.  Doubling passes a 23 MB state for a block of 32 MiB, which
+// glibc maps fresh from the kernel every round (native.warm() pins
+// M_MMAP_THRESHOLD there), and on the chip's host the spans that then
+// read those pages cost 80 ms a round more (PERF.md, PR 52).  Only a
+// capacity: too small and the buffer doubles on.
+const size_t LARGE_PACK = 1 << 20;
+size_t g_canon_last_large = 0;
+
+// The big-endian stores and the key's first word are byte swaps.
+static_assert(__BYTE_ORDER__ == __ORDER_LITTLE_ENDIAN__,
+              "canon_pack swaps bytes: a little-endian host");
+
+// A Python error is set (a failed allocation of the buffer's object).
+struct PyErrSet {};
+
 struct Out {
-  std::vector<uint8_t> b;
-  void u8(uint8_t v) { b.push_back(v); }
-  void be16(uint16_t v) { u8(v >> 8); u8(v & 0xff); }
-  void be32(uint32_t v) { be16(v >> 16); be16(v & 0xffff); }
-  void be64(uint64_t v) { be32(v >> 32); be32(v & 0xffffffffull); }
-  void raw(const void* p, size_t n) {
-    const uint8_t* c = (const uint8_t*)p;
-    b.insert(b.end(), c, c + n);
+  static const size_t INLINE = 512;
+  uint8_t* p;
+  size_t n = 0, cap = INLINE;
+  PyObject* obj = nullptr;  // the bytes object `p` points into, once grown
+  uint64_t maps = 0, maps_sorted = 0;
+  std::vector<uint8_t> scratch;  // one out-of-order map's entries, reused
+  uint8_t inl[INLINE];
+
+  Out() : p(inl) {}
+  ~Out() { Py_XDECREF(obj); }
+  Out(const Out&) = delete;
+  Out& operator=(const Out&) = delete;
+
+  void grow(size_t need) {
+    size_t nc = cap * 2;
+    if (nc < need) nc = need + (need >> 4);  // one large bin: about its size
+    if (nc > LARGE_PACK && nc < g_canon_last_large)
+      nc = g_canon_last_large + (g_canon_last_large >> 4);
+    if (nc > (size_t)PY_SSIZE_T_MAX) throw std::bad_alloc();
+    if (obj == nullptr) {
+      obj = PyBytes_FromStringAndSize(nullptr, (Py_ssize_t)nc);
+      if (obj == nullptr) throw PyErrSet();
+      memcpy(PyBytes_AS_STRING(obj), inl, n);
+    } else if (_PyBytes_Resize(&obj, (Py_ssize_t)nc) < 0) {
+      throw PyErrSet();  // obj is NULL now, the old buffer freed
+    }
+    p = (uint8_t*)PyBytes_AS_STRING(obj);
+    cap = nc;
+  }
+  // room for `k` more bytes; returns where they go
+  inline uint8_t* room(size_t k) {
+    if (__builtin_expect(n + k > cap, 0)) grow(n + k);
+    uint8_t* at = p + n;
+    n += k;
+    return at;
+  }
+  inline void u8(uint8_t v) { *room(1) = v; }
+  // a one-byte tag and a big-endian word, one reservation, one store each
+  inline void tag8(uint8_t t, uint8_t v) {
+    uint8_t* at = room(2);
+    at[0] = t;
+    at[1] = v;
+  }
+  inline void tag16(uint8_t t, uint16_t v) {
+    uint8_t* at = room(3);
+    at[0] = t;
+    v = __builtin_bswap16(v);
+    memcpy(at + 1, &v, 2);
+  }
+  inline void tag32(uint8_t t, uint32_t v) {
+    uint8_t* at = room(5);
+    at[0] = t;
+    v = __builtin_bswap32(v);
+    memcpy(at + 1, &v, 4);
+  }
+  inline void tag64(uint8_t t, uint64_t v) {
+    uint8_t* at = room(9);
+    at[0] = t;
+    v = __builtin_bswap64(v);
+    memcpy(at + 1, &v, 8);
+  }
+  inline void raw(const void* src, size_t k) { memcpy(room(k), src, k); }
+
+  // the result: the buffer's own object cut to length (no copy), or a
+  // new small bytes; NULL with the Python error set
+  PyObject* finish() {
+    if (obj == nullptr)
+      return PyBytes_FromStringAndSize((const char*)inl, (Py_ssize_t)n);
+    if (_PyBytes_Resize(&obj, (Py_ssize_t)n) < 0) return nullptr;
+    if (n > LARGE_PACK) g_canon_last_large = n;
+    PyObject* r = obj;
+    obj = nullptr;
+    return r;
   }
 };
+
+// One entry of a map being emitted: the entry (key, then value) starts
+// at `off` in the buffer and is `elen` bytes, the first `klen` the key.
+struct Rec {
+  uint64_t pre;  // the key's first 8 bytes, big-endian, zero-padded
+  uint64_t off;
+  uint32_t klen, elen;
+};
+
+const Py_ssize_t STACK_RECS = 16;
+
+inline uint64_t key_prefix(const uint8_t* k, size_t klen) {
+  uint64_t w = 0;
+  memcpy(&w, k, klen < 8 ? klen : 8);  // the low bytes, which the swap puts first
+  return __builtin_bswap64(w);
+}
+
+// `a` before `b` in the canonical order?  `base` is the buffer the
+// records index (either the live buffer or the scratch copy).  Zero
+// padding keeps the word compare lexicographic: a key shorter than 8
+// bytes that ties on the word is a prefix of the other.
+inline bool rec_less(const Rec& a, const Rec& b, const uint8_t* base) {
+  if (a.pre != b.pre) return a.pre < b.pre;
+  const size_t m = a.klen < b.klen ? a.klen : b.klen;
+  if (m > 8) {
+    const int c = memcmp(base + a.off + 8, base + b.off + 8, m - 8);
+    if (c != 0) return c < 0;
+  }
+  if (a.klen != b.klen) return a.klen < b.klen;
+  return a.off < b.off;  // equal keys (two NaNs): as inserted
+}
+
+// Put the `n` entries that `recs` index, which fill the buffer from
+// `start` to its end, into canonical order.
+void order_entries(Out& out, Rec* recs, size_t n, size_t start) {
+  const uint8_t* base = out.p;
+  // a total order (the offset breaks every tie), so any sort is stable
+  std::sort(recs, recs + n, [base](const Rec& a, const Rec& b) {
+    return rec_less(a, b, base);
+  });
+  out.scratch.assign(out.p + start, out.p + out.n);
+  const uint8_t* from = out.scratch.data() - start;
+  uint8_t* w = out.p + start;
+  const size_t AHEAD = 8;
+  for (size_t i = 0; i < n; ++i) {
+    if (i + AHEAD < n) __builtin_prefetch(from + recs[i + AHEAD].off);
+    memcpy(w, from + recs[i].off, recs[i].elen);
+    w += recs[i].elen;
+  }
+}
+
+int canon_emit(PyObject* obj, Out& out, int depth);
+
+// The entries of ``obj`` (the header is written), each key followed by
+// its value, then in order.  Returns as canon_emit.
+int canon_emit_entries(PyObject* obj, Out& out, int depth, Rec* recs,
+                       size_t n) {
+  const size_t start = out.n;
+  bool in_order = true;
+  size_t i = 0;
+  Py_ssize_t pos = 0;
+  PyObject *key, *val;
+  while (PyDict_Next(obj, &pos, &key, &val)) {
+    const size_t off = out.n;
+    int rc = canon_emit(key, out, depth + 1);
+    if (rc != 1) return rc;
+    const size_t klen = out.n - off;
+    rc = canon_emit(val, out, depth + 1);
+    if (rc != 1) return rc;
+    const size_t elen = out.n - off;
+    // a record holds 32-bit lengths: an entry of 4 GB is the Python path's
+    // (and a map that grew under the walk, which nothing here can cause)
+    if (elen > 0xffffffffull || i >= n) return 0;
+    Rec& r = recs[i];
+    r.pre = key_prefix(out.p + off, klen);
+    r.off = off;
+    r.klen = (uint32_t)klen;
+    r.elen = (uint32_t)elen;
+    if (in_order && i > 0 && !rec_less(recs[i - 1], r, out.p))
+      in_order = false;
+    ++i;
+  }
+  out.maps += 1;
+  if (!in_order) {
+    out.maps_sorted += 1;
+    order_entries(out, recs, i, start);
+  }
+  return 1;
+}
 
 // returns 1 ok, 0 unsupported (no exception), -1 python error (exc set)
 int canon_emit(PyObject* obj, Out& out, int depth) {
   if (depth > 200) return 0;
-  if (obj == Py_None) { out.u8(0xc0); return 1; }
-  if (obj == Py_True) { out.u8(0xc3); return 1; }
-  if (obj == Py_False) { out.u8(0xc2); return 1; }
   if (PyLong_CheckExact(obj)) {
-    int overflow = 0;
-    long long v = PyLong_AsLongLongAndOverflow(obj, &overflow);
-    if (overflow > 0) {
-      unsigned long long u = PyLong_AsUnsignedLongLong(obj);
-      if (u == (unsigned long long)-1 && PyErr_Occurred()) {
-        PyErr_Clear();
-        return 0;  // > 2^64-1: let the Python packer raise its error
+    long long v;
+    if (PyUnstable_Long_IsCompact((PyLongObject*)obj)) {
+      // one machine word, the counters and keys of a state: no call
+      v = (long long)PyUnstable_Long_CompactValue((PyLongObject*)obj);
+    } else {
+      int overflow = 0;
+      v = PyLong_AsLongLongAndOverflow(obj, &overflow);
+      if (overflow > 0) {
+        unsigned long long u = PyLong_AsUnsignedLongLong(obj);
+        if (u == (unsigned long long)-1 && PyErr_Occurred()) {
+          PyErr_Clear();
+          return 0;  // > 2^64-1: let the Python packer raise its error
+        }
+        out.tag64(0xcf, u);
+        return 1;
       }
-      out.u8(0xcf);
-      out.be64(u);
-      return 1;
+      if (overflow < 0) return 0;  // < -2^63
+      if (v == -1 && PyErr_Occurred()) return -1;
     }
-    if (overflow < 0) return 0;  // < -2^63
-    if (v == -1 && PyErr_Occurred()) return -1;
     if (v >= 0) {
       unsigned long long u = (unsigned long long)v;
       if (u < 0x80) out.u8((uint8_t)u);
-      else if (u <= 0xff) { out.u8(0xcc); out.u8((uint8_t)u); }
-      else if (u <= 0xffff) { out.u8(0xcd); out.be16((uint16_t)u); }
-      else if (u <= 0xffffffffull) { out.u8(0xce); out.be32((uint32_t)u); }
-      else { out.u8(0xcf); out.be64(u); }
+      else if (u <= 0xff) out.tag8(0xcc, (uint8_t)u);
+      else if (u <= 0xffff) out.tag16(0xcd, (uint16_t)u);
+      else if (u <= 0xffffffffull) out.tag32(0xce, (uint32_t)u);
+      else out.tag64(0xcf, u);
     } else {
       if (v >= -32) out.u8((uint8_t)(int8_t)v);
-      else if (v >= -128) { out.u8(0xd0); out.u8((uint8_t)(int8_t)v); }
-      else if (v >= -32768) { out.u8(0xd1); out.be16((uint16_t)(int16_t)v); }
-      else if (v >= -2147483648ll) {
-        out.u8(0xd2);
-        out.be32((uint32_t)(int32_t)v);
-      } else {
-        out.u8(0xd3);
-        out.be64((uint64_t)v);
-      }
+      else if (v >= -128) out.tag8(0xd0, (uint8_t)(int8_t)v);
+      else if (v >= -32768) out.tag16(0xd1, (uint16_t)(int16_t)v);
+      else if (v >= -2147483648ll) out.tag32(0xd2, (uint32_t)(int32_t)v);
+      else out.tag64(0xd3, (uint64_t)v);
     }
     return 1;
   }
   if (PyBytes_CheckExact(obj)) {
     const size_t n = (size_t)PyBytes_GET_SIZE(obj);
-    if (n <= 0xff) { out.u8(0xc4); out.u8((uint8_t)n); }
-    else if (n <= 0xffff) { out.u8(0xc5); out.be16((uint16_t)n); }
-    else if (n <= 0xffffffffull) { out.u8(0xc6); out.be32((uint32_t)n); }
+    if (n <= 0xff) out.tag8(0xc4, (uint8_t)n);
+    else if (n <= 0xffff) out.tag16(0xc5, (uint16_t)n);
+    else if (n <= 0xffffffffull) out.tag32(0xc6, (uint32_t)n);
     else return 0;
     out.raw(PyBytes_AS_STRING(obj), n);
     return 1;
   }
-  if (PyUnicode_CheckExact(obj)) {
-    Py_ssize_t n;
-    const char* s = PyUnicode_AsUTF8AndSize(obj, &n);
-    if (s == nullptr) return -1;
-    if (n < 32) out.u8(0xa0 | (uint8_t)n);
-    else if (n <= 0xff) { out.u8(0xd9); out.u8((uint8_t)n); }
-    else if (n <= 0xffff) { out.u8(0xda); out.be16((uint16_t)n); }
-    else if ((unsigned long long)n <= 0xffffffffull) {
-      out.u8(0xdb);
-      out.be32((uint32_t)n);
-    } else return 0;
-    out.raw(s, (size_t)n);
-    return 1;
-  }
-  if (PyFloat_CheckExact(obj)) {
-    double d = PyFloat_AS_DOUBLE(obj);
-    uint64_t bits;
-    memcpy(&bits, &d, 8);
-    out.u8(0xcb);
-    out.be64(bits);
-    return 1;
+  if (PyDict_CheckExact(obj)) {
+    const Py_ssize_t n = PyDict_GET_SIZE(obj);
+    if (n < 16) out.u8(0x80 | (uint8_t)n);
+    else if (n <= 0xffff) out.tag16(0xde, (uint16_t)n);
+    else if ((unsigned long long)n <= 0xffffffffull)
+      out.tag32(0xdf, (uint32_t)n);
+    else return 0;
+    if (n <= STACK_RECS) {
+      Rec recs[STACK_RECS];
+      return canon_emit_entries(obj, out, depth, recs, (size_t)n);
+    }
+    const std::unique_ptr<Rec[]> recs(new Rec[(size_t)n]);
+    return canon_emit_entries(obj, out, depth, recs.get(), (size_t)n);
   }
   if (PyList_CheckExact(obj) || PyTuple_CheckExact(obj)) {
     const int is_list = PyList_CheckExact(obj);
     const Py_ssize_t n =
         is_list ? PyList_GET_SIZE(obj) : PyTuple_GET_SIZE(obj);
     if (n < 16) out.u8(0x90 | (uint8_t)n);
-    else if (n <= 0xffff) { out.u8(0xdc); out.be16((uint16_t)n); }
-    else if ((unsigned long long)n <= 0xffffffffull) {
-      out.u8(0xdd);
-      out.be32((uint32_t)n);
-    } else return 0;
+    else if (n <= 0xffff) out.tag16(0xdc, (uint16_t)n);
+    else if ((unsigned long long)n <= 0xffffffffull)
+      out.tag32(0xdd, (uint32_t)n);
+    else return 0;
     for (Py_ssize_t i = 0; i < n; ++i) {
       PyObject* it =
           is_list ? PyList_GET_ITEM(obj, i) : PyTuple_GET_ITEM(obj, i);
@@ -504,37 +696,27 @@ int canon_emit(PyObject* obj, Out& out, int depth) {
     }
     return 1;
   }
-  if (PyDict_CheckExact(obj)) {
-    const Py_ssize_t n = PyDict_GET_SIZE(obj);
-    if (n < 16) out.u8(0x80 | (uint8_t)n);
-    else if (n <= 0xffff) { out.u8(0xde); out.be16((uint16_t)n); }
-    else if ((unsigned long long)n <= 0xffffffffull) {
-      out.u8(0xdf);
-      out.be32((uint32_t)n);
-    } else return 0;
-    // pack (key bytes, value bytes) pairs, sort by key bytes — the
-    // canonical-map ordering codec.pack defines
-    struct Pair {
-      std::vector<uint8_t> k, v;
-    };
-    std::vector<Pair> pairs;
-    pairs.reserve((size_t)n);
-    Py_ssize_t pos = 0;
-    PyObject *key, *val;
-    while (PyDict_Next(obj, &pos, &key, &val)) {
-      Out ko, vo;
-      int rc = canon_emit(key, ko, depth + 1);
-      if (rc != 1) return rc;
-      rc = canon_emit(val, vo, depth + 1);
-      if (rc != 1) return rc;
-      pairs.push_back(Pair{std::move(ko.b), std::move(vo.b)});
-    }
-    std::sort(pairs.begin(), pairs.end(),
-              [](const Pair& a, const Pair& b) { return a.k < b.k; });
-    for (const Pair& p : pairs) {
-      out.raw(p.k.data(), p.k.size());
-      out.raw(p.v.data(), p.v.size());
-    }
+  if (PyUnicode_CheckExact(obj)) {
+    Py_ssize_t n;
+    const char* s = PyUnicode_AsUTF8AndSize(obj, &n);
+    if (s == nullptr) return -1;
+    if (n < 32) out.u8(0xa0 | (uint8_t)n);
+    else if (n <= 0xff) out.tag8(0xd9, (uint8_t)n);
+    else if (n <= 0xffff) out.tag16(0xda, (uint16_t)n);
+    else if ((unsigned long long)n <= 0xffffffffull)
+      out.tag32(0xdb, (uint32_t)n);
+    else return 0;
+    out.raw(s, (size_t)n);
+    return 1;
+  }
+  if (obj == Py_None) { out.u8(0xc0); return 1; }
+  if (obj == Py_True) { out.u8(0xc3); return 1; }
+  if (obj == Py_False) { out.u8(0xc2); return 1; }
+  if (PyFloat_CheckExact(obj)) {
+    double d = PyFloat_AS_DOUBLE(obj);
+    uint64_t bits;
+    memcpy(&bits, &d, 8);
+    out.tag64(0xcb, bits);
     return 1;
   }
   return 0;  // sets, numpy scalars, custom types → Python fallback
@@ -695,23 +877,41 @@ PyObject* canon_same(PyObject* a, PyObject* b) {
 }
 
 // Canonical-pack ``obj``; returns a bytes object, Py_None when the
-// object graph contains a type this packer does not handle (caller
-// falls back to the Python path), or NULL on a Python error.
+// object graph contains a type this packer does not handle, wherever in
+// the graph it is met (the caller falls back to the Python path), or
+// NULL on a Python error.
 PyObject* canon_pack(PyObject* obj) {
-  // bad_alloc from buffer growth must not unwind into ctypes — surface
-  // it as a Python MemoryError instead (same convention as the fold and
-  // decode entry points)
+  g_canon_packs += 1;
+  // bad_alloc from the records or the scratch copy must not unwind into
+  // ctypes — surface it as a Python MemoryError instead (same convention
+  // as the fold and decode entry points); the buffer's own growth fails
+  // with that error already set
   try {
     Out out;
-    out.b.reserve(256);
-    int rc = canon_emit(obj, out, 0);
+    const int rc = canon_emit(obj, out, 0);
+    g_canon_maps += out.maps;
+    g_canon_maps_sorted += out.maps_sorted;
     if (rc < 0) return nullptr;
-    if (rc == 0) Py_RETURN_NONE;
-    return PyBytes_FromStringAndSize((const char*)out.b.data(),
-                                     (Py_ssize_t)out.b.size());
+    if (rc == 0) {
+      g_canon_declined += 1;
+      Py_RETURN_NONE;
+    }
+    return out.finish();
+  } catch (const PyErrSet&) {
+    return nullptr;
   } catch (const std::bad_alloc&) {
     return PyErr_NoMemory();
   }
+}
+
+// The four process totals of canon_pack as a tuple, in the order
+// ``canon_packs``, ``canon_maps``, ``canon_maps_sorted``,
+// ``canon_declined``; NULL on a Python error.
+PyObject* canon_counters() {
+  return Py_BuildValue("(KKKK)", (unsigned long long)g_canon_packs,
+                       (unsigned long long)g_canon_maps,
+                       (unsigned long long)g_canon_maps_sorted,
+                       (unsigned long long)g_canon_declined);
 }
 
 }  // extern "C" (canon_pack; the outer linkage block continues below)

@@ -9,6 +9,7 @@ normalized before packing.  msgpack's C extension does the heavy lifting.
 from __future__ import annotations
 
 import logging
+import threading
 
 import msgpack
 
@@ -21,7 +22,7 @@ _native_same = None  # likewise
 def _native_fn(name: str, instead: str):
     """A function of the native state library, or False.  Disabling a
     fast path must be VISIBLE (EXC001): a binding regression would
-    otherwise silently put ~400ms back on every canonical_bytes call.
+    otherwise silently put the Python walk back on every pack.
     Logged once a function — the resolution is cached for the process,
     so the fallback decision happens exactly once too."""
     try:
@@ -36,24 +37,79 @@ def _native_fn(name: str, instead: str):
 def pack(obj) -> bytes:
     """Deterministic msgpack: sorted map keys, bin type for bytes.
 
-    Hot path (sealing a compacted state, canonical_bytes in every
-    equality check): the native canonical packer (statebuild.cpp
-    ``canon_pack``) emits the identical bytes in one C pass — the
-    Python ``_canon`` walk + ``packb`` cost ~400ms on a 100k-replica
-    state.  Objects with types the native packer doesn't know (sets,
-    numpy scalars, custom classes) fall through to the Python path, as
-    does an environment without the native build."""
+    The one serialiser of states, ops, delta links, checkpoint payloads,
+    cursors and sort keys; a seal packs the whole state through it once
+    a round.  The native canonical packer (statebuild.cpp
+    ``canon_pack``) emits the bytes into one growing buffer and puts a
+    map in order by sorting an index over that buffer (a record an
+    entry, compared by the key's first eight packed bytes); a map whose
+    keys arrive in packed order, as every map of a state opened from a
+    snapshot does, is neither sorted nor copied.  Objects with types the
+    native packer doesn't know (sets, numpy scalars, custom classes)
+    fall through to the Python path (``_canon`` + ``packb``, the
+    reference: the same bytes, several times dearer), as does an
+    environment without the native build.  How it engaged is counted:
+    ``canon_packs``, ``canon_maps``, ``canon_maps_sorted`` and
+    ``canon_declined`` (docs/observability.md), kept by the library and
+    folded into the registry wherever it is read."""
     global _native_pack
     if _native_pack is None:
         _native_pack = _native_fn(
             "canon_pack",
             "using the Python canonicalization path for all packs",
         )
+        if _native_pack:
+            _register_canon_counters()
     if _native_pack:
         out = _native_pack(obj)
         if out is not None:
             return out
     return msgpack.packb(_canon(obj), use_bin_type=True)
+
+
+#: the native packer's process totals, in statebuild.cpp
+#: ``canon_counters``'s order: calls, maps emitted, maps whose keys arrived
+#: out of packed order (sorted and permuted), calls that declined (the
+#: Python path ran)
+CANON_COUNTERS = (
+    "canon_packs", "canon_maps", "canon_maps_sorted", "canon_declined"
+)
+
+
+_canon_totals = None  # statebuild.cpp ``canon_counters``, once resolved
+_canon_folded = [0] * len(CANON_COUNTERS)  # what the registry was handed
+_canon_fold_lock = threading.Lock()  # between readers
+
+
+def _register_canon_counters() -> None:
+    """Have the registry fold the packer's totals in wherever it is read
+    (``record.on_read``, as ``obs.runtime``'s collector totals are): a
+    pack bumps four plain integers under the interpreter lock it already
+    holds and makes no ``trace.add``."""
+    global _canon_totals
+    from . import trace
+
+    _canon_totals = _native_fn("canon_counters", "the packer goes uncounted")
+    if _canon_totals:
+        trace.on_read(_fold_canon_counters)  # once, however many callers
+
+
+def _fold_canon_counters() -> None:
+    """The growth since the last fold.  All four are published once any
+    has grown, so a healthy process reads ``canon_declined`` 0 rather
+    than nothing."""
+    from . import trace
+
+    with _canon_fold_lock:
+        now = _canon_totals()
+        if now == tuple(_canon_folded):
+            return
+        grown = {
+            name: now[i] - _canon_folded[i]
+            for i, name in enumerate(CANON_COUNTERS)
+        }
+        _canon_folded[:] = now
+    trace.fold(grown)
 
 
 def canon_same(a, b) -> bool | None:
