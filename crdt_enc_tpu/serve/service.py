@@ -776,7 +776,11 @@ class FoldService:
                     member, actor = remapped
                     members, replicas = entry.members, entry.replicas
             if entry is None:
-                K.orset_scan_vocab(state, members, replicas)
+                # no planes for this tenant (never folded here, evicted,
+                # or mutated since): the first of the rebuild's two host
+                # walks, the second is the rows' in _fold_orset_bucket
+                with trace.span("serve.rebuild", meta=w.idx):
+                    K.orset_scan_vocab(state, members, replicas)
             shape = TenantShape(
                 w.idx, "orset", len(kind), len(members), len(replicas)
             )
@@ -820,6 +824,7 @@ class FoldService:
         # from planes already in hand — no host dict walk, no retained
         # base bytes (docs/delta.md "device-cut deltas")
         cut_slots: list[tuple[int, object]] = []
+        cold_slots: list[int] = []  # no warm planes: rows built below
         tenant_cells = 0
         for slot, key in enumerate(bucket.tenants):
             w = by_idx[key]
@@ -844,16 +849,34 @@ class FoldService:
                     entry, E_b, R_b
                 )
             else:
-                clock0, add0, rm0 = K.orset_state_to_planes(
-                    w.core._data.state, members, replicas, scanned=True
-                )
-                pads = ((0, E_b - E), (0, R_b - R))
-                add0 = np.pad(add0, pads)
-                rm0 = np.pad(rm0, pads)
-                clock0 = np.pad(clock0, (0, R_b - R))
+                cold_slots.append(slot)
+                clock0 = add0 = rm0 = None
             clock_rows.append(clock0)
             add_rows.append(add0)
             rm_rows.append(rm0)
+        if cold_slots:
+            # the tenants the warm tier holds nothing for: their planes
+            # are rebuilt from the host state, a dict walk a tenant, and
+            # uploaded whole with the stacks below
+            rebuilt = 0
+            with trace.span("serve.rebuild", meta=shape):
+                for slot in cold_slots:
+                    w = by_idx[bucket.tenants[slot]]
+                    members, replicas = w.prepared[4], w.prepared[5]
+                    E, R = len(members), len(replicas)
+                    clock0, add0, rm0 = K.orset_state_to_planes(
+                        w.core._data.state, members, replicas, scanned=True
+                    )
+                    pads = ((0, E_b - E), (0, R_b - R))
+                    clock_rows[slot] = np.pad(clock0, (0, R_b - R))
+                    add_rows[slot] = np.pad(add0, pads)
+                    rm_rows[slot] = np.pad(rm0, pads)
+                    rebuilt += (
+                        clock_rows[slot].nbytes + add_rows[slot].nbytes
+                        + rm_rows[slot].nbytes
+                    )
+            trace.add("serve_warm_rebuilds", len(cold_slots))
+            trace.add("serve_warm_rebuild_bytes", rebuilt)
         # the padding share: what the stacks hold against what is a tenant's
         trace.add("serve_stack_cells", T * E_b * R_b)
         trace.add("serve_tenant_cells", tenant_cells)

@@ -17,9 +17,9 @@ Budget and visibility: ``byte_budget`` bounds the summed plane bytes;
 inserting past it evicts least-recently-used entries first (the newest
 entry itself is never evicted at insert — a single over-budget tenant
 still gets exactly one cycle of reuse and then ages out normally).
-``serve_warm_hits`` / ``serve_warm_misses`` / ``serve_warm_evictions``
-counters and the ``serve_warm_bytes`` gauge (docs/observability.md) make
-the tier's behavior auditable per cycle.
+``serve_warm_hits`` / ``serve_warm_misses`` / ``serve_warm_evictions`` /
+``serve_warm_evicted_bytes`` counters and the ``serve_warm_bytes`` gauge
+(docs/observability.md) make the tier's behavior auditable per cycle.
 
 Entries expose the same ``members / replicas / canon / planes``
 attributes as the accelerator's plane cache, so the service reuses the
@@ -93,11 +93,15 @@ class PlaneWarmTier:
     def bytes_held(self) -> int:
         return self._bytes
 
-    def _drop(self, key: int) -> None:
+    def _drop(self, key: int) -> int:
+        """Forget ``key``'s entry (its plane buffers go with their last
+        reference); returns the plane bytes it held, 0 for no entry."""
         entry = self._entries.pop(key, None)
-        if entry is not None:
-            self._bytes -= entry.nbytes
-            trace.gauge("serve_warm_bytes", self._bytes)
+        if entry is None:
+            return 0
+        self._bytes -= entry.nbytes
+        trace.gauge("serve_warm_bytes", self._bytes)
+        return entry.nbytes
 
     def lookup(self, state) -> WarmEntry | None:
         """The live entry for ``state``, or None (no entry, entry for a
@@ -152,7 +156,7 @@ class PlaneWarmTier:
             oldest = next(iter(self._entries))
             if oldest == key:
                 break  # never evict the entry being inserted
-            self._drop(oldest)
+            trace.add("serve_warm_evicted_bytes", self._drop(oldest))
             trace.add("serve_warm_evictions", 1)
         trace.gauge("serve_warm_bytes", self._bytes)
         return entry

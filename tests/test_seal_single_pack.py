@@ -283,9 +283,12 @@ LWW_TAIL = [
 
 
 def _tail_spans() -> list:
+    # a collector pass that lands in the tail is a ``pause`` entry with an id
+    # of its own (``runtime.gc``), and no span of the seal
     names = [
         e["name"] for e in sorted(
-            (e for e in trace.events() if "id" in e), key=lambda e: e["id"]
+            (e for e in trace.events() if "id" in e and e.get("kind") != "pause"),
+            key=lambda e: e["id"],
         )
     ]
     return names[names.index("seal.state_obj"):names.index("repl.status")]
